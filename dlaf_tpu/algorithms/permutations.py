@@ -26,7 +26,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from .._compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..comm.grid import COL_AXIS, ROW_AXIS

@@ -28,7 +28,8 @@ import re
 from typing import Callable, Iterator, Sequence, Tuple, Union
 
 import jax
-from jax import core as jax_core
+from jax import core as jax_core_legacy  # DropVar / Tracer still live here
+from jax.extend import core as jax_core
 
 #: Cross-device collective primitives, as spelled in this jax line's
 #: jaxprs (``lax.psum`` -> ``psum``; ``bcast``'s mask+psum realization is
@@ -343,7 +344,7 @@ def scan_carry_slots(scan_eqn) -> list:
         slots.append(CarrySlot(
             index=i, read=read,
             passthrough=getattr(ov, "val", ov) is iv,
-            out_dropped=isinstance(scan_eqn.outvars[i], jax_core.DropVar)))
+            out_dropped=isinstance(scan_eqn.outvars[i], jax_core_legacy.DropVar)))
     return slots
 
 
@@ -353,7 +354,7 @@ def dropped_outputs(scan_eqn) -> list:
     and throws away."""
     num_carry = scan_eqn.params["num_carry"]
     return [i for i, v in enumerate(scan_eqn.outvars[num_carry:])
-            if isinstance(v, jax_core.DropVar)]
+            if isinstance(v, jax_core_legacy.DropVar)]
 
 
 # ---------------------------------------------------------------------------

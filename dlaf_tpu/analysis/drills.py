@@ -46,14 +46,12 @@ def _rank_varying_collective() -> List[Finding]:
     from jax import lax
     from jax.sharding import PartitionSpec as P
 
-    from dlaf_tpu import _compat
-
     def body(x):
         return lax.cond(lax.axis_index("row") == 0,
                         lambda v: lax.psum(v, "col"),
                         lambda v: v, x)
 
-    fn = _compat.shard_map(body, mesh=_mesh22(), in_specs=P("row", "col"),
+    fn = jax.shard_map(body, mesh=_mesh22(), in_specs=P("row", "col"),
                            out_specs=P("row", "col"), check_vma=False)
     sds = jax.ShapeDtypeStruct((8, 8), jnp.float64)
     return graphcheck.audit_jaxpr("drill.rank_varying_collective",
@@ -76,12 +74,35 @@ def _host_callback() -> List[Finding]:
                                   depgraph.trace(fn, sds))
 
 
+def scan_keeping_passthrough(body, init, length: int):
+    """``lax.scan(body, init, None, length)`` bound BENEATH ``lax.scan``'s
+    own clean-up: the installed JAX forwards a carry its body passes
+    through unchanged out of the scan at trace time, so a dead carry
+    never reaches a jaxpr traced from ``lax.scan`` itself. A scan eqn
+    built by a transformation (or by hand, as here) can still hold one,
+    which is the program graph-dead-carry exists to catch."""
+    import jax
+    from jax import lax
+
+    flat, tree = jax.tree_util.tree_flatten(init)
+
+    def flat_body(*carry):
+        new, y = body(jax.tree_util.tree_unflatten(tree, carry), None)
+        return (*jax.tree_util.tree_leaves(new), y)
+
+    outs = lax.scan_p.bind(
+        *flat, jaxpr=jax.make_jaxpr(flat_body)(*flat), length=length,
+        reverse=False, linear=(False,) * len(flat), unroll=1, num_consts=0,
+        num_carry=len(flat), _split_transpose=False)
+    return (jax.tree_util.tree_unflatten(tree, outs[:len(flat)]),
+            outs[len(flat)])
+
+
 def _dropped_carry() -> List[Finding]:
     """A scan carrying a slot its body never reads (and stacking an
     output nobody consumes): the dropped-carry refactor residue."""
     import jax
     import jax.numpy as jnp
-    from jax import lax
 
     def fn(x):
         def body(carry, _):
@@ -89,7 +110,7 @@ def _dropped_carry() -> List[Finding]:
             a = a * 1.5
             return (a, dropped), a.sum()
 
-        (a, _), _ys = lax.scan(body, (x, x + 1.0), None, length=4)
+        (a, _), _ys = scan_keeping_passthrough(body, (x, x + 1.0), 4)
         return a
 
     sds = jax.ShapeDtypeStruct((8, 8), jnp.float64)

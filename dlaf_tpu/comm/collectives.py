@@ -21,7 +21,6 @@ import jax.numpy as jnp
 from jax import lax
 
 from .grid import COL_AXIS, ROW_AXIS  # re-export for convenience  # noqa: F401
-from .. import _compat
 from .. import obs
 
 
@@ -63,7 +62,7 @@ def this_rank(axis: str):
 
 def axis_size(axis: str) -> int:
     """Number of ranks along ``axis`` (reference ``Communicator::size``)."""
-    return _compat.axis_size(axis)
+    return lax.axis_size(axis)
 
 
 def bcast(x, axis: str, src: int):

@@ -78,5 +78,5 @@ def reduce(values, root: int = 0, op: str = "sum"):
 
 #: Blocking completion fence (reference ``MPI_Barrier`` in the miniapp
 #: timing protocol, ``miniapp_cholesky.cpp:134-146``); see
-#: :func:`dlaf_tpu.common.sync.hard_fence` for the tunnel-proof design.
+#: :func:`dlaf_tpu.common.sync.hard_fence` for what the fence does.
 barrier = hard_fence

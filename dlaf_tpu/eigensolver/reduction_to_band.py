@@ -38,7 +38,7 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-from .._compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..tile_ops.qr_panel import panel_qr  # geqrf-convention; route per config

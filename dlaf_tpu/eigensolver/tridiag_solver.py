@@ -58,7 +58,7 @@ def _device_secular_min_k() -> int:
     if auto:
         import jax
 
-        # measured round 4 (BASELINE.md): the CPU backend's device route
+        # measured 2026-08: the CPU backend's device route
         # loses to the native host solver at every size, so auto disables
         # it there; on TPU the device side is MXU-backed batched math
         s = 4096 if jax.default_backend() == "tpu" else (1 << 62)

@@ -261,7 +261,7 @@ def _shard_scalar(fn, mesh, n_in: int, extra_specs=()):
     the mesh (the norm.py idiom); callers read ``[0, 0]``."""
     import jax
 
-    from .._compat import shard_map
+    from jax import shard_map
     from ..comm.grid import COL_AXIS, ROW_AXIS
     from jax.sharding import PartitionSpec as P
 

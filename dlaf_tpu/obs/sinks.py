@@ -159,7 +159,7 @@ not scrape as a number). The append-only bench history
 (``.bench_history.jsonl``) has its own line schema, also owned here
 (:func:`validate_history_records`, :func:`append_history_line` — the
 validator CLI's ``--history`` mode): a malformed or non-finite history
-line must fail loudly, not silently skew the replayed-history headline.
+line must fail loudly, not silently skew a gate baseline.
 """
 
 from __future__ import annotations
@@ -1200,10 +1200,9 @@ def validate_file(path: str, **require) -> list:
 # History line schemas (.bench_history.jsonl / .accuracy_history.jsonl)
 # ---------------------------------------------------------------------------
 # Bare measurement lines (no v/type/ts envelope — the bench file predates
-# the obs schema and BASELINE.md cites it verbatim), but schema-owned
-# HERE — ONE validating reader parameterized by ``kind`` — so bench.py's
-# replayed-history headline lookup, scripts/bench_gate.py, and
-# scripts/accuracy_gate.py all read through the same code path and never
+# the obs schema), but schema-owned HERE — ONE validating reader
+# parameterized by ``kind`` — so scripts/bench_gate.py and
+# scripts/accuracy_gate.py read through the same code path and never
 # silently ingest a malformed or non-finite entry (ISSUE 8 satellite: no
 # second bespoke history parser).
 
@@ -1247,8 +1246,8 @@ def validate_history_records(records, kind: str = "bench") -> list:
 def read_history_records(path: str, kind: str = "bench") -> list:
     """Parse + validate an append-only measurement history; raises
     ValueError on an unparsable or schema-invalid line (loud by contract:
-    a bad line would otherwise skew every replayed-history headline and
-    every gate baseline derived from the file)."""
+    a bad line would otherwise skew every gate baseline derived from the
+    file)."""
     records = read_records(path)
     errors = validate_history_records(records, kind)
     if errors:

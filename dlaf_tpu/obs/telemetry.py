@@ -8,8 +8,8 @@ cached-program sites route through. Three signals, per ``site`` label:
 * ``dlaf_compile_seconds{site}`` — histogram of XLA compile wall per
   compiled program (trace wall recorded separately on the ``program``
   record). Today these numbers are buried in one-off probe scripts
-  (``scripts/tpu_mem_probe.py`` / ``scripts/compile_scaling.py``); the
-  library now owns the plumbing and the scripts call it.
+  (``scripts/compile_scaling.py``); the library now owns the plumbing
+  and the script calls it.
 * ``dlaf_retrace_total{site}`` — counter of traces (first trace = 1; a
   higher count is a retrace). This finally makes the documented
   "trace-time comm counters add again on retrace" caveat *detectable*:
@@ -34,8 +34,8 @@ Two call styles:
   jitted callable + input avals/shardings + static kwargs; invalidated
   with the config program caches).
 * :func:`aot_compile` — the explicit probe API: always measures,
-  records only when the knob is on. ``scripts/tpu_mem_probe.py`` and
-  ``scripts/compile_scaling.py`` are thin CLIs over this.
+  records only when the knob is on. ``scripts/compile_scaling.py`` is a
+  thin CLI over this.
 
 Builders whose traced bodies the library re-enters per group (e.g. the
 level-batched D&C secular dispatch) instead call :func:`count_retrace`

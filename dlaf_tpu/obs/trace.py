@@ -205,45 +205,17 @@ def start_profiler(path: str) -> bool:
     that device-time attribution (ISSUE 14, :mod:`dlaf_tpu.obs.
     devtrace`) exists to read. Host TraceMe events (our
     ``TraceAnnotation`` span mirrors) and the device op events are host-
-    tracer products and survive. jax 0.4.x's public ``start_trace``
-    exposes no options, so the option is injected by wrapping the
-    ``ProfilerSession`` constructor the public call builds its session
-    with — ``start_trace`` itself still runs (its single-trace lock,
-    its backend-before-tracer ordering, and the tests' mock seam all
-    stay jax's), and any layout mismatch degrades to an unwrapped call
-    (a flooded-but-working trace beats no trace)."""
+    tracer products and survive."""
     if STATE.profiler_started:
         return False
-    import contextlib
-
     import jax
 
-    @contextlib.contextmanager
-    def _quiet_python_tracer():
-        try:
-            from jax._src.lib import xla_client
-
-            prof_mod = xla_client.profiler
-            opts = prof_mod.ProfileOptions()
-            opts.python_tracer_level = 0
-            orig = prof_mod.ProfilerSession
-
-            def session(*a, **k):
-                return orig(opts) if not (a or k) else orig(*a, **k)
-
-            prof_mod.ProfilerSession = session
-        except Exception:
-            yield
-            return
-        try:
-            yield
-        finally:
-            prof_mod.ProfilerSession = orig
-
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
     # perfetto trace alongside the xplane: a gzipped JSON this container
     # can post-process WITHOUT tensorboard (scripts/profile_summary.py)
-    with _quiet_python_tracer():
-        jax.profiler.start_trace(path, create_perfetto_trace=True)
+    jax.profiler.start_trace(path, create_perfetto_trace=True,
+                             profiler_options=opts)
     STATE.profiler_started = True
     return True
 

@@ -13,8 +13,8 @@ surfaces:
   ``warmup(spec, ...)`` pre-compiles a bucket set, ``pin``/``evict``
   manage residency under the ``DLAF_SERVE_CACHE_BYTES`` LRU budget,
   hit/miss/evict/compile metrics per bucket, persistent-compile-cache
-  integration (``DLAF_COMPILATION_CACHE_DIR``) so a restarted server
-  warms from disk.
+  integration (``JAX_COMPILATION_CACHE_DIR``, else
+  ``<checkout>/.jax_cache``) so a restarted server warms from disk.
 * **Request queue** (:mod:`.queue`): buckets incoming (shape, dtype)
   requests to the nearest ceiling, pads, dispatches the cached program
   when a batch fills or the deadline expires, unpads — each request

@@ -7,8 +7,8 @@ donated, vmapped program per :class:`ProgramSpec` bucket key
 donate)`` and serves it warm —
 
 * :meth:`ProgramService.warmup` pre-compiles a bucket set (the server
-  bring-up step; with ``DLAF_COMPILATION_CACHE_DIR`` set, compiles land
-  in jax's persistent compile cache so a RESTARTED server warms from
+  bring-up step; compiles land in jax's persistent compile cache —
+  ``JAX_COMPILATION_CACHE_DIR``, else ``<checkout>/.jax_cache`` — so a RESTARTED server warms from
   disk instead of from XLA);
 * :meth:`ProgramService.pin` / :meth:`ProgramService.evict` manage
   residency under the ``DLAF_SERVE_CACHE_BYTES`` LRU byte budget

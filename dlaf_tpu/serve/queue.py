@@ -815,7 +815,7 @@ class Queue:
         t_compose = self.clock()
         # dispatch + compile run under the shared policy engine behind
         # the bucket's circuit breaker: a transient failure (e.g. an
-        # inject.fail_dispatch drill, a flaky tunnel) retries before any
+        # inject.fail_dispatch drill, a flaky device link) retries before any
         # ticket is poisoned; consecutive attempt failures open the
         # breaker and later dispatches fail fast (CircuitOpenError)
         breaker = _circuit.breaker(spec.site, clock=self.clock)

@@ -103,8 +103,7 @@ def _chol_inv_seed_recursive(a, base: int):
 
     — so the loop-based XLA triangular solves disappear above the leaves
     and the sequential latency is leaf chols + MXU gemms (config
-    ``mixed_seed="recursive"``; the latency attack docs/ROADMAP.md item 4
-    proposes)."""
+    ``mixed_seed="recursive"``; a latency attack on the panel chain)."""
     n = a.shape[-1]
     if n <= base:
         l = lax.linalg.cholesky(a)
@@ -123,6 +122,31 @@ def _chol_inv_seed_recursive(a, base: int):
     linv = jnp.concatenate([jnp.concatenate([i11, ztop], axis=1),
                             jnp.concatenate([i21, i22], axis=1)], axis=0)
     return l, linv
+
+
+def _chol_lower_native(a):
+    """Lower Cholesky factor of the Hermitian block ``a`` in its own
+    (f64/c128) precision as a plain left-looking column loop — the
+    slow-but-sure branch behind the ``lax.cond`` guards below.
+
+    Not ``lax.linalg.cholesky``: the TPU compiler refuses XLA's f64
+    cholesky expansion in a program partitioned over several devices
+    ("A tuple parameter that is being flattened shouldn't have frontend
+    attributes", v5e 2x2, jax 0.9.0), and this branch sits inside every
+    distributed f64 step. One ``fori_loop`` iteration per column: an
+    (n, n) matvec in emulated f64, executed only when the guard fails.
+    A non-positive pivot gives ``sqrt`` = NaN, which spreads to every
+    later column (the ``potrf_info`` contract)."""
+    n = a.shape[-1]
+    idx = jnp.arange(n)
+
+    def column(j, l):
+        done = jnp.where(idx < j, l[j, :], jnp.zeros_like(l[j, :]))
+        c = a[:, j] - l @ jnp.conj(done)
+        d = jnp.sqrt(jnp.real(c[j])).astype(a.dtype)
+        return l.at[:, j].set(jnp.where(idx >= j, c / d, jnp.zeros_like(c)))
+
+    return lax.fori_loop(0, n, column, jnp.zeros_like(a))
 
 
 def _refined_seed(a):
@@ -154,7 +178,7 @@ def _potrf_refined_l(a):
     refined, _, l32 = _refined_seed(a)
 
     def native(_):
-        return jnp.tril(lax.linalg.cholesky(a))
+        return _chol_lower_native(a)
 
     ok = (jnp.all(jnp.isfinite(refined))
           & (_diag_ratio_sq(l32) <= cond_limit()))
@@ -193,7 +217,7 @@ def _potrf_inv_refined_l(a):
     x = linv0 + linv0 @ (eye - l @ linv0)
 
     def native(_):
-        ln = jnp.tril(lax.linalg.cholesky(a))
+        ln = _chol_lower_native(a)
         return ln, lax.linalg.triangular_solve(ln, eye, left_side=True,
                                                lower=True)
 

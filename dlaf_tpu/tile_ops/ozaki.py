@@ -82,8 +82,7 @@ def _peel_slices(xn, s: int):
     with every ``|I_t| <= 2^(q-1)`` (round-to-nearest residual peeling).
 
     Two hardening rules, both REQUIRED on TPU's 2xf32 f64 emulation
-    (root-caused on the v5e 2026-08-02, ``scripts/tpu_ozaki_peel_probe.py``
-    + ``tpu_peel_dump.py`` — the source of red2band's 2e-5 eigenvalue
+    (root-caused on the v5e 2026-08-02 — the source of red2band's 2e-5 eigenvalue
     residual and the dominant term of cholesky's 6.1e-9):
 
     * The integer is extracted by a NATIVE f32 round — ``r*sc`` is cast
@@ -145,8 +144,7 @@ def _slice_dot_impl() -> str:
         get_configuration().ozaki_dot, knob="ozaki_dot",
         tpu_choice="bf16", other_choice="int8",
         detail="routes bit-identical ON DEVICE and at performance parity "
-               "at the pipeline level — dot_ab, 2026-08-01 v5e session, "
-               "BASELINE.md round 4")
+               "at the pipeline level — dot_ab, one v5e chip, 2026-08-01")
 
 
 def _group_impl() -> str:

@@ -7,8 +7,8 @@ default off-TPU (LAPACK — f64-grade) and this module's ``householder_qr``
 on TPU.
 
 History: built while chasing the session-4d red2band ~1e-5 TPU check
-failures, as the prime-suspect replacement for geqrf. The silicon probes
-(``scripts/tpu_geqrf_probe.py``) then EXONERATED geqrf — its expansion is
+failures, as the prime-suspect replacement for geqrf. The probes on the v5e
+then EXONERATED geqrf — its expansion is
 f64-grade on device (backward error ~2e-14 at every red2band panel
 shape); the real culprit was the ozaki peel's use of the emulated-f64
 ``round`` (see ``tile_ops/ozaki.py _peel_slices``). The sweep earned the
@@ -128,8 +128,7 @@ def householder_qr(a):
 def rebuild_q(vfull, taus):
     """Host-side (numpy, true f64) accumulation of the first ``k`` columns
     of ``Q = H_0 H_1 ... H_{k-1}`` from stored reflectors — the
-    verification oracle shared by the unit tests and
-    ``scripts/tpu_geqrf_probe.py``: any precision loss in ``vfull``/
+    verification oracle of the unit tests: any precision loss in ``vfull``/
     ``taus`` shows up as backward error against the input panel."""
     import numpy as np
 

@@ -6,7 +6,7 @@
 
 Compares fresh measurements against a noise-aware baseline derived from
 the git-tracked ``.bench_history.jsonl`` (121+ entries; the trajectory
-BASELINE.md cites). Per key ``(variant, platform, n, nb, workload,
+the bench artifacts cite). Per key ``(variant, platform, n, nb, workload,
 dtype)``:
 
 * **baseline** = median of the ``--best-k`` (default 3) best historical

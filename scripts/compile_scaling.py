@@ -46,15 +46,15 @@ def main():
 
         env = cpu_subprocess_env(n_virtual_devices=8)
         env["_DLAF_COMPILE_SCALING_CHILD"] = "1"
+        if args.cache:
+            # JAX's own spelling: config.initialize() then sets no directory
+            env["JAX_COMPILATION_CACHE_DIR"] = args.cache
         rc = subprocess.run([sys.executable] + sys.argv, env=env).returncode
         sys.exit(rc)
 
     import jax
 
-    jax.config.update("jax_platforms", "cpu")
     jax.config.update("jax_enable_x64", True)
-    if args.cache:
-        os.environ["DLAF_COMPILATION_CACHE_DIR"] = args.cache
 
     import numpy as np
 

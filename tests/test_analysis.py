@@ -17,7 +17,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from dlaf_tpu import _compat
 from dlaf_tpu.analysis import (Finding, depgraph, diff_baseline, drills,
                                graphcheck, lint, load_baseline,
                                write_baseline)
@@ -69,7 +68,7 @@ def test_depgraph_shard_map_body_and_collectives(devices8):
         y = lax.psum(x, "row")
         return lax.all_gather(y, "col")
 
-    fn = _compat.shard_map(body, mesh=mesh, in_specs=P("row", "col"),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P("row", "col"),
                            out_specs=P(None, None), check_vma=False)
     sds = jax.ShapeDtypeStruct((4, 4), jnp.float64)
     eqns = depgraph.shard_map_body(fn, sds)
@@ -91,7 +90,10 @@ def test_depgraph_scan_body_and_carry_slots():
             live = live * 2.0
             return (live, dead), live.sum()
 
-        (live, _dead), ys = lax.scan(body, (x, x + 1.0), None, length=3)
+        # lax.scan itself forwards the passthrough carry out of the eqn
+        # on the installed JAX; bind beneath it to keep the dead slot
+        (live, _dead), ys = drills.scan_keeping_passthrough(
+            body, (x, x + 1.0), 3)
         return live, ys
 
     jaxpr = depgraph.trace(fn, jax.ShapeDtypeStruct((4,), jnp.float64))
@@ -196,7 +198,7 @@ def test_graphcheck_hbm_denominator_is_per_shard(devices8):
         big = jnp.broadcast_to(x, (16,) + x.shape) * 2.0
         return big.sum(axis=0)
 
-    fn = _compat.shard_map(body, mesh=mesh, in_specs=P("row", "col"),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P("row", "col"),
                            out_specs=P("row", "col"), check_vma=False)
     jaxpr = depgraph.trace(fn, jax.ShapeDtypeStruct((16, 16), jnp.float64))
     fs = graphcheck.audit_jaxpr("shardtoy", jaxpr)

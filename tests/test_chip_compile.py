@@ -1,0 +1,244 @@
+"""The TPU's own compiler, without the TPU: every Pallas kernel a TPU route
+can reach, and the step programs around them, compiled for a *described*
+``v5e:2x2`` at the widths the chip runs (nb=128/256, f32/bf16, "L"/"U").
+
+Interpret mode cannot see what Mosaic and the XLA TPU pipeline refuse. In
+PR 22 that was: ``dynamic_update_slice`` inside a kernel, a select between two
+boolean vectors, i64 index-map constants under ``jax_enable_x64`` (how the
+library always runs), a compiler abort when ``masked_trailing_update`` met the
+fused panel kernels in one partitioned program, and XLA's f64 cholesky
+expansion in a program partitioned over four devices. Each has a case here.
+
+The topology is described inside a module-scoped fixture that skips when it
+cannot be (never at import: every xdist worker imports this file, and only
+one process may hold the TPU library). Everything compiles in the test's own
+process, with the persistent compilation cache off (such an entry cannot be
+read back without a chip). Nothing runs: a compile that passes is not a chip
+run — ``chip_smoke.py`` is.
+"""
+
+import importlib
+import os
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+import dlaf_tpu.config as C
+from dlaf_tpu.tile_ops import mixed
+from dlaf_tpu.tile_ops import pallas_kernels as pk
+from dlaf_tpu.tile_ops import pallas_ozaki as po
+from dlaf_tpu.tile_ops import pallas_panel as pp
+
+DTYPES = [jnp.float32, jnp.bfloat16]
+BLOCKS = [128, 256]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")   # no logs under /tmp
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh22(topo):
+    return Mesh(np.array(topo.devices, dtype=object).reshape(2, 2),
+                ("row", "col"))
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """Code that asks ``jax.default_backend()`` sees the CPU during such a
+    compile; steer it to the TPU route here, in the test."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    C.initialize()
+    yield
+    monkeypatch.undo()
+    C.initialize()
+
+
+def _compile(fn, *shapes):
+    """Lower and compile for the described chip; return the program text."""
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _kernels_in(text: str) -> int:
+    return text.count("tpu_custom_call")
+
+
+# ---------------------------------------------------------------------------
+# pallas_panel: potrf / panel solve / factor+solve / whole step
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("uplo", ["L", "U"])
+@pytest.mark.parametrize("nb", BLOCKS)
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_fused_potrf_compiles(one_chip, dtype, nb, uplo):
+    a = jax.ShapeDtypeStruct((nb, nb), dtype, sharding=one_chip)
+    assert _kernels_in(_compile(lambda x: pp.fused_potrf(uplo, x), a)) == 1
+
+
+@pytest.mark.parametrize("side,uplo,op", [("R", "L", "C"), ("L", "U", "C"),
+                                          ("L", "L", "N"), ("R", "U", "N")])
+@pytest.mark.parametrize("nb", BLOCKS)
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_fused_panel_solve_compiles(one_chip, dtype, nb, side, uplo, op):
+    a = jax.ShapeDtypeStruct((nb, nb), dtype, sharding=one_chip)
+    b = jax.ShapeDtypeStruct((3, nb, nb), dtype, sharding=one_chip)
+    text = _compile(
+        lambda x, y: pp.fused_panel_solve(side, uplo, op, "N", x, y), a, b)
+    assert _kernels_in(text) == 1
+
+
+@pytest.mark.parametrize("uplo", ["L", "U"])
+@pytest.mark.parametrize("nb", BLOCKS)
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_fused_factor_solve_compiles(one_chip, dtype, nb, uplo):
+    a = jax.ShapeDtypeStruct((nb, nb), dtype, sharding=one_chip)
+    strip = jax.ShapeDtypeStruct((3 * nb, nb) if uplo == "L" else
+                                 (nb, 3 * nb), dtype, sharding=one_chip)
+    text = _compile(lambda x, y: pp.fused_factor_solve(uplo, x, y), a, strip)
+    assert _kernels_in(text) == 1
+
+
+@pytest.mark.parametrize("uplo", ["L", "U"])
+@pytest.mark.parametrize("nb", BLOCKS)
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_fused_step_compiles(one_chip, dtype, nb, uplo):
+    a = jax.ShapeDtypeStruct((nb, nb), dtype, sharding=one_chip)
+    shape = (3 * nb, nb) if uplo == "L" else (nb, 3 * nb)
+    strip = jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    text = _compile(lambda x, y, z: pp.fused_step(uplo, x, y, z),
+                    a, strip, strip)
+    assert _kernels_in(text) == 1
+    # the budget model admits what the compiler admits
+    assert pp.step_vmem_bytes(nb, dtype) <= C.Configuration().step_vmem_limit
+
+
+# ---------------------------------------------------------------------------
+# pallas_kernels / pallas_ozaki
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("nb", BLOCKS)
+@pytest.mark.parametrize("dtype", DTYPES)
+def test_masked_trailing_update_compiles(one_chip, dtype, nb):
+    r, c = 5, 7
+    text = _compile(
+        pk.masked_trailing_update,
+        jax.ShapeDtypeStruct((r, c, nb, nb), dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((r, nb, nb), dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((c, nb, nb), dtype, sharding=one_chip),
+        jax.ShapeDtypeStruct((r, c), jnp.int32, sharding=one_chip))
+    assert _kernels_in(text) == 1
+
+
+@pytest.mark.parametrize("dot", ["int8", "bf16"])
+@pytest.mark.parametrize("kernel", ["product", "syrk", "masked128",
+                                    "masked256"])
+def test_ozaki_slice_kernels_compile(one_chip, kernel, dot):
+    s = 7       # the slice count f64_gemm_slices=auto picks on TPU
+
+    def i8(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int8, sharding=one_chip)
+
+    if kernel == "product":
+        text = _compile(lambda a, b: po.fused_slice_product(a, b, dot=dot),
+                        i8(s, 1024, 256), i8(s, 256, 1024))
+    elif kernel == "syrk":
+        text = _compile(lambda a: po.fused_slice_syrk(a, dot=dot),
+                        i8(s, 1024, 256))
+    else:
+        mb = int(kernel[len("masked"):])
+        text = _compile(
+            lambda a, b, m: po.masked_slice_product(a, b, m, dot=dot),
+            i8(s, 6, mb, mb), i8(s, 6, mb, mb),
+            jax.ShapeDtypeStruct((6, 6), jnp.int32, sharding=one_chip))
+    assert _kernels_in(text) >= 1
+
+
+# ---------------------------------------------------------------------------
+# whole step programs: what `auto` resolves to on a TPU
+# ---------------------------------------------------------------------------
+
+def test_local_f32_cholesky_steps_compile(one_chip, as_on_tpu):
+    """The f32 local Cholesky as chip_smoke's phase 3 runs it (all knobs
+    auto, resolved as on a TPU), three blocked steps deep."""
+    chol = importlib.import_module("dlaf_tpu.algorithms.cholesky")
+    n, nb, dt = 768, 256, np.dtype(np.float32)
+    assert pp.panel_uses_fused(dt, nb) and pp.step_uses_fused(dt, nb)
+    trailing = C.resolve_platform_auto(
+        C.get_configuration().cholesky_trailing, knob="cholesky_trailing",
+        tpu_choice="ozaki", other_choice="loop", detail="test")
+    lowered = chol._cholesky_local.lower(
+        jax.ShapeDtypeStruct((n, n), dt, sharding=one_chip), uplo="L",
+        nb=nb, trailing=trailing, lookahead=C.resolved_cholesky_lookahead(),
+        with_info=False, panel_fused=True, step_fused=True,
+        panel_interpret=False, route=None)
+    text = lowered.compile().as_text()
+    # one fused step kernel per strip-bearing step + the last tile's potrf
+    assert _kernels_in(text) == 3
+
+
+def test_dist_f32_cholesky_steps_compile(mesh22, as_on_tpu):
+    """The distributed f32 program of chip_smoke --multichip (c): fused
+    panel/step kernels AND masked_trailing_update in one program over the
+    2x2 mesh — the combination that aborted the compiler before the
+    trailing block was aliased in place."""
+    from dlaf_tpu.common.index2d import (GlobalElementSize, GridSize2D,
+                                         TileElementSize)
+    from dlaf_tpu.matrix.distribution import Distribution
+    from dlaf_tpu.matrix.tiling import storage_tile_grid
+
+    chol = importlib.import_module("dlaf_tpu.algorithms.cholesky")
+    n, nb, dt = 1024, 256, np.dtype(np.float32)
+    dist = Distribution(GlobalElementSize(n, n), TileElementSize(nb, nb),
+                        grid_size=GridSize2D(2, 2))
+    sr, sc, _, _ = storage_tile_grid(dist)
+    assert pk.supports_pallas_update(dt, "tpu")
+    fn = chol._build_dist_cholesky(
+        dist, mesh22, "L", True, False, use_mxu=False, use_mixed=False,
+        cplx=False, lookahead=True, comm_la=True, panel_fused=True,
+        step_fused=True)
+    text = _compile(fn, jax.ShapeDtypeStruct(
+        (sr, sc, nb, nb), dt, sharding=NamedSharding(mesh22,
+                                                     P("row", "col"))))
+    assert _kernels_in(text) >= 4 and "all-reduce" in text
+
+
+def test_mixed_f64_panel_compiles_partitioned(mesh22):
+    """The f64 panel factor of every distributed f64 step (f32 seed + one
+    Newton step, native branch behind lax.cond) inside a program
+    partitioned over four devices: XLA's own f64 cholesky expansion is
+    refused there, the column loop is not."""
+    def body(x):
+        a = x @ x.T + 512.0 * jnp.eye(x.shape[0], dtype=x.dtype)
+        fac, inv = mixed.potrf_inv_refined("L", a)
+        return fac + inv
+
+    fn = jax.shard_map(body, mesh=mesh22, in_specs=P("row", "col"),
+                       out_specs=P("row", "col"), check_vma=False)
+    _compile(fn, jax.ShapeDtypeStruct(
+        (512, 512), jnp.float64,
+        sharding=NamedSharding(mesh22, P("row", "col"))))

@@ -11,7 +11,7 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from dlaf_tpu._compat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from dlaf_tpu.comm import collectives as cc

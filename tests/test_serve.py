@@ -950,13 +950,11 @@ def test_serve_lines_never_take_cholesky_headline():
                   "t": 0.001, "ts": "2026-08-04T00:00:00",
                   "source": "bench.py", "workload": "serve",
                   "speedup": 10.0}
-    assert bench.assemble_headline([serve_line], 4096, 256,
-                                   hist_lookup=lambda **kw: None) is None
+    assert bench.assemble_headline([serve_line], 4096, 256) is None
     chol = {"variant": "loop", "platform": "cpu", "dtype": "float64",
             "n": 4096, "nb": 256, "gflops": 8.0, "t": 1.0,
             "ts": "2026-08-04T00:00:00", "source": "bench.py"}
-    head = bench.assemble_headline([serve_line, chol], 4096, 256,
-                                   hist_lookup=lambda **kw: None)
+    head = bench.assemble_headline([serve_line, chol], 4096, 256)
     assert head["value"] == 8.0 and "serve" not in head["metric"]
 
 

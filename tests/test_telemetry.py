@@ -502,25 +502,20 @@ def test_measure_common_append_validates(tmp_path, monkeypatch):
     assert len(hist) == 1
 
 
-def test_best_recorded_fails_loudly_on_malformed_history(tmp_path):
-    import importlib.util
-
-    spec = importlib.util.spec_from_file_location(
-        "bench_module_tele", os.path.join(os.path.dirname(SCRIPTS),
-                                          "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-
+def test_history_reader_fails_loudly_on_malformed_history(tmp_path):
+    """The one validating reader of .bench_history.jsonl (bench_gate's
+    and mfu_table's source) raises on a malformed or non-finite line
+    instead of skipping it."""
     path = str(tmp_path / "hist.jsonl")
     with open(path, "w") as f:
         f.write(json.dumps(_history_line()) + "\n")
         f.write('{"variant": "ozaki", "gflops": NaN}\n')
     with pytest.raises(ValueError):
-        bench.best_recorded("tpu", 4096, 256, path=path)
+        obs.read_history_records(path)
     # a clean file still resolves
     with open(path, "w") as f:
         f.write(json.dumps(_history_line()) + "\n")
-    assert bench.best_recorded("tpu", 4096, 256, path=path)["gflops"] == 100.0
+    assert obs.read_history_records(path)[0]["gflops"] == 100.0
 
 
 def test_validate_cli_history_mode(tmp_path, capsys):
