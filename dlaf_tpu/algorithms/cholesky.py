@@ -1854,37 +1854,44 @@ def _cholesky(uplo: str, mat: Matrix, *, donate: bool = False,
     use_mxu = tb.f64_gemm_uses_mxu(dt, mat.block_size.row)
     use_mixed = tb.trsm_panel_uses_mixed(dt)
     if mat.grid is None or mat.grid.num_devices == 1:
+        # host phases (``stage.*``, unfenced: each brackets an async
+        # dispatch); on a profiler timeline they label the device's idle
+        # gaps between the three programs (docs/observability.md)
         with entry_span, quiet_donation():
-            a = to_global(mat.storage, mat.dist, donate)
+            with obs.span("stage.cholesky.to_global", fenced=False):
+                a = to_global(mat.storage, mat.dist, donate)
             # program telemetry (DLAF_PROGRAM_TELEMETRY): compile wall /
             # retraces / HBM footprint per site; off = the same jitted
             # callables, bitwise no-op (docs/observability.md)
             # off-TPU the fused panel kernels run in interpret mode
             # (same convention as the pallas trailing kernels)
             panel_interp = jax.default_backend() != "tpu"
-            if trailing == "scan":
-                out = obs.telemetry.call(
-                    "cholesky.local_scan", _cholesky_local_scan, a,
-                    uplo=uplo, nb=mat.block_size.row, use_mxu=use_mxu,
-                    use_mixed=use_mixed, lookahead=lookahead,
-                    with_info=with_info, panel_fused=panel_fused,
-                    step_fused=step_fused,
-                    panel_interpret=(panel_fused or step_fused)
-                    and panel_interp,
-                    route=route)
-            else:
-                out = obs.telemetry.call(
-                    "cholesky.local", _cholesky_local, a, uplo=uplo,
-                    nb=mat.block_size.row, trailing=trailing,
-                    lookahead=lookahead, with_info=with_info,
-                    panel_fused=panel_fused, step_fused=step_fused,
-                    panel_interpret=(panel_fused or step_fused)
-                    and panel_interp,
-                    route=route)
+            with obs.span("stage.cholesky.factor", fenced=False):
+                if trailing == "scan":
+                    out = obs.telemetry.call(
+                        "cholesky.local_scan", _cholesky_local_scan, a,
+                        uplo=uplo, nb=mat.block_size.row, use_mxu=use_mxu,
+                        use_mixed=use_mixed, lookahead=lookahead,
+                        with_info=with_info, panel_fused=panel_fused,
+                        step_fused=step_fused,
+                        panel_interpret=(panel_fused or step_fused)
+                        and panel_interp,
+                        route=route)
+                else:
+                    out = obs.telemetry.call(
+                        "cholesky.local", _cholesky_local, a, uplo=uplo,
+                        nb=mat.block_size.row, trailing=trailing,
+                        lookahead=lookahead, with_info=with_info,
+                        panel_fused=panel_fused, step_fused=step_fused,
+                        panel_interpret=(panel_fused or step_fused)
+                        and panel_interp,
+                        route=route)
             info = None
             if with_info:
                 out, info = out
-            res = mat.with_storage(global_to_tiles_donated(out, mat.dist))
+            with obs.span("stage.cholesky.to_tiles", fenced=False):
+                res = mat.with_storage(
+                    global_to_tiles_donated(out, mat.dist))
             return (res, info) if with_info else res
     platform = next(iter(mat.grid.mesh.devices.flat)).platform
     # exact-flop predicated contraction (ozaki_impl="pallas"): real f64

@@ -19,6 +19,8 @@ from __future__ import annotations
 
 import numpy as np
 
+from .. import obs
+
 __all__ = ["hard_fence"]
 
 
@@ -29,20 +31,23 @@ def hard_fence(*arrays):
     arrays and ``None`` pass through untouched. Returns the single argument
     (or the tuple) for call-site chaining.
     """
-    for x in arrays:
-        if x is None:
-            continue
-        if hasattr(x, "block_until_ready"):
-            x.block_until_ready()
-            if getattr(x, "size", 0):
-                # tiny readback of a value that depends on the array.
-                # On multi-controller runs the global element (0,..,0) may
-                # live on a non-addressable device — read back from a local
-                # shard instead (completion of any output buffer implies the
-                # launched program ran).
-                if getattr(x, "is_fully_addressable", True):
-                    np.asarray(x[(0,) * x.ndim])
-                else:
-                    shard = x.addressable_shards[0].data
-                    np.asarray(shard[(0,) * shard.ndim])
+    # one host span per fence (not per array): on a profiler timeline it
+    # labels the device's idle time around the readback
+    with obs.span("stage.fence", fenced=False):
+        for x in arrays:
+            if x is None:
+                continue
+            if hasattr(x, "block_until_ready"):
+                x.block_until_ready()
+                if getattr(x, "size", 0):
+                    # tiny readback of a value that depends on the array.
+                    # On multi-controller runs the global element (0,..,0)
+                    # may live on a non-addressable device — read back from
+                    # a local shard instead (completion of any output
+                    # buffer implies the launched program ran).
+                    if getattr(x, "is_fully_addressable", True):
+                        np.asarray(x[(0,) * x.ndim])
+                    else:
+                        shard = x.addressable_shards[0].data
+                        np.asarray(shard[(0,) * shard.ndim])
     return arrays[0] if len(arrays) == 1 else arrays

@@ -4,9 +4,9 @@ TPU-native counterpart of the reference's ``common::Timer``
 (``common/timer.h``). Phase profiling is now a thin veneer over the
 :mod:`dlaf_tpu.obs` span tracer: each ``phase(...)`` region is an obs span
 (structured JSONL record + duration histogram when ``DLAF_METRICS_PATH``
-is set, ``jax.profiler.TraceAnnotation`` names on the profiler timeline
-when a trace dir is active) while the familiar ``report()`` {name:
-seconds} aggregation is kept for existing callers.
+is set, and a ``jax.profiler.TraceAnnotation`` of the phase's name on
+whatever profiler timeline is being recorded) while the familiar
+``report()`` {name: seconds} aggregation is kept for existing callers.
 """
 
 from __future__ import annotations
@@ -59,7 +59,6 @@ class PhaseTimer:
         # index and the like — in span attrs instead
         from ..obs._state import STATE
 
-        ann = contextlib.nullcontext()
         if self.profile_dir is not None and STATE.trace_dir \
                 and STATE.trace_dir != self.profile_dir:
             # jax.profiler supports one trace per process: the obs layer's
@@ -75,19 +74,19 @@ class PhaseTimer:
             # when the obs layer has no trace dir of its own — otherwise
             # the spans below start/annotate exactly one process trace
             # (a second start_trace would fail).
-            import jax
-
             if not self._tracing and obs.start_profiler(self.profile_dir):
                 # claimed via the obs layer's single-owner protocol, so a
                 # later configure(trace_dir=...) mid-phase (lazy config
                 # init inside an algorithm call) can't start_trace again
                 # over this live trace
                 self._tracing = True
-            # the obs span won't annotate (no obs trace dir): keep the
-            # profiler timeline labeled ourselves
-            ann = jax.profiler.TraceAnnotation(name)
-        sp = obs.span(name, **attrs)
-        with sp, ann:
+            # a live span even with the obs layer off: the span is what
+            # labels the profiler timeline (it emits nothing without a
+            # sink or registry)
+            sp = obs.Span(name, **attrs)
+        else:
+            sp = obs.span(name, **attrs)
+        with sp:
             # t0 after span entry: one-time jax.profiler.start_trace cost
             # (possibly hundreds of ms, paid by the first phase) stays out
             # of the reported per-phase seconds, as pre-obs

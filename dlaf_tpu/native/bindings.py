@@ -5,6 +5,9 @@ surface is the host-side stages that XLA cannot own: currently the
 bulge-chasing band->tridiag kernel (``band_to_tridiag.cpp``). The library is
 compiled on first use with g++ (no pybind11 in the image — plain C ABI via
 ctypes); failures fall back to the numpy implementation transparently.
+
+Each C++ call is bracketed by a ``stage.native.*`` host span (here, not at
+the call sites, so a numpy fallback is never timed under a native name).
 """
 
 from __future__ import annotations
@@ -16,6 +19,7 @@ import threading
 
 import numpy as np
 
+from .. import obs
 from ..types import ceil_div
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
@@ -106,11 +110,9 @@ def get_lib():
             lib.dlaf_deflate_scan_d.restype = ctypes.c_int64
         except Exception as e:
             _load_error = e
-            from ..obs import get_logger
-
             # error level: an order-of-magnitude perf cliff must stay
             # visible even under DLAF_LOG=error deployments
-            get_logger("native").error(
+            obs.get_logger("native").error(
                 f"build/load failed ({e!r}); numpy fallbacks in effect")
             raise
         _lib = lib
@@ -134,14 +136,15 @@ def secular_roots(ds: np.ndarray, zs: np.ndarray, rho: float,
     if k == 0:
         return anchor, mu
     lib = get_lib()
-    rc = lib.dlaf_secular_roots_d_nt(
-        ds.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
-        zs.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
-        ctypes.c_double(float(rho)), ctypes.c_long(k),
-        anchor.ctypes.data_as(ctypes.POINTER(ctypes.c_long)),
-        mu.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
-        ctypes.c_long(nthreads if nthreads is not None and nthreads > 0
-                      else 0))
+    with obs.span("stage.native.secular", fenced=False, k=k):
+        rc = lib.dlaf_secular_roots_d_nt(
+            ds.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+            zs.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+            ctypes.c_double(float(rho)), ctypes.c_long(k),
+            anchor.ctypes.data_as(ctypes.POINTER(ctypes.c_long)),
+            mu.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+            ctypes.c_long(nthreads if nthreads is not None and nthreads > 0
+                          else 0))
     if rc != 0:
         raise RuntimeError(f"native secular_roots failed rc={rc}")
     return anchor, mu
@@ -163,16 +166,17 @@ def deflate_scan(ds: np.ndarray, zs: np.ndarray, live: np.ndarray,
     gj = np.zeros(n, dtype=np.int64)
     gc = np.zeros(n, dtype=np.float64)
     gs = np.zeros(n, dtype=np.float64)
-    g = lib.dlaf_deflate_scan_d(
-        np.ascontiguousarray(ds, dtype=np.float64).ctypes.data_as(
-            ctypes.POINTER(ctypes.c_double)),
-        zs.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
-        live.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
-        ctypes.c_int64(n), ctypes.c_double(tol),
-        gi.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-        gj.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
-        gc.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
-        gs.ctypes.data_as(ctypes.POINTER(ctypes.c_double)))
+    ds = np.ascontiguousarray(ds, dtype=np.float64)
+    with obs.span("stage.native.deflate", fenced=False, k=n):
+        g = lib.dlaf_deflate_scan_d(
+            ds.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+            zs.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+            live.ctypes.data_as(ctypes.POINTER(ctypes.c_uint8)),
+            ctypes.c_int64(n), ctypes.c_double(tol),
+            gi.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+            gj.ctypes.data_as(ctypes.POINTER(ctypes.c_int64)),
+            gc.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+            gs.ctypes.data_as(ctypes.POINTER(ctypes.c_double)))
     if g < 0:
         raise RuntimeError(f"native deflate_scan failed rc={g}")
     return gi[:g], gj[:g], gc[:g], gs[:g]
@@ -216,14 +220,18 @@ def band_to_tridiag(band: np.ndarray, b: int, nthreads: int | None = None):
     if n_sweeps > 0 or n > 0:
         lib = get_lib()
         fn = lib.dlaf_band_to_tridiag_z if cplx else lib.dlaf_band_to_tridiag_d
-        rc = fn(band_w.ctypes.data_as(ctypes.c_void_p),
-                ctypes.c_long(n), ctypes.c_long(b), ctypes.c_long(max(n_steps, 1)),
-                v.ctypes.data_as(ctypes.c_void_p),
-                tau.ctypes.data_as(ctypes.c_void_p),
-                d.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
-                e_raw.ctypes.data_as(ctypes.c_void_p),
-                ctypes.c_long(nthreads if nthreads is not None and nthreads > 0
-                              else _chase_threads()))
+        threads = nthreads if nthreads is not None and nthreads > 0 \
+            else _chase_threads()
+        with obs.span("stage.native.band_chase", fenced=False,
+                      n=n, b=b, threads=threads):
+            rc = fn(band_w.ctypes.data_as(ctypes.c_void_p),
+                    ctypes.c_long(n), ctypes.c_long(b),
+                    ctypes.c_long(max(n_steps, 1)),
+                    v.ctypes.data_as(ctypes.c_void_p),
+                    tau.ctypes.data_as(ctypes.c_void_p),
+                    d.ctypes.data_as(ctypes.POINTER(ctypes.c_double)),
+                    e_raw.ctypes.data_as(ctypes.c_void_p),
+                    ctypes.c_long(threads))
         if rc != 0:
             raise RuntimeError(f"native band_to_tridiag failed rc={rc}")
     phase = np.ones(n, dtype=work_dtype)

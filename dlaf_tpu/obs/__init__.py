@@ -13,10 +13,10 @@ layered like every other :class:`dlaf_tpu.config.Configuration` field
   ``python -m dlaf_tpu.obs.validate``). Setting it turns the tracer and
   the metrics registry on.
 * ``DLAF_TRACE_DIR`` (``Configuration.trace_dir``) — ``jax.profiler``
-  trace directory; host spans then also carry
-  ``jax.profiler.TraceAnnotation`` names onto the profiler timeline, and
+  trace directory: obs starts (and owns) a profiler session there, and
   trace-time :func:`named_span` phases land in compiled-program op
-  metadata.
+  metadata. Live host spans carry ``jax.profiler.TraceAnnotation`` names
+  onto the timeline of *any* session, this one or a caller's.
 
 Cost contract: with all three unset, every instrumented call site
 resolves to a module-level no-op singleton — no allocation, one attribute

@@ -27,7 +27,9 @@ class _ObsState:
         self.log_level = "info"
         self.log_level_num = LOG_LEVELS["info"]
         self.metrics_on = False          # counters/spans record + JSONL sink
-        self.annotate = False            # jax named_scope/TraceAnnotation on
+        # trace dir set: spans and named scopes on, obs owns a profiler
+        # session (a live span annotates any session, with or without this)
+        self.annotate = False
         self.trace_dir = ""              # jax.profiler trace output dir
         self.sink = None                 # type: Optional[object]  # JsonlSink
         self.registry = None             # type: Optional[object]  # Registry

@@ -1,12 +1,17 @@
 """Span-based tracer: nested host-side spans + device-timeline names.
 
 A span measures host wall clock around a region (an algorithm entry, a
-pipeline stage, a timed miniapp run) and, when active, also enters a
-``jax.profiler.TraceAnnotation`` so profiler timelines carry the same
-names. Builders that run at *trace time* (the unrolled per-``k`` loops)
-use :func:`named_span` instead — a ``jax.named_scope`` whose cost is paid
-once at trace time and whose names land in the compiled program's op
-metadata (the device timeline), never in the runtime hot path.
+pipeline stage, a timed miniapp run) and, whenever it is live (metrics
+sink on or a trace dir set), also enters a
+``jax.profiler.TraceAnnotation`` of the same name: whatever profiler
+session is running -- obs's own (``DLAF_TRACE_DIR``), a ``PhaseTimer``'s,
+the benchmark's, an operator's ``jax.profiler.trace`` -- sees the span on
+the clock it shares with the device lines. Without a session the
+annotation is a flag test. Builders that run at *trace time* (the
+unrolled per-``k`` loops) use :func:`named_span` instead — a
+``jax.named_scope`` whose cost is paid once at trace time and whose names
+land in the compiled program's op metadata (the device timeline), never
+in the runtime hot path.
 
 Nesting is tracked per-thread; each emitted span record carries its
 ``depth`` and ``parent`` so ``scripts/profile_summary.py`` can rebuild the
@@ -81,20 +86,20 @@ class Span:
         self.depth = len(st)
         self.parent = st[-1].name if st else None
         st.append(self)
-        if STATE.annotate:
-            _maybe_start_profiler()
-            import jax
+        # *starting* a session stays tied to DLAF_TRACE_DIR; the
+        # annotation labels any session, whoever started it
+        _maybe_start_profiler()
+        import jax
 
-            self._ann = jax.profiler.TraceAnnotation(self.name)
-            self._ann.__enter__()
+        self._ann = jax.profiler.TraceAnnotation(self.name)
+        self._ann.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.dur_s = time.perf_counter() - self.t0
-        if self._ann is not None:
-            self._ann.__exit__(*exc)
-            self._ann = None
+        self._ann.__exit__(*exc)
+        self._ann = None
         st = _stack()
         if st and st[-1] is self:
             st.pop()

@@ -9,6 +9,7 @@ emit per-step records whose derived GFlop/s is finite, locally and —
 with collective byte counters — on a 2x2 grid.
 """
 
+import contextlib
 import math
 import os
 
@@ -714,3 +715,228 @@ def test_miniapp_cholesky_metrics_distributed(tmp_path, monkeypatch,
                 bytes_by_axis.get(m["labels"]["axis"], 0) + m["value"]
     assert bytes_by_axis.get("row", 0) > 0
     assert bytes_by_axis.get("col", 0) > 0
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock (ISSUE 25): a live span annotates whatever
+# jax.profiler session is running, whoever started it
+# ---------------------------------------------------------------------------
+
+CHOLESKY_SPANS = ("stage.cholesky.to_global", "stage.cholesky.factor",
+                  "stage.cholesky.to_tiles")
+NATIVE_SPANS = ("stage.native.band_chase", "stage.native.secular",
+                "stage.native.deflate")
+
+
+@contextlib.contextmanager
+def _test_owned_trace(trace_dir):
+    """A jax.profiler session started by the test, as an operator (or
+    benchmark/run.py) would: obs owns nothing of it."""
+    import jax
+
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(trace_dir), profiler_options=opts)
+    try:
+        yield
+    finally:
+        jax.profiler.stop_trace()
+
+
+def _host_events(trace_dir, prefixes):
+    """``[(start_ns, end_ns, name)]`` of the host planes' events whose name
+    starts with one of ``prefixes``, from the newest xplane."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    files = sorted(glob.glob(os.path.join(str(trace_dir), "**",
+                                          "*.xplane.pb"), recursive=True),
+                   key=os.path.getmtime)
+    assert files, f"no xplane under {trace_dir}"
+    return [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+            for plane in ProfileData.from_file(files[-1]).planes
+            if not plane.name.startswith("/device:")
+            for line in plane.lines for e in line.events
+            if e.name.startswith(prefixes)]
+
+
+def _tiny_spd(n=64, nb=16):
+    from dlaf_tpu.common.index2d import TileElementSize
+    from dlaf_tpu.matrix.matrix import Matrix
+
+    g = np.random.default_rng(25).standard_normal((n, n))
+    return Matrix.from_global(g @ g.T + n * np.eye(n),
+                              TileElementSize(nb, nb))
+
+
+def _call_cholesky():
+    from dlaf_tpu.algorithms.cholesky import cholesky
+
+    return cholesky("L", _tiny_spd())
+
+
+def _call_fence():
+    import jax.numpy as jnp
+
+    from dlaf_tpu.common.sync import hard_fence
+
+    hard_fence(jnp.ones(4), None, jnp.ones((2, 2)), np.ones(3))
+
+
+def _native_inputs():
+    rng = np.random.default_rng(7)
+    ds = np.sort(rng.standard_normal(12))
+    zs = rng.standard_normal(12)
+    return ds, zs / np.linalg.norm(zs)
+
+
+def _call_band_chase():
+    from dlaf_tpu.native import bindings
+
+    n, b = 24, 4
+    band = np.random.default_rng(3).standard_normal((b + 1, n))
+    bindings.band_to_tridiag(band, b, nthreads=1)
+
+
+def _call_secular():
+    from dlaf_tpu.native import bindings
+
+    bindings.secular_roots(*_native_inputs(), 0.5)
+
+
+def _call_deflate():
+    from dlaf_tpu.native import bindings
+
+    ds, zs = _native_inputs()
+    bindings.deflate_scan(ds, zs.copy(), np.ones(12, dtype=np.uint8), 1e-3)
+
+
+#: each new span name with a call that passes its call site
+NEW_SPAN_SITES = {
+    **{name: _call_cholesky for name in CHOLESKY_SPANS},
+    "stage.fence": _call_fence,
+    "stage.native.band_chase": _call_band_chase,
+    "stage.native.secular": _call_secular,
+    "stage.native.deflate": _call_deflate,
+}
+
+
+def test_cholesky_host_phases_reach_a_foreign_profiler(tmp_path):
+    """Only a metrics path configured, the session started by the test: a
+    local cholesky call leaves its entry span and its three host phases in
+    the xplane, the phases inside the entry, in dispatch order."""
+    _configure_metrics(tmp_path)
+    _call_cholesky()                       # compile outside the session
+    with _test_owned_trace(tmp_path / "trace"):
+        _call_cholesky()
+    from dlaf_tpu.obs._state import STATE
+
+    assert not STATE.profiler_started      # obs started nothing
+    ev = _host_events(tmp_path / "trace", ("cholesky", "stage."))
+    by_name = {}
+    for s, e, name in ev:
+        by_name.setdefault(name, []).append((s, e))
+    assert len(by_name["cholesky"]) == 1
+    entry = by_name["cholesky"][0]
+    starts = []
+    for name in CHOLESKY_SPANS:
+        assert len(by_name[name]) == 1, (name, by_name.get(name))
+        s, e = by_name[name][0]
+        assert entry[0] <= s <= e <= entry[1], name
+        starts.append(s)
+    assert starts == sorted(starts)
+
+
+def test_hard_fence_is_one_span_per_call(tmp_path):
+    path = _configure_metrics(tmp_path)
+    _call_fence()                          # compile the readbacks
+    with _test_owned_trace(tmp_path / "trace"):
+        _call_fence()                      # two jax arrays, one span
+        _call_fence()
+    ev = _host_events(tmp_path / "trace", ("stage.fence",))
+    assert [name for _s, _e, name in ev] == ["stage.fence"] * 2
+    recs = [r for r in obs.read_records(path)
+            if r["type"] == "span" and r["name"] == "stage.fence"]
+    assert len(recs) == 3 and all(r["fenced"] is False for r in recs)
+
+
+@pytest.mark.parametrize("name", NATIVE_SPANS)
+def test_native_binding_leaves_its_span(tmp_path, name):
+    path = _configure_metrics(tmp_path)
+    with _test_owned_trace(tmp_path / "trace"):
+        NEW_SPAN_SITES[name]()
+    ev = _host_events(tmp_path / "trace", ("stage.native.",))
+    assert [n for _s, _e, n in ev] == [name]
+    rec, = [r for r in obs.read_records(path)
+            if r["type"] == "span" and r["name"].startswith("stage.native")]
+    assert rec["name"] == name and rec["fenced"] is False
+    want = {"n", "b", "threads"} if name.endswith("band_chase") else {"k"}
+    assert set(rec["attrs"]) == want
+
+
+def test_numpy_fallback_leaves_no_native_span(tmp_path):
+    """The spans sit in bindings.py around the C++ calls, so a degraded run
+    is never timed under a native name."""
+    from dlaf_tpu.eigensolver.band_to_tridiag import band_to_tridiag
+    from dlaf_tpu.eigensolver.tridiag_solver import (_deflation_scan,
+                                                     _secular_roots_host)
+    from dlaf_tpu.health import inject
+
+    path = _configure_metrics(tmp_path)
+    ds, zs = _native_inputs()
+    band = np.random.default_rng(3).standard_normal((5, 24))
+    with inject.force_native_failure():
+        band_to_tridiag(band, 4, impl="native")
+        _secular_roots_host(ds, zs, 0.5)
+        _deflation_scan(ds, zs.copy(), np.ones(12, dtype=bool), 1e-3)
+    obs.flush()
+    recs = list(obs.read_records(path))
+    assert not [r for r in recs if r["type"] == "span"
+                and r["name"].startswith("stage.native")]
+    sites = {m["labels"].get("site") for r in recs if r["type"] == "metrics"
+             for m in r["metrics"] if m["name"] == "dlaf_fallback_total"}
+    assert {"band_to_tridiag", "secular", "deflate"} <= sites
+
+
+@pytest.mark.parametrize("name", sorted(NEW_SPAN_SITES))
+def test_new_span_sites_are_noops_when_off(monkeypatch, name):
+    """No sink, no trace dir: the call site resolves to the no-op
+    singleton and no Span is ever constructed."""
+    from dlaf_tpu.obs import trace as obs_trace
+
+    C.initialize()
+    assert not obs.enabled()
+    made = []
+    real_init = obs_trace.Span.__init__
+
+    def spy(self, span_name, *a, **kw):
+        made.append(span_name)
+        real_init(self, span_name, *a, **kw)
+
+    monkeypatch.setattr(obs_trace.Span, "__init__", spy)
+    NEW_SPAN_SITES[name]()
+    assert made == []
+    assert obs.span(name, fenced=False) is obs.NOOP_SPAN
+
+
+@pytest.mark.parametrize("sink", [False, True], ids=["obs_off", "sink_on"])
+def test_phase_timer_profile_dir_labels_each_phase_once(tmp_path, sink):
+    """PhaseTimer(profile_dir=...) owns the session; the phase's span is
+    the one annotation (the timer adds none of its own), also when the
+    metrics sink makes the span live a second way."""
+    from dlaf_tpu.common.timer import PhaseTimer
+
+    if sink:
+        _configure_metrics(tmp_path)
+    else:
+        C.initialize()
+    pt = PhaseTimer(profile_dir=str(tmp_path / "timer_trace"))
+    with pt.phase("stage.a"):
+        pass
+    with pt.phase("stage.b"):
+        pass
+    pt.stop()
+    ev = _host_events(tmp_path / "timer_trace", ("stage.",))
+    assert sorted(n for _s, _e, n in ev) == ["stage.a", "stage.b"]
+    assert set(pt.report()) == {"stage.a", "stage.b"}
