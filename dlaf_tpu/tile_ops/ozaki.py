@@ -16,7 +16,12 @@ hardware:
    (``|sum| <= k * 2^12 * s < 2^31`` for any practical ``k``),
 4. recombine partial products grouped by total shift ``d = t+u`` (at most
    ``2s-1`` int32->f64 conversions, not ``s^2``), applying the row/col
-   scales back.
+   scales back. The syrk computes only the pair half ``t < u`` of each
+   group and mirrors ONCE, after the group loop: the mirror is linear and
+   the group scales are scalars, so ``sum_d s_d (g_d + g_d^T + D_d) = C +
+   C^T`` with ``C = sum_d s_d (g_d + D_d/2)`` — one (m, m) transpose of
+   the f64 accumulator per call instead of one of the int32 partial per
+   shift group (:func:`_mirror`).
 
 Cross terms with ``t+u >= s`` fall below the kept mantissa (relative to the
 row/column scale) and are dropped, leaving ``s(s+1)/2`` int8 gemms: 36 for the
@@ -190,13 +195,20 @@ def _accum_impl() -> str:
                "2026-08-02; bit-identical results")
 
 
-def _group_scales(s):
-    """(s,) f64 per-shift-group fold scales ``2^-q(d+2)`` (cf.
-    :func:`_fold_group`)."""
+def _group_scale(d: int, half: bool = False) -> float:
+    """Fold scale ``2^-q(d+2)`` of shift group ``d`` (``half``: half of it,
+    for the syrk's un-mirrored groups, :func:`_mirror`); a power of two, so
+    multiplying by it is exact."""
+    return 2.0 ** (-SLICE_BITS * (d + 2) - int(half))
+
+
+def _group_scales(s, half: bool = False):
+    """(s,) f64 :func:`_group_scale` of every shift group (the scan
+    schedules' operand)."""
     import numpy as np
 
-    return jnp.asarray(
-        [2.0 ** (-SLICE_BITS * (d + 2)) for d in range(s)], dtype=np.float64)
+    return jnp.asarray([_group_scale(d, half) for d in range(s)],
+                       dtype=np.float64)
 
 
 def _pad_k(x, k_pad, axis):
@@ -252,16 +264,39 @@ def _dot_i8(ia, ib):
     return acc
 
 
-def _fold_group(acc, d, p):
+def _fold_group(acc, d, p, half: bool = False):
     """Fold one per-shift group into the running f64 accumulator:
-    ``acc + P_d 2^-q(d+2)``. The power-of-two constant multiply is exact
-    and avoids ldexp (s64 ops). Folding each group as soon as it is
-    complete — instead of collecting all ``s`` (m, n) groups and combining
-    at the end — keeps at most one group plus the accumulator live, which
-    is what lets the unrolled N=16384 factorization fit HBM (the collect-
-    then-combine form compiled to a 22.7 GB peak on a 16 GB v5e)."""
-    term = p.astype(jnp.float64) * float(2.0 ** (-SLICE_BITS * (d + 2)))
+    ``acc + P_d 2^-q(d+2)`` (:func:`_group_scale`). The power-of-two
+    constant multiply is exact and avoids ldexp (s64 ops). Folding each
+    group as soon as it is complete — instead of collecting all ``s``
+    (m, n) groups and combining at the end — keeps at most one group plus
+    the accumulator live, which is what lets the unrolled N=16384
+    factorization fit HBM (the collect-then-combine form compiled to a
+    22.7 GB peak on a 16 GB v5e)."""
+    term = p.astype(jnp.float64) * _group_scale(d, half)
     return term if acc is None else acc + term
+
+
+def _count_mirror(route: str) -> None:
+    """Trace-time accounting, once per emitted (m, m) mirror of a syrk:
+    ``dlaf_ozaki_mirror_total{route}``."""
+    from .. import obs
+
+    if obs.metrics_active():
+        obs.counter("dlaf_ozaki_mirror_total", route=route).inc()
+
+
+def _mirror(acc, route: str):
+    """``acc + acc^T``: the syrk's one (m, m) transpose. The slice-pair
+    half-products ``g_d = sum_{t<u, t+u=d} I_t I_u^T`` and the symmetric
+    diagonal pairs ``D_d`` make the product ``sum_d s_d (g_d + g_d^T +
+    D_d)``; the mirror is linear and the group scales are scalars, so that
+    is ``C + C^T`` with ``C = sum_d s_d (g_d + D_d / 2)``: every branch
+    of :func:`_syrk_f64_2d` folds the integers ``2 g_d + D_d`` at
+    ``s_d / 2`` (exact: a power of two) and mirrors the f64 accumulator
+    here once, instead of each group's int32 partial."""
+    _count_mirror(route)
+    return acc + jnp.swapaxes(acc, -1, -2)
 
 
 def _apply_scales(acc, sa, sb):
@@ -382,6 +417,7 @@ def _syrk_f64_2d(a, *, slices=DEFAULT_SLICES):
                                   interpret=jax.default_backend() == "cpu",
                                   dot=_slice_dot_impl())
         acc = hi.astype(jnp.float64) + lo.astype(jnp.float64)
+        _count_mirror("pallas")
         acc = jnp.tril(acc) + jnp.swapaxes(jnp.tril(acc, -1), -1, -2)
         return _apply_scales(acc, sa, jnp.swapaxes(sa, -1, -2))
     exact_i32 = (s * k) << (2 * SLICE_BITS - 2) < (1 << 31)
@@ -416,18 +452,18 @@ def _syrk_f64_2d(a, *, slices=DEFAULT_SLICES):
                 a_d, b_d, d_d, scale = xs
                 # cast BEFORE the elementwise pair sum when the group
                 # magnitude bound exceeds int32 (same guard as the
-                # "dots" branch): g + g.T + diag can wrap in the window
+                # "dots" branch): 2 g + diag can wrap in the window
                 # where s*k*2^12 >= 2^31 but the half-concat depth is
                 # still below _dot_i8's own f64-chunking threshold
-                g = cast(_dot_i8(a_d, jnp.swapaxes(b_d, -1, -2)))
-                p = g + jnp.swapaxes(g, -1, -2) \
+                p = 2 * cast(_dot_i8(a_d, jnp.swapaxes(b_d, -1, -2))) \
                     + cast(_dot_i8(d_d, jnp.swapaxes(d_d, -1, -2)))
                 return carry + p.astype(jnp.float64) * scale, None
 
             m = a.shape[-2]
             acc, _ = lax.scan(body, jnp.zeros((m, m), jnp.float64),
-                              (ga, gb, gd, _group_scales(s)))
-            return _apply_scales(acc, sa, jnp.swapaxes(sa, -1, -2))
+                              (ga, gb, gd, _group_scales(s, half=True)))
+            return _apply_scales(_mirror(acc, "scan"), sa,
+                                 jnp.swapaxes(sa, -1, -2))
         for d in range(s):
             half = [t for t in range(d // 2 + 1) if t != d - t]
             p = None
@@ -435,27 +471,28 @@ def _syrk_f64_2d(a, *, slices=DEFAULT_SLICES):
                 ga = jnp.concatenate([ia[t] for t in half], axis=-1)
                 gb = jnp.concatenate([ia[d - t] for t in half], axis=-1)
                 # cast before the elementwise pair sum (see the scan
-                # body above): int32 g + g.T + diag can wrap where
+                # body above): int32 2 g + diag can wrap where
                 # s*k*2^12 >= 2^31 but _dot_i8 still returns int32
-                g = cast(_dot_i8(ga, jnp.swapaxes(gb, -1, -2)))
-                p = g + jnp.swapaxes(g, -1, -2)
+                p = 2 * cast(_dot_i8(ga, jnp.swapaxes(gb, -1, -2)))
             if d % 2 == 0:
                 g = cast(_dot_i8(ia[d // 2], jnp.swapaxes(ia[d // 2], -1, -2)))
                 p = g if p is None else p + g
-            acc = _fold_group(acc, d, p)
-        return _apply_scales(acc, sa, jnp.swapaxes(sa, -1, -2))
+            acc = _fold_group(acc, d, p, half=True)
+        return _apply_scales(_mirror(acc, "concat"), sa,
+                             jnp.swapaxes(sa, -1, -2))
     for d in range(s):
         # G_{t,u} with t+u=d: pair (t,u) and (u,t) are mutual transposes —
-        # compute the strict-upper half once and mirror (the syrk symmetry
-        # saving: ~s^2/4 gemms instead of s^2/2)
+        # compute the strict-upper half once, counted twice, and leave the
+        # transpose to the one mirror (the syrk symmetry saving: ~s^2/4
+        # gemms instead of s^2/2)
         p = None
         for t in range(d // 2 + 1):
             u = d - t
             g = cast(_dot_i8(ia[t], jnp.swapaxes(ia[u], -1, -2)))
-            term = g if t == u else g + jnp.swapaxes(g, -1, -2)
+            term = g if t == u else 2 * g
             p = term if p is None else p + term
-        acc = _fold_group(acc, d, p)
-    return _apply_scales(acc, sa, jnp.swapaxes(sa, -1, -2))
+        acc = _fold_group(acc, d, p, half=True)
+    return _apply_scales(_mirror(acc, "dots"), sa, jnp.swapaxes(sa, -1, -2))
 
 
 def syrk_f64(a, *, slices: int = DEFAULT_SLICES):

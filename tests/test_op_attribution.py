@@ -1,0 +1,79 @@
+"""``scripts/op_attribution.py``: the parsers that join a device trace's
+instruction names to source lines (the compiled module's metadata and
+stack-frame tables). The trace reading itself needs a chip."""
+
+import importlib.util
+import os
+
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+HLO = """HloModule jit__cholesky_local, is_scheduled=true
+
+FileNames
+1 "/root/repo/dlaf_tpu/obs/telemetry.py"
+2 "/root/repo/dlaf_tpu/algorithms/cholesky.py"
+3 "/root/repo/dlaf_tpu/tile_ops/ozaki.py"
+
+FunctionNames
+1 "call"
+2 "_cholesky_local"
+3 "syrk_f64"
+4 "_mirror"
+
+FileLocations
+1 {file_name_id=1 function_name_id=1 line=240 end_line=240 column=15 end_column=34}
+2 {file_name_id=2 function_name_id=2 line=325 end_line=325 column=31 end_column=76}
+3 {file_name_id=3 function_name_id=3 line=492 end_line=492 column=11 end_column=41}
+4 {file_name_id=3 function_name_id=4 line=289 end_line=289 column=11 end_column=42}
+
+StackFrames
+1 {file_location_id=1 parent_frame_id=1}
+2 {file_location_id=2 parent_frame_id=2}
+3 {file_location_id=3 parent_frame_id=3}
+4 {file_location_id=4 parent_frame_id=4}
+
+ENTRY %main.1 (a.1: f64[256,256]) -> f64[256,256] {
+  %copy.7 = f32[256,256]{0,1:T(8,128)} copy(%get-tuple-element.3), metadata={op_name="jit(_cholesky_local)/add" stack_frame_id=4}, backend_config={"flag_configs":[]}
+  %get-tuple-element.3 = f32[256,256]{1,0:T(8,128)} get-tuple-element(%while.1), index=2, metadata={op_name="jit(_cholesky_local)/while" stack_frame_id=3}
+  ROOT %copy.9 = f32[256,256]{1,0:T(8,128)} copy(%get-tuple-element.3), backend_config={"flag_configs":[]}
+}
+"""
+
+
+@pytest.fixture(scope="module")
+def attribution():
+    spec = importlib.util.spec_from_file_location(
+        "op_attribution", os.path.join(ROOT, "scripts", "op_attribution.py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_instruction_metadata(attribution):
+    meta = attribution.instruction_metadata(HLO)
+    assert meta["copy.7"] == ("jit(_cholesky_local)/add", 4)
+    assert meta["get-tuple-element.3"] == ("jit(_cholesky_local)/while", 3)
+    assert "copy.9" not in meta         # the compiler's own copy: no metadata
+
+
+@pytest.mark.parametrize("frame,chain", [
+    (4, "ozaki.py:289(_mirror) < ozaki.py:492(syrk_f64) "
+        "< cholesky.py:325(_cholesky_local)"),
+    (2, "cholesky.py:325(_cholesky_local)"),
+    (0, ""),
+    (99, ""),
+])
+def test_frame_chain_stops_at_the_program(attribution, frame, chain):
+    tables = attribution.frame_tables(HLO)
+    assert {k: len(v) for k, v in tables.items()} == {
+        "FileNames": 3, "FunctionNames": 4, "FileLocations": 4,
+        "StackFrames": 4}
+    assert attribution.frame_chain(tables, frame) == chain
+
+
+def test_module_of(attribution):
+    modules = [(0, 10, "jit_a"), (20, 30, "jit_b")]
+    assert [attribution.module_of(modules, t) for t in (0, 9, 10, 25, 40)] \
+        == ["jit_a", "jit_a", "?", "jit_b", "?"]

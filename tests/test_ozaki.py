@@ -1061,6 +1061,104 @@ def test_concat_syrk_int32_wrap_window(accum, monkeypatch):
         config.initialize()
 
 
+#: ``(ozaki_group, ozaki_accum)`` of the three jnp syrk routes, by the
+#: ``route`` label ``dlaf_ozaki_mirror_total`` counts them under
+SYRK_ROUTES = {"scan": ("concat", "scan"), "concat": ("concat", "xla"),
+               "dots": ("dots", "xla")}
+
+
+class TestSyrkMirrorOnce:
+    """The syrk mirrors ONCE per call: every route folds the un-mirrored
+    half ``2 g_d + D_d`` of each shift group at half the group scale and
+    forms ``C + C^T`` from the f64 accumulator after the group loop — no
+    (m, m) transpose per shift group (ISSUE 26: 7 int32 transposes a step
+    on the chip's scan route). Cheap (no compile over a second), so
+    ``quick``: every case stays in the default tier (conftest's stride)."""
+
+    @pytest.fixture()
+    def route(self, request, monkeypatch):
+        from dlaf_tpu import config
+
+        group, accum = SYRK_ROUTES[request.param]
+        monkeypatch.setenv("DLAF_OZAKI_GROUP", group)
+        monkeypatch.setenv("DLAF_OZAKI_ACCUM", accum)
+        config.initialize()
+        yield request.param
+        monkeypatch.delenv("DLAF_OZAKI_GROUP")
+        monkeypatch.delenv("DLAF_OZAKI_ACCUM")
+        config.initialize()
+
+    @pytest.mark.quick
+    @pytest.mark.parametrize("s", [7, 8])
+    @pytest.mark.parametrize("route", list(SYRK_ROUTES), indirect=True)
+    def test_one_square_transpose_none_in_scan(self, route, s):
+        """jaxpr pin: exactly one transpose of an (m, m) array, and none
+        inside a ``scan`` body (m differs from k and from every padded
+        concat depth, so the dots' operand transposes are not square)."""
+        import jax
+
+        from dlaf_tpu.analysis import depgraph
+
+        m, k = 40, 24
+        jaxpr = jax.make_jaxpr(lambda x: syrk_f64(x, slices=s))(
+            jnp.zeros((m, k)))
+        square = [path for path, eqn in depgraph.iter_eqns(jaxpr)
+                  if eqn.primitive.name == "transpose"
+                  and eqn.outvars[0].aval.shape[-2:] == (m, m)]
+        assert len(square) == 1, square
+        assert not any(frame[0] == "scan" for frame in square[0])
+        scans = [eqn for _, eqn in depgraph.iter_eqns(jaxpr)
+                 if eqn.primitive.name == "scan"]
+        assert len(scans) == (1 if route == "scan" else 0)
+
+    @pytest.mark.quick
+    @pytest.mark.parametrize("s", [7, 8])
+    @pytest.mark.parametrize("route", list(SYRK_ROUTES), indirect=True)
+    def test_accuracy_and_exact_symmetry(self, route, s, monkeypatch):
+        """Rows scaled over ten orders of magnitude: the error against
+        numpy stays within the syrk budget relative to ``|a||a|^T``, and
+        the accumulator handed to ``_apply_scales`` is EXACTLY symmetric
+        (``x + y`` and ``y + x`` round alike)."""
+        from dlaf_tpu.tile_ops import ozaki as oz
+
+        rng = np.random.default_rng(26)
+        a = rng.standard_normal((56, 72)) \
+            * 10.0 ** rng.uniform(-5, 5, (56, 1))
+        seen = []
+        plain = oz._apply_scales
+        monkeypatch.setattr(oz, "_apply_scales", lambda acc, sa, sb: (
+            seen.append(np.asarray(acc)), plain(acc, sa, sb))[1])
+        got = np.asarray(syrk_f64(jnp.asarray(a), slices=s))
+        budget = 4 * EPS if s == 8 else 2.0 ** (-7 * s + 4)
+        assert _scaled_err(got, a @ a.T, a, a.T) < budget
+        assert len(seen) == 1
+        assert seen[0].tobytes() == seen[0].T.copy().tobytes()
+
+    @pytest.mark.quick
+    @pytest.mark.parametrize("route", list(SYRK_ROUTES), indirect=True)
+    def test_mirror_counter_one_per_traced_call(self, route, tmp_path):
+        """``dlaf_ozaki_mirror_total{route}``: one count per traced
+        ``syrk_f64`` call, whatever the slice count (the per-group form
+        would have read ``s``)."""
+        import os
+
+        from dlaf_tpu import config, obs
+
+        config.initialize(config.Configuration(
+            metrics_path=str(tmp_path / "mirror.jsonl"),
+            ozaki_group=os.environ["DLAF_OZAKI_GROUP"],
+            ozaki_accum=os.environ["DLAF_OZAKI_ACCUM"]))
+        counter = obs.registry().counter("dlaf_ozaki_mirror_total",
+                                         route=route)
+        base = counter.snapshot()["value"]
+        a = jnp.asarray(np.random.default_rng(27).standard_normal((24, 16)))
+        syrk_f64(a, slices=7)
+        assert counter.snapshot()["value"] - base == 1
+        syrk_f64(a, slices=8)
+        syrk_f64(jnp.stack([a, a]), slices=8)     # batched: one trace
+        assert counter.snapshot()["value"] - base == 3
+
+
 class TestPeelBoundaryRegression:
     """Regression net for the round-4 peel-corruption class (commit
     0807ec7): the TPU f64-emulation's `round` mis-rounds tie+epsilon
