@@ -1667,14 +1667,16 @@ def _build_dist_cholesky_scan(dist, mesh, uplo, use_mxu=False,
                 (sub, pvr, pvc), _ = jax.lax.scan(
                     obs.scoped_step(
                         "cholesky.scanstep",
-                        make_step_la(lu_r0, lu_c0, ltr_s, ltc_s)),
+                        make_step_la(lu_r0, lu_c0, ltr_s, ltc_s),
+                        steps=seg_len),
                     (sub, pvr, pvc), jnp.arange(k0_seg, k0_seg + seg_len))
             else:
                 _count_step_modes("cholesky_dist_scan", 0, seg_len)
                 sub, _ = jax.lax.scan(
                     obs.scoped_step(
                         "cholesky.scanstep",
-                        make_step(lu_r0, lu_c0, ltr_s, ltc_s)), sub,
+                        make_step(lu_r0, lu_c0, ltr_s, ltc_s),
+                        steps=seg_len), sub,
                     jnp.arange(k0_seg, k0_seg + seg_len))
             lt = lt.at[lu_r0:, lu_c0:].set(sub)
         if with_info:

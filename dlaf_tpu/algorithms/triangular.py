@@ -502,12 +502,14 @@ def _build_dist_solve_scan(dist_a, dist_b, mesh, side, uplo, op, diag, dtype,
                 # order (docs/observability.md one-traced-body note)
                 (sub, pe, pxk), _ = jax.lax.scan(
                     obs.scoped_step("trsm.scanstep",
-                                    make_step_la(lu0, cnt, lq0, cnt_q)),
+                                    make_step_la(lu0, cnt, lq0, cnt_q),
+                                    steps=seg_len),
                     (sub, pe, pxk), jnp.arange(i0, i0 + seg_len))
             else:
                 sub, _ = jax.lax.scan(
                     obs.scoped_step("trsm.scanstep",
-                                    make_step(lu0, cnt, lq0, cnt_q)), sub,
+                                    make_step(lu0, cnt, lq0, cnt_q),
+                                    steps=seg_len), sub,
                     jnp.arange(i0, i0 + seg_len))
             if side == "L":
                 ltb = ltb.at[lu0:lu0 + cnt].set(sub)
@@ -695,8 +697,11 @@ def _build_dist_mult_scan(dist_a, dist_b, mesh, side, uplo, op, diag, dtype):
                 telescope_windows(nt, window):
             sub = jax.lax.slice_in_dim(out, lu0, lu0 + cnt,
                                        axis=0 if side == "L" else 1)
-            sub, _ = jax.lax.scan(make_step(lu0, cnt, lq0, cnt_q), sub,
-                                  jnp.arange(k0s, k0s + seg_len))
+            sub, _ = jax.lax.scan(
+                obs.scoped_step("trmm.scanstep",
+                                make_step(lu0, cnt, lq0, cnt_q),
+                                steps=seg_len), sub,
+                jnp.arange(k0s, k0s + seg_len))
             if side == "L":
                 out = out.at[lu0:lu0 + cnt].set(sub)
             else:
@@ -842,12 +847,18 @@ def _triangular_solve(side: str, uplo: str, op: str, diag: str, alpha,
         **({"autotune_route": dict(route)} if route else {}),
         grid=f"{b.dist.grid_size.row}x{b.dist.grid_size.col}"))
     if not dist_run:
+        # host phases as unfenced spans, as the local Cholesky's
+        # (stage.cholesky.*): each is the wall of an async dispatch and
+        # labels the device's idle gaps on a profiler timeline
         with entry_span, quiet_donation():
-            bm = to_global(b.storage, b.dist, donate_b)
-            am = tiles_to_global(a.storage, a.dist)
-            out = _solve_local(am, bm, jnp.asarray(alpha, bm.dtype),
-                               side=side, uplo=uplo, op=op, diag=diag)
-            res = b.with_storage(global_to_tiles_donated(out, b.dist))
+            with obs.span("stage.triangular_solve.to_global", fenced=False):
+                bm = to_global(b.storage, b.dist, donate_b)
+                am = tiles_to_global(a.storage, a.dist)
+            with obs.span("stage.triangular_solve.solve", fenced=False):
+                out = _solve_local(am, bm, jnp.asarray(alpha, bm.dtype),
+                                   side=side, uplo=uplo, op=op, diag=diag)
+            with obs.span("stage.triangular_solve.to_tiles", fenced=False):
+                res = b.with_storage(global_to_tiles_donated(out, b.dist))
             return (res, info) if with_info else res
     # the distributed builders combine A's per-slot panels with B's slots
     # on the swept axis — misalignment corrupts silently, so contract it
@@ -873,10 +884,13 @@ def _triangular_solve(side: str, uplo: str, op: str, diag: str, alpha,
                             panel_interpret=panel_fused
                             and platform != "tpu", route=route)
     with entry_span, quiet_donation():
-        # program telemetry (DLAF_PROGRAM_TELEMETRY): off = passthrough
-        res = b.with_storage(obs.telemetry.call(
-            "triangular_solve.dist", fn, a.storage, b.storage,
-            jnp.asarray(alpha, b.dtype)))
+        # the host's wall to enqueue the one program on every device of
+        # the grid (unfenced: completion is the caller's fence); program
+        # telemetry (DLAF_PROGRAM_TELEMETRY): off = passthrough
+        with obs.span("stage.triangular_solve.dispatch", fenced=False):
+            res = b.with_storage(obs.telemetry.call(
+                "triangular_solve.dist", fn, a.storage, b.storage,
+                jnp.asarray(alpha, b.dtype)))
         return (res, info) if with_info else res
 
 

@@ -41,18 +41,27 @@ def _maybe_inject(kind: str, axis: str, x):
 def _record(kind: str, axis: str, x) -> None:
     """Per-collective accounting (the per-kind/per-axis byte counters
     arXiv:2112.09017 credits its ICI tuning to): payload element count ×
-    itemsize, attributed to the mesh axis. Shapes/dtypes are static even
-    for traced operands, so this costs nothing at run time — counts
-    accumulate when a program is TRACED (once per compiled program), which
-    is exactly the per-program traffic model the tuning sessions need.
-    With metrics off this is one attribute read and a return."""
+    itemsize, attributed to the mesh axis, per EXECUTED step. Shapes/dtypes
+    are static even for traced operands, so this costs nothing at run time
+    — counts accumulate when a program is TRACED (once per compiled
+    program). A ``lax.scan`` body is traced once for all its iterations, so
+    a collective inside one counts the enclosing scan's trip count
+    (``obs.traced_step_count()``, set by the ``obs.scoped_step`` wrapper
+    every distributed scan builder passes its body through): the scan form
+    and the unrolled form of one algorithm then report the same per-call
+    traffic, which is the per-program traffic model the tuning sessions
+    need. A ``while_loop``/``fori_loop`` body would still count once
+    whatever its trip count; none holds a collective today (the loops of
+    ``tile_ops/`` and ``eigensolver/tridiag_solver.py`` are local). With
+    metrics off this is one attribute read and a return."""
     if not obs.metrics_active():
         return
+    steps = obs.traced_step_count()
     nbytes = int(x.size) * x.dtype.itemsize if hasattr(x, "size") else 0
     obs.counter("dlaf_comm_collective_count_total",
-                kind=kind, axis=axis).inc()
+                kind=kind, axis=axis).inc(steps)
     obs.counter("dlaf_comm_collective_bytes_total",
-                kind=kind, axis=axis).inc(nbytes)
+                kind=kind, axis=axis).inc(steps * nbytes)
 
 
 def this_rank(axis: str):

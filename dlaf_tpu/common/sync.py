@@ -9,10 +9,16 @@ measuring.
 Reference analog: the fenced-timing protocol ``waitLocalTiles()`` +
 ``MPI_Barrier`` around every benchmark region (miniapp_cholesky.cpp:134-146).
 
-Note: on a sharded array the readback pulls one element from the first
-shard. All shards of one array are defined by the same launched program, so
-completion of any output buffer implies the program ran; per-device skew is
-bounded by the program itself.
+Note: the readback pulls one element from the first *addressable shard*,
+a one-device array, whatever the array's sharding. All shards of one array
+are defined by the same launched program, so completion of any output buffer
+implies the program ran; per-device skew is bounded by the program itself.
+Indexing the sharded array instead (``x[(0, ..., 0)]``) is a gather over
+every device of its mesh — on a 2x2 v5e result eight scalar transfers, a
+concatenate and a four-device gather program, 4.8 ms of host time per fence
+against 2.0 ms for the shard (PERF.md, PR 27) — and on a multi-controller run
+the global element (0, ..., 0) may live on a device this process cannot
+address at all.
 """
 
 from __future__ import annotations
@@ -40,14 +46,9 @@ def hard_fence(*arrays):
             if hasattr(x, "block_until_ready"):
                 x.block_until_ready()
                 if getattr(x, "size", 0):
-                    # tiny readback of a value that depends on the array.
-                    # On multi-controller runs the global element (0,..,0)
-                    # may live on a non-addressable device — read back from
-                    # a local shard instead (completion of any output
-                    # buffer implies the launched program ran).
-                    if getattr(x, "is_fully_addressable", True):
-                        np.asarray(x[(0,) * x.ndim])
-                    else:
-                        shard = x.addressable_shards[0].data
-                        np.asarray(shard[(0,) * shard.ndim])
+                    # tiny readback of a value that depends on the array,
+                    # from one local shard (module docstring)
+                    shards = getattr(x, "addressable_shards", None)
+                    src = shards[0].data if shards else x
+                    np.asarray(src[(0,) * src.ndim])
     return arrays[0] if len(arrays) == 1 else arrays

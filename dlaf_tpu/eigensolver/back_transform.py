@@ -545,7 +545,8 @@ def _build_dist_bt_r2b_scan(dist_a, dist_c, mesh, band, la: bool = False):
             sub_c, _ = jax.lax.scan(
                 obs.scoped_step(
                     "bt_r2b.scanstep",
-                    make_step(lu_off, lc_off, ctx_c.ltr - lu_off)), sub_c,
+                    make_step(lu_off, lc_off, ctx_c.ltr - lu_off),
+                    steps=seg_len), sub_c,
                 jnp.arange(i0, i0 + seg_len))
             lt_c = lt_c.at[lu_off:].set(sub_c)
         return lt_c

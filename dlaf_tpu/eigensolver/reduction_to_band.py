@@ -598,7 +598,8 @@ def _build_dist_red2band_scan(dist, mesh, dtype, band):
             (sub, taus), _ = jax.lax.scan(
                 obs.scoped_step(
                     "red2band.scanstep",
-                    make_step(lu_off, lc_off, ltr - lu_off, ltc - lc_off)),
+                    make_step(lu_off, lc_off, ltr - lu_off, ltc - lc_off),
+                    steps=seg_len),
                 (sub, taus), jnp.arange(p0, p0 + seg_len))
             lt = lt.at[lu_off:, lc_off:].set(sub)
         return lt, taus

@@ -54,11 +54,11 @@ from .sinks import (SCHEMA_VERSION, JsonlSink,
                     validate_file, validate_history_records, validate_records)
 from .trace import (NOOP_CTX, NOOP_SPAN, Span, current_span, entry_span,
                     named_span, scoped_step, span, start_profiler,
-                    stop_profiler)
+                    stop_profiler, traced_step_count)
 
 __all__ = [
     "configure", "enabled", "metrics_active", "span", "entry_span",
-    "named_span", "scoped_step",
+    "named_span", "scoped_step", "traced_step_count",
     "current_span", "counter", "gauge", "histogram", "registry",
     "get_logger", "emit_event", "emit_metrics_snapshot", "flush",
     "prometheus_text", "prometheus_snapshot_text", "validate_file",

@@ -169,22 +169,43 @@ def named_span(name: str):
     return jax.named_scope(name)
 
 
-def scoped_step(name: str, fn):
+def scoped_step(name: str, fn, steps: int = 1):
     """Wrap a ``lax.scan`` step body so every op it traces carries the
     ``name`` scope. A scan body is traced ONCE for all iterations, so the
     scope can carry no step index — the critpath joiner reconstructs the
     per-iteration timeline from occurrence order instead (one execution
     of the body's instruction set per iteration; the one-traced-body
-    limitation, docs/observability.md). Zero-cost pass-through when
-    observability is off (``named_span`` returns the no-op singleton)."""
+    limitation, docs/observability.md). ``steps`` is the scan's trip
+    count: while the body is traced, :func:`traced_step_count` returns it
+    (times any enclosing scoped body's), so trace-time counters such as
+    the collectives' count per EXECUTED step. Only the first trace of a
+    wrapped body counts — when ``lax.scan`` traces a body again (a
+    weakly typed carry), the count inside is 0, never double. Zero-cost
+    pass-through when observability is off (``named_span`` returns the
+    no-op singleton)."""
     if not (STATE.metrics_on or STATE.annotate):
         return fn
+    traced = False
 
     def wrapped(*args):
-        with named_span(name):
-            return fn(*args)
+        nonlocal traced
+        outer = traced_step_count()
+        _tls.step_count = 0 if traced else outer * steps
+        traced = True
+        try:
+            with named_span(name):
+                return fn(*args)
+        finally:
+            _tls.step_count = outer
 
     return wrapped
+
+
+def traced_step_count() -> int:
+    """How many times the code being traced on this thread executes per
+    run of its program: the product of the trip counts of the enclosing
+    :func:`scoped_step` bodies, 1 outside any."""
+    return getattr(_tls, "step_count", 1)
 
 
 def current_span():

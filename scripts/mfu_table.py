@@ -37,8 +37,8 @@ their single-chip rehearsal number with a note, or "pending".
   distributed program is TRACED (no compile, no execution) on a virtual
   CPU mesh of the config's grid in a subprocess — the UNROLLED builders,
   whose per-``k`` emission makes the trace-time counters exact per-run
-  traffic (a scan body's counters fire once per traced body, not per
-  executed iteration, and would undercount by the trip count) — the
+  traffic (the scan builders count per executed step too, but of their
+  telescoped windows, padded to each segment's widest step) — the
   trace-time byte counters give the per-rank ICI payload per axis, and
   the ceiling is
   ``flops_model / sum_axis(2(p-1)/p * bytes_axis / link_bw)`` — the ring
@@ -283,11 +283,11 @@ def _trace_ici_child(spec: dict) -> None:
                        jax.ShapeDtypeStruct((n_sweeps, n_steps), dtype),
                        jax.ShapeDtypeStruct((n,), dtype), sds)
 
-    # UNROLLED builders only: their per-k emission makes the trace-time
-    # byte counters exact per-run traffic; a scan body traces once per
-    # telescope segment and would undercount by the trip count.
-    # (Exception: bt_b2t's layout all_to_alls sit OUTSIDE its sweep scan
-    # — exactly two collectives per run — so its trace is exact too.)
+    # UNROLLED builders: their per-k emission makes the trace-time byte
+    # counters the exact minimal per-run traffic; the scan builders count
+    # per executed step as well, but of padded telescope windows.
+    # (bt_b2t's layout all_to_alls sit OUTSIDE its sweep scan — exactly
+    # two collectives per run — so its trace is exact too.)
     if family in ("cholesky",):
         from dlaf_tpu.algorithms.cholesky import _build_dist_cholesky
 

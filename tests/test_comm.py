@@ -218,6 +218,48 @@ def test_sync_barrier_is_hard_fence():
     assert cs.barrier is hard_fence
 
 
+def test_hard_fence_reads_back_from_one_shard(devices8):
+    """The readback indexes the first addressable shard, a one-device
+    array, never the sharded array itself (on a 2x2 v5e result that is a
+    gather over four devices: 4.8 ms of host time a fence against 2.0,
+    PERF.md PR 27); a one-device array takes the same path."""
+    from dlaf_tpu.common.sync import hard_fence
+
+    class Sharded:
+        """Stands in for a jax Array whose global index must not be used."""
+        size, ndim, ready = 16, 2, 0
+
+        def __init__(self, shards):
+            self.addressable_shards = shards
+
+        def block_until_ready(self):
+            self.ready += 1
+
+        def __getitem__(self, idx):
+            raise AssertionError("indexed the sharded array")
+
+    class Shard:
+        def __init__(self, data):
+            self.data = data
+
+    class Recorder(np.ndarray):
+        seen = []
+
+        def __getitem__(self, idx):
+            Recorder.seen.append(idx)
+            return np.asarray(self).__getitem__(idx)
+
+    x = Sharded([Shard(np.ones((2, 4)).view(Recorder)), Shard(None)])
+    assert hard_fence(x) is x
+    assert x.ready == 1 and Recorder.seen == [(0, 0)]
+
+    g = Grid(2, 2)
+    arr = jax.device_put(jnp.arange(64.0).reshape(8, 8),
+                         jax.sharding.NamedSharding(g.mesh, P("row", "col")))
+    assert len(arr.addressable_shards) == 4
+    assert hard_fence(arr, None, jnp.ones(3))[0] is arr
+
+
 @pytest.mark.parametrize("rows,cols,axis,src", [
     (2, 4, "col", 0), (2, 4, "col", 2), (1, 8, "col", 3), (8, 1, "row", 5),
     (2, 3, "col", 1),  # non-power-of-2 axis (last doubling round truncated)

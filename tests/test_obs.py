@@ -726,6 +726,11 @@ CHOLESKY_SPANS = ("stage.cholesky.to_global", "stage.cholesky.factor",
                   "stage.cholesky.to_tiles")
 NATIVE_SPANS = ("stage.native.band_chase", "stage.native.secular",
                 "stage.native.deflate")
+#: host phases of ``triangular_solve`` (ISSUE 27), by branch
+TRSM_SPANS = {"local": ("stage.triangular_solve.to_global",
+                        "stage.triangular_solve.solve",
+                        "stage.triangular_solve.to_tiles"),
+              "2x2": ("stage.triangular_solve.dispatch",)}
 
 
 @contextlib.contextmanager
@@ -776,6 +781,26 @@ def _call_cholesky():
     return cholesky("L", _tiny_spd())
 
 
+def _call_trsm(grid_shape=None):
+    from dlaf_tpu.algorithms.triangular import triangular_solve
+    from dlaf_tpu.comm.grid import Grid
+    from dlaf_tpu.common.index2d import TileElementSize
+    from dlaf_tpu.matrix.matrix import Matrix
+
+    n, nb = 64, 16
+    rng = np.random.default_rng(27)
+    t = np.tril(rng.standard_normal((n, n)), -1) + 2.0 * n * np.eye(n)
+    grid = Grid(*grid_shape) if grid_shape else None
+    size = TileElementSize(nb, nb)
+    return triangular_solve(
+        "L", "L", "N", "N", 1.0, Matrix.from_global(t, size, grid=grid),
+        Matrix.from_global(rng.standard_normal((n, n)), size, grid=grid))
+
+
+def _call_trsm_2x2():
+    return _call_trsm((2, 2))
+
+
 def _call_fence():
     import jax.numpy as jnp
 
@@ -815,6 +840,8 @@ def _call_deflate():
 #: each new span name with a call that passes its call site
 NEW_SPAN_SITES = {
     **{name: _call_cholesky for name in CHOLESKY_SPANS},
+    **{name: _call_trsm for name in TRSM_SPANS["local"]},
+    **{name: _call_trsm_2x2 for name in TRSM_SPANS["2x2"]},
     "stage.fence": _call_fence,
     "stage.native.band_chase": _call_band_chase,
     "stage.native.secular": _call_secular,
@@ -846,6 +873,35 @@ def test_cholesky_host_phases_reach_a_foreign_profiler(tmp_path):
         assert entry[0] <= s <= e <= entry[1], name
         starts.append(s)
     assert starts == sorted(starts)
+
+
+@pytest.mark.parametrize("branch", sorted(TRSM_SPANS))
+def test_triangular_solve_host_phases_once_per_call(tmp_path, branch,
+                                                    devices8):
+    """The local solve leaves its three host phases, the distributed one
+    its dispatch phase: once per call, inside the entry span, unfenced, in
+    the JSONL and in a profiler session the test owns."""
+    call = _call_trsm if branch == "local" else _call_trsm_2x2
+    path = _configure_metrics(tmp_path)
+    call()                                 # compile outside the session
+    with _test_owned_trace(tmp_path / "trace"):
+        call()
+        call()
+    ev = _host_events(tmp_path / "trace", ("triangular_solve",
+                                           "stage.triangular_solve."))
+    entries = sorted((s, e) for s, e, n in ev if n == "triangular_solve")
+    assert len(entries) == 2
+    phases = sorted((s, e, n) for s, e, n in ev if n.startswith("stage."))
+    assert [n for _s, _e, n in phases] == list(TRSM_SPANS[branch]) * 2
+    per_call = len(TRSM_SPANS[branch])
+    for k, (s, e, name) in enumerate(phases):
+        entry = entries[k // per_call]
+        assert entry[0] <= s <= e <= entry[1], name
+    recs = [r for r in obs.read_records(path) if r["type"] == "span"
+            and r["name"].startswith("stage.triangular_solve.")]
+    assert len(recs) == 3 * per_call
+    assert all(r["fenced"] is False and r["parent"] == "triangular_solve"
+               for r in recs)
 
 
 def test_hard_fence_is_one_span_per_call(tmp_path):
