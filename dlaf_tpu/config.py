@@ -193,42 +193,38 @@ class Configuration:
     #: default as the hardware's first-class MXU path.
     ozaki_dot: str = "auto"
     #: Shape of the jnp path's per-shift group sums: "dots" (one MXU dot
-    #: per slice pair, group summed elementwise in HBM — the original,
-    #: hardware-proven form) or "concat" (ONE dot per shift group over
-    #: k-concatenated slice operands: the d+1 pair sums ride the MXU
-    #: accumulator instead of materializing d+1 (m, n) int32 buffers).
-    #: Bit-identical integer math either way (tests/test_ozaki.py); the
-    #: r4 session data pins the jnp path ~100x under the raw MXU dot
-    #: ceiling, i.e. HBM-bound on exactly this traffic, so "concat"
-    #: trades more int8 operand reads (cheap, 1 B/elt) for fewer int32
-    #: intermediates (4 B/elt). The 2026-08-01 dot_ab session confirmed
-    #: the traffic model on silicon: trailing-syrk chains 16.6 vs
-    #: 19.1 ms/step and full config #1 at 112.1/111.7 GF/s (int8/bf16)
-    #: vs 105.1/104.5 for "dots", identical residuals — so "auto"
-    #: (default) resolves concat on TPU and keeps dots elsewhere (the
-    #: traffic argument is TPU-HBM-specific; off-TPU stays on the
-    #: long-proven form until measured). Syrk's
-    #: even-shift groups keep their diagonal pair as a second dot to
-    #: preserve the transpose-mirroring MAC saving.
+    #: per slice pair, group summed elementwise in HBM — the original
+    #: form) or "concat" (ONE dot per shift group over k-concatenated
+    #: slice operands: the d+1 pair sums ride the MXU accumulator instead
+    #: of materializing d+1 (m, n) int32 buffers; "concat" trades more
+    #: int8 operand reads, 1 B/elt, for fewer int32 intermediates,
+    #: 4 B/elt). Outside the padded scans of ozaki_accum="scan", group
+    #: d's operands are static slices of one concatenation per operand,
+    #: at the group's real depth (d+1) k.
+    #: Bit-identical integer math either way (tests/test_ozaki.py).
+    #: "auto" (default) resolves concat on TPU and dots elsewhere; the
+    #: two forms have not been compared through benchmark/run.py
+    #: (ROADMAP S9: not measured). Syrk's even-shift groups keep their
+    #: diagonal pair as a second dot to preserve the transpose-mirroring
+    #: MAC saving.
     ozaki_group: str = "auto"
-    #: Schedule of the concat group form's per-shift accumulation: "xla"
-    #: (straight-line trace — XLA owns the schedule and may keep several
-    #: (m, n) int32 group partials live at once; the suspected config-#1
-    #: N=16384 OOM, where ~13 live partials of the whole trailing block
-    #: would exceed HBM on their own) or "scan" (lax.scan over
-    #: zero-padded uniform shift groups — the carry forces one partial +
-    #: the f64 accumulator live, O(1) in the slice count; zero int8 pad
-    #: columns contribute exactly nothing on either dot route, so the
-    #: results are bit-identical — tests/test_ozaki.py
-    #: TestScanAccumRoute). "auto" (default): scan on TPU, xla
-    #: elsewhere. The 2026-08-02 session-4d A/B: at N=4096 (fits both
-    #: ways) the scan schedule measured 119.6 GF/s vs the 112.8
-    #: xla-schedule best (+6% — fewer live int32 partials = less HBM
-    #: traffic), identical residual; the 4d OOM diag confirmed the
-    #: straight-line schedule keeps ~13 GB of ~1 GB trailing-block
-    #: planes live at N=16384 (13.95G program ask vs 15.75G HBM; scan
-    #: still OOMs there via other buffers, but is never worse). Off-TPU
-    #: stays on the straight-line trace (XLA CPU schedules it fine).
+    #: Schedule of the concat group form's per-shift accumulation;
+    #: bit-identical results either way (tests/test_ozaki.py
+    #: TestScanAccumRoute). "xla": a straight-line trace of the ragged
+    #: group dots — XLA owns the schedule and keeps several (m, n) int32
+    #: group partials live at once (the TPU compiler sizes the solve's
+    #: 4096 x 256 x 4096 product at 2.6 times the temporaries, the
+    #: N=4096 local Cholesky at 2.9 times; PERF.md section 6, PR 28).
+    #: "scan": the sequenced schedule — one partial + the f64
+    #: accumulator live, O(1) in the slice count, which is what the
+    #: N=16384 local Cholesky needs to fit a chip. Bulk products (both
+    #: output dimensions wider than the contraction) run the same ragged
+    #: dots ordered by an optimization_barrier per group, no padding;
+    #: panel products and the syrk keep lax.scan over zero-padded uniform
+    #: groups (one body: the least program code, which is resident in
+    #: HBM; tile_ops/ozaki.py:_sequenced_ragged). "auto" (default): scan
+    #: on TPU, xla elsewhere (XLA:CPU schedules the straight line fine
+    #: and ignores the barrier's hint).
     ozaki_accum: str = "auto"
     #: Ozaki slice-reduction implementation: "jnp" (per-shift int32 groups +
     #: full-f64 combine — f64-grade dots at f64_gemm_slices >= 8) or

@@ -137,12 +137,13 @@ def _slice_dot_impl() -> str:
     every value is a small integer in [-2^6, 2^6], exactly representable —
     and contract on the MXU's native bf16 path with f32 accumulation,
     which is integer-exact while ``k * 2^12 <= 2^24`` (deeper
-    contractions are chunked). Same bits out either way; the knob exists
-    because XLA's HLO-level int8 dot has measured far below MXU peak on
-    v5e (~1-4.5 TF/s-int8) while bf16 matmul is the hardware's first-class
-    path. The "auto" default resolves bf16 on TPU, int8 elsewhere, keyed
-    on the PROCESS default backend like blas._oz_slices (config
-    ``ozaki_dot``)."""
+    contractions are chunked). Same bits out either way. On the v5e the
+    two routes time alike where it was measured: the 2x2 solve's cell
+    0.1359 s a call on "int8" against 0.1374 on "bf16", its group dots
+    46.9 against 48.1 ms (PERF.md section 7, PR 28), so the int8 peak of
+    twice the bf16 rate does not show through XLA's s8 dot. The "auto"
+    default resolves bf16 on TPU, int8 elsewhere, keyed on the PROCESS
+    default backend like blas._oz_slices (config ``ozaki_dot``)."""
     from ..config import get_configuration, resolve_platform_auto
 
     return resolve_platform_auto(
@@ -157,32 +158,32 @@ def _group_impl() -> str:
     (one dot per slice pair + elementwise group sums) or "concat" (one
     dot per group over k-concatenated operands). Trace-time knob like
     :func:`_slice_dot_impl`; bit-identical results (tests/test_ozaki.py
-    TestConcatGroupRoute). "auto" resolves concat on TPU — the
-    2026-08-01 dot_ab session measured concat at 16.6 vs 19.1 ms/step
-    on chained trailing syrks and 112.1 vs 105.1 GF/s on full config
-    #1, confirming the HBM-traffic model — and dots elsewhere."""
+    TestConcatGroupRoute). "auto" resolves concat on TPU (fewer (m, n)
+    int32 intermediates through HBM) and dots elsewhere."""
     from ..config import get_configuration, resolve_platform_auto
 
     return resolve_platform_auto(
         get_configuration().ozaki_group, knob="ozaki_group",
         tpu_choice="concat", other_choice="dots",
-        detail="concat measured +7% on config #1 and -13% ms/step on "
-               "trailing chains, 2026-08-01 v5e session; bit-identical "
-               "results")
+        detail="one dot per shift group: the pair sums ride the MXU "
+               "accumulator instead of (m, n) int32 buffers; "
+               "bit-identical results")
 
 
 def _accum_impl() -> str:
     """Schedule of the per-shift group accumulation under the concat
-    group form (config ``ozaki_accum``): "xla" (straight-line trace; XLA
-    owns the schedule and MAY keep several (m, n) int32 group partials
-    live at once — measured at ~13 GB of live ~1 GB planes in the
-    N=16384 OOM diag) or "scan" (``lax.scan`` over zero-padded uniform
-    shift groups: the loop carry forces one partial + the f64
-    accumulator live, O(1) in the slice count). Bit-identical results —
-    zero int8 pad columns contribute exactly nothing on either dot
-    route. "auto" resolves scan on TPU (session-4d A/B: 119.6 vs 112.8
-    GF/s on config #1 at N=4096 — the bounded live set is also the
-    faster HBM schedule) and xla elsewhere. The "dots" group form
+    group form (config ``ozaki_accum``): "xla" (straight-line trace of
+    the ragged group dots; XLA owns the schedule and DOES keep several
+    (m, n) int32 group partials live at once on the TPU) or "scan": the
+    sequenced schedule, one int32 partial + the f64 accumulator live,
+    O(1) in the slice count (the bound the N=16384 local Cholesky
+    needs). It has two forms (:func:`_sequenced_ragged`): bulk products
+    run the same ragged group dots as "xla", ordered by an
+    ``optimization_barrier`` per group; panel products and the syrk keep
+    the ``lax.scan`` over zero-padded uniform groups, whose one body is
+    the least program code. Bit-identical results either way — zero int8
+    pad columns contribute exactly nothing on either dot route. "auto"
+    resolves scan on TPU and xla elsewhere. The "dots" group form
     ignores this knob (its partials are per-pair and XLA fuses them
     well)."""
     from ..config import get_configuration, resolve_platform_auto
@@ -190,9 +191,15 @@ def _accum_impl() -> str:
     return resolve_platform_auto(
         get_configuration().ozaki_accum, knob="ozaki_accum",
         tpu_choice="scan", other_choice="xla",
-        detail="scan schedule measured 119.6 vs 112.8 GF/s on config #1 "
-               "at N=4096 with an O(1) live-partials bound — session 4d, "
-               "2026-08-02; bit-identical results")
+        detail="scan keeps one int32 group partial plus the f64 "
+               "accumulator live whatever the slice count; bit-identical "
+               "results")
+
+
+def _concat_route() -> str:
+    """The concat group form's ``route`` label on the ``dlaf_ozaki_*``
+    counters: "scan" or, under the straight-line schedule, "concat"."""
+    return "scan" if _accum_impl() == "scan" else "concat"
 
 
 def _group_scale(d: int, half: bool = False) -> float:
@@ -203,8 +210,8 @@ def _group_scale(d: int, half: bool = False) -> float:
 
 
 def _group_scales(s, half: bool = False):
-    """(s,) f64 :func:`_group_scale` of every shift group (the scan
-    schedules' operand)."""
+    """(s,) f64 :func:`_group_scale` of every shift group (the padded
+    scan's operand)."""
     import numpy as np
 
     return jnp.asarray([_group_scale(d, half) for d in range(s)],
@@ -220,6 +227,19 @@ def _pad_k(x, k_pad, axis):
     widths = [(0, 0)] * x.ndim
     widths[axis] = (0, pad)
     return jnp.pad(x, widths)
+
+
+def _sequenced_ragged(m: int, n: int, k: int) -> bool:
+    """Which form the sequenced schedule takes for an (m, k) x (k, n)
+    product. Ragged groups multiply no padding (the padded scan spends
+    ``s (s - 1) / 2`` of its ``s^2`` depth slots on zeros) but are ``s``
+    distinct dot kernels where the scan has one body, and a program's
+    code is resident in HBM: ~0.4 MiB a kernel, once per product
+    instance. Bulk products — both output dimensions wider than the
+    contraction, where the flops are — take the ragged form; panel
+    products (one block wide: lower-order flops, one instance per step
+    of an unrolled builder) keep the scan (PERF.md section 6, PR 28)."""
+    return min(m, n) > k
 
 
 def _dot_bf16(ia, ib):
@@ -275,6 +295,51 @@ def _fold_group(acc, d, p, half: bool = False):
     22.7 GB peak on a 16 GB v5e)."""
     term = p.astype(jnp.float64) * _group_scale(d, half)
     return term if acc is None else acc + term
+
+
+def _fold_groups(s, group, cats, half: bool = False):
+    """``sum_d group(d, *cats) 2^-q(d+2)`` folded in the order ``d =
+    0..s-1`` (:func:`_fold_group`). ``group(d, *cats)`` builds shift
+    group ``d``'s integer partial from static slices of the operands'
+    concatenations ``cats``, at the group's real depth. Under the
+    sequenced schedule (:func:`_accum_impl` "scan") a barrier per group
+    makes group ``d``'s operands available only with the accumulator
+    group ``d - 1`` folded into, so the compiler cannot run the dots
+    ahead of the folds and hold their (m, n) partials: the live set of
+    the padded scan's carry, without the scan (on the TPU compiler;
+    XLA:CPU ignores the hint, tests/test_chip_compile.py)."""
+    sequenced = _accum_impl() == "scan"
+    acc = None
+    for d in range(s):
+        if sequenced and d:
+            acc, cats = lax.optimization_barrier((acc, cats))
+        acc = _fold_group(acc, d, group(d, *cats), half)
+    return acc
+
+
+def _count_macs(route: str, mn: int, real: int, emitted: int) -> None:
+    """Trace-time accounting of the slice dots' multiply-accumulates,
+    ``dlaf_ozaki_macs_total{route, kind}``, per traced 2D product and
+    EXECUTED step (a product in a step builder's scan body counts the
+    scan's trip count, ``obs.traced_step_count()``, like the collectives'
+    counters): ``real`` the ``mn * real`` the slice pairs need, ``zero``
+    what the emitted depth holds beyond that (padding)."""
+    from .. import obs
+
+    if obs.metrics_active():
+        mn *= obs.traced_step_count()
+        obs.counter("dlaf_ozaki_macs_total", route=route,
+                    kind="real").inc(mn * real)
+        obs.counter("dlaf_ozaki_macs_total", route=route,
+                    kind="zero").inc(mn * (emitted - real))
+
+
+def _group_dot(route: str, ga, gb, pairs: int, k: int):
+    """``ga @ gb``: ``pairs`` slice-pair products of depth ``k`` summed on
+    the MXU accumulator by one exact dot that runs once per call, counted
+    (:func:`_count_macs`) at the depth of the operands it was handed."""
+    _count_macs(route, ga.shape[-2] * gb.shape[-1], pairs * k, ga.shape[-1])
+    return _dot_i8(ga, gb)
 
 
 def _count_mirror(route: str) -> None:
@@ -349,9 +414,12 @@ def _matmul_f64_2d(a, b, *, slices=DEFAULT_SLICES):
         # of the per-pair contractions — so chunking/exactness bounds in
         # _dot_i8/_dot_bf16 apply to (d+1)*k unchanged, and they chunk
         # at depths far above s*k for every supported shape)
-        if _accum_impl() == "scan":
-            # uniform zero-padded groups scanned with an f64 carry: one
-            # int32 partial live instead of (potentially) all s
+        route = _concat_route()
+        m, n = a.shape[-2], b.shape[-1]
+        if route == "scan" and not _sequenced_ragged(m, n, k):
+            # panel product: uniform zero-padded groups scanned with an
+            # f64 carry (one body; s (s - 1) / 2 of its s^2 slots are
+            # zero columns)
             k_pad = s * k
             ga = jnp.stack([_pad_k(jnp.concatenate(
                 [ia[t] for t in range(d + 1)], axis=-1), k_pad, -1)
@@ -359,23 +427,32 @@ def _matmul_f64_2d(a, b, *, slices=DEFAULT_SLICES):
             gb = jnp.stack([_pad_k(jnp.concatenate(
                 [ib[d - t] for t in range(d + 1)], axis=-2), k_pad, -2)
                 for d in range(s)])
+            _count_macs(route, m * n, s * (s + 1) // 2 * k,
+                        s * ga.shape[-1])
 
             def body(carry, xs):
                 a_d, b_d, scale = xs
                 p = _dot_i8(a_d, b_d)
                 return carry + p.astype(jnp.float64) * scale, None
 
-            acc0 = jnp.zeros((a.shape[-2], b.shape[-1]), jnp.float64)
-            acc, _ = lax.scan(body, acc0, (ga, gb, _group_scales(s)))
+            acc, _ = lax.scan(body, jnp.zeros((m, n), jnp.float64),
+                              (ga, gb, _group_scales(s)))
             return _apply_scales(acc, sa, sb)
-        for d in range(s):
-            ga = jnp.concatenate([ia[t] for t in range(d + 1)], axis=-1)
-            gb = jnp.concatenate([ib[d - t] for t in range(d + 1)], axis=-2)
-            p = _dot_i8(ga, gb)
-            acc = _fold_group(acc, d, p)
+        # ragged groups: group d's operands [I_0 | ... | I_d] and
+        # [J_d; ...; J_0] are contiguous slices of ONE concatenation per
+        # operand, at their real depth (d + 1) k
+        a_cat = jnp.concatenate(ia, axis=-1)          # [I_0 | ... | I_{s-1}]
+        b_rev = jnp.concatenate(ib[::-1], axis=-2)    # [J_{s-1}; ...; J_0]
+
+        def group(d, a_cat, b_rev):
+            return _group_dot(route, a_cat[..., :(d + 1) * k],
+                              b_rev[..., (s - 1 - d) * k:, :], d + 1, k)
+
+        acc = _fold_groups(s, group, (a_cat, b_rev))
         return _apply_scales(acc, sa, sb)
     for d in range(s):
-        terms = [_dot_i8(ia[t], ib[d - t]) for t in range(d + 1)]
+        terms = [_group_dot("dots", ia[t], ib[d - t], 1, k)
+                 for t in range(d + 1)]
         if exact_i32:
             p = terms[0]
             for t in terms[1:]:
@@ -428,13 +505,18 @@ def _syrk_f64_2d(a, *, slices=DEFAULT_SLICES):
         # (mirrored once), plus the even-shift diagonal pair separately —
         # keeps the syrk MAC halving while the pair sums ride the MXU
         # accumulator; exactness as in _matmul_f64_2d's concat branch
-        if _accum_impl() == "scan":
-            # scan form of the same math: half-pair concats zero-padded
-            # to the widest group, the diagonal pair as a zeroed operand
-            # on odd shifts (its dot is then exactly zero — one wasted
-            # (m, k) pass per odd shift, ~1/s of a group's MACs)
-            halves = [[t for t in range(d // 2 + 1) if t != d - t]
-                      for d in range(s)]
+        route = _concat_route()
+        m = a.shape[-2]
+        halves = [[t for t in range(d // 2 + 1) if t != d - t]
+                  for d in range(s)]
+        if route == "scan":
+            # the sequenced schedule keeps the padded scan here: its
+            # only caller of size is the unrolled local Cholesky, where
+            # the ragged form's s + s // 2 kernels a product (one body
+            # here) are resident code, +95 MiB at N=4096 (PERF.md
+            # section 6, PR 28). Half-pair concats zero-padded to the
+            # widest group, the diagonal pair as a zeroed operand on odd
+            # shifts (its dot is then exactly zero)
             h_pad = max(max((len(h) for h in halves), default=0), 1) * k
             zero = jnp.zeros_like(ia[0])
 
@@ -447,6 +529,9 @@ def _syrk_f64_2d(a, *, slices=DEFAULT_SLICES):
                             for d in range(s)])
             gd = jnp.stack([ia[d // 2] if d % 2 == 0 else zero
                             for d in range(s)])
+            _count_macs(route, m * m,
+                        (sum(map(len, halves)) + (s + 1) // 2) * k,
+                        s * (ga.shape[-1] + gd.shape[-1]))
 
             def body(carry, xs):
                 a_d, b_d, d_d, scale = xs
@@ -459,26 +544,38 @@ def _syrk_f64_2d(a, *, slices=DEFAULT_SLICES):
                     + cast(_dot_i8(d_d, jnp.swapaxes(d_d, -1, -2)))
                 return carry + p.astype(jnp.float64) * scale, None
 
-            m = a.shape[-2]
             acc, _ = lax.scan(body, jnp.zeros((m, m), jnp.float64),
                               (ga, gb, gd, _group_scales(s, half=True)))
-            return _apply_scales(_mirror(acc, "scan"), sa,
+            return _apply_scales(_mirror(acc, route), sa,
                                  jnp.swapaxes(sa, -1, -2))
-        for d in range(s):
-            half = [t for t in range(d // 2 + 1) if t != d - t]
+        # straight line over ragged groups: the half pairs t < d - t of
+        # group d are [I_0 | ... | I_{h-1}] against [I_d | ... |
+        # I_{d-h+1}], contiguous slices of the concatenation and of its
+        # block-reversed twin (d = 0 has no half pair, an odd shift no
+        # diagonal pair)
+        a_cat = jnp.concatenate(ia, axis=-1)          # [I_0 | ... | I_{s-1}]
+        a_rev = jnp.concatenate(ia[::-1], axis=-1)    # [I_{s-1} | ... | I_0]
+
+        def group(d, a_cat, a_rev):
+            h = len(halves[d])
             p = None
-            if half:
-                ga = jnp.concatenate([ia[t] for t in half], axis=-1)
-                gb = jnp.concatenate([ia[d - t] for t in half], axis=-1)
+            if h:
+                lo = (s - 1 - d) * k
                 # cast before the elementwise pair sum (see the scan
                 # body above): int32 2 g + diag can wrap where
                 # s*k*2^12 >= 2^31 but _dot_i8 still returns int32
-                p = 2 * cast(_dot_i8(ga, jnp.swapaxes(gb, -1, -2)))
+                p = 2 * cast(_group_dot(
+                    route, a_cat[..., :h * k],
+                    jnp.swapaxes(a_rev[..., lo:lo + h * k], -1, -2), h, k))
             if d % 2 == 0:
-                g = cast(_dot_i8(ia[d // 2], jnp.swapaxes(ia[d // 2], -1, -2)))
+                i_d = a_cat[..., d // 2 * k:(d // 2 + 1) * k]
+                g = cast(_group_dot(route, i_d, jnp.swapaxes(i_d, -1, -2),
+                                    1, k))
                 p = g if p is None else p + g
-            acc = _fold_group(acc, d, p, half=True)
-        return _apply_scales(_mirror(acc, "concat"), sa,
+            return p
+
+        acc = _fold_groups(s, group, (a_cat, a_rev), half=True)
+        return _apply_scales(_mirror(acc, route), sa,
                              jnp.swapaxes(sa, -1, -2))
     for d in range(s):
         # G_{t,u} with t+u=d: pair (t,u) and (u,t) are mutual transposes —
@@ -488,7 +585,8 @@ def _syrk_f64_2d(a, *, slices=DEFAULT_SLICES):
         p = None
         for t in range(d // 2 + 1):
             u = d - t
-            g = cast(_dot_i8(ia[t], jnp.swapaxes(ia[u], -1, -2)))
+            g = cast(_group_dot("dots", ia[t],
+                                jnp.swapaxes(ia[u], -1, -2), 1, k))
             term = g if t == u else 2 * g
             p = term if p is None else p + term
         acc = _fold_group(acc, d, p, half=True)

@@ -242,3 +242,47 @@ def test_mixed_f64_panel_compiles_partitioned(mesh22):
     _compile(fn, jax.ShapeDtypeStruct(
         (512, 512), jnp.float64,
         sharding=NamedSharding(mesh22, P("row", "col"))))
+
+
+# ---------------------------------------------------------------------------
+# the f64 slice products' sequenced schedule (tile_ops/ozaki.py)
+# ---------------------------------------------------------------------------
+
+#: temporaries the TPU compiler gave the padded scan of the parent of PR 28
+#: at these shapes, MiB (``memory_analysis()``; not a device number)
+PADDED_SCAN_TEMP_MIB = {"bulk": 129.6, "panel": 1.0}
+
+
+@pytest.mark.parametrize("accum", ["scan", "xla"])
+@pytest.mark.parametrize("shape", ["bulk", "panel"])
+def test_f64_product_schedules_on_the_tpu_compiler(one_chip, as_on_tpu,
+                                                   monkeypatch, shape,
+                                                   accum):
+    """The distributed solve's bulk product (4096 x 256 x 4096, s = 7,
+    bf16 route) and a panel product (1024 x 256 x 256) under both
+    ``ozaki_accum`` values. Sequenced, the bulk product is seven ragged
+    dots, no loop and no conditional, and the barriers between its groups
+    hold the compiler to the live set of the padded scan's carry (one
+    partial + the accumulator); the straight line keeps the partials live
+    (2.6 times the temporaries). The panel product stays one scan body."""
+    from dlaf_tpu.tile_ops import ozaki
+
+    monkeypatch.setenv("DLAF_OZAKI_ACCUM", accum)
+    C.initialize()
+    m, n = (4096, 4096) if shape == "bulk" else (1024, 256)
+    compiled = jax.jit(lambda a, b: ozaki.matmul_f64(a, b, slices=7)).lower(
+        jax.ShapeDtypeStruct((m, 256), jnp.float64, sharding=one_chip),
+        jax.ShapeDtypeStruct((256, n), jnp.float64, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    temp_mib = compiled.memory_analysis().temp_size_in_bytes / 2 ** 20
+    dots, loops = text.count(" convolution("), text.count(" while(")
+    assert " conditional(" not in text
+    if accum == "scan" and shape == "panel":
+        assert (dots, loops) == (1, 1)
+    else:
+        assert (dots, loops) == (7, 0)
+    if accum == "scan":
+        assert temp_mib <= PADDED_SCAN_TEMP_MIB[shape] + 0.5
+    elif shape == "bulk":
+        assert temp_mib > 2 * PADDED_SCAN_TEMP_MIB[shape]

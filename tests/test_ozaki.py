@@ -936,11 +936,13 @@ class TestConcatGroupRoute:
 
 
 class TestScanAccumRoute:
-    """ozaki_accum="scan" (lax.scan'd zero-padded shift groups, O(1) live
-    partials) must be BIT-IDENTICAL to the straight-line "xla" schedule
-    under the concat group form — the padded columns are int8 zeros,
-    which contribute exactly nothing on either dot route, and the f64
-    carry folds groups in the same order with the same scales."""
+    """ozaki_accum="scan" (the sequenced schedule, O(1) live partials:
+    barriers between the ragged groups of a bulk product, lax.scan'd
+    zero-padded groups for panel products and the syrk) must be
+    BIT-IDENTICAL to the straight-line "xla" schedule under the concat
+    group form — padded columns are int8 zeros, which contribute exactly
+    nothing on either dot route, and the groups fold in the same order
+    with the same scales."""
 
     def _ab(self, monkeypatch, fn, *args, dot):
         from dlaf_tpu import config
@@ -980,11 +982,31 @@ class TestScanAccumRoute:
         self._ab(monkeypatch, lambda x: syrk_f64(x, slices=s),
                  jnp.asarray(a), dot=dot)
 
+    @pytest.mark.quick
+    @pytest.mark.parametrize("dot", ["int8", "bf16"])
+    @pytest.mark.parametrize("which", ["bulk", "panel", "syrk"])
+    def test_batched_bitwise_equal(self, which, dot, monkeypatch):
+        """Under ``jnp.vectorize`` batching (a stack of tiles, as the
+        step builders hand them over) both forms of the sequenced
+        schedule (barriers between ragged groups of a bulk product, the
+        padded scan of a panel product and of the syrk) keep the bits of
+        the straight line."""
+        rng = np.random.default_rng(24)
+        a = rng.standard_normal((3, 72, 56)) \
+            * 10.0 ** rng.integers(-4, 4, (3, 72, 1))
+        n = 64 if which == "bulk" else 24
+        b = rng.standard_normal((56, n))        # broadcast over the batch
+        if which == "syrk":
+            self._ab(monkeypatch, lambda x: syrk_f64(x, slices=7),
+                     jnp.asarray(a), dot=dot)
+        else:
+            self._ab(monkeypatch, lambda x, y: matmul_f64(x, y, slices=7),
+                     jnp.asarray(a), jnp.asarray(b), dot=dot)
+
     def test_auto_resolves_per_platform(self, monkeypatch):
-        """ozaki_accum="auto" (the default): scan on TPU — the measured
-        winner of the session-4d A/B (119.6 vs 112.8 GF/s at N=4096 with
-        an O(1) live-partials bound) — and the straight-line xla schedule
-        elsewhere; explicit values pass through untouched."""
+        """ozaki_accum="auto" (the default): scan on TPU (the bounded
+        live set) and the straight-line xla schedule elsewhere; explicit
+        values pass through untouched."""
         import jax
 
         from dlaf_tpu import config
@@ -1067,6 +1089,182 @@ SYRK_ROUTES = {"scan": ("concat", "scan"), "concat": ("concat", "xla"),
                "dots": ("dots", "xla")}
 
 
+@pytest.fixture()
+def route(request, monkeypatch):
+    """One of :data:`SYRK_ROUTES` configured for the test; its label."""
+    from dlaf_tpu import config
+
+    group, accum = SYRK_ROUTES[request.param]
+    monkeypatch.setenv("DLAF_OZAKI_GROUP", group)
+    monkeypatch.setenv("DLAF_OZAKI_ACCUM", accum)
+    config.initialize()
+    yield request.param
+    monkeypatch.delenv("DLAF_OZAKI_GROUP")
+    monkeypatch.delenv("DLAF_OZAKI_ACCUM")
+    config.initialize()
+
+
+def _dot_depths(fn, *args):
+    """Contraction depth of every dot in ``fn``'s lowered program, read
+    from the text (operand types and ``contracting_dims`` of each
+    ``stablehlo.dot_general``), in program order."""
+    import re
+
+    import jax
+
+    text = jax.jit(fn).lower(*args).as_text()
+    depths = []
+    for line in text.splitlines():
+        if "stablehlo.dot_general" not in line:
+            continue
+        lhs_axis = int(re.search(r"contracting_dims = \[(\d+)\]", line)[1])
+        lhs = re.search(r": \(tensor<([0-9x]+)x[a-z]", line)[1]
+        depths.append(int(lhs.split("x")[lhs_axis]))
+    return depths
+
+
+def _syrk_pairs(s):
+    """(half pairs, diagonal pairs) of the syrk's ``s`` shift groups."""
+    return (sum((d + 1) // 2 for d in range(s)),
+            sum(d % 2 == 0 for d in range(s)))
+
+
+class TestRaggedGroups:
+    """Ragged shift groups (ISSUE 28): a group's dot has its real depth,
+    sliced from one concatenation per operand. The straight-line schedule
+    ("concat" route) is ragged everywhere; the sequenced schedule ("scan"
+    route) is ragged for bulk products (both output dimensions wider than
+    the contraction) and keeps the zero-padded ``lax.scan`` for panel
+    products and the syrk, where every group has the widest depth:
+    ``s * s * k`` deep in all for the product (49 k at s = 7 for 28 k
+    real), ``s (h + 1) k`` for the syrk (28 k for 16 k real)."""
+
+    K = 40
+    BULK = (48, 56)         # (m, n): both wider than K
+    PANELS = [(24, 56), (56, 40), (24, 24)]
+
+    @staticmethod
+    def _matmul_depths(m, k, n, s):
+        return _dot_depths(lambda x, y: matmul_f64(x, y, slices=s),
+                           jnp.zeros((m, k)), jnp.zeros((k, n)))
+
+    @pytest.mark.quick
+    @pytest.mark.parametrize("dot", ["int8", "bf16"])
+    @pytest.mark.parametrize("s", [6, 7, 8])
+    @pytest.mark.parametrize("route", ["scan", "concat"], indirect=True)
+    def test_bulk_matmul_dots_have_their_real_depth(self, route, s, dot,
+                                                    monkeypatch):
+        from dlaf_tpu import config
+
+        monkeypatch.setenv("DLAF_OZAKI_DOT", dot)
+        config.initialize()
+        try:
+            depths = self._matmul_depths(self.BULK[0], self.K,
+                                         self.BULK[1], s)
+        finally:    # before the route fixture re-initializes the config
+            monkeypatch.delenv("DLAF_OZAKI_DOT")
+        # s (s + 1) / 2 * k deep in all: 28 k at s = 7
+        assert sorted(depths) == [(d + 1) * self.K for d in range(s)]
+
+    @pytest.mark.quick
+    @pytest.mark.parametrize("m,n", PANELS)
+    @pytest.mark.parametrize("route", ["scan", "concat"], indirect=True)
+    def test_panel_matmul_scans_one_padded_body(self, route, m, n):
+        """A product one block wide keeps ONE dot of the widest depth in
+        a scan body under the sequenced schedule; the straight line is
+        ragged whatever the shape."""
+        import jax
+
+        from dlaf_tpu.analysis import depgraph
+
+        depths = self._matmul_depths(m, self.K, n, 7)
+        jaxpr = jax.make_jaxpr(lambda x, y: matmul_f64(x, y, slices=7))(
+            jnp.zeros((m, self.K)), jnp.zeros((self.K, n)))
+        scans = sum(eqn.primitive.name == "scan"
+                    for _, eqn in depgraph.iter_eqns(jaxpr))
+        if route == "scan":
+            assert depths == [7 * self.K] and scans == 1
+        else:
+            assert sorted(depths) == [(d + 1) * self.K for d in range(7)]
+            assert scans == 0
+
+    @pytest.mark.quick
+    @pytest.mark.parametrize("s", [6, 7, 8])
+    @pytest.mark.parametrize("route", ["scan", "concat"], indirect=True)
+    def test_syrk_dot_depths(self, route, s):
+        depths = _dot_depths(lambda x: syrk_f64(x, slices=s),
+                             jnp.zeros((self.BULK[0], self.K)))
+        halves, diagonals = _syrk_pairs(s)
+        if s == 7:
+            assert (halves, diagonals) == (12, 4)   # 16 k; padded: 28 k
+        if route == "scan":     # one body: widest half pair + diagonal
+            assert sorted(depths) == [self.K, (s // 2) * self.K]
+        else:
+            want = [(d + 1) // 2 * self.K for d in range(1, s)] \
+                + [self.K] * diagonals
+            assert sorted(depths) == sorted(want)
+
+    @pytest.mark.quick
+    @pytest.mark.parametrize("route", ["scan", "concat"], indirect=True)
+    def test_sequenced_schedule_orders_groups_by_a_barrier(self, route):
+        """One ``optimization_barrier`` between consecutive groups of a
+        bulk product under the sequenced schedule, none on the straight
+        line (what it buys is the TPU compiler's to show:
+        tests/test_chip_compile.py)."""
+        import jax
+
+        from dlaf_tpu.analysis import depgraph
+
+        jaxpr = jax.make_jaxpr(lambda x, y: matmul_f64(x, y, slices=7))(
+            jnp.zeros((self.BULK[0], self.K)),
+            jnp.zeros((self.K, self.BULK[1])))
+        barriers = sum(eqn.primitive.name == "optimization_barrier"
+                       for _, eqn in depgraph.iter_eqns(jaxpr))
+        assert barriers == (6 if route == "scan" else 0)
+
+    @pytest.mark.quick
+    @pytest.mark.parametrize("s", [7, 8])
+    @pytest.mark.parametrize("which", ["bulk", "panel", "syrk"])
+    @pytest.mark.parametrize("route", list(SYRK_ROUTES), indirect=True)
+    def test_mac_counter_real_and_zero_by_hand(self, route, which, s,
+                                               tmp_path):
+        """``dlaf_ozaki_macs_total{route, kind}``: per traced 2D product
+        ``real`` is ``m n k`` times the slice pairs it multiplies
+        (product: s (s + 1) / 2; syrk: the half pairs and the diagonal
+        pairs); ``zero`` is the padding of the two padded scans (product:
+        s (s - 1) / 2 slots; syrk: ``s (s // 2 + 1)`` emitted less the
+        real pairs) and 0 everywhere else."""
+        import os
+
+        from dlaf_tpu import config, obs
+
+        config.initialize(config.Configuration(
+            metrics_path=str(tmp_path / "macs.jsonl"),
+            ozaki_group=os.environ["DLAF_OZAKI_GROUP"],
+            ozaki_accum=os.environ["DLAF_OZAKI_ACCUM"]))
+        real, zero = (obs.registry().counter("dlaf_ozaki_macs_total",
+                                             route=route, kind=kind)
+                      for kind in ("real", "zero"))
+        base = real.snapshot()["value"], zero.snapshot()["value"]
+        k = self.K
+        m, n = self.PANELS[0] if which == "panel" else self.BULK
+        a = jnp.asarray(np.random.default_rng(28).standard_normal((m, k)))
+        if which == "syrk":
+            syrk_f64(a, slices=s)
+            pairs = sum(_syrk_pairs(s))
+            out, padded = m * m, s * (s // 2 + 1) - pairs
+        else:
+            b = jnp.asarray(
+                np.random.default_rng(29).standard_normal((k, n)))
+            matmul_f64(jnp.stack([a, a]), b, slices=s)  # batched: one trace
+            pairs = s * (s + 1) // 2
+            out, padded = m * n, s * (s - 1) // 2
+        if route != "scan" or which == "bulk":
+            padded = 0
+        assert real.snapshot()["value"] - base[0] == out * k * pairs
+        assert zero.snapshot()["value"] - base[1] == out * k * padded
+
+
 class TestSyrkMirrorOnce:
     """The syrk mirrors ONCE per call: every route folds the un-mirrored
     half ``2 g_d + D_d`` of each shift group at half the group scale and
@@ -1074,19 +1272,6 @@ class TestSyrkMirrorOnce:
     (m, m) transpose per shift group (ISSUE 26: 7 int32 transposes a step
     on the chip's scan route). Cheap (no compile over a second), so
     ``quick``: every case stays in the default tier (conftest's stride)."""
-
-    @pytest.fixture()
-    def route(self, request, monkeypatch):
-        from dlaf_tpu import config
-
-        group, accum = SYRK_ROUTES[request.param]
-        monkeypatch.setenv("DLAF_OZAKI_GROUP", group)
-        monkeypatch.setenv("DLAF_OZAKI_ACCUM", accum)
-        config.initialize()
-        yield request.param
-        monkeypatch.delenv("DLAF_OZAKI_GROUP")
-        monkeypatch.delenv("DLAF_OZAKI_ACCUM")
-        config.initialize()
 
     @pytest.mark.quick
     @pytest.mark.parametrize("s", [7, 8])
