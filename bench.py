@@ -101,15 +101,6 @@ MULTIPROCESS_ARMS = ("fleet",)
 #: observed — asserting in-arm that depth never exceeded the bound and
 #: no accepted ticket was stranded. workload="overload" keeps it out of
 #: every headline. Sized via DLAF_BENCH_SERVE_N / DLAF_BENCH_OVERLOAD_DEPTH.
-#: "autotune" (ISSUE 15, docs/autotune.md): the accuracy-steered
-#: precision-route A/B arm — steady-state f64 cholesky GF/s under the
-#: LEARNED route table (DLAF_AUTOTUNE=1, loop settled in-arm; the arm
-#: also reports decisions/s) vs the PINNED worst-case route (autotune
-#: off, f64_gemm_slices=8 + f64_trsm=native — the ladder's safety top).
-#: The learned/pinned ratio rides as the "speedup" field
-#: scripts/bench_gate.py holds to the history-free
-#: --min-autotune-speedup floor; workload="autotune" keeps both numbers
-#: out of every headline. Sized via DLAF_BENCH_AUTOTUNE_N.
 #: "fleet" (ISSUE 18, docs/fleet.md): the multi-replica serve-tier arm —
 #: the same seeded mixed-bucket stream through a fleet Router over ONE
 #: real subprocess replica vs DLAF_BENCH_FLEET_WORKERS replicas; the
@@ -119,7 +110,7 @@ MULTIPROCESS_ARMS = ("fleet",)
 #: cost as "recovery_s". workload="fleet" keeps every number out of the
 #: headlines. Sized via DLAF_BENCH_FLEET_N / DLAF_BENCH_FLEET_REQS.
 STAGE_BASES = ("tridiag", "btr2b", "btb2t", "fpanel", "fstep", "serve",
-               "overload", "autotune", "fleet")
+               "overload", "fleet")
 
 
 def _run_fpanel_variant(variant: str, platform: str,
@@ -410,123 +401,6 @@ def _run_overload_variant(variant: str, platform: str) -> None:
     print(json.dumps(line), flush=True)
 
 
-def _run_autotune_variant(variant: str, platform: str) -> None:
-    """Measure the accuracy-steered precision autotuner (ISSUE 15,
-    docs/autotune.md): steady-state f64 cholesky throughput under the
-    LEARNED route table vs the PINNED worst-case route, plus the
-    decision rate of the settling phase. Off-TPU every ladder rung is
-    behavior-inert (the routed knobs only bind on the mxu/mixed paths),
-    so the honest expectation there is parity minus the probe cost —
-    exactly what the gate's 0.8x floor allows; on TPU the learned
-    routes (s<8, fused reductions) are the win this arm certifies.
-    (Measured on this container: ~0.72x at n=192 with probe-per-call —
-    which is why bench_gate's history-free floor defaults to 0.5, not
-    parity; scripts/bench_gate.py DEFAULT_MIN_AUTOTUNE_SPEEDUP.)"""
-    import dlaf_tpu.autotune as autotune
-    import dlaf_tpu.config as config
-    from dlaf_tpu.algorithms.cholesky import cholesky
-    from dlaf_tpu.common.index2d import GlobalElementSize, TileElementSize
-    from dlaf_tpu.matrix.matrix import Matrix
-    from dlaf_tpu.miniapp.generators import hpd_element_fn
-    from dlaf_tpu.types import total_ops
-
-    n = int(os.environ.get("DLAF_BENCH_AUTOTUNE_N") or
-            (os.environ.get("DLAF_BENCH_N", "4096")
-             if platform == "tpu" else "192"))
-    nb = min(int(os.environ.get("DLAF_BENCH_NB", "256")),
-             max(n // 3, 32))
-    ref = Matrix.from_element_fn(hpd_element_fn(n, np.float64),
-                                 GlobalElementSize(n, n),
-                                 TileElementSize(nb, nb), dtype=np.float64)
-    flops = total_ops(np.float64, n**3 / 6, n**3 / 6)
-    sys.path.insert(0, os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "scripts"))
-    from measure_common import append_history, best_time
-
-    saved = {k: os.environ.get(k) for k in
-             ("DLAF_AUTOTUNE", "DLAF_AUTOTUNE_TABLE",
-              "DLAF_F64_GEMM_SLICES", "DLAF_F64_TRSM")}
-
-    def _restore():
-        for k, v in saved.items():
-            if v is None:
-                os.environ.pop(k, None)
-            else:
-                os.environ[k] = v
-        config.initialize()
-
-    try:
-        # learned arm: fresh in-memory table, loop armed; let the table
-        # settle (enough comfortable probes to relax from the start rung
-        # to the floor), counting the decision rate of the settling runs
-        os.environ["DLAF_AUTOTUNE"] = "1"
-        # the arm measures a FRESH in-memory table: an ambient
-        # DLAF_AUTOTUNE_TABLE would warm-start it (settle would measure
-        # nothing) AND persist every arm decision into the operator's —
-        # possibly git-tracked — table
-        os.environ.pop("DLAF_AUTOTUNE_TABLE", None)
-        os.environ.pop("DLAF_F64_GEMM_SLICES", None)
-        os.environ.pop("DLAF_F64_TRSM", None)
-        cfg = config.initialize()
-        autotune._reset_for_tests()
-        ladder = autotune.LADDER_F64
-        settle = max(2, int(cfg.autotune_relax_after) * ladder.start + 1)
-        t0 = time.perf_counter()
-        for _ in range(settle):
-            cholesky("L", ref)
-        learn_t = time.perf_counter() - t0
-        decisions_per_s = settle / learn_t if learn_t > 0 else 0.0
-        rungs = {label: e["rung"]
-                 for label, e in autotune.get_table().snapshot().items()}
-        log(f"[{variant}] settled after {settle} probe(s) in "
-            f"{learn_t:.2f}s ({decisions_per_s:.2f} decisions/s); "
-            f"rungs {rungs}")
-
-        def measure_learned():
-            # steady state INCLUDES the probe: that is what a steered
-            # deployment actually pays per call
-            return cholesky("L", ref).storage
-
-        t_learned, _ = best_time(measure_learned, reps=3, return_last=True)
-        g_learned = flops / t_learned / 1e9
-        log(f"[{variant}] learned-table best of 3: {t_learned:.4f}s "
-            f"{g_learned:.1f} GFlop/s")
-
-        # pinned worst-case arm: the ladder's safety top as static knobs
-        os.environ["DLAF_AUTOTUNE"] = "0"
-        os.environ["DLAF_F64_GEMM_SLICES"] = "8"
-        os.environ["DLAF_F64_TRSM"] = "native"
-        config.initialize()
-
-        def measure_pinned():
-            return cholesky("L", ref).storage
-
-        measure_pinned()                   # warm the pinned-route program
-        t_pinned, _ = best_time(measure_pinned, reps=3, return_last=True)
-        g_pinned = flops / t_pinned / 1e9
-        speedup = g_learned / g_pinned if g_pinned > 0 else float("nan")
-        log(f"[{variant}] pinned-worst best of 3: {t_pinned:.4f}s "
-            f"{g_pinned:.1f} GFlop/s -> learned/pinned speedup "
-            f"{speedup:.2f}x")
-    finally:
-        _restore()
-
-    line = append_history(platform, n, nb, g_learned, t_learned,
-                          source="bench.py", variant=variant,
-                          dtype="float64", workload="autotune",
-                          extra={"speedup": round(float(speedup), 3),
-                                 "pinned_gflops": round(float(g_pinned), 3),
-                                 "decisions_per_s": round(
-                                     float(decisions_per_s), 3),
-                                 "settle_probes": settle,
-                                 "rungs": rungs})
-    from dlaf_tpu import obs
-
-    obs.emit_event("bench_result", payload=line)
-    obs.flush()
-    print(json.dumps(line), flush=True)
-
-
 def _run_fleet_variant(variant: str, platform: str) -> None:
     """Measure the fleet serve tier (ISSUE 18, docs/fleet.md): the SAME
     seeded mixed-bucket cholesky/solve stream through a Router over ONE
@@ -718,9 +592,6 @@ def _run_stage_variant(variant: str, base: str, mods: set) -> None:
         return
     if base == "overload":
         _run_overload_variant(variant, platform)
-        return
-    if base == "autotune":
-        _run_autotune_variant(variant, platform)
         return
     if base == "fleet":
         _run_fleet_variant(variant, platform)
@@ -1049,7 +920,7 @@ def sweep(platform: str) -> None:
              "loop", "loop+la1", "biggemm", "biggemm+la1", "invgemm",
              "tridiag", "tridiag+dcb1", "btr2b", "btr2b+btla1", "btb2t",
              "fpanel", "fpanel+fp1", "fstep", "fstep+fs1", "serve",
-             "overload", "autotune", "fleet"]
+             "overload", "fleet"]
 
     def _known(v):
         b = v[: -len("+la1")] if v.endswith("+la1") else v

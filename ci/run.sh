@@ -908,153 +908,6 @@ EOF
       exit 1
     fi
     echo "bench_gate serve-speedup leg trips as required"
-    echo "== smoke: autotune closed loop (ISSUE 15, docs/autotune.md) =="
-    # leg 0 — clean warm start: a run steered by the COMMITTED route
-    # table must hold its routes (ZERO escalate/relax records) and never
-    # retrace a program (dlaf_retrace_total stays <= 1 per site). The
-    # committed table is copied aside first: a CI run must never mutate
-    # the git-tracked warm-start file (the .bench_history.jsonl rule).
-    AT_DIR=$(mktemp -d)
-    SMOKE_KEEP+=("$AT_DIR")
-    cp .autotune_table.json "$AT_DIR/table.json"
-    AT_CLEAN_ART="$AT_DIR/clean.jsonl"
-    DLAF_AUTOTUNE=1 DLAF_AUTOTUNE_TABLE="$AT_DIR/table.json" \
-      DLAF_PROGRAM_TELEMETRY=1 DLAF_METRICS_PATH="$AT_CLEAN_ART" \
-      python - <<'EOF'
-import numpy as np
-import dlaf_tpu.config as C
-from dlaf_tpu import obs
-from dlaf_tpu.algorithms.cholesky import cholesky
-from dlaf_tpu.common.index2d import TileElementSize
-from dlaf_tpu.matrix.matrix import Matrix
-
-C.initialize()
-rng = np.random.default_rng(5)
-n, nb = 48, 16
-x = rng.standard_normal((n, n))
-mat = Matrix.from_global(x @ x.T + n * np.eye(n), TileElementSize(nb, nb))
-for _ in range(4):
-    cholesky("L", mat)
-obs.flush()
-EOF
-    python - "$AT_CLEAN_ART" <<'EOF'
-import json
-import sys
-
-recs = [json.loads(line) for line in open(sys.argv[1])]
-decisions = [r for r in recs if r.get("type") == "autotune"]
-assert decisions, "clean warm-started run emitted no autotune decisions"
-moves = [r for r in decisions if r["reason"] in ("escalate", "relax")]
-assert not moves, f"clean warm-started run CHANGED routes: {moves}"
-hot = [m for r in recs if r.get("type") == "metrics"
-       for m in r["metrics"]
-       if m.get("name") == "dlaf_retrace_total" and m.get("value", 0) >= 2]
-assert not hot, f"clean warm-started run retraced: {hot}"
-print(f"clean warm start held the committed route ({len(decisions)} hold "
-      "decision(s), zero route changes, zero retraces)")
-EOF
-    # drill A — injected accuracy breach: a nan_tile-poisoned input's
-    # probe is non-finite, the autotuner must escalate within the ladder
-    # budget, and the artifact must PASS --require-autotune (decision
-    # records + gauge transitions)
-    AT_BREACH_ART="$AT_DIR/breach.jsonl"
-    DLAF_AUTOTUNE=1 DLAF_METRICS_PATH="$AT_BREACH_ART" python - <<'EOF'
-import numpy as np
-import dlaf_tpu.config as C
-import dlaf_tpu.autotune as autotune
-from dlaf_tpu import obs
-from dlaf_tpu.algorithms.cholesky import cholesky
-from dlaf_tpu.common.index2d import TileElementSize
-from dlaf_tpu.health import inject
-from dlaf_tpu.matrix.matrix import Matrix
-
-C.initialize()
-rng = np.random.default_rng(6)
-n, nb = 48, 16
-x = rng.standard_normal((n, n))
-mat = Matrix.from_global(x @ x.T + n * np.eye(n), TileElementSize(nb, nb))
-start = autotune.LADDER_F64.start
-cholesky("L", inject.nan_tile(mat, tile=(1, 0), element=(2, 3)))
-key = autotune.site_key("cholesky", n=n, nb=nb, dtype=np.float64,
-                        platform="cpu")
-rung = autotune.get_table().rung_of(key)
-assert rung == start + 1, f"breach did not escalate: rung {rung}"
-gauge = obs.registry().gauge("dlaf_autotune_route", op="cholesky",
-                             knob="rung").snapshot()
-assert gauge["value"] == start + 1, gauge
-cholesky("L", mat)          # a clean follow-up holds the escalated route
-assert autotune.get_table().rung_of(key) == start + 1
-print(f"injected breach escalated rung {start} -> {start + 1} "
-      "(gauge transition verified); clean follow-up held")
-obs.flush()
-EOF
-    python -m dlaf_tpu.obs.validate "$AT_BREACH_ART" --require-autotune
-    # drill B — escalation exhaustion at the ladder top: under
-    # DLAF_STRICT the run must die with AutotuneExhaustedError, the
-    # flight recorder must dump with the autotune_exhausted trigger, and
-    # the open-state artifact must be REJECTED by --require-autotune
-    # (the teeth leg)
-    AT_EXH_ART="$AT_DIR/exhaust.jsonl"
-    exh_rc=0
-    DLAF_AUTOTUNE=1 DLAF_STRICT=1 DLAF_FLIGHT_RECORDER=64 \
-      DLAF_METRICS_PATH="$AT_EXH_ART" \
-      python - > "$AT_DIR/exhaust.log" 2>&1 <<'EOF' || exh_rc=$?
-import numpy as np
-import dlaf_tpu.config as C
-import dlaf_tpu.autotune as autotune
-from dlaf_tpu.algorithms.cholesky import cholesky
-from dlaf_tpu.common.index2d import TileElementSize
-from dlaf_tpu.health import inject
-from dlaf_tpu.matrix.matrix import Matrix
-
-C.initialize()
-rng = np.random.default_rng(7)
-n, nb = 48, 16
-x = rng.standard_normal((n, n))
-mat = Matrix.from_global(x @ x.T + n * np.eye(n), TileElementSize(nb, nb))
-bad = inject.nan_tile(mat, tile=(0, 0), element=(1, 1))
-ladder = autotune.LADDER_F64
-for _ in range(len(ladder.rungs)):     # breach past the top: must raise
-    cholesky("L", bad)
-raise SystemExit(3)                    # reaching here = never exhausted
-EOF
-    if [ "$exh_rc" -eq 0 ] || [ "$exh_rc" -eq 3 ] \
-        || ! grep -q "AutotuneExhaustedError" "$AT_DIR/exhaust.log"; then
-      echo "autotune exhaustion drill did not raise under DLAF_STRICT" \
-           "(rc=$exh_rc)" >&2
-      cat "$AT_DIR/exhaust.log" >&2; exit 1
-    fi
-    if [ ! -f "$AT_EXH_ART.flight.jsonl" ] \
-        || ! head -1 "$AT_EXH_ART.flight.jsonl" \
-             | grep -q '"reason": "autotune_exhausted"'; then
-      echo "exhaustion drill left no autotune_exhausted flight dump" >&2
-      exit 1
-    fi
-    python -m dlaf_tpu.obs.validate "$AT_EXH_ART.flight.jsonl" \
-      --require-flight
-    if python -m dlaf_tpu.obs.validate "$AT_EXH_ART" --require-autotune \
-        > /dev/null 2>&1; then
-      echo "--require-autotune FAILED to reject the exhausted-ladder" \
-           "artifact" >&2; exit 1
-    fi
-    echo "exhaustion drill: strict raise + flight dump + open state" \
-         "rejected by --require-autotune"
-    echo "== smoke: autotune bench arm + speedup gate =="
-    # the autotune workload arm (bench.py, workload=autotune): learned
-    # table vs pinned worst-case route, gated by bench_gate's
-    # history-free --min-autotune-speedup leg — and an absurd floor must
-    # trip it (the leg's own must-trip)
-    AT_BENCH_ART="$AT_DIR/autotune_bench.jsonl"
-    DLAF_BENCH_VARIANT=autotune DLAF_METRICS_PATH="$AT_BENCH_ART" \
-      DLAF_BENCH_HISTORY_PATH="$AT_DIR/bench_history.jsonl" \
-      python bench.py > /dev/null
-    python scripts/bench_gate.py --fresh "$AT_BENCH_ART"
-    if python scripts/bench_gate.py --fresh "$AT_BENCH_ART" \
-        --min-autotune-speedup 1000 > /dev/null 2>&1; then
-      echo "bench_gate FAILED to flag a sub-floor autotune speedup" >&2
-      exit 1
-    fi
-    echo "bench_gate autotune-speedup leg trips as required"
     echo "== smoke: chaos drill 1 — preempt at b2t -> resume -> identical =="
     # the kill-and-resume proof (docs/robustness.md §5), CROSS-PROCESS:
     # (a) an uninterrupted reference run records its eigenpairs; (b) a

@@ -100,17 +100,12 @@ def _count_step_modes(algo: str, overlapped: int, serialized: int) -> None:
 @functools.partial(jax.jit, static_argnames=("uplo", "nb", "trailing",
                                              "lookahead", "with_info",
                                              "panel_fused", "step_fused",
-                                             "panel_interpret", "route"),
+                                             "panel_interpret"),
                    donate_argnums=0)
 def _cholesky_local(a, *, uplo: str, nb: int, trailing: str = "loop",
                     lookahead: bool = False, with_info: bool = False,
                     panel_fused: bool = False, step_fused: bool = False,
-                    panel_interpret: bool = False,
-                    route: tuple = ()):
-    # ``route`` is the active autotune route's cache-key component
-    # (docs/autotune.md): the builders read route-sensitive knobs at
-    # trace time (_oz_slices / trsm_panel route), so a route change must
-    # be a different compiled program, never a stale-trace reuse
+                    panel_interpret: bool = False):
     n = a.shape[0]
     # "ozaki": route the flops-dominant trailing update through int8 MXU
     # passes (tile_ops.ozaki) — f64 and complex128 (4-real-product form);
@@ -411,13 +406,13 @@ def _cholesky_local(a, *, uplo: str, nb: int, trailing: str = "loop",
                                              "use_mixed", "lookahead",
                                              "with_info", "panel_fused",
                                              "step_fused",
-                                             "panel_interpret", "route"),
+                                             "panel_interpret"),
                    donate_argnums=0)
 def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
                          use_mixed: bool = False, lookahead: bool = False,
                          with_info: bool = False, panel_fused: bool = False,
                          step_fused: bool = False,
-                         panel_interpret: bool = False, route: tuple = ()):
+                         panel_interpret: bool = False):
     """``lax.scan`` formulation of the local factorization: ONE compiled
     step body, looped ``nt`` times with uniform full-size shapes.
 
@@ -1694,13 +1689,9 @@ def _dist_cholesky_cached(dist, mesh, dtype, uplo, use_pallas,
                           pallas_interpret, use_mxu, use_mixed,
                           use_oz_pallas=False, scan=False, donate=False,
                           lookahead=False, comm_la=False, with_info=False,
-                          panel_fused=False, step_fused=False, route=()):
+                          panel_fused=False, step_fused=False):
     # dtype stays in the cache key: storage dtype changes retrace the jit
     # anyway, but distinct keys keep program caches per element type.
-    # ``route`` (the active autotune route, docs/autotune.md) is a pure
-    # cache-key member: the builders read the routed knobs (_oz_slices /
-    # trsm_panel) at trace time, so a route change must land in a
-    # DIFFERENT compiled program — never an in-place retrace
     donate_kw = donate_argnums_kw(donate, 0)
     if scan:
         # comm_la is not a scan cache key: the pipelined scan body already
@@ -1737,41 +1728,6 @@ def cholesky(uplo: str, mat: Matrix, *, donate: bool = False,
     """Factorize the Hermitian positive-definite ``mat`` in the ``uplo``
     triangle: L L^H (uplo='L') or U^H U (uplo='U').
 
-    Under ``DLAF_AUTOTUNE`` (docs/autotune.md) the call first consults
-    the autotune route table for this (n-bucket, nb, dtype, platform)
-    site — the selected precision route rides the builder cache keys, so
-    a learned route change dispatches a different compiled program
-    without retracing the old one — and, when ``mat`` survives the call
-    (``donate=False``), feeds the factor's cheap Hutchinson residual
-    probe back into the table (escalate on breach / relax after K
-    comfortable probes). Donated inputs skip the probe: there is nothing
-    left to compare against.
-
-    See :func:`_cholesky` for the factorization semantics proper
-    (info contract, donation, builder routing).
-    """
-    from .. import autotune
-
-    steer = autotune.steering_for_matrix("cholesky", mat)
-    if steer is None:
-        return _cholesky(uplo, mat, donate=donate, with_info=with_info)
-    with steer.applied():
-        out = _cholesky(uplo, mat, donate=donate, with_info=with_info,
-                        route=steer.route.key())
-    if not donate and steer.probe_due:
-        res = out[0] if with_info else out
-        steer.observe(
-            obs.accuracy.cholesky_residual(uplo, mat, res),
-            c=60.0, of=res.storage, attrs={"entry": "cholesky",
-                                           "uplo": uplo})
-    return out
-
-
-def _cholesky(uplo: str, mat: Matrix, *, donate: bool = False,
-              with_info: bool = False, route: tuple = ()):
-    """Factorize the Hermitian positive-definite ``mat`` in the ``uplo``
-    triangle: L L^H (uplo='L') or U^H U (uplo='U').
-
     Local (1x1 grid) or distributed over ``mat.grid``'s mesh, like the
     reference's two overloads. Returns a new Matrix whose ``uplo`` triangle
     holds the factor; the other triangle passes through.
@@ -1803,8 +1759,9 @@ def _cholesky(uplo: str, mat: Matrix, *, donate: bool = False,
     trailing = resolve_platform_auto(
         get_configuration().cholesky_trailing, knob="cholesky_trailing",
         tpu_choice="ozaki", other_choice="loop",
-        detail="ozaki trailing measured 112.8/351.0 GF/s at N=4096/8192 "
-               "vs 42-47 for loop/xla — 2026-08-01 v5e session")
+        detail="the route of the chol_d_n4096_1x1 cell: call_s 0.0464 s "
+               "(PERF_LEDGER.jsonl, PR 28); the other forms are not "
+               "measured on the chip through benchmark/run.py")
     dlaf_assert(trailing in VALID_TRAILING,
                 f"cholesky_trailing must be one of {VALID_TRAILING}, got {trailing!r}")
     dlaf_assert(mat.size.row == mat.size.col, "cholesky: matrix must be square")
@@ -1848,7 +1805,6 @@ def _cholesky(uplo: str, mat: Matrix, *, donate: bool = False,
         comm_lookahead=int(comm_la),
         panel_impl="fused" if panel_fused else "xla",
         step_impl="fused" if step_fused else "xla",
-        **({"autotune_route": dict(route)} if route else {}),
         grid=f"{grid_shape[0]}x{grid_shape[1]}"))
     # the scan formulations follow the f64_gemm/f64_trsm knobs (identical
     # resolution local and distributed, single owner in tile_ops.blas);
@@ -1877,8 +1833,7 @@ def _cholesky(uplo: str, mat: Matrix, *, donate: bool = False,
                         with_info=with_info, panel_fused=panel_fused,
                         step_fused=step_fused,
                         panel_interpret=(panel_fused or step_fused)
-                        and panel_interp,
-                        route=route)
+                        and panel_interp)
                 else:
                     out = obs.telemetry.call(
                         "cholesky.local", _cholesky_local, a, uplo=uplo,
@@ -1886,8 +1841,7 @@ def _cholesky(uplo: str, mat: Matrix, *, donate: bool = False,
                         lookahead=lookahead, with_info=with_info,
                         panel_fused=panel_fused, step_fused=step_fused,
                         panel_interpret=(panel_fused or step_fused)
-                        and panel_interp,
-                        route=route)
+                        and panel_interp)
             info = None
             if with_info:
                 out, info = out
@@ -1902,10 +1856,7 @@ def _cholesky(uplo: str, mat: Matrix, *, donate: bool = False,
     from ..health.registry import route_available
     from ..tile_ops.pallas_ozaki import MASKED_MB_MAX
 
-    from ..config import _route_override
-
-    oz_impl = _route_override("ozaki_impl") or cfg.ozaki_impl
-    want_oz_pallas = use_mxu and oz_impl == "pallas"
+    want_oz_pallas = use_mxu and cfg.ozaki_impl == "pallas"
     use_oz_pallas = (want_oz_pallas and dt == np.dtype(np.float64)
                      and mat.block_size.row <= MASKED_MB_MAX)
     if use_oz_pallas and not route_available("pallas", "ozaki_pallas"):
@@ -1941,7 +1892,7 @@ def _cholesky(uplo: str, mat: Matrix, *, donate: bool = False,
                                comm_la=comm_la and not scan_mode,
                                with_info=with_info,
                                panel_fused=panel_fused,
-                               step_fused=step_fused, route=route)
+                               step_fused=step_fused)
     with entry_span, quiet_donation():
         if with_info:
             storage, info = obs.telemetry.call("cholesky.dist", fn,

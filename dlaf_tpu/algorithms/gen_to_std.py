@@ -133,11 +133,11 @@ def _step_inv(uplo: str, lkk):
 # (the caller's matrices are re-read only at the final triangle merge)
 @functools.partial(jax.jit, static_argnames=("uplo", "nb", "lookahead",
                                              "panel_fused",
-                                             "panel_interpret", "route"),
+                                             "panel_interpret"),
                    donate_argnums=(0, 1))
 def _hegst_local_blocked(a, l, *, uplo: str, nb: int, lookahead: bool = False,
                          panel_fused: bool = False,
-                         panel_interpret: bool = False, route: tuple = ()):
+                         panel_interpret: bool = False):
     """Unrolled blocked two-sided transform on the global 2D array.
 
     Per step (uplo='L', LAPACK xHEGST itype=1 structure, which the
@@ -730,10 +730,7 @@ def _build_dist_hegst(dist, mesh, uplo: str, use_mxu=False, cplx=False,
 @functools.lru_cache(maxsize=64)
 def _dist_hegst_cached(dist, mesh, dtype, uplo, use_mxu, donate=False,
                        lookahead=False, comm_la=False, panel_fused=False,
-                       panel_interpret=False, route=()):
-    # ``route``: active autotune route as a pure cache-key member
-    # (docs/autotune.md) — the builder reads the routed knobs
-    # (_oz_slices / trsm_panel) at trace time
+                       panel_interpret=False):
     return jax.jit(_build_dist_hegst(dist, mesh, uplo, use_mxu=use_mxu,
                                      cplx=dtype.startswith("complex"),
                                      lookahead=lookahead, comm_la=comm_la,
@@ -744,37 +741,6 @@ def _dist_hegst_cached(dist, mesh, dtype, uplo, use_mxu, donate=False,
 
 def gen_to_std(uplo: str, a: Matrix, b_factor: Matrix, *,
                donate: bool = False, with_info: bool = False):
-    """Transform ``a`` (Hermitian, stored in ``uplo``) using ``b_factor`` =
-    the Cholesky factor of B (same ``uplo``); see :func:`_gen_to_std`.
-
-    Under ``DLAF_AUTOTUNE`` (docs/autotune.md) the blocked forms'
-    precision route is selected from the route table (op key ``hegst``),
-    and — when ``a`` survives the call — the transform's Hutchinson
-    residual probe feeds the table back. The twosolve form inherits its
-    routes through the triangular solver's own ``trsm`` steering (its
-    pivot chains live there), but the HEGST probe still reports here.
-    """
-    from .. import autotune
-
-    steer = autotune.steering_for_matrix("hegst", a)
-    if steer is None:
-        return _gen_to_std(uplo, a, b_factor, donate=donate,
-                           with_info=with_info)
-    with steer.applied():
-        out = _gen_to_std(uplo, a, b_factor, donate=donate,
-                          with_info=with_info, route=steer.route.key())
-    if not donate and steer.probe_due:
-        res = out[0] if with_info else out
-        steer.observe(
-            obs.accuracy.hegst_residual(uplo, a, b_factor, res),
-            c=100.0, of=res.storage, attrs={"entry": "gen_to_std",
-                                            "uplo": uplo})
-    return out
-
-
-def _gen_to_std(uplo: str, a: Matrix, b_factor: Matrix, *,
-                donate: bool = False, with_info: bool = False,
-                route: tuple = ()):
     """Transform ``a`` (Hermitian, stored in ``uplo``) using ``b_factor`` =
     the Cholesky factor of B (same ``uplo``). Returns the transformed A with
     its opposite triangle passing through unchanged.
@@ -834,7 +800,6 @@ def _gen_to_std(uplo: str, a: Matrix, b_factor: Matrix, *,
         dtype=np.dtype(a.dtype).name,
         impl="twosolve" if use_twosolve else hegst_impl,
         panel_impl="fused" if panel_fused else "xla",
-        **({"autotune_route": dict(route)} if route else {}),
         grid=f"{a.dist.grid_size.row}x{a.dist.grid_size.col}"))
     if use_twosolve:
         with entry_span:
@@ -861,7 +826,7 @@ def _gen_to_std(uplo: str, a: Matrix, b_factor: Matrix, *,
                 nb=a.block_size.row, lookahead=lookahead,
                 panel_fused=panel_fused,
                 panel_interpret=panel_fused
-                and jax.default_backend() != "tpu", route=route)
+                and jax.default_backend() != "tpu")
             out_m = a.with_storage(global_to_tiles_donated(out, a.dist))
         res = mops.merge_triangle(out_m, a, uplo, donate_orig=donate)
         return (res, info) if with_info else res
@@ -876,7 +841,7 @@ def _gen_to_std(uplo: str, a: Matrix, b_factor: Matrix, *,
                             donate=donate, lookahead=lookahead,
                             comm_la=comm_la, panel_fused=panel_fused,
                             panel_interpret=panel_fused
-                            and platform != "tpu", route=route)
+                            and platform != "tpu")
     with entry_span, quiet_donation():
         res = a.with_storage(obs.telemetry.call(
             "gen_to_std.dist", fn, a.storage, b_factor.storage))

@@ -733,11 +733,7 @@ def _unit_diag(t, diag):
 def _dist_solve_cached(dist_a, dist_b, mesh, side, uplo, op, diag, dtype,
                        scan=False, donate_b=False, lookahead=False,
                        comm_la=False, panel_fused=False,
-                       panel_interpret=False, route=()):
-    # ``route``: the active autotune route's cache-key component
-    # (docs/autotune.md) — the builders read the routed knobs
-    # (trsm_panel's mixed/native split, _oz_slices) at trace time, so a
-    # route change must be a different compiled program
+                       panel_interpret=False):
     if scan:
         built = _build_dist_solve_scan(dist_a, dist_b, mesh, side, uplo, op,
                                        diag, dtype, lookahead=lookahead,
@@ -771,41 +767,6 @@ def _check_args(side, a: Matrix, b: Matrix):
 def triangular_solve(side: str, uplo: str, op: str, diag: str, alpha,
                      a: Matrix, b: Matrix, *, donate_b: bool = False,
                      with_info: bool = False):
-    """``X: op(A) X = alpha B`` (side='L') or ``X op(A) = alpha B`` ('R');
-    all 8 combos, local + distributed (reference ``solver::triangular``).
-
-    Under ``DLAF_AUTOTUNE`` (docs/autotune.md) the distributed pivot
-    chain's precision route (``f64_trsm`` / ``f64_gemm_slices`` /
-    ``panel_impl``) is selected from the route table for this
-    (n-bucket, nb, dtype, platform) site — op key ``trsm`` — and the
-    solve's Hutchinson residual probe feeds the table back when ``b``
-    survives the call (``donate_b=False``); see :func:`_triangular_solve`
-    for the solve semantics proper.
-    """
-    from .. import autotune
-
-    steer = autotune.steering_for_matrix("trsm", a)
-    if steer is None:
-        return _triangular_solve(side, uplo, op, diag, alpha, a, b,
-                                 donate_b=donate_b, with_info=with_info)
-    with steer.applied():
-        out = _triangular_solve(side, uplo, op, diag, alpha, a, b,
-                                donate_b=donate_b, with_info=with_info,
-                                route=steer.route.key())
-    if not donate_b and steer.probe_due:
-        res = out[0] if with_info else out
-        steer.observe(
-            obs.accuracy.trsm_residual(side, uplo, op, diag, alpha,
-                                       a, b, res),
-            c=60.0, of=res.storage,
-            attrs={"entry": "triangular_solve",
-                   "combo": f"{side}{uplo}{op}{diag}"})
-    return out
-
-
-def _triangular_solve(side: str, uplo: str, op: str, diag: str, alpha,
-                      a: Matrix, b: Matrix, *, donate_b: bool = False,
-                      with_info: bool = False, route: tuple = ()):
     """``X: op(A) X = alpha B`` (side='L') or ``X op(A) = alpha B`` ('R');
     all 8 combos, local + distributed (reference ``solver::triangular``).
 
@@ -844,7 +805,6 @@ def _triangular_solve(side: str, uplo: str, op: str, diag: str, alpha,
         side=side, uplo=uplo, op=op, diag=diag, m=b.size.row,
         n=b.size.col, nb=b.block_size.row, dtype=np.dtype(b.dtype).name,
         panel_impl="fused" if panel_fused else "xla",
-        **({"autotune_route": dict(route)} if route else {}),
         grid=f"{b.dist.grid_size.row}x{b.dist.grid_size.col}"))
     if not dist_run:
         # host phases as unfenced spans, as the local Cholesky's
@@ -882,7 +842,7 @@ def _triangular_solve(side: str, uplo: str, op: str, diag: str, alpha,
                             comm_la=la and resolved_comm_lookahead(),
                             panel_fused=panel_fused,
                             panel_interpret=panel_fused
-                            and platform != "tpu", route=route)
+                            and platform != "tpu")
     with entry_span, quiet_donation():
         # the host's wall to enqueue the one program on every device of
         # the grid (unfenced: completion is the caller's fence); program

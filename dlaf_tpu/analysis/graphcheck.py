@@ -102,10 +102,6 @@ def pinned_native_config():
             cholesky_trailing="loop", cholesky_lookahead="0",
             comm_lookahead="0", dc_level_batch="0", bt_lookahead="0",
             hegst_impl="blocked", dist_step_mode="unrolled",
-            # the traced-program matrix must audit DETERMINISTIC routes:
-            # an adaptive autotune table steering mid-audit would make
-            # the audited programs depend on probe history
-            autotune="0",
             # panel_impl pinned to the XLA route so the precision-
             # demotion and route audits keep auditing the native path;
             # the fused route gets its OWN f32 traced-program entries
@@ -157,8 +153,8 @@ def program_specs(rows: int = 2, cols: int = 2, n: int = 24, nb: int = 4,
 
     specs: List[ProgramSpec] = []
 
-    def add(name, make, **kw):
-        specs.append(ProgramSpec(name=name, build=make, **kw))
+    def add(name, make):
+        specs.append(ProgramSpec(name=name, build=make))
 
     # ---- local Cholesky (unrolled trailing forms + scan form) ----
     from dlaf_tpu.algorithms.cholesky import (_build_dist_cholesky,
@@ -250,43 +246,6 @@ def program_specs(rows: int = 2, cols: int = 2, n: int = 24, nb: int = 4,
                                            lookahead=True,
                                            pallas_interpret=True,
                                            step_fused=True), (st32,)))
-
-    # ---- autotune-routed programs (ISSUE 15, docs/autotune.md): the
-    # re-routed programs the steered entries dispatch — a fast rung
-    # (s=5 + the fused ozaki reduction) and the safety-top rung traced
-    # with the route context LIVE (the routed knobs are read at trace
-    # time). native_route=False: the mxu slicing and the mixed f32 seed
-    # are the demotion rule's documented gated exceptions, and these
-    # specs deliberately trace them ON. ----
-    from dlaf_tpu.autotune.routes import LADDER_F64
-    from dlaf_tpu.autotune.routes import applied as _route_applied
-
-    def _under_route(rung: int, make):
-        route = LADDER_F64.rungs[rung]
-
-        def build():
-            with _route_applied(route):
-                fn, args = make()
-
-            def traced(*xs):
-                with _route_applied(route):
-                    return fn(*xs)
-
-            return traced, args
-
-        return build
-
-    add("cholesky.dist.atroute.rung0.L.la1",
-        _under_route(0, lambda: (
-            _build_dist_cholesky(dist, grid.mesh, "L", False, True,
-                                 use_mxu=True, use_mixed=True,
-                                 use_oz_pallas=True, lookahead=True),
-            (st,))), native_route=False)
-    add("cholesky.dist.atroute.top.L.la1",
-        _under_route(len(LADDER_F64.rungs) - 1, lambda: (
-            _build_dist_cholesky(dist, grid.mesh, "L", False, True,
-                                 use_mxu=True, lookahead=True),
-            (st,))), native_route=False)
 
     # ---- distributed triangular solve / multiply ----
     from dlaf_tpu.algorithms.triangular import (_build_dist_mult,

@@ -63,10 +63,10 @@ class Configuration:
     #: buffer reuse at ~3x the exact trailing flops; the compile/HBM
     #: escape hatch at large tile counts, algorithms/cholesky.py). Also
     #: "ozaki" (error-free int8-slice trailing on the MXU) and "auto"
-    #: (default): ozaki on TPU — the measured winner every silicon
-    #: session (112.8/351.0 GF/s at N=4096/8192 vs 42-47 for the other
-    #: forms, 2026-08-01) — and loop elsewhere.
-    #: Benchmarked per hardware; see bench.py.
+    #: (default): ozaki on TPU (the route of the benchmark's
+    #: ``chol_d_n4096_1x1`` cell: ``call_s`` 0.0464 s, PERF_LEDGER.jsonl
+    #: PR 28; the other forms are not measured on the chip through
+    #: benchmark/run.py) and loop elsewhere.
     cholesky_trailing: str = "auto"
     #: Look-ahead (software-pipelined) step formulation for the blocked
     #: Cholesky (and the analogous panel-chain splits in the triangular
@@ -277,8 +277,8 @@ class Configuration:
     #: would exceed it degrade to the composed-op step route (counted
     #: under explicit "fused", silent policy under "auto"). The default
     #: caps the kernel at 10 MiB, leaving ~6 MiB of a v5e core's
-    #: ~16 MiB VMEM for the compiler's own buffers; the autotune ladder
-    #: and ``health.inject`` drills exercise the degrade path.
+    #: ~16 MiB VMEM for the compiler's own buffers; the
+    #: ``health.inject`` drills exercise the degrade path.
     step_vmem_limit: int = 10 * 2 ** 20
     #: Panel-level factor/solve ops (real f64): "native" (XLA — latency-bound
     #: under TPU f64 emulation), "mixed" (f32 seed + Newton refinement,
@@ -438,55 +438,6 @@ class Configuration:
     #: verifies regardless of the knob — the knob only picks the
     #: estimator mode ("0" checks with the "1" probe).
     accuracy: str = "0"
-    #: Accuracy-steered precision autotuning (``DLAF_AUTOTUNE``, ISSUE 15,
-    #: docs/autotune.md): "1" closes the loop on the accuracy signal —
-    #: the precision routes that dominate TPU f64-emulation cost
-    #: (``f64_gemm_slices`` / ``f64_trsm`` / ``panel_impl`` /
-    #: ``ozaki_impl``) are chosen per (op, n-bucket, nb, dtype, platform)
-    #: from a route table fed by PR 8's cheap Hutchinson probe after each
-    #: factorization: escalate one ladder rung immediately on a
-    #: ``bound_ratio`` breach, relax one rung after
-    #: ``autotune_relax_after`` consecutive comfortable probes
-    #: (dlaf_tpu.autotune; decisions are pure functions of
-    #: (table, probe), so drills replay exactly). "0" (the bitwise
-    #: passthrough: ladders start at the platform-default route, and off
-    #: nothing is probed or overridden). "auto" (default): 1 on TPU —
-    #: exactly where the emulation routes bind — and 0 elsewhere. Probe
-    #: cost: one O(n^2 k) device estimate per non-donated entry call;
-    #: donated inputs skip the probe (nothing to compare against).
-    autotune: str = "auto"
-    #: Route-table persistence path (``DLAF_AUTOTUNE_TABLE``,
-    #: docs/autotune.md): when non-empty, the autotuner warm-starts from
-    #: this schema-validated JSON table (malformed/stale/version-mismatch
-    #: refuses loudly, naming the field) and re-serializes it ATOMICALLY
-    #: after every decision, so learned routes survive restarts — the
-    #: committed ``.autotune_table.json`` is the repo's warm-start
-    #: convention (copy it aside before pointing a mutating run at it,
-    #: like ``.bench_history.jsonl``). Empty (default): in-memory only.
-    autotune_table: str = ""
-    #: Relax-comfort threshold (``DLAF_AUTOTUNE_MARGIN``): a probe with
-    #: ``bound_ratio <= margin`` counts toward relaxing one rung; ratios
-    #: in (margin, 1] hold the route (and reset the comfortable streak —
-    #: the documented hysteresis band, docs/autotune.md).
-    autotune_margin: float = 0.25
-    #: Consecutive comfortable probes required before the route relaxes
-    #: one rung toward the fast end (``DLAF_AUTOTUNE_RELAX_AFTER``) —
-    #: escalation on a breach is always immediate.
-    autotune_relax_after: int = 3
-    #: Probe cadence (``DLAF_AUTOTUNE_PROBE_EVERY``): the algorithm
-    #: entries run the Hutchinson probe on every K-th call per table
-    #: entry (the first call always probes). The probe is O(n^2 k)
-    #: against the factorization's O(n^3) — negligible at production
-    #: sizes, measurable at toy ones — so latency-sensitive deployments
-    #: amortize it here. Un-probed calls still apply the learned route;
-    #: the serve queue's per-dispatch residuals (already gated on
-    #: ``DLAF_ACCURACY``) ignore this cadence.
-    autotune_probe_every: int = 1
-    #: Per-site relax budget per process run (``DLAF_AUTOTUNE_BUDGET``):
-    #: at most this many relax route changes per table entry, bounding
-    #: route churn (each change is a new compiled program). Escalations
-    #: are NEVER budget-limited — safety moves always run. 0 = unbounded.
-    autotune_budget: int = 16
     #: Bucket ceilings of the serving layer (``DLAF_SERVE_BUCKETS``,
     #: docs/serving.md): a comma-separated ascending list of matrix sizes
     #: (e.g. "32,64,128") that :class:`dlaf_tpu.serve.Queue` rounds
@@ -734,7 +685,6 @@ _VALID_CHOICES = {
     "bcast_impl": ("psum", "tree"),
     "log": ("debug", "info", "warning", "error", "off"),
     "accuracy": ("0", "1", "full"),
-    "autotune": ("0", "1", "auto"),
 }
 
 
@@ -816,21 +766,6 @@ def _validate(cfg: Configuration) -> None:
     if not cfg.circuit_cooldown_s >= 0:
         raise ValueError(f"circuit_cooldown_s={cfg.circuit_cooldown_s}: "
                          "must be >= 0 (open -> half-open probe delay)")
-    if not 0 < cfg.autotune_margin <= 1:
-        raise ValueError(f"autotune_margin={cfg.autotune_margin}: must be "
-                         "in (0, 1] (the relax-comfort bound_ratio "
-                         "threshold; 1 would erase the hysteresis band)")
-    if cfg.autotune_relax_after < 1:
-        raise ValueError(f"autotune_relax_after={cfg.autotune_relax_after}:"
-                         " must be >= 1 (consecutive comfortable probes "
-                         "before a relax)")
-    if cfg.autotune_probe_every < 1:
-        raise ValueError(f"autotune_probe_every="
-                         f"{cfg.autotune_probe_every}: must be >= 1 "
-                         "(probe every K-th entry call per site)")
-    if cfg.autotune_budget < 0:
-        raise ValueError(f"autotune_budget={cfg.autotune_budget}: must be "
-                         ">= 0 (0 = unbounded per-site relax budget)")
     parse_serve_buckets(cfg.serve_buckets)   # raises on a malformed list
     # cholesky_trailing is validated against VALID_TRAILING at the use site
     # (algorithms/cholesky.py) to keep the list next to the implementations
@@ -988,24 +923,9 @@ def resolved_f64_gemm() -> str:
                "2026-08-01 v5e session")
 
 
-def _route_override(field: str):
-    """The active autotune route's override for ``field`` (None =
-    inherit the ordinary resolution) — docs/autotune.md. Consulted by
-    the knob resolvers whose decisions the autotuner steers; every
-    program cache on such a path carries the route in its cache key
-    (dlaf_tpu.autotune.routes module docstring)."""
-    from .autotune.routes import override
-
-    return override(field)
-
-
 def resolved_f64_trsm() -> str:
     """``f64_trsm`` with "auto" resolved: mixed on TPU, native elsewhere
-    (see the knob docstring for the measurement basis). An active
-    autotune route (docs/autotune.md) overrides the resolution."""
-    routed = _route_override("f64_trsm")
-    if routed is not None:
-        return routed
+    (see the knob docstring for the measurement basis)."""
     return resolve_platform_auto(
         get_configuration().f64_trsm, knob="f64_trsm",
         tpu_choice="mixed", other_choice="native",
@@ -1017,11 +937,7 @@ def resolved_panel_impl() -> str:
     """``panel_impl`` with "auto" resolved: fused on TPU, xla elsewhere
     (platform leg only — the dtype/block-size leg lives in
     ``tile_ops.pallas_panel.panel_uses_fused``, the route's single
-    owner). An active autotune route (docs/autotune.md) overrides the
-    resolution."""
-    routed = _route_override("panel_impl")
-    if routed is not None:
-        return routed
+    owner)."""
     return resolve_platform_auto(
         get_configuration().panel_impl, knob="panel_impl",
         tpu_choice="fused", other_choice="xla",
@@ -1035,11 +951,7 @@ def resolved_step_impl() -> str:
     """``step_impl`` with "auto" resolved: fused on TPU, xla elsewhere
     (platform leg only — the dtype/block/VMEM-budget legs live in
     ``tile_ops.pallas_panel.step_uses_fused``, the route's single
-    owner). An active autotune route (docs/autotune.md) overrides the
-    resolution."""
-    routed = _route_override("step_impl")
-    if routed is not None:
-        return routed
+    owner)."""
     return resolve_platform_auto(
         get_configuration().step_impl, knob="step_impl",
         tpu_choice="fused", other_choice="xla",

@@ -318,9 +318,8 @@ def bt_band_to_tridiag(tri: TridiagResult, evecs):
 
 
 @register_program_cache
-@functools.partial(jax.jit, static_argnames=("nb", "la", "route"))
-def _bt_r2b_local(a_v, taus, e, *, nb: int, la: bool = False,
-                  route: tuple = ()):
+@functools.partial(jax.jit, static_argnames=("nb", "la"))
+def _bt_r2b_local(a_v, taus, e, *, nb: int, la: bool = False):
     """C <- (I - V T V^H) C per reflector block, reverse order.
 
     ``la`` (``bt_lookahead=1``, docs/eigensolver_perf.md): the next
@@ -558,11 +557,7 @@ def _build_dist_bt_r2b_scan(dist_a, dist_c, mesh, band, la: bool = False):
 
 @register_program_cache
 @functools.lru_cache(maxsize=32)
-def _dist_bt_r2b_cached(dist_a, dist_c, mesh, band, scan=False, la=False,
-                        route=()):
-    # ``route``: the eigensolver's active autotune route as a pure
-    # cache-key member (docs/autotune.md) — the bulk trmm/gemm
-    # application reads _oz_slices at trace time on the mxu path
+def _dist_bt_r2b_cached(dist_a, dist_c, mesh, band, scan=False, la=False):
     build = _build_dist_bt_r2b_scan if scan else _build_dist_bt_r2b
     return jax.jit(build(dist_a, dist_c, mesh, band, la=la))
 
@@ -580,7 +575,7 @@ def _bt_r2b_entry_span(red: BandReduction, n: int, m: int, la: bool,
         band=red.band, dtype=dt.name, bt_lookahead=int(la), grid=grid))
 
 
-def bt_reduction_to_band(red: BandReduction, evecs, *, route: tuple = ()):
+def bt_reduction_to_band(red: BandReduction, evecs):
     """Eigenvectors of the ORIGINAL matrix from eigenvectors of the band
     matrix: apply the panel reflector blocks in reverse order.
 
@@ -616,7 +611,7 @@ def bt_reduction_to_band(red: BandReduction, evecs, *, route: tuple = ()):
         fn = _dist_bt_r2b_cached(a.dist, evecs.dist, a.grid.mesh, red.band,
                                  scan=resolve_step_mode(max(
                                      -(-a.size.row // red.band) - 1, 1))
-                                 == "scan", la=la, route=route)
+                                 == "scan", la=la)
         with _bt_r2b_entry_span(
                 red, a.size.row, evecs.size.col, la,
                 f"{a.dist.grid_size.row}x{a.dist.grid_size.col}"):
@@ -640,7 +635,7 @@ def bt_reduction_to_band(red: BandReduction, evecs, *, route: tuple = ()):
         out = obs.telemetry.call("bt_reduction_to_band.local",
                                  _bt_r2b_local, a_v,
                                  memory.as_device(red.taus), e, nb=red.band,
-                                 la=la, route=route)
+                                 la=la)
     if ret_matrix:
         return Matrix(evecs.dist, global_to_tiles(out, evecs.dist), evecs.grid)
     return out

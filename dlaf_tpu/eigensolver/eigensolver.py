@@ -99,37 +99,15 @@ def eigensolver(uplo: str, a: Matrix,
     # muls+adds; the five stage spans below nest under this one. The
     # pipeline-throughput knobs (docs/eigensolver_perf.md) ride along so
     # one span record says which trailing-stage formulation ran.
-    # accuracy-steered precision route (docs/autotune.md): one steering
-    # handle for the whole pipeline — the route is applied (and threaded
-    # as a cache-key member) around the route-sensitive device stages
-    # (reduction_to_band, bt_reduction_to_band); the host chase and the
-    # D&C tridiag programs keep the config route (their caches are not
-    # route-keyed — documented scope, docs/autotune.md §threading)
-    from .. import autotune
-
-    steer = autotune.steering_for_matrix("eigensolver", a)
-    route = steer.route.key() if steer is not None else ()
     pipeline_span = obs.entry_span("eigensolver", lambda: dict(
         flops=total_ops(np.dtype(a.dtype), 5 * n**3 / 3, 5 * n**3 / 3),
         n=n, nb=nb, uplo=uplo, dtype=np.dtype(a.dtype).name,
         dc_level_batch=int(resolved_dc_level_batch()),
         bt_lookahead=int(resolved_bt_lookahead()),
-        **({"autotune_route": dict(route)} if route else {}),
         grid=f"{a.dist.grid_size.row}x{a.dist.grid_size.col}"))
     with pipeline_span:
-        result = _eigensolver_pipeline(uplo, a, pt, fence, distributed,
-                                       band_size, donate, n, nb, resume,
-                                       steer=steer, route=route)
-    if steer is not None and not donate and steer.probe_due:
-        # close the loop: the pipeline's cheap Hutchinson eigenpair
-        # residual (PR 8's estimator — no new device code) feeds the
-        # route table; donated inputs have nothing left to probe against
-        est = obs.accuracy.eigen_residuals(
-            uplo, a, result.eigenvalues, result.eigenvectors)
-        steer.observe(est["eigen_residual"], c=200.0,
-                      of=result.eigenvectors.storage,
-                      attrs={"entry": "eigensolver", "uplo": uplo})
-    return result
+        return _eigensolver_pipeline(uplo, a, pt, fence, distributed,
+                                     band_size, donate, n, nb, resume)
 
 
 def _stage_fingerprint(uplo, a, band_size, n, nb) -> dict:
@@ -196,12 +174,9 @@ def _load_tri(arrays):
 
 
 def _eigensolver_pipeline(uplo, a, pt, fence, distributed, band_size,
-                          donate, n, nb, resume, steer=None, route=()):
-    from .. import autotune
+                          donate, n, nb, resume):
     from ..health import resume as hresume
     from ..matrix.checkpoint import matrix_arrays, matrix_from_arrays
-
-    _route = steer.route if steer is not None else None
 
     ck = hresume.stage_checkpointer(
         "eigensolver", _stage_fingerprint(uplo, a, band_size, n, nb),
@@ -215,13 +190,7 @@ def _eigensolver_pipeline(uplo, a, pt, fence, distributed, band_size,
             # it to the reduction (one full matrix off peak HBM either
             # way)
             ah = mops.hermitianize(a, uplo, donate=donate)
-            # route context + cache-key threading (docs/autotune.md):
-            # the trailing gemms read the routed slice count at trace
-            # time, so the route must be live for the trace AND a
-            # member of the builder's cache key
-            with autotune.applied(_route):
-                red = reduction_to_band(ah, band_size=band_size,
-                                        donate=True, route=route)
+            red = reduction_to_band(ah, band_size=band_size, donate=True)
             ck.commit("red2band", _pack_red(red))
         fence(red.matrix.storage)
     with pt.phase("stage.band_to_tridiag"):
@@ -270,8 +239,7 @@ def _eigensolver_pipeline(uplo, a, pt, fence, distributed, band_size,
         if ck.completed("bt_r2b"):
             vecs = matrix_from_arrays(ck.load("bt_r2b"), "vecs", a.grid)
         else:
-            with autotune.applied(_route):
-                out = bt_reduction_to_band(red, zb, route=route)
+            out = bt_reduction_to_band(red, zb)
             if distributed:
                 vecs = out
                 fence(vecs.storage)

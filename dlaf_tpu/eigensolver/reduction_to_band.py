@@ -127,9 +127,8 @@ def _map_row_chunks(fn, cw: int, *arrs):
 
 
 @register_program_cache
-@functools.partial(jax.jit, static_argnames=("nb", "route"),
-                   donate_argnums=0)
-def _red2band_local(a, *, nb: int, route: tuple = ()):
+@functools.partial(jax.jit, static_argnames=("nb",), donate_argnums=0)
+def _red2band_local(a, *, nb: int):
     """Panels of width ``nb`` = the target bandwidth (any 1 <= nb <= n; the
     reference's local variant likewise supports band_size < block size,
     ``reduction_to_band.h:78-87`` with ``mb % band_size == 0``)."""
@@ -169,9 +168,8 @@ def _red2band_local(a, *, nb: int, route: tuple = ()):
 
 
 @register_program_cache
-@functools.partial(jax.jit, static_argnames=("nb", "route"),
-                   donate_argnums=0)
-def _red2band_local_scan(a, *, nb: int, route: tuple = ()):
+@functools.partial(jax.jit, static_argnames=("nb",), donate_argnums=0)
+def _red2band_local_scan(a, *, nb: int):
     """``lax.scan`` form of the local reduction (``dist_step_mode="scan"``):
     one compiled panel step — the local unrolled trace costs ~19 s/panel
     on the hardware AOT toolchain and config #4's single-chip form is 127
@@ -611,10 +609,7 @@ def _build_dist_red2band_scan(dist, mesh, dtype, band):
 @register_program_cache
 @functools.lru_cache(maxsize=32)
 def _dist_red2band_cached(dist, mesh, dtype, band, scan=False, donate=False,
-                          comm_la=False, route=()):
-    # ``route``: the eigensolver's active autotune route as a pure
-    # cache-key member (docs/autotune.md) — the trailing gemms read
-    # _oz_slices at trace time on the mxu path
+                          comm_la=False):
     if scan:
         # the scan body's W reads the whole trailing matrix every
         # iteration, so the panel gather cannot be hoisted across the
@@ -631,8 +626,7 @@ def _dist_red2band_cached(dist, mesh, dtype, band, scan=False, donate=False,
 # ---------------------------------------------------------------------------
 
 def reduction_to_band(a: Matrix, band_size: int | None = None, *,
-                      donate: bool = False,
-                      route: tuple = ()) -> BandReduction:
+                      donate: bool = False) -> BandReduction:
     """Reduce Hermitian ``a`` (FULL storage — both triangles) to band form.
 
     ``band_size`` (default: block size) sets the bandwidth; it must divide
@@ -676,11 +670,10 @@ def reduction_to_band(a: Matrix, band_size: int | None = None, *,
             if resolve_step_mode(steps) == "scan":
                 out, taus = obs.telemetry.call(
                     "reduction_to_band.local_scan", _red2band_local_scan,
-                    g, nb=band, route=route)
+                    g, nb=band)
             else:
                 out, taus = obs.telemetry.call(
-                    "reduction_to_band.local", _red2band_local, g, nb=band,
-                    route=route)
+                    "reduction_to_band.local", _red2band_local, g, nb=band)
             return BandReduction(
                 a.with_storage(global_to_tiles_donated(out, a.dist)),
                 taus, band)
@@ -696,7 +689,7 @@ def reduction_to_band(a: Matrix, band_size: int | None = None, *,
                                # (docs/comm_overlap.md); no compute-carry
                                # prerequisite here — the knob acts alone
                                comm_la=not scan_mode
-                               and resolved_comm_lookahead(), route=route)
+                               and resolved_comm_lookahead())
     with entry_span, quiet_donation():
         storage, taus = obs.telemetry.call("reduction_to_band.dist", fn,
                                            a.storage)

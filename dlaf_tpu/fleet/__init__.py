@@ -5,8 +5,8 @@ The jump from "a server" to "a service": a :class:`~.router.Router`
 front tier shards bucketed requests across N :class:`~.worker.
 FleetWorker` replicas — each one the existing single-process serve
 stack (``serve.Queue`` + ``ProgramService``), warm-started from the
-persistent compile cache and the committed autotune table — over the
-zero-new-deps length-prefixed-JSON transport of :mod:`.transport`.
+persistent compile cache — over the zero-new-deps
+length-prefixed-JSON transport of :mod:`.transport`.
 
 Robustness contract (the headline, docs/fleet.md):
 

@@ -3,10 +3,10 @@
 A :class:`FleetWorker` wraps the existing per-replica stack — one
 :class:`~dlaf_tpu.serve.queue.Queue` over one
 :class:`~dlaf_tpu.serve.programs.ProgramService`, warm-started from the
-jax persistent compile cache (placed by ``config.initialize()``) and the
-committed autotune table (``DLAF_AUTOTUNE_TABLE``) exactly like a
-single-process server — and speaks the length-prefixed JSON protocol of
-:mod:`.transport` back to the router over one connect-back socket.
+jax persistent compile cache (placed by ``config.initialize()``) exactly
+like a single-process server — and speaks the length-prefixed JSON
+protocol of :mod:`.transport` back to the router over one connect-back
+socket.
 
 The protocol loop is deliberately SINGLE-THREADED: a wedged dispatch
 blocks the pong too, so the router's heartbeat timeout observes real

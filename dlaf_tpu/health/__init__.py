@@ -24,8 +24,8 @@ from __future__ import annotations
 
 from . import circuit, info, inject, policy, registry  # noqa: F401
 from .circuit import CIRCUIT_GAUGE, CircuitBreaker, breaker  # noqa: F401
-from .errors import (AutotuneExhaustedError, CheckError,  # noqa: F401
-                     CircuitOpenError, DeadlineExceededError,
+from .errors import (CheckError, CircuitOpenError,  # noqa: F401
+                     DeadlineExceededError,
                      DegradationError, FactorizationError, HealthError,
                      OverloadError, PreemptionError, ResumeError)
 from .info import matrix_diag_info  # noqa: F401
@@ -35,7 +35,6 @@ from .registry import (FALLBACK_COUNTER, report_fallback, route_available,  # no
                        run_with_fallback, strict_mode)
 
 __all__ = [
-    "AutotuneExhaustedError",
     "CheckError", "CircuitBreaker", "CircuitOpenError",
     "DeadlineExceededError", "DegradationError", "FactorizationError",
     "HealthError", "OverloadError", "PreemptionError", "ResumeError",

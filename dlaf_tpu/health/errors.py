@@ -165,37 +165,6 @@ class ResumeError(HealthError):
         super().__init__(f"cannot resume{where}: {self.detail}")
 
 
-class AutotuneExhaustedError(HealthError):
-    """An accuracy probe breached its analytic budget at the TOP rung of
-    an autotune precision ladder (:mod:`dlaf_tpu.autotune`,
-    docs/autotune.md): every safer route has already been tried and the
-    numbers are still out of budget. Raised under ``DLAF_STRICT``
-    (non-strict deployments hold at the top rung, count
-    ``dlaf_autotune_exhausted_total``, and dump the flight recorder —
-    the validator's ``--require-autotune`` rejects the open state).
-
-    Attributes:
-        site: the route-table key label (op.nN.nbN.dtype.platform).
-        rung: the (top) rung the ladder is pinned at.
-        ladder: the ladder's name (e.g. "f64").
-        bound_ratio: the breaching probe's normalized ratio (inf for a
-            non-finite estimate).
-    """
-
-    def __init__(self, site: str, *, rung: int, ladder: str,
-                 bound_ratio: float):
-        self.site = str(site)
-        self.rung = int(rung)
-        self.ladder = str(ladder)
-        self.bound_ratio = float(bound_ratio)
-        super().__init__(
-            f"autotune ladder exhausted at {self.site!r}: probe "
-            f"bound_ratio {self.bound_ratio!r} breached the budget at "
-            f"the top rung ({self.rung}) of the {self.ladder!r} ladder "
-            "— no safer precision route exists (DLAF_STRICT=1 raises; "
-            "see docs/autotune.md)")
-
-
 class DrainedError(HealthError):
     """A queued request was drained undispatched (:meth:`dlaf_tpu.serve.
     queue.Queue.drain` — graceful worker shutdown, docs/fleet.md). The

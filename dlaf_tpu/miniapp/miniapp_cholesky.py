@@ -137,20 +137,11 @@ def _timed_runs(args, opts, ref, ptimer, backend, threads, results):
             # Checked runs skip this: check_cholesky runs the identical
             # probe and emits the record itself.
             value = accuracy.cholesky_residual(args.uplo, ref, out)
-            res = accuracy.emit(
+            accuracy.emit(
                 "miniapp_cholesky", "cholesky_residual", value, n=n, nb=nb,
                 c=60.0, dtype=opts.dtype, of=out.storage,
                 attrs={"uplo": args.uplo, "run": run_i,
                        "grid": f"{opts.grid_rows}x{opts.grid_cols}"})
-            # donated-entry autotune feed (docs/autotune.md): the timed
-            # run donated its input, so the entry could not probe — this
-            # probe against the kept reference closes the loop instead
-            from .. import autotune
-
-            autotune.ingest_result("cholesky", res, n=n, nb=nb,
-                                   dtype=opts.dtype,
-                                   attrs={"entry": "miniapp_cholesky",
-                                          "run": run_i})
         if checked:
             check_cholesky(args.uplo, ref, out)
     # land the counters (collective bytes, tile ops, span histograms) in
@@ -176,15 +167,6 @@ def check_cholesky(uplo: str, ref: Matrix, out: Matrix) -> None:
         "miniapp_cholesky", "cholesky_residual", resid, n=n,
         nb=ref.block_size.row, c=60.0, dtype=ref.dtype, of=out.storage,
         attrs={"uplo": uplo, "check": True})
-    from .. import autotune
-
-    # donated-entry autotune feed (docs/autotune.md): checked runs
-    # compute this residual anyway — ingest it so miniapp streams steer
-    # even though the timed factorization donated its input
-    autotune.ingest_result("cholesky", res, n=n, nb=ref.block_size.row,
-                           dtype=ref.dtype,
-                           attrs={"entry": "miniapp_cholesky",
-                                  "check": True})
     status = "PASSED" if res.passed else "FAILED"
     print(f"check: {status} residual={resid:.3e} tol={res.tol:.3e}{res.eps_label}", flush=True)
     if not res.passed:

@@ -243,70 +243,6 @@ def format_accuracy_table(rows, top_n: int = 25) -> list:
     return lines
 
 
-def autotune_rows(records) -> list:
-    """Per route-table site: the ordered decision trail from the merged
-    ``autotune`` records (docs/autotune.md) — escalations/exhaustions
-    first, then by decision count, so the sites the loop actually moved
-    (or failed) top the section."""
-    per: dict = {}
-    for r in records:
-        if r.get("type") != "autotune":
-            continue
-        site = r.get("site", "?")
-        cell = per.setdefault(site, {"decisions": [], "escalations": 0,
-                                     "exhausted": 0, "moves": 0})
-        cell["decisions"].append(r)
-        reason = r.get("reason")
-        if reason == "escalate":
-            cell["escalations"] += 1
-        if reason == "exhausted":
-            cell["exhausted"] += 1
-        if reason in ("escalate", "relax"):
-            cell["moves"] += 1
-    rows = []
-    for site, cell in per.items():
-        last = cell["decisions"][-1]
-        rows.append({"site": site, "decisions": cell["decisions"],
-                     "count": len(cell["decisions"]),
-                     "escalations": cell["escalations"],
-                     "exhausted": cell["exhausted"],
-                     "moves": cell["moves"],
-                     "final_rung": last.get("rung_new"),
-                     "final_reason": last.get("reason"),
-                     "final_route": last.get("route_new")})
-    rows.sort(key=lambda row: (-row["exhausted"], -row["escalations"],
-                               -row["count"], row["site"]))
-    return rows
-
-
-def format_autotune_trail(rows, top_n: int = 10,
-                          trail_n: int = 6) -> list:
-    """Printable lines for the autotune decision-trail section (shared
-    with ``scripts/profile_summary.py`` — single owner, not a fork):
-    one summary line per site plus its last ``trail_n`` decisions."""
-    lines = []
-    for row in rows[:top_n]:
-        flag = "  !! EXHAUSTED" if row["exhausted"] else ""
-        route = row["final_route"] or {}
-        route_s = " ".join(f"{k}={v}" for k, v in sorted(route.items())) \
-            or "default"
-        lines.append(
-            "%s: %d decision(s), %d move(s), %d escalation(s); final "
-            "rung %s (%s) via %s%s"
-            % (row["site"], row["count"], row["moves"],
-               row["escalations"], row["final_rung"], route_s,
-               row["final_reason"], flag))
-        for r in row["decisions"][-trail_n:]:
-            probe = ("NONFINITE" if r.get("nonfinite")
-                     else ("%.3g" % r["probe"]
-                           if isinstance(r.get("probe"), (int, float))
-                           else "-"))
-            lines.append("  %-9s rung %s -> %s  probe %s"
-                         % (r.get("reason"), r.get("rung_old"),
-                            r.get("rung_new"), probe))
-    return lines
-
-
 #: Waterfall stage order: queue wait from the request record, then the
 #: dispatch record's ``stages`` object (serve/queue.py emits them).
 WATERFALL_STAGES = (("queue wait", None), ("compose", "compose_s"),
@@ -706,12 +642,6 @@ def main(argv=None) -> int:
         print("\n== accuracy (worst bound_ratio per rank; docs/accuracy.md)"
               " ==")
         for line in format_accuracy_table(acc, top_n):
-            print(f"  {line}")
-
-    atn = autotune_rows(view)
-    if atn:
-        print("\n== autotune decision trail (docs/autotune.md) ==")
-        for line in format_autotune_trail(atn, top_n):
             print(f"  {line}")
 
     imb = collective_imbalance(view)

@@ -26,9 +26,6 @@ schema owner) and their call sites:
   its payload (obs/exporter.py);
 * ``slo_breach_burst`` — >= ``DLAF_SLO_BURST`` over-objective latencies
   inside one rolling SLO window for one op (obs/slo.py, ISSUE 14);
-* ``autotune_exhausted`` — an accuracy probe breached the budget at the
-  TOP rung of a precision ladder: no safer route exists
-  (autotune/controller.py, ISSUE 15; docs/autotune.md);
 * ``fleet_worker_down`` — the fleet router reaped a dead replica still
   holding unacknowledged tickets (fleet/router.py, ISSUE 18;
   docs/fleet.md) — the ring captures the routing decisions that led

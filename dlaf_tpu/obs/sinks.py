@@ -90,25 +90,6 @@ Schema (version 1). Every record carries ``v`` (int schema version),
     AND >= 1 ordinary record after it (an incident dump with no
     pre-trigger context captured nothing worth gating on).
 
-``autotune``
-    One precision-route decision (:mod:`dlaf_tpu.autotune`, the
-    ``DLAF_AUTOTUNE`` knob; docs/autotune.md): ``site`` non-empty str
-    (the route-table key label), ``op``/``dtype``/``platform`` non-empty
-    strs, ``n_bucket``/``nb`` non-negative ints, ``reason`` one of
-    :data:`AUTOTUNE_REASONS`, ``rung_old``/``rung_new`` non-negative
-    ints consistent with the reason (``escalate``: new > old;
-    ``relax``: new < old; ``hold``/``exhausted``: new == old),
-    ``route_old``/``route_new`` objects (the knob overrides in effect),
-    ``probe`` finite >= 0 — or null with ``nonfinite: true`` (a
-    corrupted estimate, treated as a breach) — and ``attrs`` object.
-    The ``--require-autotune`` CI obligation: >= 1 ``escalate`` or
-    ``relax`` decision (the loop actually moved a route — a hold-only
-    artifact proves nothing about closure), and NO site whose LAST
-    decision is ``exhausted`` — an artifact that ends with a ladder
-    pinned at its top under a breach is an open incident and must fail
-    the gate, exactly like an open breaker under
-    ``--require-resilience``.
-
 ``devtrace``
     Device-timeline attribution summary (:mod:`dlaf_tpu.obs.devtrace`,
     ISSUE 14; docs/observability.md device-time attribution): ``trace``
@@ -174,8 +155,8 @@ SCHEMA_VERSION = 1
 
 KNOWN_TYPES = ("span", "metrics", "log", "bench_result", "program",
                "accuracy", "serve", "resilience", "flight_trigger",
-               "devtrace", "measured_overlap", "autotune",
-               "schedule", "critpath", "whatif", "fleet")
+               "devtrace", "measured_overlap", "schedule", "critpath",
+               "whatif", "fleet")
 
 #: Documented attribution-coverage floor of ``--require-devtrace``
 #: (docs/observability.md device-time attribution): a devtrace record
@@ -212,7 +193,7 @@ RESILIENCE_EVENTS = ("retry", "give_up", "deadline", "circuit_open",
 FLIGHT_REASONS = ("breaker_open", "overload_shed",
                   "factorization_exhausted", "accuracy_breach",
                   "healthz_failure", "slo_breach_burst",
-                  "autotune_exhausted", "fleet_worker_down")
+                  "fleet_worker_down")
 
 #: The fleet record's event vocabulary (docs/fleet.md; emitted by
 #: :class:`dlaf_tpu.fleet.router.Router` — the router is the ONLY
@@ -222,10 +203,6 @@ FLIGHT_REASONS = ("breaker_open", "overload_shed",
 FLEET_EVENTS = ("route", "redispatch", "handback", "worker_up",
                 "worker_dead", "heartbeat_timeout", "draining",
                 "drained", "probe", "ticket_lost")
-
-#: The autotune decision vocabulary (docs/autotune.md; decision core in
-#: :func:`dlaf_tpu.autotune.table.decide`).
-AUTOTUNE_REASONS = ("escalate", "relax", "hold", "exhausted")
 
 
 def expand_rank_template(path: str) -> str:
@@ -679,48 +656,6 @@ def _validate_whatif(r: dict, where: str, errors: list) -> None:
                       f"[0, 100], got {pct!r}")
 
 
-def _validate_autotune(r: dict, where: str, errors: list) -> None:
-    for key in ("site", "op", "dtype", "platform"):
-        if not isinstance(r.get(key), str) or not r.get(key):
-            errors.append(f"{where}: autotune record without a {key}")
-    for key in ("n_bucket", "nb", "rung_old", "rung_new"):
-        if not isinstance(r.get(key), int) or isinstance(r.get(key), bool) \
-                or r.get(key, -1) < 0:
-            errors.append(f"{where}: autotune {key} must be a non-negative "
-                          "int")
-    reason = r.get("reason")
-    if reason not in AUTOTUNE_REASONS:
-        errors.append(f"{where}: autotune reason must be one of "
-                      f"{AUTOTUNE_REASONS}, got {reason!r}")
-    old, new = r.get("rung_old"), r.get("rung_new")
-    if isinstance(old, int) and isinstance(new, int):
-        # a record whose rung transition contradicts its reason would let
-        # a decision trail lie about what the controller actually did
-        if reason == "escalate" and not new > old:
-            errors.append(f"{where}: autotune escalate must raise the "
-                          f"rung (old {old}, new {new})")
-        if reason == "relax" and not new < old:
-            errors.append(f"{where}: autotune relax must lower the rung "
-                          f"(old {old}, new {new})")
-        if reason in ("hold", "exhausted") and new != old:
-            errors.append(f"{where}: autotune {reason} must keep the "
-                          f"rung (old {old}, new {new})")
-    probe = r.get("probe")
-    if r.get("nonfinite") is True:
-        if probe is not None:
-            errors.append(f"{where}: nonfinite autotune record must carry "
-                          "probe null")
-    elif not _finite(probe) or probe < 0:
-        errors.append(f"{where}: autotune probe missing/non-finite/"
-                      "negative (use probe null + nonfinite true for "
-                      "corrupted estimates)")
-    for key in ("route_old", "route_new"):
-        if not isinstance(r.get(key), dict):
-            errors.append(f"{where}: autotune {key} must be an object")
-    if not isinstance(r.get("attrs", {}), dict):
-        errors.append(f"{where}: autotune attrs must be an object")
-
-
 def _validate_flight_trigger(r: dict, where: str, errors: list) -> None:
     if r.get("reason") not in FLIGHT_REASONS:
         errors.append(f"{where}: flight_trigger reason must be one of "
@@ -784,7 +719,7 @@ def validate_records(records, require_spans=False, require_gflops=False,
                      require_telemetry=False, require_accuracy=False,
                      require_serve=False, require_resilience=False,
                      require_flight=False, require_devtrace=False,
-                     require_autotune=False, require_critpath=False,
+                     require_critpath=False,
                      require_fleet=False) -> list:
     """Validate parsed records; returns a list of error strings (empty =
     valid). ``require_*`` add the CI smoke-tier artifact obligations:
@@ -838,12 +773,7 @@ def validate_records(records, require_spans=False, require_gflops=False,
     ``devtrace`` record with attribution coverage >=
     :data:`DEVTRACE_COVERAGE_FLOOR` (the schema validation above
     already rejects NaN phase walls unconditionally) — and
-    (``require_autotune``) the closed-loop precision-steering obligation
-    (docs/autotune.md): >= 1 ``autotune`` record with reason
-    ``escalate`` or ``relax`` (the loop actually moved a route), and NO
-    site whose LAST decision is ``exhausted`` (an artifact ending with
-    the ladder pinned at its top under a breach is an open incident and
-    must be REJECTED, like an open breaker) — and (``require_critpath``)
+    (``require_critpath``)
     the per-step critical-path attribution obligation (ISSUE 16,
     docs/observability.md): >= 1 ``critpath`` record with >= 1 step and
     join coverage >= :data:`CRITPATH_COVERAGE_FLOOR` (below the floor
@@ -867,11 +797,9 @@ def validate_records(records, require_spans=False, require_gflops=False,
     n_resilience_proof = 0
     n_flight_triggers = n_flight_context = 0
     n_overlap_proof = n_devtrace_covered = 0
-    n_autotune_moves = 0
     n_critpath_covered = n_whatif = 0
     n_fleet_routes = n_fleet_redispatch = n_fleet_lost = 0
     n_fleet_ungraceful_dead = 0
-    autotune_last = {}                # site -> last decision reason seen
     devtrace_coverages = []
     critpath_coverages = []
     circuit_state = {}                # site -> latest gauge value seen
@@ -940,15 +868,6 @@ def validate_records(records, require_spans=False, require_gflops=False,
             elif event == "worker_dead" \
                     and (r.get("attrs") or {}).get("reason") != "drained":
                 n_fleet_ungraceful_dead += 1
-        elif rtype == "autotune":
-            _validate_autotune(r, where, errors)
-            if r.get("reason") in ("escalate", "relax"):
-                n_autotune_moves += 1
-            if isinstance(r.get("site"), str) \
-                    and r.get("reason") in AUTOTUNE_REASONS:
-                # records are ordered: this ends at each site's LAST
-                # decision — the state the run finished in
-                autotune_last[r["site"]] = r["reason"]
         elif rtype == "program":
             _validate_program(r, where, errors)
             if r.get("event") == "compile" and _finite(r.get("compile_s")):
@@ -1138,16 +1057,6 @@ def validate_records(records, require_spans=False, require_gflops=False,
             errors.append("artifact contains no whatif projection record "
                           "(critpath attribution produced no headroom "
                           "ranking)")
-    if require_autotune:
-        if n_autotune_moves == 0:
-            errors.append("artifact contains no autotune escalate/relax "
-                          "decision record (the closed loop never moved "
-                          "a route)")
-        exhausted = sorted(s for s, reason in autotune_last.items()
-                           if reason == "exhausted")
-        if exhausted:
-            errors.append("autotune ladder(s) left exhausted at artifact "
-                          f"end (last decision 'exhausted'): {exhausted}")
     if require_fleet:
         if n_fleet_routes == 0:
             errors.append("artifact contains no fleet route record (the "

@@ -59,15 +59,6 @@ Flags:
                             operations): >= 1 flight_trigger record with
                             a known reason AND >= 1 ordinary pre-trigger
                             record captured by the ring
-    --require-autotune      fail unless the artifact carries the
-                            closed-loop precision-steering trail
-                            (DLAF_AUTOTUNE, docs/autotune.md): >= 1
-                            autotune record with reason escalate|relax
-                            (the loop actually moved a route), and no
-                            site whose LAST decision is 'exhausted' —
-                            an artifact ending with the ladder pinned
-                            at its top under a breach is an open
-                            incident and must be REJECTED
     --require-devtrace      fail unless the artifact carries the
                             device-timeline attribution trail (ISSUE 14,
                             docs/observability.md): >= 1 measured_overlap
@@ -133,9 +124,9 @@ def main(argv=None) -> int:
              "--require-bt-overlap", "--require-telemetry",
              "--require-accuracy", "--require-serve",
              "--require-resilience", "--require-flight",
-             "--require-devtrace", "--require-autotune",
-             "--require-critpath", "--require-fleet", "--history",
-             "--accuracy-history", "--prom"}
+             "--require-devtrace", "--require-critpath",
+             "--require-fleet", "--history", "--accuracy-history",
+             "--prom"}
     requires = {f for f in flags if f.startswith("--require-")}
     history_modes = flags & {"--history", "--accuracy-history"}
     if len(paths) != 1 or flags - known \
@@ -173,7 +164,6 @@ def main(argv=None) -> int:
         require_resilience="--require-resilience" in flags,
         require_flight="--require-flight" in flags,
         require_devtrace="--require-devtrace" in flags,
-        require_autotune="--require-autotune" in flags,
         require_critpath="--require-critpath" in flags,
         require_fleet="--require-fleet" in flags)
     if errors:
@@ -189,7 +179,6 @@ def main(argv=None) -> int:
     n_flight = sum(r.get("type") == "flight_trigger" for r in records)
     n_devtrace = sum(r.get("type") in ("devtrace", "measured_overlap")
                      for r in records)
-    n_autotune = sum(r.get("type") == "autotune" for r in records)
     n_critpath = sum(r.get("type") in ("schedule", "critpath", "whatif")
                      for r in records)
     n_fleet = sum(r.get("type") == "fleet" for r in records)
@@ -201,7 +190,6 @@ def main(argv=None) -> int:
     extra += f", {n_res} resilience records" if n_res else ""
     extra += f", {n_flight} flight triggers" if n_flight else ""
     extra += f", {n_devtrace} devtrace records" if n_devtrace else ""
-    extra += f", {n_autotune} autotune decisions" if n_autotune else ""
     extra += f", {n_critpath} critpath records" if n_critpath else ""
     extra += f", {n_fleet} fleet records" if n_fleet else ""
     extra += f", ranks {ranks}" if ranks else ""

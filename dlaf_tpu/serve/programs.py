@@ -61,24 +61,14 @@ class ProgramSpec:
     nrhs: int = 0           # solve only: rhs free-axis width
     with_info: bool = True
     donate: bool = False
-    #: Active autotune route (``Route.key()`` tuple, docs/autotune.md):
-    #: a spec member, so a learned route change is a NEW bucket program
-    #: (a visible miss + compile) — never an in-place retrace of the old
-    #: one. The serve queue stamps it per bucket from the route table.
-    route: tuple = ()
 
     @property
     def site(self) -> str:
         """Per-bucket telemetry site label (bounded cardinality: one per
-        cached program; the route suffix adds at most one label per
-        ladder rung)."""
+        cached program)."""
         extra = (f".{self.side}{self.uplo}{self.transa}{self.diag}"
                  f".r{self.nrhs}" if self.op == "solve"
                  else f".{self.uplo}")
-        if self.route:
-            from ..autotune.routes import Route
-
-            extra += f".rt_{Route(**dict(self.route)).tag()}"
         return (f"serve.{self.op}.b{self.batch}n{self.n}nb{self.nb}"
                 f".{self.dtype}{extra}"
                 + (".info" if self.with_info else "")
@@ -86,49 +76,39 @@ class ProgramSpec:
 
     def to_wire(self) -> dict:
         """JSON-safe form (fleet warmup handoff, docs/fleet.md): every
-        field is already a JSON scalar except ``route``, whose
-        key/value pairs survive the list round-trip."""
-        doc = dataclasses.asdict(self)
-        doc["route"] = [list(pair) for pair in self.route]
-        return doc
+        field is a JSON scalar."""
+        return dataclasses.asdict(self)
 
     @classmethod
     def from_wire(cls, doc: dict) -> "ProgramSpec":
-        """Inverse of :meth:`to_wire` — restores the route pairs to the
-        tuples the (frozen, hashed) spec is keyed by, so a wire-round-
-        tripped spec is ``==`` to the original."""
-        doc = dict(doc)
-        doc["route"] = tuple(tuple(pair) for pair in doc.get("route", ()))
+        """Inverse of :meth:`to_wire`: a wire-round-tripped spec is
+        ``==`` to the original."""
         return cls(**doc)
 
 
 def cholesky_spec(*, batch: int, n: int, nb: int, dtype: str,
                   uplo: str = "L", with_info: bool = True,
-                  donate: bool = False, route: tuple = ()) -> ProgramSpec:
+                  donate: bool = False) -> ProgramSpec:
     return ProgramSpec(op="cholesky", batch=int(batch), n=int(n),
                        nb=int(nb), dtype=str(dtype), uplo=uplo,
-                       with_info=bool(with_info), donate=bool(donate),
-                       route=tuple(route))
+                       with_info=bool(with_info), donate=bool(donate))
 
 
 def solve_spec(*, batch: int, n: int, nrhs: int, nb: int, dtype: str,
                side: str = "L", uplo: str = "L", transa: str = "N",
                diag: str = "N", with_info: bool = True,
-               donate: bool = False, route: tuple = ()) -> ProgramSpec:
+               donate: bool = False) -> ProgramSpec:
     return ProgramSpec(op="solve", batch=int(batch), n=int(n), nb=int(nb),
                        dtype=str(dtype), uplo=uplo, side=side,
                        transa=transa, diag=diag, nrhs=int(nrhs),
-                       with_info=bool(with_info), donate=bool(donate),
-                       route=tuple(route))
+                       with_info=bool(with_info), donate=bool(donate))
 
 
 def eigh_spec(*, batch: int, n: int, nb: int, dtype: str, uplo: str = "L",
-              with_info: bool = True, donate: bool = False,
-              route: tuple = ()) -> ProgramSpec:
+              with_info: bool = True, donate: bool = False) -> ProgramSpec:
     return ProgramSpec(op="eigh", batch=int(batch), n=int(n), nb=int(nb),
                        dtype=str(dtype), uplo=uplo,
-                       with_info=bool(with_info), donate=bool(donate),
-                       route=tuple(route))
+                       with_info=bool(with_info), donate=bool(donate))
 
 
 def program_builder(spec: ProgramSpec):
@@ -247,17 +227,9 @@ class ProgramService:
     def _compile(self, spec: ProgramSpec) -> _Entry:
         import jax
 
-        from ..autotune.routes import Route, applied
-
         fn, args, donate = program_builder(spec)
         jitted = jax.jit(fn, donate_argnums=donate)
-        # the spec's autotune route must be LIVE while the program
-        # traces (the routed knobs are read at trace time) — warmup and
-        # miss compiles therefore bake the same route the spec is keyed
-        # by, wherever the compile happens (docs/autotune.md)
-        route = Route(**dict(spec.route)) if spec.route else None
-        with applied(route):
-            prog = obs.telemetry.aot_compile(spec.site, jitted, *args)
+        prog = obs.telemetry.aot_compile(spec.site, jitted, *args)
         self._stats["compiles"] += 1
         self._stats["compile_s"] += prog.compile_s
         return _Entry(compiled=prog.compiled,

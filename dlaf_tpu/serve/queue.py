@@ -389,12 +389,6 @@ _ACCURACY = {"cholesky": ("cholesky_residual", 60.0),
              "solve": ("trsm_residual", 60.0),
              "eigh": ("eigen_residual", 200.0)}
 
-#: serve op -> route-table op key (docs/autotune.md §serving): the
-#: serve buckets consult the SAME table entries the offline algorithm
-#: entries learn, so committed routes apply to batched traffic.
-_AUTOTUNE_OP = {"cholesky": "cholesky", "solve": "trsm",
-                "eigh": "eigensolver"}
-
 
 # ---------------------------------------------------------------------------
 # The queue
@@ -670,32 +664,20 @@ class Queue:
 
     # -- warmup sugar ----------------------------------------------------
 
-    def _steering(self, key: _BucketKey):
-        """The bucket's autotune steering handle (None = loop closed for
-        it): per-bucket route consultation against the SAME table the
-        algorithm entries learn (docs/autotune.md §serving)."""
-        from .. import autotune
-
-        return autotune.steering(_AUTOTUNE_OP[key.op], n=key.n,
-                                 nb=_default_nb(key.n), dtype=key.dtype)
-
     def _spec(self, key: _BucketKey):
-        steer = self._steering(key)
-        route = steer.route.key() if steer is not None else ()
         if key.op == "cholesky":
             return cholesky_spec(batch=self.batch, n=key.n,
                                  nb=_default_nb(key.n), dtype=key.dtype,
-                                 uplo=key.uplo, with_info=True, donate=True,
-                                 route=route)
+                                 uplo=key.uplo, with_info=True, donate=True)
         if key.op == "solve":
             return solve_spec(batch=self.batch, n=key.n, nrhs=key.nrhs,
                               nb=_default_nb(key.n), dtype=key.dtype,
                               side=key.side, uplo=key.uplo,
                               transa=key.transa, diag=key.diag,
-                              with_info=True, donate=True, route=route)
+                              with_info=True, donate=True)
         return eigh_spec(batch=self.batch, n=key.n, nb=_default_nb(key.n),
                          dtype=key.dtype, uplo=key.uplo, with_info=True,
-                         donate=True, route=route)
+                         donate=True)
 
     def warmup_specs(self, requests) -> tuple:
         """The exact ProgramSpecs a stream of ``requests`` will dispatch
@@ -714,10 +696,8 @@ class Queue:
             obs.gauge("dlaf_serve_depth", op=key.op,
                       bucket_n=key.n).set(0.0)
         self._in_flight += 1
-        observe = None
         try:
-            ran, observe = self._dispatch_lanes(key, lanes)
-            if ran:
+            if self._dispatch_lanes(key, lanes):
                 self._bucket_counts(key)["dispatches"] += 1
         except Exception as e:
             self._bucket_counts(key)["failures"] += 1
@@ -733,15 +713,6 @@ class Queue:
             raise
         finally:
             self._in_flight -= 1
-        if observe is not None:
-            # the autotune feedback runs AFTER the dispatch bookkeeping:
-            # the batch completed and its tickets are fulfilled, so a
-            # strict-mode AutotuneExhaustedError here must surface to
-            # the caller WITHOUT counting a failure or desyncing
-            # stats()['dispatches'] from the dispatch records (the
-            # /healthz agreement leg) — the dispatch did not fail, the
-            # accuracy budget did
-            observe()
 
     def _expire_lanes(self, key: _BucketKey, lanes: list, now: float
                       ) -> list:
@@ -771,15 +742,13 @@ class Queue:
                 live.append((req, ticket))
         return live
 
-    def _dispatch_lanes(self, key: _BucketKey, lanes: list):
-        """Returns ``(ran, observe)``: whether a program actually ran —
-        an all-expired batch does not count as a dispatch anywhere
-        (stats, records, metrics all stay consistent) — and the deferred
-        autotune-feedback thunk (None when the loop is closed), which
-        ``_dispatch`` runs after its own bookkeeping."""
+    def _dispatch_lanes(self, key: _BucketKey, lanes: list) -> bool:
+        """Returns whether a program actually ran — an all-expired batch
+        does not count as a dispatch anywhere (stats, records, metrics
+        all stay consistent)."""
         lanes = self._expire_lanes(key, lanes, self.clock())
         if not lanes:
-            return False, None  # everything expired: nothing to run
+            return False        # everything expired: nothing to run
         reqs = [r for r, _ in lanes]
         tickets = [t for _, t in lanes]
         spec = self._spec(key)
@@ -796,7 +765,7 @@ class Queue:
                                          resident, span_id)
 
     def _dispatch_traced(self, key: _BucketKey, reqs: list, tickets: list,
-                         spec, resident: bool, span_id: str):
+                         spec, resident: bool, span_id: str) -> bool:
         t0 = self.clock()
         # assemble the padded batch (host: request shapes are serve-small)
         a_batch = np.stack(
@@ -904,36 +873,7 @@ class Queue:
                         of=_lane_array(dev_outs),
                         attrs={"op": key.op, "rid": req.rid,
                                "bucket_n": key.n})
-        observe = None
-        if residuals is not None:
-            # close the loop for batched traffic (docs/autotune.md
-            # §serving): the dispatch's WORST real-lane residual feeds
-            # the bucket's route-table entry — one decision per
-            # dispatch, so a breaching batch escalates the bucket's
-            # route (the next dispatch compiles the safer program) and
-            # a comfortable steady state can relax it. DEFERRED to
-            # _dispatch (post-bookkeeping): a strict exhaustion raise
-            # is an accuracy incident, never a dispatch failure
-            steer = self._steering(key)
-            if steer is not None:
-                worst = residuals.max() if len(residuals) else 0.0
-                if not np.isfinite(residuals).all():
-                    worst = float("nan")
-                _, c = _ACCURACY[key.op]
-                member_ids = [t.trace_id for t in tickets]
-                of = _lane_array(dev_outs)
-
-                def observe():
-                    # re-enter the batch trace scope the decision
-                    # belongs to (the deferral left the context manager)
-                    with obs.trace_context(trace_id=member_ids,
-                                           span_id=span_id):
-                        steer.observe(
-                            worst, c=c, of=of,
-                            attrs={"source": "serve", "op": key.op,
-                                   "bucket_n": key.n,
-                                   "lanes": len(reqs)})
-        return True, observe
+        return True
 
     def _residuals(self, key, reqs, args, lane_outs):
         """Per-real-lane residual vector under DLAF_ACCURACY, else None

@@ -60,15 +60,9 @@ def _oz_slices() -> int:
     inherits the process tier — set the knob explicitly for that case.
     The auto resolution is announced once per (backend, count) on stderr
     so the tier in effect is never silent. See
-    Configuration.f64_gemm_slices. An active autotune route
-    (docs/autotune.md) overrides the whole resolution — read at trace
-    time, so every program cache on the mxu path carries the route in
-    its cache key."""
-    from ..config import _route_override, get_configuration
+    Configuration.f64_gemm_slices."""
+    from ..config import get_configuration
 
-    routed = _route_override("f64_gemm_slices")
-    if routed is not None:
-        return int(routed)
     s = int(get_configuration().f64_gemm_slices)
     if s:
         return s

@@ -655,10 +655,6 @@ def step_uses_fused(dtype, nb: int) -> bool:
       dtype/block or a VMEM-budget overflow registers through
       ``report_fallback(site="step")`` (counted, strict raises).
 
-    An autotune ROUTE override to "fused" binds only on TPU — the
-    ladder rung stays behavior-inert on CPU per the docs/autotune.md
-    ladder discipline — while explicit config ``step_impl=fused`` binds
-    everywhere (tests/CI use it in interpret mode).
     ``health.inject.disable_route("pallas")`` forces the gate closed;
     when that flips a would-be-True answer the degradation is counted
     at ``site="step"`` like every pallas route.
@@ -671,9 +667,6 @@ def step_uses_fused(dtype, nb: int) -> bool:
         return False
     cfg = get_configuration()
     explicit = cfg.step_impl == "fused"
-    if not explicit and jax.default_backend() != "tpu":
-        # route-override rung relaxing onto "fused" off-TPU: stay inert
-        return False
     supported = jnp.dtype(dtype) in _SUPPORTED and nb <= PANEL_MB_MAX
     need = step_vmem_bytes(nb, dtype)
     if not supported or need > cfg.step_vmem_limit:

@@ -41,13 +41,6 @@ analytic-budget leg, this gates a brand-new serve measurement before
 any history accumulates, and a committed serve history line keeps the
 floor enforced in every ``--replay``.
 
-``workload="autotune"`` lines (bench.py's accuracy-steered precision
-arm, ISSUE 15, docs/autotune.md) face the analogous history-free leg:
-their learned-table vs pinned-worst-case-route ``speedup`` field must
-be >= ``--min-autotune-speedup`` (default 0.5 — parity minus
-probe-per-call overhead on platforms where the ladder is inert; on TPU
-the learned routes sit well above 1).
-
 ``workload="fstep"`` lines (bench.py's fused-step A/B arm, ISSUE 19,
 docs/pallas_panel.md "Fused step kernel") face a history-free
 COMPLETENESS leg: the pair is the claim — when any fstep line is fresh,
@@ -153,18 +146,6 @@ def baselines(history, best_k: int) -> dict:
 
 DEFAULT_MIN_SERVE_SPEEDUP = 3.0
 
-#: History-free floor on the autotune arm's learned-table vs pinned-
-#: worst-case-route speedup (ISSUE 15): the learned routes must never
-#: cost more than this fraction of the conservative route's throughput.
-#: On CPU every ladder rung is behavior-inert, so the honest expectation
-#: is parity minus probe overhead — and at the arm's toy sizes the
-#: O(n^2 k) probe is a real fraction of the O(n^3) factor (measured
-#: ~0.7-0.8x at n=192-512 with probe-per-call; DLAF_AUTOTUNE_PROBE_EVERY
-#: amortizes it in production). 0.5 trips a pathological steering loop
-#: without tripping probe arithmetic; on TPU the learned routes are the
-#: whole point and sit well above 1.
-DEFAULT_MIN_AUTOTUNE_SPEEDUP = 0.5
-
 #: History-free floor on the fleet arm's N-replica vs 1-replica
 #: requests/s ratio (ISSUE 18, docs/fleet.md). The single-threaded
 #: router serializes every request onto the wire, so at the arm's toy
@@ -199,8 +180,6 @@ def _best_speedup_per_key(fresh, workload: str) -> dict:
 def run_gate(history, fresh, *, tolerance: float, min_history: int,
              best_k: int, log=print,
              min_serve_speedup: float = DEFAULT_MIN_SERVE_SPEEDUP,
-             min_autotune_speedup: float
-             = DEFAULT_MIN_AUTOTUNE_SPEEDUP,
              min_fleet_scaling: float = DEFAULT_MIN_FLEET_SCALING) -> int:
     """Compare fresh bests against history baselines; returns the number
     of regressed keys. Keys without fresh measurements are skipped (the
@@ -253,23 +232,9 @@ def run_gate(history, fresh, *, tolerance: float, min_history: int,
         else:
             log(f"OK         {fmt_key(key)}: batched-vs-singles speedup "
                 f"{s:.2f}x >= {min_serve_speedup:.1f}x")
-    # autotune-speedup floor (ISSUE 15, docs/autotune.md): the learned
-    # route table vs the pinned worst-case route (s=8 + native trsm) —
-    # history-free like the serve leg, so a first-round autotune
-    # measurement already gates
-    for key, s in sorted(_best_speedup_per_key(fresh, "autotune").items(),
-                         key=lambda kv: fmt_key(kv[0])):
-        if s < min_autotune_speedup:
-            regressions += 1
-            log(f"REGRESSION {fmt_key(key)}: learned-vs-pinned-worst "
-                f"speedup {s:.2f}x < {min_autotune_speedup:.2f}x "
-                "(ISSUE-15 autotune floor; history-free leg)")
-        else:
-            log(f"OK         {fmt_key(key)}: learned-vs-pinned-worst "
-                f"speedup {s:.2f}x >= {min_autotune_speedup:.2f}x")
     # fleet-scaling floor (ISSUE 18, docs/fleet.md): N replicas vs one
-    # through the same router — history-free like the serve/autotune
-    # legs, so a first-round fleet measurement already gates
+    # through the same router — history-free like the serve
+    # leg, so a first-round fleet measurement already gates
     for key, s in sorted(_best_speedup_per_key(fresh, "fleet").items(),
                          key=lambda kv: fmt_key(kv[0])):
         if s < min_fleet_scaling:
@@ -324,11 +289,6 @@ def main(argv=None) -> int:
                     default=DEFAULT_MIN_SERVE_SPEEDUP,
                     help="history-free floor on the serve arm's batched-"
                          "vs-singles speedup field (ISSUE 11: >= 3x)")
-    ap.add_argument("--min-autotune-speedup", type=float,
-                    default=DEFAULT_MIN_AUTOTUNE_SPEEDUP,
-                    help="history-free floor on the autotune arm's "
-                         "learned-table vs pinned-worst-case-route "
-                         "speedup field (ISSUE 15; docs/autotune.md)")
     ap.add_argument("--min-fleet-scaling", type=float,
                     default=DEFAULT_MIN_FLEET_SCALING,
                     help="history-free floor on the fleet arm's "
@@ -384,7 +344,6 @@ def main(argv=None) -> int:
                            min_history=args.min_history,
                            best_k=args.best_k,
                            min_serve_speedup=args.min_serve_speedup,
-                           min_autotune_speedup=args.min_autotune_speedup,
                            min_fleet_scaling=args.min_fleet_scaling)
     if regressions:
         print(f"bench_gate: {regressions} regressed key(s)",

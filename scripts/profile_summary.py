@@ -114,16 +114,6 @@ def summarize_jsonl(path: str, top_n: int) -> None:
         for line in format_accuracy_table(accuracy_rows(records), top_n):
             print(f"  {line}")
 
-    if any(r.get("type") == "autotune" for r in records):
-        # decision-trail rendering is obs.aggregate's — single owner,
-        # not a fork (docs/autotune.md)
-        from dlaf_tpu.obs.aggregate import (autotune_rows,
-                                            format_autotune_trail)
-
-        print("\n== autotune decision trail ==")
-        for line in format_autotune_trail(autotune_rows(records), top_n):
-            print(f"  {line}")
-
     serve = [r for r in records if r.get("type") == "serve"]
     resil = [r for r in records if r.get("type") == "resilience"]
     if serve or resil:

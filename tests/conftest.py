@@ -206,3 +206,21 @@ def devices8():
     devs = jax.devices()
     assert len(devs) == 8, f"expected 8 virtual devices, got {len(devs)}"
     return devs
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """Resolve every platform-keyed knob as a TPU process does, on this
+    CPU: ``jax.default_backend()`` answers "tpu" for the test. The knobs
+    are read at trace time and are not cache keys, so the registered
+    program caches are dropped on the way in and on the way out (a trace
+    made under one resolution must not serve the other)."""
+    import dlaf_tpu.config as C
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    C.initialize()
+    C._clear_program_caches()
+    yield
+    monkeypatch.undo()
+    C.initialize()
+    C._clear_program_caches()

@@ -68,17 +68,6 @@ def mesh22(topo):
                 ("row", "col"))
 
 
-@pytest.fixture
-def as_on_tpu(monkeypatch):
-    """Code that asks ``jax.default_backend()`` sees the CPU during such a
-    compile; steer it to the TPU route here, in the test."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    C.initialize()
-    yield
-    monkeypatch.undo()
-    C.initialize()
-
-
 def _compile(fn, *shapes):
     """Lower and compile for the described chip; return the program text."""
     return jax.jit(fn).lower(*shapes).compile().as_text()
@@ -195,7 +184,7 @@ def test_local_f32_cholesky_steps_compile(one_chip, as_on_tpu):
         jax.ShapeDtypeStruct((n, n), dt, sharding=one_chip), uplo="L",
         nb=nb, trailing=trailing, lookahead=C.resolved_cholesky_lookahead(),
         with_info=False, panel_fused=True, step_fused=True,
-        panel_interpret=False, route=None)
+        panel_interpret=False)
     text = lowered.compile().as_text()
     # one fused step kernel per strip-bearing step + the last tile's potrf
     assert _kernels_in(text) == 3

@@ -212,3 +212,32 @@ def test_cholesky_lookahead_knob(monkeypatch):
                 forget_once("config", key)
     finally:
         C.initialize(C.Configuration())
+
+
+def test_lower_layers_import_nothing_above():
+    """``config.py``, ``tile_ops/``, ``matrix/``, ``comm/`` and ``common/``
+    sit under the algorithms: none imports from ``algorithms``,
+    ``eigensolver``, ``serve``, ``fleet`` or ``miniapp`` (they still
+    report to ``obs`` and ``health``). One import is known and named as
+    debt (ROADMAP D15): ``tile_ops/lapack.py`` takes the host secular
+    solver from ``eigensolver/tridiag_solver.py``."""
+    import glob
+    import os
+    import re
+
+    root = os.path.dirname(os.path.abspath(C.__file__))
+    files = [os.path.join(root, "config.py")]
+    for layer in ("tile_ops", "matrix", "comm", "common"):
+        files += sorted(glob.glob(os.path.join(root, layer, "*.py")))
+    above = re.compile(r"^\s*(?:from|import)\s+(?:\.\.?|dlaf_tpu\.)"
+                       r"(algorithms|eigensolver|serve|fleet|miniapp)\b")
+    found = []
+    for path in files:
+        with open(path) as f:
+            for line in f:
+                if above.match(line):
+                    found.append((os.path.relpath(path, root),
+                                  line.strip()))
+    assert found == [(os.path.join("tile_ops", "lapack.py"),
+                      "from ..eigensolver.tridiag_solver import "
+                      "_secular_roots_host")], found
