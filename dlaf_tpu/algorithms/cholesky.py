@@ -47,8 +47,8 @@ from ..matrix.matrix import Matrix
 from ..matrix.panel import (DistContext, pad_diag_identity_dyn,
                             transpose_col_to_rows, transpose_row_to_cols,
                             uniform_slot_start)
-from ..matrix.tiling import (storage_tile_grid, global_to_tiles_donated,
-                             to_global, quiet_donation, donate_argnums_kw)
+from ..matrix.tiling import (storage_tile_grid, on_global, quiet_donation,
+                             donate_argnums_kw)
 from ..tile_ops import blas as tb
 from ..tile_ops import lapack as tl
 from ..tile_ops import mixed as mx
@@ -1719,6 +1719,25 @@ def _dist_cholesky_cached(dist, mesh, dtype, uplo, use_pallas,
 
 
 
+@register_program_cache
+@functools.lru_cache(maxsize=64)
+def _local_cholesky_cached(local, dist, donate, statics):
+    """The local branch's ONE program: tile storage in, the factor's tile
+    storage (and ``info``) out, the layout moves inside it
+    (``matrix/tiling.py:on_global``), so the matrix crosses no program
+    boundary between them. ``local`` is :func:`_cholesky_local` or
+    :func:`_cholesky_local_scan`, which inlines here, ``statics`` its
+    sorted static keyword arguments; ``donate`` is the caller's opt-in,
+    the hand-offs inside are the compiler's."""
+    kw = dict(statics)
+
+    def cholesky_local(a):
+        return local(a, **kw)
+
+    return jax.jit(on_global(cholesky_local, dist),
+                   **donate_argnums_kw(donate, 0))
+
+
 # ---------------------------------------------------------------------------
 # Public API (reference factorization/cholesky.h:36,62)
 # ---------------------------------------------------------------------------
@@ -1750,8 +1769,9 @@ def cholesky(uplo: str, mat: Matrix, *, donate: bool = False,
     This removes one full-matrix HBM buffer from the peak live set — the
     difference between fitting and OOM near the single-chip ceiling
     (N=16384 asked ~14-16 GB of 15.75 with all step forms pre-donation).
-    Internal stage hand-offs (layout transform -> factorization -> layout
-    transform) are always donated; they are owned by this function.
+    On one device the call is ONE program either way (layout transform ->
+    factorization -> layout transform inside it); ``donate`` only decides
+    whether that program may reuse ``mat``'s buffer.
     """
     dlaf_assert(uplo in ("L", "U"), f"cholesky: uplo must be 'L' or 'U', got {uplo!r}")
     from ..config import get_configuration, resolve_platform_auto
@@ -1812,43 +1832,37 @@ def cholesky(uplo: str, mat: Matrix, *, donate: bool = False,
     use_mxu = tb.f64_gemm_uses_mxu(dt, mat.block_size.row)
     use_mixed = tb.trsm_panel_uses_mixed(dt)
     if mat.grid is None or mat.grid.num_devices == 1:
-        # host phases (``stage.*``, unfenced: each brackets an async
-        # dispatch); on a profiler timeline they label the device's idle
-        # gaps between the three programs (docs/observability.md)
+        # off-TPU the fused panel kernels run in interpret mode (same
+        # convention as the pallas trailing kernels)
+        statics = dict(
+            uplo=uplo, nb=mat.block_size.row, lookahead=lookahead,
+            with_info=with_info, panel_fused=panel_fused,
+            step_fused=step_fused,
+            panel_interpret=(panel_fused or step_fused)
+            and jax.default_backend() != "tpu")
+        if trailing == "scan":
+            site, local = "cholesky.local_scan", _cholesky_local_scan
+            statics.update(use_mxu=use_mxu, use_mixed=use_mixed)
+        else:
+            site, local = "cholesky.local", _cholesky_local
+            statics.update(trailing=trailing)
+        fn = _local_cholesky_cached(local, mat.dist, donate,
+                                    tuple(sorted(statics.items())))
+        # ONE program a call, tile storage to tile storage; the host phase
+        # (``stage.*``, unfenced: it brackets an async dispatch) labels the
+        # device's idle gap before it on a profiler timeline; program
+        # telemetry (DLAF_PROGRAM_TELEMETRY): compile wall / retraces / HBM
+        # footprint per site, off = the same jitted callable
+        # (docs/observability.md)
         with entry_span, quiet_donation():
-            with obs.span("stage.cholesky.to_global", fenced=False):
-                a = to_global(mat.storage, mat.dist, donate)
-            # program telemetry (DLAF_PROGRAM_TELEMETRY): compile wall /
-            # retraces / HBM footprint per site; off = the same jitted
-            # callables, bitwise no-op (docs/observability.md)
-            # off-TPU the fused panel kernels run in interpret mode
-            # (same convention as the pallas trailing kernels)
-            panel_interp = jax.default_backend() != "tpu"
             with obs.span("stage.cholesky.factor", fenced=False):
-                if trailing == "scan":
-                    out = obs.telemetry.call(
-                        "cholesky.local_scan", _cholesky_local_scan, a,
-                        uplo=uplo, nb=mat.block_size.row, use_mxu=use_mxu,
-                        use_mixed=use_mixed, lookahead=lookahead,
-                        with_info=with_info, panel_fused=panel_fused,
-                        step_fused=step_fused,
-                        panel_interpret=(panel_fused or step_fused)
-                        and panel_interp)
-                else:
-                    out = obs.telemetry.call(
-                        "cholesky.local", _cholesky_local, a, uplo=uplo,
-                        nb=mat.block_size.row, trailing=trailing,
-                        lookahead=lookahead, with_info=with_info,
-                        panel_fused=panel_fused, step_fused=step_fused,
-                        panel_interpret=(panel_fused or step_fused)
-                        and panel_interp)
-            info = None
+                if obs.metrics_active():
+                    obs.counter("dlaf_entry_programs_total",
+                                entry="cholesky").inc()
+                out = obs.telemetry.call(site, fn, mat.storage)
             if with_info:
-                out, info = out
-            with obs.span("stage.cholesky.to_tiles", fenced=False):
-                res = mat.with_storage(
-                    global_to_tiles_donated(out, mat.dist))
-            return (res, info) if with_info else res
+                return mat.with_storage(out[0]), out[1]
+            return mat.with_storage(out)
     platform = next(iter(mat.grid.mesh.devices.flat)).platform
     # exact-flop predicated contraction (ozaki_impl="pallas"): real f64
     # only (complex keeps the 4-real-product composition), within the

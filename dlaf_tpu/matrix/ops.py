@@ -20,20 +20,16 @@ import jax.numpy as jnp
 
 from ..common.asserts import dlaf_assert
 from .matrix import Matrix
-from .tiling import (global_to_tiles, tiles_to_global,
+from .tiling import (global_to_tiles, tiles_to_global, on_global,
                      quiet_donation, donate_argnums_kw)
 
 
 def _global_op_jit(dist, sharding, fn, donate=False):
     """jit storage->storage running ``fn`` on the global view."""
-    def prog(storage):
-        g = tiles_to_global(storage, dist)
-        return global_to_tiles(fn(g), dist)
-
     kw = dict(donate_argnums_kw(donate, 0))
     if sharding is not None:
         kw.update(in_shardings=sharding, out_shardings=sharding)
-    return jax.jit(prog, **kw)
+    return jax.jit(on_global(fn, dist), **kw)
 
 
 @functools.lru_cache(maxsize=256)

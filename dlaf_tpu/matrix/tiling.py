@@ -17,7 +17,18 @@ zero-padded to full ``(mb, nb)``; ranks owning fewer tiles than the max get
 all-zero padding tiles.
 
 All transforms are pure jnp functions (jit-able, run on device). The
-permutations are trace-time constants derived from :class:`Distribution`.
+permutations are trace-time constants derived from :class:`Distribution`,
+so the transforms decide at trace time what they emit: an axis whose
+permutation is the identity gets no gather, and where both are (no padding
+slot can be referenced: every 1x1 grid, and any grid axis with one rank) no
+zero tile is appended either. On a 1x1 grid with ``m``, ``n`` whole
+multiples of the block the transform is ``reshape`` + ``transpose(0, 2, 1,
+3)`` and nothing else; every other distribution traces the gathers it
+always did. Values are the same bit for bit either way.
+
+:func:`on_global` lifts a function of the global array to one of tile
+storage, so that an entry point's local branch is ONE program (layout in,
+work, layout out) instead of three with an f64 hand-off between each.
 """
 
 from __future__ import annotations
@@ -66,6 +77,19 @@ def _axis_perm_inv(n_tiles: int, grid: int, src: int, lt: int) -> list[int]:
     return inv
 
 
+def _axes_in_order(dist: Distribution) -> tuple[bool, bool]:
+    """(rows, cols): True where storage order IS global tile order on that
+    axis: its permutation is the identity over exactly ``n_tiles`` slots,
+    so there is no padding slot and the inverse is the identity too."""
+    nt = dist.nr_tiles
+    _, _, ltr, ltc = storage_tile_grid(dist)
+    return tuple(
+        _axis_perm(n, grid, src, lt) == list(range(n))
+        for n, grid, src, lt in (
+            (nt.row, dist.grid_size.row, dist.source_rank.row, ltr),
+            (nt.col, dist.grid_size.col, dist.source_rank.col, ltc)))
+
+
 def global_to_tiles(a, dist: Distribution):
     """Global ``(m, n)`` array -> tile storage ``(P*ltr, Q*ltc, mb, nb)``."""
     m, n = dist.size.row, dist.size.col
@@ -80,14 +104,23 @@ def global_to_tiles(a, dist: Distribution):
         a = _memory.place(np.asarray(a))
     a = jnp.asarray(a)
     # pad to whole tiles, split into the (ntr, ntc, mb, nb) tile grid
-    a = jnp.pad(a, ((0, nt.row * mb - m), (0, nt.col * nb - n)))
+    if (nt.row * mb, nt.col * nb) != (m, n):
+        a = jnp.pad(a, ((0, nt.row * mb - m), (0, nt.col * nb - n)))
     t = a.reshape(nt.row, mb, nt.col, nb).transpose(0, 2, 1, 3)
-    # append one zero tile row/col as the target of padding slots, permute
-    t = jnp.pad(t, ((0, 1), (0, 1), (0, 0), (0, 0)))
-    pr = _axis_perm(nt.row, dist.grid_size.row, dist.source_rank.row, ltr)
-    pc = _axis_perm(nt.col, dist.grid_size.col, dist.source_rank.col, ltc)
-    t = jnp.take(t, jnp.array(pr, dtype=jnp.int32), axis=0)
-    t = jnp.take(t, jnp.array(pc, dtype=jnp.int32), axis=1)
+    keep_r, keep_c = _axes_in_order(dist)
+    if not (keep_r and keep_c):
+        # append one zero tile row/col as the target of padding slots, on
+        # the axes that have a permutation to apply
+        t = jnp.pad(t, ((0, int(not keep_r)), (0, int(not keep_c)),
+                        (0, 0), (0, 0)))
+    if not keep_r:
+        pr = _axis_perm(nt.row, dist.grid_size.row, dist.source_rank.row,
+                        ltr)
+        t = jnp.take(t, jnp.array(pr, dtype=jnp.int32), axis=0)
+    if not keep_c:
+        pc = _axis_perm(nt.col, dist.grid_size.col, dist.source_rank.col,
+                        ltc)
+        t = jnp.take(t, jnp.array(pc, dtype=jnp.int32), axis=1)
     assert t.shape == (Sr, Sc, mb, nb)
     return t
 
@@ -98,13 +131,36 @@ def tiles_to_global(t, dist: Distribution):
     mb, nb = dist.block_size.row, dist.block_size.col
     nt = dist.nr_tiles
     _, _, ltr, ltc = storage_tile_grid(dist)
-    pr = _axis_perm_inv(nt.row, dist.grid_size.row, dist.source_rank.row, ltr)
-    pc = _axis_perm_inv(nt.col, dist.grid_size.col, dist.source_rank.col, ltc)
+    keep_r, keep_c = _axes_in_order(dist)
     t = jnp.asarray(t)
-    t = jnp.take(t, jnp.array(pr, dtype=jnp.int32), axis=0)
-    t = jnp.take(t, jnp.array(pc, dtype=jnp.int32), axis=1)
+    if not keep_r:
+        pr = _axis_perm_inv(nt.row, dist.grid_size.row,
+                            dist.source_rank.row, ltr)
+        t = jnp.take(t, jnp.array(pr, dtype=jnp.int32), axis=0)
+    if not keep_c:
+        pc = _axis_perm_inv(nt.col, dist.grid_size.col,
+                            dist.source_rank.col, ltc)
+        t = jnp.take(t, jnp.array(pc, dtype=jnp.int32), axis=1)
     a = t.transpose(0, 2, 1, 3).reshape(nt.row * mb, nt.col * nb)
     return a[:m, :n]
+
+
+def on_global(fn, dist: Distribution):
+    """Lift ``fn``, a function of the global ``(m, n)`` array, to tile
+    storage: ``storage -> global_to_tiles(fn(tiles_to_global(storage)))``,
+    to be jitted by the caller as ONE program (the layout moves happen
+    inside it, once, next to the work). ``fn`` may return a tuple whose
+    first element is the global result; the rest (``info`` scalars) pass
+    through."""
+    def prog(storage):
+        out = fn(tiles_to_global(storage, dist))
+        if isinstance(out, tuple):
+            return (global_to_tiles(out[0], dist),) + out[1:]
+        return global_to_tiles(out, dist)
+
+    # the compiled program's name (``jit_<name>`` on a profiler timeline)
+    prog.__name__ = f"{getattr(fn, '__name__', 'fn')}_on_tiles"
+    return prog
 
 
 # Donated jit forms of the two layout transforms, shared by the algorithm

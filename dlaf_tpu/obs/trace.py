@@ -150,9 +150,14 @@ def entry_span(name: str, attrs_fn):
     lazily built attrs — ``attrs_fn`` is a zero-argument callable returning
     the attr dict (``flops`` allowed as a key) that is never invoked when
     observability is off, keeping flop models and attr strings off the
-    disabled path (the cost contract)."""
+    disabled path (the cost contract). While the metrics sink is on it
+    also counts the call, ``dlaf_entry_calls_total{entry}``: the base of
+    ``dlaf_entry_programs_total{entry}`` (device programs an entry
+    dispatches, counted at its dispatch sites)."""
     if not (STATE.metrics_on or STATE.annotate):
         return NOOP_SPAN
+    if STATE.metrics_on:
+        STATE.registry.counter("dlaf_entry_calls_total", entry=name).inc()
     kw = dict(attrs_fn())
     return Span(name, flops=kw.pop("flops", None), fenced=False, **kw)
 

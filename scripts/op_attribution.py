@@ -10,8 +10,9 @@ the chip), then reads the run's xplane once more with the benchmark's own
 readers: every ``XLA Ops`` event of the traced window is given to the ``XLA
 Modules`` event that encloses it, own
 time (``trace_reduce.self_times``) is summed per (module, instruction name),
-and the instruction name is looked up in the compiled text of
-``_cholesky_local`` (``.lower(...).compile().as_text()``, which keeps
+and the instruction name is looked up in the compiled text of the local
+Cholesky's one program (``jit_cholesky_local_on_tiles``: the layout moves
+and ``_cholesky_local``; ``.lower(...).compile().as_text()``, which keeps
 ``metadata={op_name=... stack_frame_id=...}`` and the tables that resolve a
 frame to file, line and function; the trace's event names do not, PERF.md
 section 3). Writes ``<out>/attribution.json`` and the compiled text
@@ -33,6 +34,7 @@ _META = re.compile(r'op_name="([^"]*)"(?:[^}]*?stack_frame_id=(\d+))?')
 _RESULT = re.compile(r" = \(?([a-z0-9]+\[[0-9,]*\](?:\{[^}]*\})?)")
 _OPERAND = re.compile(r"%([A-Za-z_][\w.\-]*)")
 _TABLE_ROW = re.compile(r"^(\d+) (.*)$")
+_MODULE = re.compile(r"HloModule ([\w.\-]+)")
 
 
 def frame_tables(text: str) -> dict:
@@ -154,10 +156,11 @@ def main() -> int:
             [(s, e, module_of(programs, s) + "\t" + n)
              for s, e, n in trace_reduce.clip(events, window)]))
 
-    meta, tables = {}, {}
+    meta, tables, program = {}, {}, None
     if captured:
         fn, avals, kw = captured["lower"]
         text = fn.lower(*avals, **kw).compile().as_text()
+        program = _MODULE.match(text).group(1)
         with gzip.open(os.path.join(args.out, "cholesky_local.hlo.txt.gz"),
                        "wt") as f:
             f.write(text)
@@ -172,7 +175,7 @@ def main() -> int:
         inst = name.partition(" = ")[0].lstrip("%")
         shape = _RESULT.search(name)
         op_name, frame, via = "", 0, ""
-        if module.startswith("jit__cholesky_local"):
+        if program and module.startswith(program):
             # a copy the compiler put in has no metadata of its own: take
             # its first operand's
             for cand in [inst] + _OPERAND.findall(name.partition(" = ")[2]):

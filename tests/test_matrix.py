@@ -63,6 +63,156 @@ def test_tiling_places_tiles_correctly():
             assert np.all(t[r, c, :, ts.col:] == 0)
 
 
+def _storage_reference(a, d):
+    """Tile storage by the module docstring's formula, in numpy alone:
+    ``storage[p*ltr + l_r, q*ltc + l_c]`` is global tile ``(l_r*P + (p -
+    src_r) % P, l_c*Q + (q - src_c) % Q)``, zero-padded to a whole tile,
+    all zeros where no such tile exists."""
+    mb, nb = d.block_size.row, d.block_size.col
+    P, Q = d.grid_size.row, d.grid_size.col
+    nt = d.nr_tiles
+    ltr, ltc = -(-nt.row // P), -(-nt.col // Q)
+    out = np.zeros((P * ltr, Q * ltc, mb, nb), dtype=a.dtype)
+    for p in range(P):
+        for q in range(Q):
+            for lr in range(ltr):
+                for lc in range(ltc):
+                    tr = lr * P + (p - d.source_rank.row) % P
+                    tc = lc * Q + (q - d.source_rank.col) % Q
+                    if tr < nt.row and tc < nt.col:
+                        blk = a[tr * mb:(tr + 1) * mb, tc * nb:(tc + 1) * nb]
+                        out[p * ltr + lr, q * ltc + lc,
+                            :blk.shape[0], :blk.shape[1]] = blk
+    return out
+
+
+#: name -> (m, n, mb, nb, P, Q, src_r, src_c, axes in storage order)
+LAYOUT_CASES = {
+    "1x1_divisible": (16, 24, 4, 8, 1, 1, 0, 0, (True, True)),
+    "1x1_ragged": (15, 22, 4, 8, 1, 1, 0, 0, (True, True)),
+    "2x2": (16, 24, 4, 4, 2, 2, 0, 0, (False, False)),
+    "2x2_ragged_src": (13, 26, 5, 5, 2, 2, 1, 1, (False, False)),
+    "2x1_src": (19, 8, 4, 4, 2, 1, 1, 0, (False, True)),
+    # one tile a rank: storage order is tile order on a 2x2 grid too ...
+    "2x2_one_tile_each": (8, 8, 4, 4, 2, 2, 0, 0, (True, True)),
+    # ... but not where a rank's slot is padding (one tile, two ranks)
+    "2x2_one_tile": (3, 3, 4, 4, 2, 2, 0, 0, (False, False)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LAYOUT_CASES))
+def test_layout_transforms_equal_the_numpy_reference_bitwise(case):
+    *shape, in_order = LAYOUT_CASES[case]
+    d = _dist(*shape)
+    assert tiling._axes_in_order(d) == in_order
+    a = np.random.default_rng(30).standard_normal(shape[:2])
+    want = _storage_reference(a, d)
+    t = tiling.global_to_tiles(a, d)
+    np.testing.assert_array_equal(np.asarray(t), want)
+    np.testing.assert_array_equal(
+        np.asarray(tiling.tiles_to_global(jax.numpy.asarray(want), d)), a)
+    # and under jit, as the entry points' programs trace them
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(lambda x: tiling.global_to_tiles(x, d))(
+            jax.numpy.asarray(a))), want)
+
+
+def _primitives(fn, *args):
+    """Names of the primitives ``fn`` traces, nested ``jit``s flattened."""
+    def walk(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            subs = [getattr(v, "jaxpr", None) for v in eqn.params.values()]
+            subs = [s for s in subs if s is not None]
+            for sub in subs:
+                walk(sub, out)
+            if not subs:
+                out.append(eqn.primitive.name)
+        return out
+
+    return walk(jax.make_jaxpr(fn)(*args).jaxpr, [])
+
+
+#: what ``jnp.take`` with constant indices traces, and the transforms of
+#: the parent commit (0cdaec9) around it, recorded from its jaxpr
+_TAKE = ["lt", "add", "select_n", "broadcast_in_dim", "gather"]
+_PAD = ["convert_element_type", "pad"]
+PARENT_TO_TILES = _PAD + ["reshape", "transpose"] + _PAD + 2 * _TAKE
+PARENT_TO_GLOBAL = 2 * _TAKE + ["transpose", "reshape", "slice"]
+
+
+def test_one_device_whole_tiles_is_a_plain_transpose():
+    """No gather, no pad, no slice: what the compiler gets on a 1x1 grid
+    with whole tiles is ``reshape`` + ``transpose`` (ISSUE 30: the identity
+    permutation is decided at trace time, XLA does not fold the gather)."""
+    d = _dist(*LAYOUT_CASES["1x1_divisible"][:8])
+    a = jax.numpy.zeros((16, 24))
+    t = tiling.global_to_tiles(a, d)
+    assert _primitives(lambda x: tiling.global_to_tiles(x, d), a) == [
+        "reshape", "transpose"]
+    assert _primitives(lambda x: tiling.tiles_to_global(x, d), t) == [
+        "transpose", "reshape"]
+
+
+def test_one_device_ragged_pads_and_slices_elements_only():
+    d = _dist(*LAYOUT_CASES["1x1_ragged"][:8])
+    a = jax.numpy.zeros((15, 22))
+    t = tiling.global_to_tiles(a, d)
+    assert _primitives(lambda x: tiling.global_to_tiles(x, d), a) == (
+        _PAD + ["reshape", "transpose"])
+    assert _primitives(lambda x: tiling.tiles_to_global(x, d), t) == [
+        "transpose", "reshape", "slice"]
+
+
+@pytest.mark.parametrize("case,drop_to_tiles,drop_to_global", [
+    ("2x2_ragged_src", [], []),
+    # whole tiles: the parent's zero-width element pad and its no-op slice
+    # are not emitted (the slice JAX dropped itself), the rest is its trace
+    ("2x2", _PAD, ["slice"]),
+])
+def test_permuted_grid_traces_the_parents_operations(case, drop_to_tiles,
+                                                     drop_to_global):
+    shape = LAYOUT_CASES[case][:8]
+    d = _dist(*shape)
+    a = jax.numpy.zeros(shape[:2])
+    t = tiling.global_to_tiles(a, d)
+    assert _primitives(lambda x: tiling.global_to_tiles(x, d), a) == (
+        PARENT_TO_TILES[len(drop_to_tiles):])
+    want = [p for p in PARENT_TO_GLOBAL if p not in drop_to_global]
+    assert _primitives(lambda x: tiling.tiles_to_global(x, d), t) == want
+
+
+def test_one_axis_in_order_gathers_the_other_only():
+    shape = LAYOUT_CASES["2x1_src"][:8]
+    d = _dist(*shape)
+    a = jax.numpy.zeros(shape[:2])
+    prims = _primitives(lambda x: tiling.global_to_tiles(x, d), a)
+    assert prims.count("gather") == 1 and prims.count("pad") == 2
+    t = tiling.global_to_tiles(a, d)
+    assert _primitives(lambda x: tiling.tiles_to_global(x, d),
+                       t).count("gather") == 1
+
+
+@pytest.mark.parametrize("case", ["1x1_ragged", "2x2_ragged_src"])
+def test_on_global_is_the_composition_and_passes_extras_through(case):
+    shape = LAYOUT_CASES[case][:8]
+    d = _dist(*shape)
+    a = np.random.default_rng(31).standard_normal(shape[:2])
+    st = tiling.global_to_tiles(a, d)
+    prog = tiling.on_global(lambda g: 2.0 * g, d)
+    assert prog.__name__ == "<lambda>_on_tiles"
+    np.testing.assert_array_equal(
+        np.asarray(jax.jit(prog)(st)),
+        np.asarray(tiling.global_to_tiles(2.0 * a, d)))
+
+    def with_info(g):
+        return g + 1.0, g.shape[0], jax.numpy.int32(7)
+
+    out, rows, info = jax.jit(tiling.on_global(with_info, d))(st)
+    np.testing.assert_array_equal(
+        np.asarray(out), np.asarray(tiling.global_to_tiles(a + 1.0, d)))
+    assert (int(rows), int(info)) == (shape[0], 7)
+
+
 @pytest.mark.parametrize("m,n,mb,nb,P,Q,sr,sc", CASES)
 def test_matrix_roundtrip_local(m, n, mb, nb, P, Q, sr, sc):
     rng = np.random.default_rng(7)

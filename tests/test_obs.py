@@ -722,8 +722,8 @@ def test_miniapp_cholesky_metrics_distributed(tmp_path, monkeypatch,
 # jax.profiler session is running, whoever started it
 # ---------------------------------------------------------------------------
 
-CHOLESKY_SPANS = ("stage.cholesky.to_global", "stage.cholesky.factor",
-                  "stage.cholesky.to_tiles")
+#: the local Cholesky dispatches ONE program (ISSUE 30): one host phase
+CHOLESKY_SPANS = ("stage.cholesky.factor",)
 NATIVE_SPANS = ("stage.native.band_chase", "stage.native.secular",
                 "stage.native.deflate")
 #: host phases of ``triangular_solve`` (ISSUE 27), by branch
@@ -851,8 +851,9 @@ NEW_SPAN_SITES = {
 
 def test_cholesky_host_phases_reach_a_foreign_profiler(tmp_path):
     """Only a metrics path configured, the session started by the test: a
-    local cholesky call leaves its entry span and its three host phases in
-    the xplane, the phases inside the entry, in dispatch order."""
+    local cholesky call leaves its entry span and its one host phase in
+    the xplane, the phase inside the entry, and no phase of the layout
+    programs it no longer dispatches."""
     _configure_metrics(tmp_path)
     _call_cholesky()                       # compile outside the session
     with _test_owned_trace(tmp_path / "trace"):
@@ -873,6 +874,8 @@ def test_cholesky_host_phases_reach_a_foreign_profiler(tmp_path):
         assert entry[0] <= s <= e <= entry[1], name
         starts.append(s)
     assert starts == sorted(starts)
+    assert sorted(n for n in by_name if n.startswith("stage.cholesky.")) \
+        == sorted(CHOLESKY_SPANS)
 
 
 @pytest.mark.parametrize("branch", sorted(TRSM_SPANS))
