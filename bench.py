@@ -742,6 +742,13 @@ def run_variant() -> None:
         _run_stage_variant(variant, parts[0], set(parts[1:]))
         _emit_devtrace(variant)
         return
+    # the platform check comes BEFORE this child writes its arm's knobs
+    # into its own environment: a refused run leaves the process as it
+    # found it (a test that calls this entry in-process used to leave
+    # DLAF_CHOLESKY_TRAILING / _LOOKAHEAD set for every later test of its
+    # worker: tests/test_config.py::test_cholesky_lookahead_knob, PR 30's
+    # red tier-1)
+    platform = require_platform()
     os.environ.setdefault("DLAF_CHOLESKY_LOOKAHEAD", la or "0")
     # "ozaki_concat"/"ozaki_dots" = the ozaki trailing with the group form
     # pinned (config ozaki_group) — labeled separately so the sweep A/Bs
@@ -758,7 +765,6 @@ def run_variant() -> None:
     import dlaf_tpu.config as config
 
     config.initialize()
-    platform = require_platform()
     log(f"[{variant}] devices: {jax.devices()} ({time.time() - t_start:.1f}s)")
     if base == "scan" and platform == "tpu":
         # the scan formulation follows the f64_gemm/f64_trsm knobs (it no

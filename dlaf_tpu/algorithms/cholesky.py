@@ -73,6 +73,15 @@ _telescope_segments = telescope_segments
 #: 1 device and on a grid.
 VALID_TRAILING = ("loop", "biggemm", "invgemm", "xla", "ozaki", "scan")
 
+#: The local scan builder's bulk product on the slice-product route: from
+#: ``SCAN_BULK_CHUNK_AT`` rows of a segment's block on, it is formed
+#: ``SCAN_BULK_CHUNK`` columns at a time (``_cholesky_local_scan``:
+#: ``bulk_chunks``). The numbers are those of ``trsm_rhs_chunk`` and
+#: ``red2band_trail_chunk`` auto (config.py): the same workspaces, the
+#: same chip. Not options: the shape decides.
+SCAN_BULK_CHUNK = 4096
+SCAN_BULK_CHUNK_AT = 8192
+
 
 
 def _oz_product(x, y):
@@ -86,8 +95,13 @@ def _oz_product(x, y):
 def _count_step_modes(algo: str, overlapped: int, serialized: int) -> None:
     """Trace-time tile-step accounting for the lookahead pipeline: how many
     steps of the compiled program were emitted in the overlapped (next-
-    panel-column-first) order vs the plain serialized order."""
+    panel-column-first) order vs the plain serialized order, and in how
+    many traced step bodies (``dlaf_cholesky_bodies_total{algo}``: one a
+    call — an unrolled builder calls once per step, a scan builder once
+    per telescoped segment — so a run says which builder it took and how
+    many bodies the program holds)."""
     if obs.metrics_active():
+        obs.counter("dlaf_cholesky_bodies_total", algo=algo).inc()
         if overlapped:
             obs.counter("dlaf_cholesky_steps_total", algo=algo,
                         mode="overlapped").inc(overlapped)
@@ -413,19 +427,27 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
                          with_info: bool = False, panel_fused: bool = False,
                          step_fused: bool = False,
                          panel_interpret: bool = False):
-    """``lax.scan`` formulation of the local factorization: ONE compiled
-    step body, looped ``nt`` times with uniform full-size shapes.
+    """``lax.scan`` formulation of the local factorization: one compiled
+    step body per telescoped segment (``types.telescope_segments``: eight
+    steps each up to 64), looped with the uniform shapes of the segment's
+    block. The local entry takes it from the step count
+    (:func:`local_step_form`: on a TPU from 32 block steps on).
 
     Why it exists: the unrolled trace (:func:`_cholesky_local`) compiles in
-    time linear in ``nt`` with a ~19 s/step constant for the v5e
-    (docs/DESIGN.md) and its per-step intermediates
-    are all simultaneously visible to the allocator. The scanned form
-    compiles O(1) programs and reuses carry buffers, at the documented
-    price of uniform-shape work: the panel is the FULL block column (rows
-    above the pivot masked) and the trailing update is a FULL (n, n)
-    masked product every step — ~3x the exact trailing flops. The right
-    trade when compile latency or HBM liveness binds, not when flops do
-    (bench.py sweeps both).
+    time linear in ``nt`` and its per-step intermediates are all visible to
+    the allocator at once. The one size at which both forms ran on the chip
+    (N=16384, nb=512, f64, one v5e; PERF.md section 6, PR 31): unrolled,
+    779.8 s to the first call and the host's 40 GiB exhausted after it,
+    1.338 s a warm call; this form, 149.5 s to the first call (12 s from
+    the cache), 1.2284 s a call, a 59.6 MB cache entry. The scanned form
+    compiles one body a segment and reuses its carry buffers, at the price
+    of uniform-shape work: the panel is the segment's FULL block column
+    (rows above the pivot masked) and the trailing update covers the
+    segment's whole block every step, as one masked (m, m) self-product or,
+    on the slice-product route from ``SCAN_BULK_CHUNK_AT`` rows on, as
+    block-column trapezoids (``bulk_chunks``). What the masks throw away is
+    counted (``dlaf_ozaki_masked_macs_total``, the benchmark's
+    ``masked_mac_share``).
 
     The panel and trailing routes follow the same knobs as the distributed
     scan builder (:func:`_build_dist_cholesky_scan`): ``use_mixed``
@@ -449,7 +471,103 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
         a = a.at[jnp.arange(n, nt * nb), jnp.arange(n, nt * nb)].set(1)
     other = "U" if uplo == "L" else "L"
 
-    def make_step(m):
+    def syrk_like(x):
+        """Masked-panel self-product on the configured trailing route: the
+        scan forms' one bulk product (x zeroed above its pivot)."""
+        if use_mxu:
+            return (oz.herk_c128(x, slices=tb._oz_slices())
+                    if jnp.iscomplexobj(x)
+                    else oz.syrk_f64(x, slices=tb._oz_slices()))
+        return x @ jnp.conj(x).T
+
+    def bulk_chunks(m):
+        """Static block-column (``L``; block-row for ``U``) chunks
+        ``(c0, c1)`` of a segment's bulk product, or None for the one
+        (m, m) self-product. The shape decides, by the rule of the other
+        workspace bounds (``trsm_rhs_chunk`` / ``red2band_trail_chunk``
+        auto): on the slice-product route, from :data:`SCAN_BULK_CHUNK_AT`
+        rows on, the update is formed :data:`SCAN_BULK_CHUNK` columns at a
+        time: the (m, m) self-product's f64 accumulator, int32 partials
+        and mirror stand beside the carry (N=16384 compiled for a described
+        v5e: 8.68 GiB of temporaries with the square, 5.31 with the
+        chunks). Each chunk starts at its own diagonal, so the square's
+        strict other triangle is never multiplied (1.41x fewer
+        multiply-accumulates at N=16384, nb=512, by the counters)."""
+        if not use_mxu or m < SCAN_BULK_CHUNK_AT:
+            return None
+        w = max(nb, SCAN_BULK_CHUNK // nb * nb)
+        return [(c0, min(c0 + w, m)) for c0 in range(0, m, w)]
+
+    def live_lower(m, lo, c0, c1):
+        """Elements ``(i, j)`` of an (m, m) block with ``c0 <= j < c1``,
+        ``j >= lo`` and ``i >= j``: what a stored-triangle update keeps
+        of the columns ``[c0, c1)`` once the pivot stands at ``lo``."""
+        j0 = max(c0, lo)
+        cnt = c1 - j0
+        return cnt * m - (j0 + c1 - 1) * cnt // 2 if cnt > 0 else 0
+
+    def live_counts(m, seg_len, first):
+        """Output elements a segment's products keep, summed over its
+        ``seg_len`` executed steps (``oz.live_outputs``): the panel
+        product's rows below the pivot, the strip's stored trapezoid and,
+        per chunk, the bulk's stored triangle past the pivot (under
+        look-ahead the bulk of body k is step k-1's, past column block k;
+        the factorization's very first body has none pending)."""
+        chunks = bulk_chunks(m) or [(0, m)]
+        out = {"panel": 0, "strip": 0, "bulk": [0] * len(chunks)}
+        for k in range(seg_len):
+            lo = (k + 1) * nb
+            out["panel"] += (m - lo) * nb
+            if lookahead:
+                out["strip"] += live_lower(m, lo, lo, min(lo + nb, m))
+                if first and k == 0:
+                    continue
+            for i, (c0, c1) in enumerate(chunks):
+                out["bulk"][i] += live_lower(m, lo, c0, c1)
+        return out
+
+    def bulk_update(acc, xt, lo, rows, live):
+        """``acc`` minus the stored triangle of ``xt @ xt^H`` (``xt``: the
+        (m, nb) masked panel, transposed for ``U``), past column / row
+        ``lo`` where the pending panel of the look-ahead form reaches
+        further up than its update may (None: the panel's own zeros do
+        it). One self-product, or :func:`bulk_chunks`' trapezoids."""
+        m = xt.shape[0]
+        chunks = bulk_chunks(m)
+        if chunks is None:
+            with oz.live_outputs(live[0]):
+                upd = syrk_like(xt)
+            if uplo == "L":
+                mask = rows[:, None] >= rows[None, :]
+                if lo is not None:
+                    mask = mask & (rows[None, :] >= lo)
+            else:
+                mask = rows[:, None] <= rows[None, :]
+                if lo is not None:
+                    mask = mask & (rows[:, None] >= lo)
+            return acc - jnp.where(mask, upd, 0)
+        for (c0, c1), kept in zip(chunks, live):
+            long, short = xt[c0:], xt[c0:c1]
+            rl, rs = rows[c0:], rows[c0:c1]
+            if uplo == "L":
+                with oz.live_outputs(kept):
+                    upd = _oz_product(long, jnp.conj(short).T)
+                mask = rl[:, None] >= rs[None, :]
+                if lo is not None:
+                    mask = mask & (rs[None, :] >= lo)
+                acc = acc.at[c0:, c0:c1].set(
+                    acc[c0:, c0:c1] - jnp.where(mask, upd, 0))
+            else:
+                with oz.live_outputs(kept):
+                    upd = _oz_product(short, jnp.conj(long).T)
+                mask = rs[:, None] <= rl[None, :]
+                if lo is not None:
+                    mask = mask & (rs[:, None] >= lo)
+                acc = acc.at[c0:c1, c0:].set(
+                    acc[c0:c1, c0:] - jnp.where(mask, upd, 0))
+        return acc
+
+    def make_step(m, live):
         rows = jnp.arange(m)
 
         def step(acc, k):
@@ -478,7 +596,9 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
                 if use_mixed:
                     ppan.count_panel_kernel("xla", "solve")
                     inv_t = jnp.conj(fac_inv).T
-                    pfull = tb.mm_mxu(col, inv_t) if use_mxu else col @ inv_t
+                    with oz.live_outputs(live["panel"]):
+                        pfull = (tb.mm_mxu(col, inv_t) if use_mxu
+                                 else col @ inv_t)
                 elif step_fused:
                     # col's pivot rows hold the unfactored blk; the
                     # write-back + explicit diag update below restore
@@ -497,22 +617,17 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
                     acc, jnp.where(below[:, None], pfull, col), (0, k0))
                 if step_fused:
                     acc = jax.lax.dynamic_update_slice(acc, diag, (k0, k0))
-                if use_mxu:
-                    upd = (oz.herk_c128(panel, slices=tb._oz_slices())
-                           if jnp.iscomplexobj(panel)
-                           else oz.syrk_f64(panel, slices=tb._oz_slices()))
-                else:
-                    upd = panel @ jnp.conj(panel).T
-                # panel is zero at rows <= pivot, so upd lives only in the
-                # trailing block; restrict to the stored lower triangle
-                tri = rows[:, None] >= rows[None, :]
-                acc = acc - jnp.where(tri, upd, 0)
+                # panel is zero at rows <= pivot, so the update lives only
+                # in the trailing block; restricted to the stored triangle
+                acc = bulk_update(acc, panel, None, rows, live["bulk"])
             else:
                 row = jax.lax.dynamic_slice(acc, (k0, 0), (nb, m))
                 if use_mixed:
                     ppan.count_panel_kernel("xla", "solve")
                     inv_t = jnp.conj(fac_inv).T
-                    pfull = tb.mm_mxu(inv_t, row) if use_mxu else inv_t @ row
+                    with oz.live_outputs(live["panel"]):
+                        pfull = (tb.mm_mxu(inv_t, row) if use_mxu
+                                 else inv_t @ row)
                 elif step_fused:
                     diag, pfull = ppan.fused_factor_solve(
                         "U", blk, row, interpret=panel_interpret)
@@ -529,28 +644,12 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
                 if step_fused:
                     acc = jax.lax.dynamic_update_slice(acc, diag, (k0, k0))
                 pt = jnp.conj(jnp.swapaxes(panel, -1, -2))
-                if use_mxu:
-                    upd = (oz.herk_c128(pt, slices=tb._oz_slices())
-                           if jnp.iscomplexobj(panel)
-                           else oz.syrk_f64(pt, slices=tb._oz_slices()))
-                else:
-                    upd = pt @ jnp.conj(pt).T
-                tri = rows[:, None] <= rows[None, :]
-                acc = acc - jnp.where(tri, upd, 0)
+                acc = bulk_update(acc, pt, None, rows, live["bulk"])
             return acc, None
 
         return step
 
-    def syrk_like(x):
-        """Masked-panel self-product on the configured trailing route: the
-        scan forms' one bulk product (x zeroed above its pivot)."""
-        if use_mxu:
-            return (oz.herk_c128(x, slices=tb._oz_slices())
-                    if jnp.iscomplexobj(x)
-                    else oz.syrk_f64(x, slices=tb._oz_slices()))
-        return x @ jnp.conj(x).T
-
-    def make_step_la(m):
+    def make_step_la(m, live):
         """Software-pipelined step body (``cholesky_lookahead=1``): the
         bulk trailing product of step k-1 is DEFERRED into body k, where
         it carries no dependency on body k's latency-bound potrf/trsm
@@ -581,15 +680,15 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
             if diag is not None:
                 acc = jax.lax.dynamic_update_slice(acc, diag, (k0, k0))
             below = rows >= k0 + nb
-            tri = (rows[:, None] >= rows[None, :] if uplo == "L"
-                   else rows[:, None] <= rows[None, :])
             valid1 = k0 + 2 * nb <= m    # next block col/row exists
             if uplo == "L":
                 col = jax.lax.dynamic_slice(acc, (0, k0), (m, nb))
                 if use_mixed:
                     ppan.count_panel_kernel("xla", "solve")
                     inv_t = jnp.conj(fac_inv).T
-                    pfull = tb.mm_mxu(col, inv_t) if use_mxu else col @ inv_t
+                    with oz.live_outputs(live["panel"]):
+                        pfull = (tb.mm_mxu(col, inv_t) if use_mxu
+                                 else col @ inv_t)
                 elif step_fused:
                     diag, pfull = ppan.fused_factor_solve(
                         "L", blk, col, interpret=panel_interpret)
@@ -607,14 +706,13 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
                     acc = jax.lax.dynamic_update_slice(acc, diag, (k0, k0))
                 # deferred bulk of step k-1: its next-col (block col k)
                 # was applied in body k-1, the rest lands here
-                pupd = syrk_like(pp)
-                pmask = tri & (rows[None, :] >= k0 + nb)
-                acc = acc - jnp.where(pmask, pupd, 0)
+                acc = bulk_update(acc, pp, k0 + nb, rows, live["bulk"])
                 # eager next-column strip from THIS panel
                 nstrip = jax.lax.dynamic_slice(panel, (k0 + nb, 0),
                                                (nb, nb))
-                updc = (_oz_product(panel, jnp.conj(nstrip).T) if use_mxu
-                        else panel @ jnp.conj(nstrip).T)
+                with oz.live_outputs(live["strip"]):
+                    updc = (_oz_product(panel, jnp.conj(nstrip).T)
+                            if use_mxu else panel @ jnp.conj(nstrip).T)
                 ccur = jax.lax.dynamic_slice(acc, (0, k0 + nb), (m, nb))
                 cols1 = k0 + nb + jnp.arange(nb)
                 cmask = (rows[:, None] >= cols1[None, :]) & valid1
@@ -625,7 +723,9 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
                 if use_mixed:
                     ppan.count_panel_kernel("xla", "solve")
                     inv_t = jnp.conj(fac_inv).T
-                    pfull = tb.mm_mxu(inv_t, row) if use_mxu else inv_t @ row
+                    with oz.live_outputs(live["panel"]):
+                        pfull = (tb.mm_mxu(inv_t, row) if use_mxu
+                                 else inv_t @ row)
                 elif step_fused:
                     diag, pfull = ppan.fused_factor_solve(
                         "U", blk, row, interpret=panel_interpret)
@@ -642,15 +742,14 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
                 if step_fused:
                     acc = jax.lax.dynamic_update_slice(acc, diag, (k0, k0))
                 ppt = jnp.conj(jnp.swapaxes(pp, -1, -2))
-                pupd = syrk_like(ppt)
-                pmask = tri & (rows[:, None] >= k0 + nb)
-                acc = acc - jnp.where(pmask, pupd, 0)
+                acc = bulk_update(acc, ppt, k0 + nb, rows, live["bulk"])
                 pt = jnp.conj(jnp.swapaxes(panel, -1, -2))
                 nstrip = jax.lax.dynamic_slice(pt, (k0 + nb, 0), (nb, nb))
                 # nstrip = conj(panel_block)^T, so nstrip @ panel IS the
                 # strip of conj(panel)^T @ panel (same dots as serial)
-                updr = (_oz_product(nstrip, jnp.conj(pt).T) if use_mxu
-                        else nstrip @ panel)
+                with oz.live_outputs(live["strip"]):
+                    updr = (_oz_product(nstrip, jnp.conj(pt).T) if use_mxu
+                            else nstrip @ panel)
                 rcur = jax.lax.dynamic_slice(acc, (k0 + nb, 0), (nb, m))
                 rows1 = k0 + nb + jnp.arange(nb)
                 rmask = (rows1[:, None] <= rows[None, :]) & valid1
@@ -662,10 +761,9 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
 
     # telescoped segments: each segment scans the SHRINKING trailing
     # submatrix (completed panel columns live outside it and are final),
-    # so the uniform full-size masked work tracks the live trailing block
-    # instead of the original matrix — premium drops from ~3x toward
-    # ~1.7x at O(log nt) step programs instead of O(1) (still far below
-    # the unrolled form's O(nt) on the ~19 s/step AOT toolchain).
+    # so the uniform masked work tracks the live trailing block instead
+    # of the original matrix, at one step body a segment (the unrolled
+    # form has one a step).
     # Under lookahead the pending panel is carried ACROSS segments (the
     # dropped slots are zero — the panel is masked below its pivot), so
     # no flush products are ever paid; the last step's pending is
@@ -682,12 +780,19 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
                       else jnp.zeros((nb, m_seg), a.dtype))
             else:
                 pp = pp[-m_seg:] if uplo == "L" else pp[:, -m_seg:]
-            (sub, pp), _ = jax.lax.scan(make_step_la(m_seg), (sub, pp),
-                                        jnp.arange(seg_len))
+            body = make_step_la(m_seg, live_counts(m_seg, seg_len, off == 0))
         else:
             _count_step_modes("cholesky_scan", 0, seg_len)
-            sub, _ = jax.lax.scan(make_step(m_seg), sub,
-                                  jnp.arange(seg_len))
+            body = make_step(m_seg, live_counts(m_seg, seg_len, off == 0))
+        # the index-free scope of the distributed scan builder: ONE traced
+        # body serves the segment's iterations, and trace-time counters
+        # inside count per executed step (obs.scoped_step)
+        body = obs.scoped_step("cholesky.scanstep", body, steps=seg_len)
+        if lookahead:
+            (sub, pp), _ = jax.lax.scan(body, (sub, pp),
+                                        jnp.arange(seg_len))
+        else:
+            sub, _ = jax.lax.scan(body, sub, jnp.arange(seg_len))
         a = a.at[off * nb:, off * nb:].set(sub)
         off += seg_len
     out = a[:n, :n]
@@ -1742,6 +1847,23 @@ def _local_cholesky_cached(local, dist, donate, statics):
 # Public API (reference factorization/cholesky.h:36,62)
 # ---------------------------------------------------------------------------
 
+def local_step_form(steps: int) -> str:
+    """``"scan"`` or ``"unrolled"``: the builder the local branch of
+    :func:`cholesky` takes for a matrix of ``steps`` block steps under the
+    active configuration. A ``cholesky_trailing`` that names a form keeps
+    it ("scan" the scan builder, every other the unrolled one); "auto"
+    asks the resolver of every other builder, ``config.resolve_step_mode``
+    (``dist_step_mode``; auto: the scan form from
+    ``STEP_MODE_AUTO_SCAN_AT`` steps on, 32 on a TPU). What a caller, or
+    the benchmark's op file, can ask before it pays for a compile."""
+    from ..config import get_configuration, resolve_step_mode
+
+    trailing = get_configuration().cholesky_trailing
+    if trailing != "auto":
+        return "scan" if trailing == "scan" else "unrolled"
+    return resolve_step_mode(steps)
+
+
 def cholesky(uplo: str, mat: Matrix, *, donate: bool = False,
              with_info: bool = False):
     """Factorize the Hermitian positive-definite ``mat`` in the ``uplo``
@@ -1776,8 +1898,9 @@ def cholesky(uplo: str, mat: Matrix, *, donate: bool = False,
     dlaf_assert(uplo in ("L", "U"), f"cholesky: uplo must be 'L' or 'U', got {uplo!r}")
     from ..config import get_configuration, resolve_platform_auto
 
+    cfg = get_configuration()
     trailing = resolve_platform_auto(
-        get_configuration().cholesky_trailing, knob="cholesky_trailing",
+        cfg.cholesky_trailing, knob="cholesky_trailing",
         tpu_choice="ozaki", other_choice="loop",
         detail="the route of the chol_d_n4096_1x1 cell: call_s 0.0464 s "
                "(PERF_LEDGER.jsonl, PR 28); the other forms are not "
@@ -1787,10 +1910,19 @@ def cholesky(uplo: str, mat: Matrix, *, donate: bool = False,
     dlaf_assert(mat.size.row == mat.size.col, "cholesky: matrix must be square")
     dlaf_assert(mat.block_size.row == mat.block_size.col,
                 "cholesky: block must be square")
-    cfg = get_configuration()
     dt = np.dtype(mat.dtype)
     n = mat.size.row
     grid_shape = (mat.dist.grid_size.row, mat.dist.grid_size.col)
+    local = mat.grid is None or mat.grid.num_devices == 1
+    # the local step form comes from the step count (local_step_form: on a
+    # TPU the scan builder from 32 block steps on) unless cholesky_trailing
+    # names a form itself. The products keep the platform's route: where
+    # "auto" is the slice-product form ("ozaki"), the scan bodies run it too
+    scan_form = local and local_step_form(mat.dist.nr_tiles.row) == "scan"
+    oz_scan = scan_form and trailing == "ozaki" \
+        and dt in (np.dtype(np.float64), np.dtype(np.complex128))
+    if scan_form:
+        trailing = "scan"
     # look-ahead step order (docs/lookahead.md): pipelined when the knob
     # resolves 1; the whole-matrix "xla" delegation has no step structure
     # to pipeline. comm_lookahead (docs/comm_overlap.md) extends the
@@ -1829,9 +1961,9 @@ def cholesky(uplo: str, mat: Matrix, *, donate: bool = False,
     # the scan formulations follow the f64_gemm/f64_trsm knobs (identical
     # resolution local and distributed, single owner in tile_ops.blas);
     # the unrolled local path selects its route via cholesky_trailing
-    use_mxu = tb.f64_gemm_uses_mxu(dt, mat.block_size.row)
-    use_mixed = tb.trsm_panel_uses_mixed(dt)
-    if mat.grid is None or mat.grid.num_devices == 1:
+    use_mxu = oz_scan or tb.f64_gemm_uses_mxu(dt, mat.block_size.row)
+    use_mixed = oz_scan or tb.trsm_panel_uses_mixed(dt)
+    if local:
         # off-TPU the fused panel kernels run in interpret mode (same
         # convention as the pallas trailing kernels)
         statics = dict(

@@ -298,9 +298,10 @@ class Configuration:
     #: count), "scan" (lax.scan'd uniform masked step — O(1) compile,
     #: ~2-3x masked-shape work; the compile-latency escape hatch at large
     #: tile counts, docs/DESIGN.md), or "auto" (default): pick per (step
-    #: count, platform) from the measured compile constants via
-    #: :func:`resolve_step_mode`. Cholesky selects its scan form via
-    #: cholesky_trailing="scan".
+    #: count, platform) via :func:`resolve_step_mode`. The LOCAL Cholesky
+    #: asks the same resolver while cholesky_trailing is "auto"
+    #: (algorithms/cholesky.py:local_step_form); the distributed Cholesky
+    #: selects its scan form via cholesky_trailing="scan".
     dist_step_mode: str = "auto"
     #: HEGST (gen_to_std) formulation: "blocked" (per-k two-sided update —
     #: hegst diag, panel trsm/hemm, her2k trailing, deferred trailing
@@ -610,7 +611,7 @@ class Configuration:
     #: compile walls (``dlaf_compile_seconds{site}``), trace counts
     #: (``dlaf_retrace_total{site}`` — first trace = 1, more = retraces),
     #: and ``compiled.memory_analysis()`` HBM gauges
-    #: (``dlaf_hbm_bytes{what=args|output|temp|peak,site}``), each compile
+    #: (``dlaf_hbm_bytes{what=args|output|temp|code|peak,site}``), each compile
     #: also landing as a ``program`` record in the ``metrics_path``
     #: artifact (dlaf_tpu.obs.telemetry; docs/observability.md). Off
     #: (default): every instrumented site is a passthrough to the same
@@ -1015,18 +1016,19 @@ def resolved_bt_lookahead() -> bool:
 
 
 #: Step counts at which ``dist_step_mode="auto"`` switches to the scan
-#: formulation, per platform. The TPU point now rests on the MEASURED
-#: v5e ladder (one chip, 2026-08-01, telescoped
-#: scan, nb=256): run premium 1.149x at nt=16 (N=4096) and 1.248x at
-#: nt=32 (N=8192) — the premium GROWS with nt (more telescope windows =
-#: more slot padding), so lowering the threshold buys nothing, while the
-#: compile side still cliffs: the hardware AOT toolchain compiles
-#: unrolled per-step programs at ~19 s/step (vs ~2.3 s total for scan),
-#: i.e. 10+ cold minutes at nt=32 against a 0.13 s/run premium — a
-#: ~4600-run break-even no real session reaches. 32 therefore stays: a
-#: COLD cache argues for scan well below it, a warm cache amortizes
-#: unrolled compiles away above it. The CPU toolchain's ~0.35 s/step
-#: constant moves the breakpoint to ~128.
+#: formulation, per platform; the local Cholesky's step form comes from
+#: the same table (algorithms/cholesky.py:local_step_form). What the chip
+#: has shown (one v5e, through benchmark/run.py; PERF.md section 6): at 32
+#: steps (local Cholesky, N=16384, nb=512, f64; PR 31) the unrolled
+#: program took 779.8 s to its first call, cold, ran 1.338 s a call and
+#: left the 40 GiB host out of memory, the telescoped scan 149.5 s (12 s
+#: from the cache) and 1.2284 s a call; the distributed solve at 32 steps
+#: (trsm_d_n8192_2x2, PR 27) compiles 110-120 s in its scan form. At 16
+#: steps (chol_d_n4096_1x1) the unrolled program compiles ~290 s cold and
+#: is the measured route; its scan form is not measured on the chip, nor
+#: is any other step count: 32 rests on the one comparison above. The
+#: CPU's 128 rests on no measurement of this round (compiles there are
+#: cheap; tier-1 runs both forms).
 STEP_MODE_AUTO_SCAN_AT = {"tpu": 32, "cpu": 128}
 
 

@@ -15,7 +15,7 @@ cached-program sites route through. Three signals, per ``site`` label:
   "trace-time comm counters add again on retrace" caveat *detectable*:
   the collective byte counters are per-program models, and
   ``dlaf_retrace_total`` says how many programs contributed.
-* ``dlaf_hbm_bytes{what=args|output|temp|peak,site}`` — gauges from
+* ``dlaf_hbm_bytes{what=args|output|temp|code|peak,site}`` — gauges from
   ``compiled.memory_analysis()`` (the allocator's own accounting; the
   OOM-vs-fit oracle of the round-4 probe sessions).
 
@@ -162,7 +162,7 @@ def record_compile(site: str, *, compile_s: float,
     reg = _registry()
     reg.histogram("dlaf_compile_seconds", site=site).observe(compile_s)
     if memory:
-        for what in ("args", "output", "temp", "peak"):
+        for what in ("args", "output", "temp", "code", "peak"):
             if what in memory:
                 reg.gauge("dlaf_hbm_bytes", what=what,
                           site=site).set(memory[what])

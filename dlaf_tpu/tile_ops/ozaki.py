@@ -50,7 +50,9 @@ property, not an algorithm one — the CPU path handles the full f64 range
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import threading
 
 import jax.numpy as jnp
 from jax import lax
@@ -317,13 +319,38 @@ def _fold_groups(s, group, cats, half: bool = False):
     return acc
 
 
+_live = threading.local()
+
+
+@contextlib.contextmanager
+def live_outputs(live: int):
+    """Around a product whose output a uniform-shape step body masks:
+    ``live`` is how many of its output elements the body keeps, summed
+    over the EXECUTED steps of the scan body being traced (a scan step's
+    shapes cover the segment's whole block, the stored triangle of the
+    live trailing block is what the mathematics needs). The products
+    traced inside count the rest of their multiply-accumulates, real and
+    padding alike, under ``dlaf_ozaki_masked_macs_total{route}``
+    (:func:`_count_macs`); outside any such scope nothing is masked."""
+    prev = getattr(_live, "n", None)
+    _live.n = live
+    try:
+        yield
+    finally:
+        _live.n = prev
+
+
 def _count_macs(route: str, mn: int, real: int, emitted: int) -> None:
     """Trace-time accounting of the slice dots' multiply-accumulates,
     ``dlaf_ozaki_macs_total{route, kind}``, per traced 2D product and
     EXECUTED step (a product in a step builder's scan body counts the
     scan's trip count, ``obs.traced_step_count()``, like the collectives'
     counters): ``real`` the ``mn * real`` the slice pairs need, ``zero``
-    what the emitted depth holds beyond that (padding)."""
+    what the emitted depth holds beyond that (padding). Inside a
+    :func:`live_outputs` scope the output elements beyond the live ones
+    count their whole emitted depth under
+    ``dlaf_ozaki_masked_macs_total{route}`` too: a share of the first
+    counter's sum, not a third kind of it."""
     from .. import obs
 
     if obs.metrics_active():
@@ -332,6 +359,10 @@ def _count_macs(route: str, mn: int, real: int, emitted: int) -> None:
                     kind="real").inc(mn * real)
         obs.counter("dlaf_ozaki_macs_total", route=route,
                     kind="zero").inc(mn * (emitted - real))
+        live = getattr(_live, "n", None)
+        if live is not None and mn:
+            obs.counter("dlaf_ozaki_masked_macs_total",
+                        route=route).inc((mn - live) * emitted)
 
 
 def _group_dot(route: str, ga, gb, pairs: int, k: int):

@@ -3,7 +3,8 @@
 the source lines that emitted it.
 
     python3 scripts/op_attribution.py --workload chol_d_n4096_1x1 --seed 7 \\
-        --out chiprun_out/attr [--root <checkout>] [--opcode copy]
+        --out chiprun_out/attr [--root <checkout>] [--opcode copy] \
+        [--program _cholesky_local_scan]
 
 Runs ``benchmark/run.py --trace 1`` of ``--root`` in this process (it holds
 the chip), then reads the run's xplane once more with the benchmark's own
@@ -12,7 +13,10 @@ Modules`` event that encloses it, own
 time (``trace_reduce.self_times``) is summed per (module, instruction name),
 and the instruction name is looked up in the compiled text of the local
 Cholesky's one program (``jit_cholesky_local_on_tiles``: the layout moves
-and ``_cholesky_local``; ``.lower(...).compile().as_text()``, which keeps
+and the builder the entry took, ``_cholesky_local`` or, from 32 block steps
+on, ``_cholesky_local_scan``: ``--program`` names the builder at which a
+source chain stops, by default the one the run dispatched;
+``.lower(...).compile().as_text()``, which keeps
 ``metadata={op_name=... stack_frame_id=...}`` and the tables that resolve a
 frame to file, line and function; the trace's event names do not, PERF.md
 section 3). Writes ``<out>/attribution.json`` and the compiled text
@@ -35,6 +39,9 @@ _RESULT = re.compile(r" = \(?([a-z0-9]+\[[0-9,]*\](?:\{[^}]*\})?)")
 _OPERAND = re.compile(r"%([A-Za-z_][\w.\-]*)")
 _TABLE_ROW = re.compile(r"^(\d+) (.*)$")
 _MODULE = re.compile(r"HloModule ([\w.\-]+)")
+#: the local Cholesky's telemetry sites and the builder each dispatches
+SITE_PROGRAMS = {"cholesky.local": "_cholesky_local",
+                 "cholesky.local_scan": "_cholesky_local_scan"}
 
 
 def frame_tables(text: str) -> dict:
@@ -105,6 +112,9 @@ def main() -> int:
     ap.add_argument("--root", default=os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     ap.add_argument("--opcode", default="copy")
+    ap.add_argument("--program", default=None,
+                    help="builder function at which a source chain stops "
+                         "(default: the one the run dispatched)")
     args = ap.parse_args()
     root = os.path.abspath(args.root)
     os.makedirs(args.out, exist_ok=True)
@@ -126,6 +136,7 @@ def main() -> int:
 
             captured["lower"] = (fn, [jax.ShapeDtypeStruct(x.shape, x.dtype)
                                       for x in a], kw)
+            captured["site"] = site
         return plain_call(site, fn, *a, **kw)
 
     telemetry.call = capturing_call
@@ -157,11 +168,14 @@ def main() -> int:
              for s, e, n in trace_reduce.clip(events, window)]))
 
     meta, tables, program = {}, {}, None
+    builder = args.program or SITE_PROGRAMS.get(captured.get("site"),
+                                                "_cholesky_local")
     if captured:
         fn, avals, kw = captured["lower"]
         text = fn.lower(*avals, **kw).compile().as_text()
         program = _MODULE.match(text).group(1)
-        with gzip.open(os.path.join(args.out, "cholesky_local.hlo.txt.gz"),
+        with gzip.open(os.path.join(args.out,
+                                    builder.lstrip("_") + ".hlo.txt.gz"),
                        "wt") as f:
             f.write(text)
         meta, tables = instruction_metadata(text), frame_tables(text)
@@ -186,7 +200,7 @@ def main() -> int:
         # the op_name's tail (the primitive and its nearest scopes)
         tail = via + "/".join(op_name.split("/")[-3:])
         row = rows[(module, label, opcode, shape.group(1) if shape else "",
-                    frame_chain(tables, frame), tail)]
+                    frame_chain(tables, frame, builder), tail)]
         row[0] += ns
         row[1] += 1
     table = sorted(([*k, v[0] / 1e9, v[1]] for k, v in rows.items()),
@@ -194,6 +208,7 @@ def main() -> int:
     busy = sum(own.values()) / 1e9
     with open(os.path.join(args.out, "attribution.json"), "w") as f:
         json.dump({"workload": args.workload, "seed": args.seed,
+                   "program": builder,
                    "calls": calls, "window_s": window_s,
                    "own_s_total": busy,
                    "by_label": sorted(([k, v / 1e9]

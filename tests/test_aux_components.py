@@ -307,10 +307,14 @@ def test_bench_refuses_a_platform_that_is_not_the_chip(monkeypatch, capsys):
     monkeypatch.delenv("JAX_PLATFORMS", raising=False)
     assert bench.expected_platform() == "tpu"
     monkeypatch.setenv("DLAF_BENCH_VARIANT", "loop")
+    before = dict(os.environ)
     with pytest.raises(SystemExit) as exc:
         bench.main()
     assert exc.value.code not in (0, None)
     assert capsys.readouterr().out == ""
+    # the refused child wrote none of its arm's knobs into the process
+    # (they would steer every later test of this worker)
+    assert dict(os.environ) == before
     # the caller's own JAX_PLATFORMS=cpu is the one way onto the CPU
     monkeypatch.setenv("JAX_PLATFORMS", "cpu")
     assert bench.expected_platform() == "cpu"

@@ -73,6 +73,23 @@ def test_frame_chain_stops_at_the_program(attribution, frame, chain):
     assert attribution.frame_chain(tables, frame) == chain
 
 
+def test_frame_chain_stops_at_the_named_program(attribution):
+    """``--program``: the scan builder's chains stop at its own name (the
+    default stop is the unrolled builder's), and the two telemetry sites
+    of the local entry name their builders."""
+    hlo = HLO.replace('2 "_cholesky_local"', '2 "_cholesky_local_scan"')
+    tables = attribution.frame_tables(hlo)
+    assert attribution.frame_chain(tables, 4, "_cholesky_local_scan") == (
+        "ozaki.py:289(_mirror) < ozaki.py:492(syrk_f64) "
+        "< cholesky.py:325(_cholesky_local_scan)")
+    # without the name the chain runs on to the outermost frame
+    assert attribution.frame_chain(tables, 4).endswith(
+        "< telemetry.py:240(call)")
+    assert attribution.SITE_PROGRAMS == {
+        "cholesky.local": "_cholesky_local",
+        "cholesky.local_scan": "_cholesky_local_scan"}
+
+
 def test_module_of(attribution):
     modules = [(0, 10, "jit_a"), (20, 30, "jit_b")]
     assert [attribution.module_of(modules, t) for t in (0, 9, 10, 25, 40)] \

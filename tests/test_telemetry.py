@@ -88,7 +88,7 @@ def test_telemetry_call_records_compile_and_retrace(tmp_path):
         and retrace[0]["value"] == 2.0
     hbm = {(m["labels"]["what"]) for m in snap
            if m["name"] == "dlaf_hbm_bytes"}
-    assert {"args", "output", "temp", "peak"} <= hbm
+    assert {"args", "output", "temp", "code", "peak"} <= hbm
     assert obs.validate_file(path, require_telemetry=True) == []
 
 
