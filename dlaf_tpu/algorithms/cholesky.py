@@ -415,6 +415,65 @@ def _cholesky_local(a, *, uplo: str, nb: int, trailing: str = "loop",
     return (a, hinfo.local_factor_info(a)) if with_info else a
 
 
+def _carry_window(acc, start, shape):
+    """The ``shape`` window of the scan carry ``acc`` at ``start`` as a
+    value of its own, for a step that is about to overwrite it: behind the
+    barrier the compiler cannot fold the slice into the fusions that write
+    the two f32 planes of an f64 carry, and so never has to copy a plane to
+    keep its old window readable (:func:`_cholesky_local_scan`: "What a
+    step reads before it writes")."""
+    return jax.lax.optimization_barrier(
+        jax.lax.dynamic_slice(acc, start, shape))
+
+
+def _scan_bulk_update(acc, xt, lo, rows, live, *, uplo, chunks, syrk_like):
+    """``acc`` minus the stored triangle of ``xt @ xt^H`` (``xt``: the
+    (m, nb) masked panel, transposed for ``U``), past column / row
+    ``lo`` where the pending panel of the look-ahead form reaches
+    further up than its update may (None: the panel's own zeros do
+    it). One ``syrk_like`` self-product (``chunks`` None), or the
+    trapezoids of ``_cholesky_local_scan``'s ``bulk_chunks``. A chunk's
+    new contents are formed as a value of their own, behind a barrier,
+    and then written back (:func:`_cholesky_local_scan`: "What a step
+    reads before it writes"): the value lives from the chunk's last dot
+    to its write. Reading the old window out first (:func:`_carry_window`)
+    keeps it alive across the chunk's seven dots instead: 0.4% faster at
+    N=16384, nb=512 on a v5e and 0.4 GB more of temporaries there, 0.6 GB
+    more at n=8192, nb=1024 (PERF.md section 6, PR 32)."""
+    if chunks is None:
+        with oz.live_outputs(live[0]):
+            upd = syrk_like(xt)
+        if uplo == "L":
+            mask = rows[:, None] >= rows[None, :]
+            if lo is not None:
+                mask = mask & (rows[None, :] >= lo)
+        else:
+            mask = rows[:, None] <= rows[None, :]
+            if lo is not None:
+                mask = mask & (rows[:, None] >= lo)
+        return acc - jnp.where(mask, upd, 0)
+    for (c0, c1), kept in zip(chunks, live):
+        long, short = xt[c0:], xt[c0:c1]
+        rl, rs = rows[c0:], rows[c0:c1]
+        if uplo == "L":
+            with oz.live_outputs(kept):
+                upd = _oz_product(long, jnp.conj(short).T)
+            mask = rl[:, None] >= rs[None, :]
+            if lo is not None:
+                mask = mask & (rs[None, :] >= lo)
+            acc = acc.at[c0:, c0:c1].set(jax.lax.optimization_barrier(
+                acc[c0:, c0:c1] - jnp.where(mask, upd, 0)))
+        else:
+            with oz.live_outputs(kept):
+                upd = _oz_product(short, jnp.conj(long).T)
+            mask = rs[:, None] <= rl[None, :]
+            if lo is not None:
+                mask = mask & (rs[:, None] >= lo)
+            acc = acc.at[c0:c1, c0:].set(jax.lax.optimization_barrier(
+                acc[c0:c1, c0:] - jnp.where(mask, upd, 0)))
+    return acc
+
+
 @register_program_cache
 @functools.partial(jax.jit, static_argnames=("uplo", "nb", "use_mxu",
                                              "use_mixed", "lookahead",
@@ -448,6 +507,24 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
     block-column trapezoids (``bulk_chunks``). What the masks throw away is
     counted (``dlaf_ozaki_masked_macs_total``, the benchmark's
     ``masked_mac_share``).
+
+    What a step reads before it writes: between a step's read of a window
+    of the carried block and its write to the same window stands a value
+    of the window's size, behind an ``optimization_barrier``. The small
+    windows (the diagonal block, the panel column, the next column's
+    strip) are read out as values (:func:`_carry_window`) and the new
+    window is computed from them; a chunk of the bulk update is formed
+    whole, old window minus update, and then written
+    (:func:`_scan_bulk_update`). On the TPU an f64 block is two f32
+    planes, and the compiler updates each plane in place with a fusion of
+    its own; the double-f32 arithmetic of either plane reads the old
+    window of BOTH, so with read and write in one expression the second
+    fusion read plane A after the first had overwritten it, and the
+    compiler kept the old plane alive by copying all of it: one
+    whole-plane ``copy`` a chunk a step and one ahead of the strip's
+    update (N=16384, nb=512, one v5e: 181 ms of a 1212 ms program, PERF.md
+    section 5, PR 32). A window-sized value costs what it holds; the
+    arithmetic, and so every bit of the result, is the same.
 
     The panel and trailing routes follow the same knobs as the distributed
     scan builder (:func:`_build_dist_cholesky_scan`): ``use_mixed``
@@ -527,52 +604,16 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
         return out
 
     def bulk_update(acc, xt, lo, rows, live):
-        """``acc`` minus the stored triangle of ``xt @ xt^H`` (``xt``: the
-        (m, nb) masked panel, transposed for ``U``), past column / row
-        ``lo`` where the pending panel of the look-ahead form reaches
-        further up than its update may (None: the panel's own zeros do
-        it). One self-product, or :func:`bulk_chunks`' trapezoids."""
-        m = xt.shape[0]
-        chunks = bulk_chunks(m)
-        if chunks is None:
-            with oz.live_outputs(live[0]):
-                upd = syrk_like(xt)
-            if uplo == "L":
-                mask = rows[:, None] >= rows[None, :]
-                if lo is not None:
-                    mask = mask & (rows[None, :] >= lo)
-            else:
-                mask = rows[:, None] <= rows[None, :]
-                if lo is not None:
-                    mask = mask & (rows[:, None] >= lo)
-            return acc - jnp.where(mask, upd, 0)
-        for (c0, c1), kept in zip(chunks, live):
-            long, short = xt[c0:], xt[c0:c1]
-            rl, rs = rows[c0:], rows[c0:c1]
-            if uplo == "L":
-                with oz.live_outputs(kept):
-                    upd = _oz_product(long, jnp.conj(short).T)
-                mask = rl[:, None] >= rs[None, :]
-                if lo is not None:
-                    mask = mask & (rs[None, :] >= lo)
-                acc = acc.at[c0:, c0:c1].set(
-                    acc[c0:, c0:c1] - jnp.where(mask, upd, 0))
-            else:
-                with oz.live_outputs(kept):
-                    upd = _oz_product(short, jnp.conj(long).T)
-                mask = rs[:, None] <= rl[None, :]
-                if lo is not None:
-                    mask = mask & (rs[:, None] >= lo)
-                acc = acc.at[c0:c1, c0:].set(
-                    acc[c0:c1, c0:] - jnp.where(mask, upd, 0))
-        return acc
+        return _scan_bulk_update(acc, xt, lo, rows, live, uplo=uplo,
+                                 chunks=bulk_chunks(xt.shape[0]),
+                                 syrk_like=syrk_like)
 
     def make_step(m, live):
         rows = jnp.arange(m)
 
         def step(acc, k):
             k0 = k * nb
-            blk = jax.lax.dynamic_slice(acc, (k0, k0), (nb, nb))
+            blk = _carry_window(acc, (k0, k0), (nb, nb))
             ppan.count_step_kernel("fused" if step_fused else "xla")
             if use_mixed:
                 ppan.count_panel_kernel("xla", "potrf")
@@ -592,7 +633,7 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
                 acc = jax.lax.dynamic_update_slice(acc, diag, (k0, k0))
             below = rows >= k0 + nb      # (m,) rows/cols past the pivot
             if uplo == "L":
-                col = jax.lax.dynamic_slice(acc, (0, k0), (m, nb))
+                col = _carry_window(acc, (0, k0), (m, nb))
                 if use_mixed:
                     ppan.count_panel_kernel("xla", "solve")
                     inv_t = jnp.conj(fac_inv).T
@@ -621,7 +662,7 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
                 # in the trailing block; restricted to the stored triangle
                 acc = bulk_update(acc, panel, None, rows, live["bulk"])
             else:
-                row = jax.lax.dynamic_slice(acc, (k0, 0), (nb, m))
+                row = _carry_window(acc, (k0, 0), (nb, m))
                 if use_mixed:
                     ppan.count_panel_kernel("xla", "solve")
                     inv_t = jnp.conj(fac_inv).T
@@ -664,7 +705,7 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
         def step(carry, k):
             acc, pp = carry      # pp: previous step's masked panel
             k0 = k * nb
-            blk = jax.lax.dynamic_slice(acc, (k0, k0), (nb, nb))
+            blk = _carry_window(acc, (k0, k0), (nb, nb))
             ppan.count_step_kernel("fused" if step_fused else "xla")
             if use_mixed:
                 ppan.count_panel_kernel("xla", "potrf")
@@ -682,7 +723,7 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
             below = rows >= k0 + nb
             valid1 = k0 + 2 * nb <= m    # next block col/row exists
             if uplo == "L":
-                col = jax.lax.dynamic_slice(acc, (0, k0), (m, nb))
+                col = _carry_window(acc, (0, k0), (m, nb))
                 if use_mixed:
                     ppan.count_panel_kernel("xla", "solve")
                     inv_t = jnp.conj(fac_inv).T
@@ -713,13 +754,13 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
                 with oz.live_outputs(live["strip"]):
                     updc = (_oz_product(panel, jnp.conj(nstrip).T)
                             if use_mxu else panel @ jnp.conj(nstrip).T)
-                ccur = jax.lax.dynamic_slice(acc, (0, k0 + nb), (m, nb))
+                ccur = _carry_window(acc, (0, k0 + nb), (m, nb))
                 cols1 = k0 + nb + jnp.arange(nb)
                 cmask = (rows[:, None] >= cols1[None, :]) & valid1
                 acc = jax.lax.dynamic_update_slice(
                     acc, ccur - jnp.where(cmask, updc, 0), (0, k0 + nb))
             else:
-                row = jax.lax.dynamic_slice(acc, (k0, 0), (nb, m))
+                row = _carry_window(acc, (k0, 0), (nb, m))
                 if use_mixed:
                     ppan.count_panel_kernel("xla", "solve")
                     inv_t = jnp.conj(fac_inv).T
@@ -750,7 +791,7 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
                 with oz.live_outputs(live["strip"]):
                     updr = (_oz_product(nstrip, jnp.conj(pt).T) if use_mxu
                             else nstrip @ panel)
-                rcur = jax.lax.dynamic_slice(acc, (k0 + nb, 0), (nb, m))
+                rcur = _carry_window(acc, (k0 + nb, 0), (nb, m))
                 rows1 = k0 + nb + jnp.arange(nb)
                 rmask = (rows1[:, None] <= rows[None, :]) & valid1
                 acc = jax.lax.dynamic_update_slice(

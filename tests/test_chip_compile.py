@@ -17,8 +17,10 @@ read back without a chip). Nothing runs: a compile that passes is not a chip
 run — ``chip_smoke.py`` is.
 """
 
+import functools
 import importlib
 import os
+import re
 
 import numpy as np
 import pytest
@@ -275,3 +277,60 @@ def test_f64_product_schedules_on_the_tpu_compiler(one_chip, as_on_tpu,
         assert temp_mib <= PADDED_SCAN_TEMP_MIB[shape] + 0.5
     elif shape == "bulk":
         assert temp_mib > 2 * PADDED_SCAN_TEMP_MIB[shape]
+
+
+# ---------------------------------------------------------------------------
+# the local scan Cholesky's chunked segment (algorithms/cholesky.py)
+# ---------------------------------------------------------------------------
+
+#: ``temp_size_in_bytes`` the TPU compiler gave the parent of PR 32
+#: (950257e) for the program below, not donated (``memory_analysis()``; not
+#: a device number). Its ``while`` body held 2 ``copy`` of a whole
+#: ``f32[8192,8192]`` plane of the carry, one a chunk: 0.5 GiB written and
+#: as much read every step.
+SCAN_SEGMENT_PARENT_TEMP = 1_774_813_184
+
+
+def _while_bodies(text: str) -> dict:
+    """``{computation name: its lines}`` for the computations of a compiled
+    module's text that some ``while`` names as its body."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        head = re.match(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$", line)
+        if head:
+            cur = comps.setdefault(head.group(1), [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line)
+    bodies = set(re.findall(r" while\(.*?body=%?([\w.\-]+)", text))
+    return {name: comps[name] for name in bodies}
+
+
+def test_scan_cholesky_segment_copies_no_plane_of_its_carry(one_chip,
+                                                            as_on_tpu):
+    """ONE chunked segment of ``_cholesky_local_scan`` on the chip's route
+    (slice products, mixed panels, look-ahead) at n = 8192, nb = 1024: 8
+    steps, two chunks. No ``copy`` inside a ``while`` body has the shape of
+    a whole plane of the segment's block (the parent had 2: a step read the
+    window it was overwriting from the plane itself, see
+    ``_carry_window``), and the temporaries stay within the parent's plus
+    the two chunk values a step now forms before it writes them."""
+    chol = importlib.import_module("dlaf_tpu.algorithms.cholesky")
+    n, nb = 8192, 1024
+    assert chol.SCAN_BULK_CHUNK_AT <= n and n // chol.SCAN_BULK_CHUNK == 2
+    compiled = jax.jit(functools.partial(
+        chol._cholesky_local_scan.__wrapped__, uplo="L", nb=nb,
+        use_mxu=True, use_mixed=True, lookahead=True)).lower(
+        jax.ShapeDtypeStruct((n, n), jnp.float64, sharding=one_chip)
+    ).compile()
+    bodies = _while_bodies(compiled.as_text())
+    assert bodies
+    plane_copies = [line.strip()[:120] for lines in bodies.values()
+                    for line in lines
+                    if re.search(rf"= f32\[{n},{n}\]\S* copy\(", line)]
+    assert plane_copies == []
+    w = chol.SCAN_BULK_CHUNK
+    chunk_values = sum((n - c0) * w * 8 for c0 in range(0, n, w))
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= SCAN_SEGMENT_PARENT_TEMP + chunk_values, temp

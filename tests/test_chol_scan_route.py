@@ -11,12 +11,21 @@ slice products (64), against ``numpy.linalg.cholesky`` at the cell's
 tolerance ``60 n 2^-47``; at 31 steps the unrolled builder is asserted and
 its lowering pinned to the parent commit's. The counters the cell's
 metrics read are checked against hand counts.
+
+ISSUE 32: the scan bodies put a value of its own between their read of a
+window of the carry and their write to it (``_carry_window``,
+``_scan_bulk_update``). The factor must stay the parent's bit for bit:
+``reference_carry_window`` and ``reference_bulk_update`` below keep the
+parent's formulation (commit 950257e) of the two helpers, and every form
+of the builder is run both ways.
 """
 
+import functools
 import hashlib
 import importlib
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -255,6 +264,120 @@ CASES = [
 def test_local_cholesky_step_form(case, uplo, n, route, tmp_path,
                                   monkeypatch):
     case(uplo, n, route, tmp_path, monkeypatch)
+
+
+# ---------------------------------------------------------------------------
+# ISSUE 32: the bodies against the parent's formulation, bit for bit
+# ---------------------------------------------------------------------------
+
+def reference_carry_window(acc, start, shape):
+    """The parent's reads of the carry (950257e): plain slices, fused by
+    the compiler into whatever consumes them."""
+    return jax.lax.dynamic_slice(acc, start, shape)
+
+
+def reference_bulk_update(acc, xt, lo, rows, live, *, uplo, chunks,
+                          syrk_like):
+    """``bulk_update`` as the parent had it (950257e,
+    ``algorithms/cholesky.py:529-568``): each chunk subtracted from a slice
+    of the carry taken inside the expression that writes it back."""
+    if chunks is None:
+        with oz.live_outputs(live[0]):
+            upd = syrk_like(xt)
+        if uplo == "L":
+            mask = rows[:, None] >= rows[None, :]
+            if lo is not None:
+                mask = mask & (rows[None, :] >= lo)
+        else:
+            mask = rows[:, None] <= rows[None, :]
+            if lo is not None:
+                mask = mask & (rows[:, None] >= lo)
+        return acc - jnp.where(mask, upd, 0)
+    for (c0, c1), kept in zip(chunks, live):
+        long, short = xt[c0:], xt[c0:c1]
+        rl, rs = rows[c0:], rows[c0:c1]
+        if uplo == "L":
+            with oz.live_outputs(kept):
+                upd = chol_mod._oz_product(long, jnp.conj(short).T)
+            mask = rl[:, None] >= rs[None, :]
+            if lo is not None:
+                mask = mask & (rs[None, :] >= lo)
+            acc = acc.at[c0:, c0:c1].set(
+                acc[c0:, c0:c1] - jnp.where(mask, upd, 0))
+        else:
+            with oz.live_outputs(kept):
+                upd = chol_mod._oz_product(short, jnp.conj(long).T)
+            mask = rs[:, None] <= rl[None, :]
+            if lo is not None:
+                mask = mask & (rs[:, None] >= lo)
+            acc = acc.at[c0:c1, c0:].set(
+                acc[c0:c1, c0:] - jnp.where(mask, upd, 0))
+    return acc
+
+
+def _hpd_c128(n, seed):
+    rng = np.random.default_rng(seed)
+    g = rng.standard_normal((n, n)) + 1j * rng.standard_normal((n, n))
+    return (g + g.conj().T) / 2 + 2 * n * np.eye(n)
+
+
+@pytest.mark.parametrize("uplo, n, lookahead, with_info, dtype", [
+    pytest.param("L", 32 * NB, True, False, "f64", id="L-la"),
+    pytest.param("U", 32 * NB, True, False, "f64", id="U-la"),
+    pytest.param("L", 32 * NB, False, False, "f64", id="L-serial"),
+    pytest.param("U", 32 * NB, False, False, "f64", id="U-serial"),
+    pytest.param("L", 32 * NB, True, True, "f64", id="L-la-with-info"),
+    pytest.param("L", 32 * NB + 32, True, False, "f64",
+                 id="L-la-33steps-padded"),
+    pytest.param("U", 16 * NB + 32, False, True, "c128",
+                 id="U-serial-17steps-padded-info-c128"),
+])
+def test_scan_bodies_match_the_parents_bit_for_bit(
+        uplo, n, lookahead, with_info, dtype, as_on_tpu, monkeypatch):
+    """The route the chip runs (slice products, mixed panels) with the
+    chunk rule's constants at 256 / 1024: at nb = 64 the segments' blocks
+    have 2048, 1536 and 1024 rows (chunked: 8, 6 and 4 trapezoids a step)
+    and 512 (one self-product; 33 steps add a one-step segment of one
+    padded block; the complex case has 17 steps: 1088 rows chunked, 576 and
+    64 not). Reading the windows as values changes no bit of the factor,
+    of ``info`` or of the triangle that passes through."""
+    monkeypatch.setattr(chol_mod, "SCAN_BULK_CHUNK", 256)
+    monkeypatch.setattr(chol_mod, "SCAN_BULK_CHUNK_AT", 1024)
+    a = _hpd(n, seed=n) if dtype == "f64" else _hpd_c128(n, seed=n)
+
+    def factor():
+        fn = jax.jit(functools.partial(
+            chol_mod._cholesky_local_scan.__wrapped__, uplo=uplo, nb=NB,
+            use_mxu=True, use_mixed=True, lookahead=lookahead,
+            with_info=with_info))
+        out = fn(jnp.asarray(a))
+        return [np.asarray(x) for x in (out if with_info else (out,))]
+
+    got = factor()
+    windows, updates = [], []
+    monkeypatch.setattr(
+        chol_mod, "_carry_window",
+        lambda *args: windows.append(1) or reference_carry_window(*args))
+    monkeypatch.setattr(
+        chol_mod, "_scan_bulk_update",
+        lambda *args, **kw: updates.append(kw["chunks"] is not None)
+        or reference_bulk_update(*args, **kw))
+    want = factor()
+    # the reference ran, on chunked and unchunked segments
+    assert windows and True in updates and False in updates
+    nt = -(-n // NB)
+    assert len(updates) == -(-nt // 8)      # one traced body a segment
+    for g, w in zip(got, want):
+        assert g.dtype == w.dtype and np.array_equal(g, w)
+    if with_info:
+        assert got[1] == 0
+    ref = np.linalg.cholesky(a)
+    low = np.tril(got[0]) if uplo == "L" else np.triu(got[0]).conj().T
+    err = np.linalg.norm(low - ref) / np.linalg.norm(ref)
+    assert err <= 60 * n * EPS_TPU, err
+    keep = np.triu(a, 1) if uplo == "L" else np.tril(a, -1)
+    other = np.triu(got[0], 1) if uplo == "L" else np.tril(got[0], -1)
+    np.testing.assert_array_equal(other, keep)
 
 
 @pytest.mark.parametrize("trailing, builder", [
