@@ -74,6 +74,33 @@ class BandReduction:
 # Local
 # ---------------------------------------------------------------------------
 
+def _count_form(form: str, steps: int, columns: int) -> None:
+    """Trace-time accounting of the local builders, once per traced step
+    body: ``dlaf_red2band_bodies_total{form}`` (an unrolled builder traces
+    one body a panel, the scan builder one a telescoped segment),
+    ``dlaf_red2band_steps_total{form}`` (panel steps the body serves) and
+    ``dlaf_red2band_panel_columns_total{form}`` (Householder columns its
+    panel factorizations sweep, one sequential column step each). A
+    program is traced once a process, so the sums are one call's."""
+    if obs.metrics_active():
+        obs.counter("dlaf_red2band_bodies_total", form=form).inc()
+        obs.counter("dlaf_red2band_steps_total", form=form).inc(steps)
+        obs.counter("dlaf_red2band_panel_columns_total",
+                    form=form).inc(columns)
+
+
+def _local_phase(name: str):
+    """Host phase around ONE program the local branch dispatches
+    (``stage.reduction_to_band.<name>``, unfenced: the wall of an async
+    dispatch), counted as the entry's (``dlaf_entry_programs_total``): it
+    labels the device's idle gap before the program on a profiler
+    timeline."""
+    if obs.metrics_active():
+        obs.counter("dlaf_entry_programs_total",
+                    entry="reduction_to_band").inc()
+    return obs.span(f"stage.reduction_to_band.{name}", fenced=False)
+
+
 def _trail_chunk(m: int, nb: int, dtype) -> int:
     """Trace-time: row-chunk width for the local trailing update, 0 =
     unchunked (config ``red2band_trail_chunk``; see the knob docstring).
@@ -120,7 +147,10 @@ def _map_row_chunks(fn, cw: int, *arrs):
         return fn(*(lax.dynamic_slice(x, (i,) + (zero,) * (x.ndim - 1),
                                       (cw,) + x.shape[1:]) for x in arrs))
 
-    out = lax.map(body, starts)
+    # ONE traced body serves the nc chunks: trace-time counters inside
+    # (the slice products' MACs) count per executed chunk (obs.scoped_step)
+    out = lax.map(obs.scoped_step("red2band.rowchunk", body, steps=nc),
+                  starts)
     tail = m - (nc - 1) * cw          # static: rows only the last chunk has
     head = out[:-1].reshape(((nc - 1) * cw,) + out.shape[2:])
     return jnp.concatenate([head, out[-1, cw - tail:]], axis=0)
@@ -139,6 +169,7 @@ def _red2band_local(a, *, nb: int):
         k0, k1 = k * nb, (k + 1) * nb
         m_p = n - k1
         panel = a[k1:, k0:k1]
+        _count_form("unrolled", 1, min(m_p, nb))
         vfull, taus = panel_qr(panel)
         a = a.at[k1:, k0:k1].set(vfull)          # R in upper part, V below
         ntau = taus.shape[0]
@@ -250,9 +281,14 @@ def _red2band_local_scan(a, *, nb: int):
         off = p_start
         m_seg = (nt - off) * nb
         sub = a[off * nb:, off * nb:]
+        # a full-height panel of m_seg >= 2 nb rows sweeps nb columns a step
+        _count_form("scan", seg_len, seg_len * nb)
+        # ONE traced body serves the segment's steps: trace-time counters
+        # inside count per executed step (obs.scoped_step)
         (sub, taus), _ = jax.lax.scan(
-            make_step(m_seg, off), (sub, taus),
-            jnp.arange(p_start, p_start + seg_len))
+            obs.scoped_step("red2band.scanstep", make_step(m_seg, off),
+                            steps=seg_len),
+            (sub, taus), jnp.arange(p_start, p_start + seg_len))
         a = a.at[off * nb:, off * nb:].set(sub)
         p_start += seg_len
     return a[:n, :n], taus
@@ -664,19 +700,20 @@ def reduction_to_band(a: Matrix, band_size: int | None = None, *,
         n=n, nb=nb, band=band, dtype=np.dtype(a.dtype).name,
         grid=f"{a.dist.grid_size.row}x{a.dist.grid_size.col}"))
     if a.grid is None or a.grid.num_devices == 1:
+        if resolve_step_mode(steps) == "scan":
+            site, local = "reduction_to_band.local_scan", _red2band_local_scan
+        else:
+            site, local = "reduction_to_band.local", _red2band_local
+
         with entry_span, quiet_donation():
-            g = to_global(a.storage, a.dist, donate)
+            with _local_phase("to_global"):
+                g = to_global(a.storage, a.dist, donate)
             # program telemetry (DLAF_PROGRAM_TELEMETRY): off = passthrough
-            if resolve_step_mode(steps) == "scan":
-                out, taus = obs.telemetry.call(
-                    "reduction_to_band.local_scan", _red2band_local_scan,
-                    g, nb=band)
-            else:
-                out, taus = obs.telemetry.call(
-                    "reduction_to_band.local", _red2band_local, g, nb=band)
-            return BandReduction(
-                a.with_storage(global_to_tiles_donated(out, a.dist)),
-                taus, band)
+            with _local_phase("reduce"):
+                out, taus = obs.telemetry.call(site, local, g, nb=band)
+            with _local_phase("to_tiles"):
+                storage = global_to_tiles_donated(out, a.dist)
+            return BandReduction(a.with_storage(storage), taus, band)
     from ..config import resolved_comm_lookahead
 
     scan_mode = resolve_step_mode(steps) == "scan"

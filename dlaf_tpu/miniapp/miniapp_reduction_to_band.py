@@ -38,9 +38,13 @@ def build_parser() -> argparse.ArgumentParser:
     p.add_argument("--band-size", type=int, default=-1,
                    help="bandwidth; negative = block-size (reference "
                         "--band-size; must divide block-size; unlike the "
-                        "reference this also works distributed). NOTE: the "
-                        "step loop unrolls ceil(n/band)-1 panels at trace "
-                        "time — very small bands inflate compile time")
+                        "reference this also works distributed). The "
+                        "ceil(n/band)-1 panel steps are unrolled at trace "
+                        "time below config.resolve_step_mode's threshold "
+                        "(dist_step_mode auto: 32 panels on a TPU, 128 "
+                        "elsewhere) and run as telescoped lax.scan "
+                        "segments from it on, so the compile time stops "
+                        "growing with the panel count")
     add_miniapp_arguments(p)
     return p
 

@@ -4,7 +4,7 @@ the source lines that emitted it.
 
     python3 scripts/op_attribution.py --workload chol_d_n4096_1x1 --seed 7 \\
         --out chiprun_out/attr [--root <checkout>] [--opcode copy] \
-        [--program _cholesky_local_scan]
+        [--program _cholesky_local_scan] [--calls 1]
 
 Runs ``benchmark/run.py --trace 1`` of ``--root`` in this process (it holds
 the chip), then reads the run's xplane once more with the benchmark's own
@@ -12,15 +12,19 @@ readers: every ``XLA Ops`` event of the traced window is given to the ``XLA
 Modules`` event that encloses it, own
 time (``trace_reduce.self_times``) is summed per (module, instruction name),
 and the instruction name is looked up in the compiled text of the local
-Cholesky's one program (``jit_cholesky_local_on_tiles``: the layout moves
-and the builder the entry took, ``_cholesky_local`` or, from 32 block steps
-on, ``_cholesky_local_scan``: ``--program`` names the builder at which a
+entry's program (``SITE_PROGRAMS``: the Cholesky's one program,
+``jit_cholesky_local_on_tiles``, with the layout moves and the builder the
+entry took, ``_cholesky_local`` or, from 32 block steps on,
+``_cholesky_local_scan``; ``reduction_to_band``'s ``_red2band_local`` /
+``_red2band_local_scan``: ``--program`` names the builder at which a
 source chain stops, by default the one the run dispatched;
 ``.lower(...).compile().as_text()``, which keeps
 ``metadata={op_name=... stack_frame_id=...}`` and the tables that resolve a
 frame to file, line and function; the trace's event names do not, PERF.md
-section 3). Writes ``<out>/attribution.json`` and the compiled text
-(gzipped), and prints the ``--opcode`` rows by result shape and source line.
+section 3). Writes ``<out>/attribution.json`` (with own time by the source
+file of the innermost frame, ``by_file``, and by the builder's own line
+that emitted the instruction, ``by_site``) and the compiled text (gzipped), and prints the ``--opcode``
+rows by result shape and source line.
 """
 
 from __future__ import annotations
@@ -39,9 +43,11 @@ _RESULT = re.compile(r" = \(?([a-z0-9]+\[[0-9,]*\](?:\{[^}]*\})?)")
 _OPERAND = re.compile(r"%([A-Za-z_][\w.\-]*)")
 _TABLE_ROW = re.compile(r"^(\d+) (.*)$")
 _MODULE = re.compile(r"HloModule ([\w.\-]+)")
-#: the local Cholesky's telemetry sites and the builder each dispatches
+#: the local entries' telemetry sites and the builder each dispatches
 SITE_PROGRAMS = {"cholesky.local": "_cholesky_local",
-                 "cholesky.local_scan": "_cholesky_local_scan"}
+                 "cholesky.local_scan": "_cholesky_local_scan",
+                 "reduction_to_band.local": "_red2band_local",
+                 "reduction_to_band.local_scan": "_red2band_local_scan"}
 
 
 def frame_tables(text: str) -> dict:
@@ -61,11 +67,10 @@ def frame_tables(text: str) -> dict:
     return tables
 
 
-def frame_chain(tables: dict, frame_id: int, stop="_cholesky_local") -> str:
-    """``file:line(function) < caller ...`` from the innermost frame out to
-    the first frame in ``stop`` (a frame's ``parent_frame_id`` is its
-    parent's id plus one; 0 is none)."""
-    out = []
+def frames(tables: dict, frame_id: int):
+    """``(file name, line, function)`` of a stack frame and its callers,
+    innermost first (a frame's ``parent_frame_id`` is its parent's id plus
+    one; 0 is none)."""
     while frame_id and frame_id in tables.get("StackFrames", {}):
         ids = dict(kv.split("=") for kv in re.findall(
             r"\w+=\d+", tables["StackFrames"][frame_id]))
@@ -73,11 +78,29 @@ def frame_chain(tables: dict, frame_id: int, stop="_cholesky_local") -> str:
             r"\w+=\d+", tables["FileLocations"][int(ids["file_location_id"])]))
         fn = tables["FunctionNames"][int(loc["function_name_id"])].strip('"')
         path = tables["FileNames"][int(loc["file_name_id"])].strip('"')
-        out.append(f"{os.path.basename(path)}:{loc['line']}({fn})")
+        yield os.path.basename(path), int(loc["line"]), fn
+        frame_id = int(ids["parent_frame_id"]) - 1
+
+
+def frame_chain(tables: dict, frame_id: int, stop="_cholesky_local") -> str:
+    """``file:line(function) < caller ...`` from the innermost frame out to
+    the first frame in ``stop``, eight frames at most."""
+    out = []
+    for path, line, fn in frames(tables, frame_id):
+        out.append(f"{path}:{line}({fn})")
         if fn == stop or len(out) >= 8:
             break
-        frame_id = int(ids["parent_frame_id"]) - 1
     return " < ".join(out)
+
+
+def builder_site(tables: dict, frame_id: int, stop: str) -> str:
+    """``file:line`` of the innermost frame in the builder's own file (the
+    file of the frame of function ``stop``): the line of the builder that
+    emitted the instruction, however deep the library calls under it."""
+    chain = list(frames(tables, frame_id))
+    home = next((path for path, _line, fn in chain if fn == stop), None)
+    return next((f"{path}:{line}" for path, line, _fn in chain
+                 if path == home), "")
 
 
 def instruction_metadata(text: str) -> dict:
@@ -112,6 +135,11 @@ def main() -> int:
     ap.add_argument("--root", default=os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
     ap.add_argument("--opcode", default="copy")
+    ap.add_argument("--calls", type=int, default=0,
+                    help="read the window's first N calls only (a window "
+                         "whose device trace was cut off at the profiler's "
+                         "event limit: red2band_d_n8192_1x1 holds one call "
+                         "and a half); default: the whole window")
     ap.add_argument("--program", default=None,
                     help="builder function at which a source chain stops "
                          "(default: the one the run dispatched)")
@@ -131,7 +159,7 @@ def main() -> int:
     plain_call = telemetry.call
 
     def capturing_call(site, fn, *a, **kw):
-        if site.startswith("cholesky.local") and not captured:
+        if site in SITE_PROGRAMS and not captured:
             import jax
 
             captured["lower"] = (fn, [jax.ShapeDtypeStruct(x.shape, x.dtype)
@@ -156,6 +184,10 @@ def main() -> int:
     window_s = (window[1] - window[0]) / 1e9
     with open(os.path.join(out_dir, "walls.json")) as f:
         calls = len(json.load(f))
+    if args.calls:
+        spans = span_reduce.calls_of(host_spans)[:args.calls]
+        window, calls = (spans[0][0], spans[-1][1]), len(spans)
+        window_s = (window[1] - window[0]) / 1e9
 
     # own time per (module, instruction): self_times keys by name, so key
     # each device's events by the program that encloses them first
@@ -182,6 +214,8 @@ def main() -> int:
 
     rows = collections.defaultdict(lambda: [0, 0])
     by_label = collections.defaultdict(int)
+    by_file = collections.defaultdict(int)
+    by_site = collections.defaultdict(int)
     for key, ns in own.items():
         module, name = key.split("\t", 1)
         label, opcode, _stem = trace_reduce.parse_op(name)
@@ -199,8 +233,15 @@ def main() -> int:
                     break
         # the op_name's tail (the primitive and its nearest scopes)
         tail = via + "/".join(op_name.split("/")[-3:])
+        chain = frame_chain(tables, frame, builder)
+        # an instruction with an op_name and no frame (XLA's expansions:
+        # triangular_solve) goes by its primitive
+        bare = f"no frame: {op_name.rpartition('/')[2]} ({module})" \
+            if op_name else f"no metadata ({module})"
+        by_file[chain.split(":")[0] or bare] += ns
+        by_site[builder_site(tables, frame, builder) or bare] += ns
         row = rows[(module, label, opcode, shape.group(1) if shape else "",
-                    frame_chain(tables, frame, builder), tail)]
+                    chain, tail)]
         row[0] += ns
         row[1] += 1
     table = sorted(([*k, v[0] / 1e9, v[1]] for k, v in rows.items()),
@@ -214,6 +255,12 @@ def main() -> int:
                    "by_label": sorted(([k, v / 1e9]
                                        for k, v in by_label.items()),
                                       key=lambda kv: -kv[1]),
+                   "by_file": sorted(([k, v / 1e9]
+                                      for k, v in by_file.items()),
+                                     key=lambda kv: -kv[1]),
+                   "by_site": sorted(([k, v / 1e9]
+                                      for k, v in by_site.items()),
+                                     key=lambda kv: -kv[1]),
                    "rows": table}, f, indent=1)
     print(f"[attribution] calls={calls} own_s_total={busy:.4f} "
           f"window_s={window_s:.4f}")

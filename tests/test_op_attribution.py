@@ -87,7 +87,24 @@ def test_frame_chain_stops_at_the_named_program(attribution):
         "< telemetry.py:240(call)")
     assert attribution.SITE_PROGRAMS == {
         "cholesky.local": "_cholesky_local",
-        "cholesky.local_scan": "_cholesky_local_scan"}
+        "cholesky.local_scan": "_cholesky_local_scan",
+        "reduction_to_band.local": "_red2band_local",
+        "reduction_to_band.local_scan": "_red2band_local_scan"}
+
+
+def test_the_sites_are_the_entries_own(attribution):
+    """Every site of ``SITE_PROGRAMS`` is a telemetry site a local entry
+    dispatches its builder under, and the builder is a function of that
+    entry's module."""
+    import importlib
+
+    modules = {"cholesky": "dlaf_tpu.algorithms.cholesky",
+               "reduction_to_band": "dlaf_tpu.eigensolver.reduction_to_band"}
+    for site, builder in attribution.SITE_PROGRAMS.items():
+        mod = importlib.import_module(modules[site.split(".")[0]])
+        assert callable(getattr(mod, builder))
+        with open(mod.__file__) as f:
+            assert f'"{site}"' in f.read()
 
 
 def test_module_of(attribution):
