@@ -21,11 +21,23 @@ pays per-block dispatch this single fused loop avoids.
 ``geqrf``'s own algorithm — reference tile op ``dlaf/lapack/tile.h``
 geqrf wrapper) in plain jnp elementwise / reduction / outer-product ops.
 One ``lax.fori_loop`` iteration per column keeps the compile cost O(1) in
-the panel width; the per-column work is one masked column reduction + one
+the panel width; the per-column work is a masked column norm (``m``
+elements), ``v^H a`` as ONE multiply-and-sum pass over the panel and one
 rank-1 update of the trailing columns — ``m*k`` elements each, the same
 flop count as any Householder QR. A width-``k`` panel costs ``k``
 sequential steps; red2band panels are ``k = band`` (128-512) on ``m`` up
 to the matrix size.
+
+``v^H a`` is an elementwise product summed over the rows, in the panel's
+own dtype, and NOT ``conj(v) @ a``: a product with one output row has no
+MXU shape, the TPU compiler already turns the f32 / c64 ``dot_general``
+into that multiply-and-reduce, and for the emulated f64 it expands it into
+five nested loops, ~350 kernels a column: 296 of a column's 316 us on
+(8192, 128) panels, 52% of a reduction to band at N=8192 (4.58 s a call,
+2026-10 v5e). As a sum it is one kernel of 6.5 us, a column 25.8 us (9.3
+the loop's own overhead, 6.7 the rank-1 update, 0.8 the norm) and that
+reduction 2.21 s a call at equal residuals (PERF.md, PR 34;
+``tests/test_qr_panel.py`` keeps ``dot_general`` out of the loop body).
 
 ``panel_qr`` is the drop-in ``geqrf`` replacement used by the algorithm
 layer: it dispatches per the ``qr_panel`` config knob ("auto" = the
@@ -54,7 +66,9 @@ def _qr_panel_impl() -> str:
         tpu_choice="householder", other_choice="geqrf",
         detail="the jnp householder sweep measured 74.9 GF/s vs 49.3 for "
                "XLA's geqrf expansion on red2band 4096 scan at equal "
-               "7e-14-grade residuals — 2026-08-02 v5e")
+               "7e-14-grade residuals — 2026-08-02 v5e; 25.8 us a column "
+               "on (8192, 128) f64 panels since v^H a is a multiply-and-"
+               "sum (316 as a dot_general) — 2026-10-03 v5e")
 
 
 @functools.partial(jnp.vectorize, signature="(m,k)->(m,k),(p)")
@@ -108,7 +122,7 @@ def householder_qr(a):
         # storing tau itself for Q = H_1 ... H_k (real: conj is identity).
         # Earlier columns hold stored reflectors; later rows of col j are
         # written as the stored tail below.
-        vha = jnp.conj(v) @ a                                    # (k,)
+        vha = jnp.sum(jnp.conj(v)[:, None] * a, axis=0)          # (k,)
         upd = jnp.conj(tau) * v[:, None] * vha[None, :]
         a = a - jnp.where(cols[None, :] > j, upd, jnp.zeros_like(upd))
         # column j: R above (rows < j untouched), beta on the diagonal

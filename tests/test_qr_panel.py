@@ -163,3 +163,82 @@ def test_red2band_residual_parity_under_householder(monkeypatch):
     finally:
         monkeypatch.delenv("DLAF_QR_PANEL")
         config.initialize()
+
+
+_DTYPES = [np.float32, np.float64, np.complex64, np.complex128]
+
+
+def _panel(kind, dtype, rng):
+    """Panels that stress the column step's ``v^H a`` sum: ``parallel``,
+    trailing columns within 1e-6 of multiples of column 0, so the reflector
+    cancels them almost to nothing; ``orthogonal``, trailing columns
+    orthogonal to column 0's reflector, so every ``v^H a_c`` is a sum of
+    O(1) terms that cancels to roundoff; ``tall``, a (4096, 128) panel (the
+    sum's length on the chip-sized reductions)."""
+    cplx = np.issubdtype(dtype, np.complexfloating)
+
+    def normal(*shape):
+        x = rng.standard_normal(shape)
+        return x + 1j * rng.standard_normal(shape) if cplx else x
+
+    if kind == "tall":
+        return normal(4096, 128).astype(dtype)
+    m, k = 512, 32
+    x = normal(m)
+    if kind == "parallel":
+        a = np.outer(x, normal(k)) + 1e-6 * normal(m, k)
+        a[:, 0] = x
+        return a.astype(dtype)
+    # the reflector of column 0 (LAPACK's larfg), in float64
+    beta = -np.sign(x[0].real if x[0].real != 0 else 1.0) * np.linalg.norm(x)
+    v = x / (x[0] - beta)
+    v[0] = 1.0
+    a = normal(m, k)
+    a -= np.outer(v, np.conj(v) @ a) / np.vdot(v, v)
+    a[:, 0] = x
+    return a.astype(dtype)
+
+
+@pytest.mark.parametrize("dtype", _DTYPES)
+def test_column_loop_holds_no_dot_general(dtype):
+    """``v^H a`` is a multiply and a sum over the rows: XLA expands an
+    emulated-f64 ``dot_general`` with one output row into five loops a
+    column on the TPU (2.4 s of a 4.6 s reduction at N=8192, PERF.md, PR
+    34), so the loop body must stay free of it at every dtype."""
+    import jax
+
+    from dlaf_tpu.analysis import depgraph
+
+    x = jnp.zeros((64, 16), dtype)
+    eqns = list(depgraph.iter_eqns(jax.make_jaxpr(householder_qr)(x)))
+    # ONE fori_loop (a scan in the jaxpr: static trip count), not unrolled
+    # or split: the benchmark finds the sweep by its trip count
+    loops = [e for _, e in eqns if e.primitive.name in ("scan", "while")]
+    assert [e.params.get("length") for e in loops] == [16]
+    body = {e.primitive.name for path, e in eqns if path}
+    assert "reduce_sum" in body and "dot_general" not in body
+    text = jax.jit(householder_qr).lower(x).as_text()
+    assert text.count("stablehlo.while") == 1 and "dot_general" not in text
+
+
+@pytest.mark.parametrize("dtype", _DTYPES)
+@pytest.mark.parametrize("kind", ["parallel", "orthogonal", "tall"])
+def test_sweep_under_cancellation_and_height(kind, dtype):
+    """Backward error and orthogonality, computed by numpy in float64 /
+    complex128 from the stored reflectors, at the dtype's own grade."""
+    rng = np.random.default_rng(34)
+    a = _panel(kind, dtype, rng)
+    k = a.shape[1]
+    wide = np.complex128 if np.iscomplexobj(a) else np.float64
+    eps = np.finfo(dtype).eps
+    vfull, taus = householder_qr(jnp.asarray(a))
+    assert vfull.dtype == dtype and taus.dtype == dtype
+    vfull = np.asarray(vfull).astype(wide)
+    q = rebuild_q(vfull, np.asarray(taus).astype(wide))
+    r = np.triu(vfull[:k])
+    a = a.astype(wide)
+    assert np.linalg.norm(a - q @ r) / np.linalg.norm(a) < 50 * k * eps
+    assert np.linalg.norm(np.conj(q.T) @ q - np.eye(k)) < 50 * k * eps
+    if kind == "parallel":
+        # the cancelled columns are left at the perturbation's size
+        assert np.abs(r[1:, 1:]).max() < 1e-3 * np.abs(r[0]).max()
