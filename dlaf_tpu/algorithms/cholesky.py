@@ -154,21 +154,8 @@ def _cholesky_local(a, *, uplo: str, nb: int, trailing: str = "loop",
     # ``factorization/cholesky/impl.h:147-156,187-189``)
     la = None
     for k in range(nt):
-        if obs.metrics_active():
-            # trace-time tile-op accounting (once per compiled program):
-            # one potrf + (nt-k-1) panel-solve tiles per step, and the
-            # trailing update's tile-pair count under the loop schedule
-            tail = nt - k - 1
-            obs.counter("dlaf_algo_tile_ops_total", algo="cholesky",
-                        op="potrf").inc()
-            obs.counter("dlaf_algo_tile_ops_total", algo="cholesky",
-                        op="trsm").inc(tail)
-            obs.counter("dlaf_algo_tile_ops_total", algo="cholesky",
-                        op="herk").inc(tail)
-            obs.counter("dlaf_algo_tile_ops_total", algo="cholesky",
-                        op="gemm").inc(tail * (tail - 1) // 2)
-            _count_step_modes("cholesky", *((1, 0) if lookahead and tail
-                                            else (0, 1)))
+        _count_step_modes("cholesky", *((1, 0) if lookahead and k < nt - 1
+                                        else (0, 1)))
         k0, k1 = k * nb, min((k + 1) * nb, n)
         blk = a[k0:k1, k0:k1] if la is None else la[0]
         if step_fused and k1 < n:
@@ -239,26 +226,27 @@ def _cholesky_local(a, *, uplo: str, nb: int, trailing: str = "loop",
                     mask = jnp.triu(jnp.ones((m - w, m - w), dtype=bool))
                     a = a.at[k1 + w:, k1 + w:].add(jnp.where(mask, -upd, 0))
             continue
-        if use_oz:
-            # latency-bound panel ops in mixed precision (f32 seed + Newton,
-            # tile_ops.mixed): emulated-f64 potrf/trsm are the wall-clock
-            # bottleneck on TPU, not the trailing flops. The fused form
-            # shares the f32 seed solves between factor and inverse — one
-            # f32 cholesky + one f32 solve per step instead of two solves.
-            # Counted under impl="xla" like every non-fused panel kernel
-            # (the mixed form is still an XLA op chain)
-            ppan.count_panel_kernel("xla", "potrf")
-            fac, fac_inv = mx.potrf_inv_refined(uplo, blk)
-            other = "U" if uplo == "L" else "L"
-            diag = fac + tb.tri_mask(blk, other, k=-1)
-        else:
-            # panel_impl route (docs/pallas_panel.md): the fused Pallas
-            # potrf collapses XLA's blocked-cholesky thunk chain into one
-            # VMEM-resident kernel; "xla" keeps tl.potrf
-            fac_inv = None
-            diag = ppan.panel_potrf(uplo, blk, fused=panel_fused,
-                                  interpret=panel_interpret)
-        a = a.at[k0:k1, k0:k1].set(diag)
+        with obs.named_span("cholesky.panel"):
+            if use_oz:
+                # latency-bound panel ops in mixed precision (f32 seed + Newton,
+                # tile_ops.mixed): emulated-f64 potrf/trsm are the wall-clock
+                # bottleneck on TPU, not the trailing flops. The fused form
+                # shares the f32 seed solves between factor and inverse — one
+                # f32 cholesky + one f32 solve per step instead of two solves.
+                # Counted under impl="xla" like every non-fused panel kernel
+                # (the mixed form is still an XLA op chain)
+                ppan.count_panel_kernel("xla", "potrf")
+                fac, fac_inv = mx.potrf_inv_refined(uplo, blk)
+                other = "U" if uplo == "L" else "L"
+                diag = fac + tb.tri_mask(blk, other, k=-1)
+            else:
+                # panel_impl route (docs/pallas_panel.md): the fused Pallas
+                # potrf collapses XLA's blocked-cholesky thunk chain into one
+                # VMEM-resident kernel; "xla" keeps tl.potrf
+                fac_inv = None
+                diag = ppan.panel_potrf(uplo, blk, fused=panel_fused,
+                                      interpret=panel_interpret)
+            a = a.at[k0:k1, k0:k1].set(diag)
         if k1 == n:
             break
         m = n - k1
@@ -270,148 +258,158 @@ def _cholesky_local(a, *, uplo: str, nb: int, trailing: str = "loop",
             # in the reference impl.h:147-156; here XLA schedules it) —
             # under lookahead the panel source is the carried next-column
             # value from step k-1, not an `a` read
-            colsrc = a[k1:, k0:k1] if la is None else la[1]
-            if use_oz:
-                # refined explicit inverse (from the fused step above) ->
-                # the panel solve is one gemm instead of an emulated trsm;
-                # the gemm itself rides the int8 MXU path like the trailing
-                # update (native emulated-f64 gemm is ~3x slower)
-                ppan.count_panel_kernel("xla", "solve")
-                panel = tb.mm_mxu(colsrc, jnp.conj(fac_inv).T)
-            elif trailing == "invgemm":
-                ppan.count_panel_kernel("xla", "solve")
-                # explicit small triangular inverse, panel formed on the MXU
-                dinv = tb.trsm("L", "L", "N", "N", diag,
-                               jnp.eye(k1 - k0, dtype=a.dtype))
-                panel = colsrc @ jnp.conj(dinv).T
-            elif panel_fused:
-                # one grid-batched Pallas kernel for the whole strip
-                panel = ppan.panel_solve("R", "L", "C", "N", diag, colsrc,
-                                       fused=True, interpret=panel_interpret)
-            else:
-                ppan.count_panel_kernel("xla", "solve")
-                panel = tb.trsm("R", "L", "C", "N", diag, colsrc)
-            a = a.at[k1:, k0:k1].set(panel)
+            with obs.named_span("cholesky.panel"):
+                colsrc = a[k1:, k0:k1] if la is None else la[1]
+                if use_oz:
+                    # refined explicit inverse (from the fused step above) ->
+                    # the panel solve is one gemm instead of an emulated trsm;
+                    # the gemm itself rides the int8 MXU path like the trailing
+                    # update (native emulated-f64 gemm is ~3x slower)
+                    ppan.count_panel_kernel("xla", "solve")
+                    panel = tb.mm_mxu(colsrc, jnp.conj(fac_inv).T)
+                elif trailing == "invgemm":
+                    ppan.count_panel_kernel("xla", "solve")
+                    # explicit small triangular inverse, panel formed on the MXU
+                    dinv = tb.trsm("L", "L", "N", "N", diag,
+                                   jnp.eye(k1 - k0, dtype=a.dtype))
+                    panel = colsrc @ jnp.conj(dinv).T
+                elif panel_fused:
+                    # one grid-batched Pallas kernel for the whole strip
+                    panel = ppan.panel_solve("R", "L", "C", "N", diag, colsrc,
+                                           fused=True, interpret=panel_interpret)
+                else:
+                    ppan.count_panel_kernel("xla", "solve")
+                    panel = tb.trsm("R", "L", "C", "N", diag, colsrc)
+                a = a.at[k1:, k0:k1].set(panel)
             la = None
             if trailing == "loop":
-                # trailing per block column: herk on the diagonal block + one
-                # gemm below it — exact n^3/3 flops (reference impl.h:242-271)
-                for j in range(k + 1, nt):
-                    j0, j1 = j * nb, min((j + 1) * nb, n)
-                    pj = panel[j0 - k1: j1 - k1]
-                    dj = tb.herk("L", "N", pj, a[j0:j1, j0:j1], alpha=-1.0)
-                    a = a.at[j0:j1, j0:j1].set(dj)
-                    below = None
-                    if j1 < n:
-                        below = tb.gemm(panel[j1 - k1:], pj, a[j1:, j0:j1],
-                                        alpha=-1.0, beta=1.0, op_b="C")
-                        a = a.at[j1:, j0:j1].set(below)
-                    if lookahead and j == k + 1:
-                        # the loop schedule already emits column k+1 first;
-                        # carrying its values is what frees step k+1 from
-                        # the later columns' scatter chain
-                        la = (dj, below)
+                with obs.named_span("cholesky.bulk"):
+                    # trailing per block column: herk on the diagonal block + one
+                    # gemm below it — exact n^3/3 flops (reference impl.h:242-271)
+                    for j in range(k + 1, nt):
+                        j0, j1 = j * nb, min((j + 1) * nb, n)
+                        pj = panel[j0 - k1: j1 - k1]
+                        dj = tb.herk("L", "N", pj, a[j0:j1, j0:j1], alpha=-1.0)
+                        a = a.at[j0:j1, j0:j1].set(dj)
+                        below = None
+                        if j1 < n:
+                            below = tb.gemm(panel[j1 - k1:], pj, a[j1:, j0:j1],
+                                            alpha=-1.0, beta=1.0, op_b="C")
+                            a = a.at[j1:, j0:j1].set(below)
+                        if lookahead and j == k + 1:
+                            # the loop schedule already emits column k+1 first;
+                            # carrying its values is what frees step k+1 from
+                            # the later columns' scatter chain
+                            la = (dj, below)
             elif lookahead:
-                # next-panel-column strip first (consumed by step k+1 via
-                # the carry), then the remaining trailing as a (m-w)^2
-                # herk of the row-trimmed panel — same dots, same per-cell
-                # application order as the single masked product
-                w = min(nb, m)
-                pj = panel[:w]
-                updc = (_oz_product(panel, jnp.conj(pj).T) if use_oz
-                        else panel @ jnp.conj(pj).T)
-                cmask = jnp.arange(m)[:, None] >= jnp.arange(w)[None, :]
-                # x + where(mask, -upd, 0): the exact per-cell application
-                # the serial masked add performs (bitwise, zeros included)
-                new_col = a[k1:, k1:k1 + w] + jnp.where(cmask, -updc, 0)
-                a = a.at[k1:, k1:k1 + w].set(new_col)
-                la = (new_col[:w], new_col[w:] if k1 + w < n else None)
+                with obs.named_span("cholesky.strip"):
+                    # next-panel-column strip first (consumed by step k+1 via
+                    # the carry), then the remaining trailing as a (m-w)^2
+                    # herk of the row-trimmed panel — same dots, same per-cell
+                    # application order as the single masked product
+                    w = min(nb, m)
+                    pj = panel[:w]
+                    updc = (_oz_product(panel, jnp.conj(pj).T) if use_oz
+                            else panel @ jnp.conj(pj).T)
+                    cmask = jnp.arange(m)[:, None] >= jnp.arange(w)[None, :]
+                    # x + where(mask, -upd, 0): the exact per-cell application
+                    # the serial masked add performs (bitwise, zeros included)
+                    new_col = a[k1:, k1:k1 + w] + jnp.where(cmask, -updc, 0)
+                    a = a.at[k1:, k1:k1 + w].set(new_col)
+                    la = (new_col[:w], new_col[w:] if k1 + w < n else None)
                 if m > w:
-                    pr = panel[w:]
-                    if use_oz:
-                        upd = (oz.herk_c128(pr, slices=tb._oz_slices())
-                               if jnp.iscomplexobj(pr)
-                               else oz.syrk_f64(pr, slices=tb._oz_slices()))
-                    else:
-                        upd = pr @ jnp.conj(pr).T
-                    mask = jnp.tril(jnp.ones((m - w, m - w), dtype=bool))
-                    a = a.at[k1 + w:, k1 + w:].add(jnp.where(mask, -upd, 0))
+                    with obs.named_span("cholesky.bulk"):
+                        pr = panel[w:]
+                        if use_oz:
+                            upd = (oz.herk_c128(pr, slices=tb._oz_slices())
+                                   if jnp.iscomplexobj(pr)
+                                   else oz.syrk_f64(pr, slices=tb._oz_slices()))
+                        else:
+                            upd = pr @ jnp.conj(pr).T
+                        mask = jnp.tril(jnp.ones((m - w, m - w), dtype=bool))
+                        a = a.at[k1 + w:, k1 + w:].add(jnp.where(mask, -upd, 0))
             else:
-                # ONE full trailing update, masked to the lower triangle;
-                # "ozaki" forms it with int8 MXU passes instead of the
-                # software-emulated f64 gemm
-                if use_oz:
-                    upd = (oz.herk_c128(panel, slices=tb._oz_slices())
-                           if jnp.iscomplexobj(panel)
-                           else oz.syrk_f64(panel, slices=tb._oz_slices()))
-                else:
-                    upd = panel @ jnp.conj(panel).T
-                mask = jnp.tril(jnp.ones((m, m), dtype=bool))
-                a = a.at[k1:, k1:].add(jnp.where(mask, -upd, 0))
+                with obs.named_span("cholesky.bulk"):
+                    # ONE full trailing update, masked to the lower triangle;
+                    # "ozaki" forms it with int8 MXU passes instead of the
+                    # software-emulated f64 gemm
+                    if use_oz:
+                        upd = (oz.herk_c128(panel, slices=tb._oz_slices())
+                               if jnp.iscomplexobj(panel)
+                               else oz.syrk_f64(panel, slices=tb._oz_slices()))
+                    else:
+                        upd = panel @ jnp.conj(panel).T
+                    mask = jnp.tril(jnp.ones((m, m), dtype=bool))
+                    a = a.at[k1:, k1:].add(jnp.where(mask, -upd, 0))
         else:
             # upper: A = U^H U; panel is a block row
-            rowsrc = a[k0:k1, k1:] if la is None else la[1]
-            if use_oz:
-                ppan.count_panel_kernel("xla", "solve")
-                panel = tb.mm_mxu(jnp.conj(fac_inv).T, rowsrc)
-            elif trailing == "invgemm":
-                ppan.count_panel_kernel("xla", "solve")
-                dinv = tb.trsm("L", "U", "N", "N", diag,
-                               jnp.eye(k1 - k0, dtype=a.dtype))
-                panel = jnp.conj(dinv).T @ rowsrc
-            elif panel_fused:
-                panel = ppan.panel_solve("L", "U", "C", "N", diag, rowsrc,
-                                       fused=True, interpret=panel_interpret)
-            else:
-                ppan.count_panel_kernel("xla", "solve")
-                panel = tb.trsm("L", "U", "C", "N", diag, rowsrc)
-            a = a.at[k0:k1, k1:].set(panel)
+            with obs.named_span("cholesky.panel"):
+                rowsrc = a[k0:k1, k1:] if la is None else la[1]
+                if use_oz:
+                    ppan.count_panel_kernel("xla", "solve")
+                    panel = tb.mm_mxu(jnp.conj(fac_inv).T, rowsrc)
+                elif trailing == "invgemm":
+                    ppan.count_panel_kernel("xla", "solve")
+                    dinv = tb.trsm("L", "U", "N", "N", diag,
+                                   jnp.eye(k1 - k0, dtype=a.dtype))
+                    panel = jnp.conj(dinv).T @ rowsrc
+                elif panel_fused:
+                    panel = ppan.panel_solve("L", "U", "C", "N", diag, rowsrc,
+                                           fused=True, interpret=panel_interpret)
+                else:
+                    ppan.count_panel_kernel("xla", "solve")
+                    panel = tb.trsm("L", "U", "C", "N", diag, rowsrc)
+                a = a.at[k0:k1, k1:].set(panel)
             la = None
             if trailing == "loop":
-                for j in range(k + 1, nt):
-                    j0, j1 = j * nb, min((j + 1) * nb, n)
-                    pj = panel[:, j0 - k1: j1 - k1]
-                    dj = tb.herk("U", "C", pj, a[j0:j1, j0:j1], alpha=-1.0)
-                    a = a.at[j0:j1, j0:j1].set(dj)
-                    right = None
-                    if j1 < n:
-                        right = tb.gemm(pj, panel[:, j1 - k1:], a[j0:j1, j1:],
-                                        alpha=-1.0, beta=1.0, op_a="C")
-                        a = a.at[j0:j1, j1:].set(right)
-                    if lookahead and j == k + 1:
-                        la = (dj, right)
+                with obs.named_span("cholesky.bulk"):
+                    for j in range(k + 1, nt):
+                        j0, j1 = j * nb, min((j + 1) * nb, n)
+                        pj = panel[:, j0 - k1: j1 - k1]
+                        dj = tb.herk("U", "C", pj, a[j0:j1, j0:j1], alpha=-1.0)
+                        a = a.at[j0:j1, j0:j1].set(dj)
+                        right = None
+                        if j1 < n:
+                            right = tb.gemm(pj, panel[:, j1 - k1:], a[j0:j1, j1:],
+                                            alpha=-1.0, beta=1.0, op_a="C")
+                            a = a.at[j0:j1, j1:].set(right)
+                        if lookahead and j == k + 1:
+                            la = (dj, right)
             elif lookahead:
-                # next block-row strip first (carried), rest as the
-                # column-trimmed herk — the mirrored split
-                w = min(nb, m)
-                pt = jnp.conj(jnp.swapaxes(panel, -1, -2))
-                updr = (_oz_product(pt[:w], jnp.conj(pt).T) if use_oz
-                        else jnp.conj(panel[:, :w]).T @ panel)
-                rmask = jnp.arange(w)[:, None] <= jnp.arange(m)[None, :]
-                new_row = a[k1:k1 + w, k1:] + jnp.where(rmask, -updr, 0)
-                a = a.at[k1:k1 + w, k1:].set(new_row)
-                la = (new_row[:, :w], new_row[:, w:] if k1 + w < n else None)
-                if m > w:
-                    ptr = pt[w:]
-                    if use_oz:
-                        upd = (oz.herk_c128(ptr, slices=tb._oz_slices())
-                               if jnp.iscomplexobj(ptr)
-                               else oz.syrk_f64(ptr, slices=tb._oz_slices()))
-                    else:
-                        pr = panel[:, w:]
-                        upd = jnp.conj(pr).T @ pr
-                    mask = jnp.triu(jnp.ones((m - w, m - w), dtype=bool))
-                    a = a.at[k1 + w:, k1 + w:].add(jnp.where(mask, -upd, 0))
-            else:
-                if use_oz:
+                with obs.named_span("cholesky.strip"):
+                    # next block-row strip first (carried), rest as the
+                    # column-trimmed herk — the mirrored split
+                    w = min(nb, m)
                     pt = jnp.conj(jnp.swapaxes(panel, -1, -2))
-                    upd = (oz.herk_c128(pt, slices=tb._oz_slices())
-                           if jnp.iscomplexobj(panel)
-                           else oz.syrk_f64(pt, slices=tb._oz_slices()))
-                else:
-                    upd = jnp.conj(panel).T @ panel
-                mask = jnp.triu(jnp.ones((m, m), dtype=bool))
-                a = a.at[k1:, k1:].add(jnp.where(mask, -upd, 0))
+                    updr = (_oz_product(pt[:w], jnp.conj(pt).T) if use_oz
+                            else jnp.conj(panel[:, :w]).T @ panel)
+                    rmask = jnp.arange(w)[:, None] <= jnp.arange(m)[None, :]
+                    new_row = a[k1:k1 + w, k1:] + jnp.where(rmask, -updr, 0)
+                    a = a.at[k1:k1 + w, k1:].set(new_row)
+                    la = (new_row[:, :w], new_row[:, w:] if k1 + w < n else None)
+                if m > w:
+                    with obs.named_span("cholesky.bulk"):
+                        ptr = pt[w:]
+                        if use_oz:
+                            upd = (oz.herk_c128(ptr, slices=tb._oz_slices())
+                                   if jnp.iscomplexobj(ptr)
+                                   else oz.syrk_f64(ptr, slices=tb._oz_slices()))
+                        else:
+                            pr = panel[:, w:]
+                            upd = jnp.conj(pr).T @ pr
+                        mask = jnp.triu(jnp.ones((m - w, m - w), dtype=bool))
+                        a = a.at[k1 + w:, k1 + w:].add(jnp.where(mask, -upd, 0))
+            else:
+                with obs.named_span("cholesky.bulk"):
+                    if use_oz:
+                        pt = jnp.conj(jnp.swapaxes(panel, -1, -2))
+                        upd = (oz.herk_c128(pt, slices=tb._oz_slices())
+                               if jnp.iscomplexobj(panel)
+                               else oz.syrk_f64(pt, slices=tb._oz_slices()))
+                    else:
+                        upd = jnp.conj(panel).T @ panel
+                    mask = jnp.triu(jnp.ones((m, m), dtype=bool))
+                    a = a.at[k1:, k1:].add(jnp.where(mask, -upd, 0))
     return (a, hinfo.local_factor_info(a)) if with_info else a
 
 
@@ -604,88 +602,93 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
         return out
 
     def bulk_update(acc, xt, lo, rows, live):
-        return _scan_bulk_update(acc, xt, lo, rows, live, uplo=uplo,
-                                 chunks=bulk_chunks(xt.shape[0]),
-                                 syrk_like=syrk_like)
+        with obs.named_span("cholesky.bulk"):
+            return _scan_bulk_update(acc, xt, lo, rows, live, uplo=uplo,
+                                     chunks=bulk_chunks(xt.shape[0]),
+                                     syrk_like=syrk_like)
 
     def make_step(m, live):
         rows = jnp.arange(m)
 
         def step(acc, k):
             k0 = k * nb
-            blk = _carry_window(acc, (k0, k0), (nb, nb))
-            ppan.count_step_kernel("fused" if step_fused else "xla")
-            if use_mixed:
-                ppan.count_panel_kernel("xla", "potrf")
-                fac, fac_inv = mx.potrf_inv_refined(uplo, blk)
-                diag = fac + tb.tri_mask(blk, other, k=-1)
-            elif step_fused:
-                # step_impl route, scan form: the potrf is DEFERRED into
-                # the fused factor+solve kernel below (the trailing
-                # update's traced-index masks keep it outside the
-                # kernel, so the scan forms fuse the 2-op panel chain)
-                fac_inv = diag = None
-            else:
-                fac_inv = None
-                diag = ppan.panel_potrf(uplo, blk, fused=panel_fused,
-                                      interpret=panel_interpret)
-            if diag is not None:
-                acc = jax.lax.dynamic_update_slice(acc, diag, (k0, k0))
-            below = rows >= k0 + nb      # (m,) rows/cols past the pivot
-            if uplo == "L":
-                col = _carry_window(acc, (0, k0), (m, nb))
+            with obs.named_span("cholesky.panel"):
+                blk = _carry_window(acc, (k0, k0), (nb, nb))
+                ppan.count_step_kernel("fused" if step_fused else "xla")
                 if use_mixed:
-                    ppan.count_panel_kernel("xla", "solve")
-                    inv_t = jnp.conj(fac_inv).T
-                    with oz.live_outputs(live["panel"]):
-                        pfull = (tb.mm_mxu(col, inv_t) if use_mxu
-                                 else col @ inv_t)
+                    ppan.count_panel_kernel("xla", "potrf")
+                    fac, fac_inv = mx.potrf_inv_refined(uplo, blk)
+                    diag = fac + tb.tri_mask(blk, other, k=-1)
                 elif step_fused:
-                    # col's pivot rows hold the unfactored blk; the
-                    # write-back + explicit diag update below restore
-                    # the factored tile
-                    diag, pfull = ppan.fused_factor_solve(
-                        "L", blk, col, interpret=panel_interpret)
-                elif panel_fused:
-                    pfull = ppan.panel_solve("R", "L", "C", "N", diag, col,
-                                           fused=True,
-                                           interpret=panel_interpret)
+                    # step_impl route, scan form: the potrf is DEFERRED into
+                    # the fused factor+solve kernel below (the trailing
+                    # update's traced-index masks keep it outside the
+                    # kernel, so the scan forms fuse the 2-op panel chain)
+                    fac_inv = diag = None
                 else:
-                    ppan.count_panel_kernel("xla", "solve")
-                    pfull = tb.trsm("R", "L", "C", "N", diag, col)
-                panel = jnp.where(below[:, None], pfull, 0)
-                acc = jax.lax.dynamic_update_slice(
-                    acc, jnp.where(below[:, None], pfull, col), (0, k0))
-                if step_fused:
+                    fac_inv = None
+                    diag = ppan.panel_potrf(uplo, blk, fused=panel_fused,
+                                          interpret=panel_interpret)
+                if diag is not None:
                     acc = jax.lax.dynamic_update_slice(acc, diag, (k0, k0))
+                below = rows >= k0 + nb      # (m,) rows/cols past the pivot
+            if uplo == "L":
+                with obs.named_span("cholesky.panel"):
+                    col = _carry_window(acc, (0, k0), (m, nb))
+                    if use_mixed:
+                        ppan.count_panel_kernel("xla", "solve")
+                        inv_t = jnp.conj(fac_inv).T
+                        with oz.live_outputs(live["panel"]):
+                            pfull = (tb.mm_mxu(col, inv_t) if use_mxu
+                                     else col @ inv_t)
+                    elif step_fused:
+                        # col's pivot rows hold the unfactored blk; the
+                        # write-back + explicit diag update below restore
+                        # the factored tile
+                        diag, pfull = ppan.fused_factor_solve(
+                            "L", blk, col, interpret=panel_interpret)
+                    elif panel_fused:
+                        pfull = ppan.panel_solve("R", "L", "C", "N", diag, col,
+                                               fused=True,
+                                               interpret=panel_interpret)
+                    else:
+                        ppan.count_panel_kernel("xla", "solve")
+                        pfull = tb.trsm("R", "L", "C", "N", diag, col)
+                    panel = jnp.where(below[:, None], pfull, 0)
+                    acc = jax.lax.dynamic_update_slice(
+                        acc, jnp.where(below[:, None], pfull, col), (0, k0))
+                    if step_fused:
+                        acc = jax.lax.dynamic_update_slice(acc, diag, (k0, k0))
                 # panel is zero at rows <= pivot, so the update lives only
                 # in the trailing block; restricted to the stored triangle
                 acc = bulk_update(acc, panel, None, rows, live["bulk"])
             else:
-                row = _carry_window(acc, (k0, 0), (nb, m))
-                if use_mixed:
-                    ppan.count_panel_kernel("xla", "solve")
-                    inv_t = jnp.conj(fac_inv).T
-                    with oz.live_outputs(live["panel"]):
-                        pfull = (tb.mm_mxu(inv_t, row) if use_mxu
-                                 else inv_t @ row)
-                elif step_fused:
-                    diag, pfull = ppan.fused_factor_solve(
-                        "U", blk, row, interpret=panel_interpret)
-                elif panel_fused:
-                    pfull = ppan.panel_solve("L", "U", "C", "N", diag, row,
-                                           fused=True,
-                                           interpret=panel_interpret)
-                else:
-                    ppan.count_panel_kernel("xla", "solve")
-                    pfull = tb.trsm("L", "U", "C", "N", diag, row)
-                panel = jnp.where(below[None, :], pfull, 0)
-                acc = jax.lax.dynamic_update_slice(
-                    acc, jnp.where(below[None, :], pfull, row), (k0, 0))
-                if step_fused:
-                    acc = jax.lax.dynamic_update_slice(acc, diag, (k0, k0))
-                pt = jnp.conj(jnp.swapaxes(panel, -1, -2))
-                acc = bulk_update(acc, pt, None, rows, live["bulk"])
+                with obs.named_span("cholesky.panel"):
+                    row = _carry_window(acc, (k0, 0), (nb, m))
+                    if use_mixed:
+                        ppan.count_panel_kernel("xla", "solve")
+                        inv_t = jnp.conj(fac_inv).T
+                        with oz.live_outputs(live["panel"]):
+                            pfull = (tb.mm_mxu(inv_t, row) if use_mxu
+                                     else inv_t @ row)
+                    elif step_fused:
+                        diag, pfull = ppan.fused_factor_solve(
+                            "U", blk, row, interpret=panel_interpret)
+                    elif panel_fused:
+                        pfull = ppan.panel_solve("L", "U", "C", "N", diag, row,
+                                               fused=True,
+                                               interpret=panel_interpret)
+                    else:
+                        ppan.count_panel_kernel("xla", "solve")
+                        pfull = tb.trsm("L", "U", "C", "N", diag, row)
+                    panel = jnp.where(below[None, :], pfull, 0)
+                    acc = jax.lax.dynamic_update_slice(
+                        acc, jnp.where(below[None, :], pfull, row), (k0, 0))
+                    if step_fused:
+                        acc = jax.lax.dynamic_update_slice(acc, diag, (k0, k0))
+                with obs.named_span("cholesky.bulk"):
+                    pt = jnp.conj(jnp.swapaxes(panel, -1, -2))
+                    acc = bulk_update(acc, pt, None, rows, live["bulk"])
             return acc, None
 
         return step
@@ -705,97 +708,103 @@ def _cholesky_local_scan(a, *, uplo: str, nb: int, use_mxu: bool = False,
         def step(carry, k):
             acc, pp = carry      # pp: previous step's masked panel
             k0 = k * nb
-            blk = _carry_window(acc, (k0, k0), (nb, nb))
-            ppan.count_step_kernel("fused" if step_fused else "xla")
-            if use_mixed:
-                ppan.count_panel_kernel("xla", "potrf")
-                fac, fac_inv = mx.potrf_inv_refined(uplo, blk)
-                diag = fac + tb.tri_mask(blk, other, k=-1)
-            elif step_fused:
-                # potrf deferred into the fused factor+solve kernel
-                fac_inv = diag = None
-            else:
-                fac_inv = None
-                diag = ppan.panel_potrf(uplo, blk, fused=panel_fused,
-                                      interpret=panel_interpret)
-            if diag is not None:
-                acc = jax.lax.dynamic_update_slice(acc, diag, (k0, k0))
-            below = rows >= k0 + nb
-            valid1 = k0 + 2 * nb <= m    # next block col/row exists
-            if uplo == "L":
-                col = _carry_window(acc, (0, k0), (m, nb))
+            with obs.named_span("cholesky.panel"):
+                blk = _carry_window(acc, (k0, k0), (nb, nb))
+                ppan.count_step_kernel("fused" if step_fused else "xla")
                 if use_mixed:
-                    ppan.count_panel_kernel("xla", "solve")
-                    inv_t = jnp.conj(fac_inv).T
-                    with oz.live_outputs(live["panel"]):
-                        pfull = (tb.mm_mxu(col, inv_t) if use_mxu
-                                 else col @ inv_t)
+                    ppan.count_panel_kernel("xla", "potrf")
+                    fac, fac_inv = mx.potrf_inv_refined(uplo, blk)
+                    diag = fac + tb.tri_mask(blk, other, k=-1)
                 elif step_fused:
-                    diag, pfull = ppan.fused_factor_solve(
-                        "L", blk, col, interpret=panel_interpret)
-                elif panel_fused:
-                    pfull = ppan.panel_solve("R", "L", "C", "N", diag, col,
-                                           fused=True,
-                                           interpret=panel_interpret)
+                    # potrf deferred into the fused factor+solve kernel
+                    fac_inv = diag = None
                 else:
-                    ppan.count_panel_kernel("xla", "solve")
-                    pfull = tb.trsm("R", "L", "C", "N", diag, col)
-                panel = jnp.where(below[:, None], pfull, 0)
-                acc = jax.lax.dynamic_update_slice(
-                    acc, jnp.where(below[:, None], pfull, col), (0, k0))
-                if step_fused:
+                    fac_inv = None
+                    diag = ppan.panel_potrf(uplo, blk, fused=panel_fused,
+                                          interpret=panel_interpret)
+                if diag is not None:
                     acc = jax.lax.dynamic_update_slice(acc, diag, (k0, k0))
+                below = rows >= k0 + nb
+                valid1 = k0 + 2 * nb <= m    # next block col/row exists
+            if uplo == "L":
+                with obs.named_span("cholesky.panel"):
+                    col = _carry_window(acc, (0, k0), (m, nb))
+                    if use_mixed:
+                        ppan.count_panel_kernel("xla", "solve")
+                        inv_t = jnp.conj(fac_inv).T
+                        with oz.live_outputs(live["panel"]):
+                            pfull = (tb.mm_mxu(col, inv_t) if use_mxu
+                                     else col @ inv_t)
+                    elif step_fused:
+                        diag, pfull = ppan.fused_factor_solve(
+                            "L", blk, col, interpret=panel_interpret)
+                    elif panel_fused:
+                        pfull = ppan.panel_solve("R", "L", "C", "N", diag, col,
+                                               fused=True,
+                                               interpret=panel_interpret)
+                    else:
+                        ppan.count_panel_kernel("xla", "solve")
+                        pfull = tb.trsm("R", "L", "C", "N", diag, col)
+                    panel = jnp.where(below[:, None], pfull, 0)
+                    acc = jax.lax.dynamic_update_slice(
+                        acc, jnp.where(below[:, None], pfull, col), (0, k0))
+                    if step_fused:
+                        acc = jax.lax.dynamic_update_slice(acc, diag, (k0, k0))
                 # deferred bulk of step k-1: its next-col (block col k)
                 # was applied in body k-1, the rest lands here
                 acc = bulk_update(acc, pp, k0 + nb, rows, live["bulk"])
-                # eager next-column strip from THIS panel
-                nstrip = jax.lax.dynamic_slice(panel, (k0 + nb, 0),
-                                               (nb, nb))
-                with oz.live_outputs(live["strip"]):
-                    updc = (_oz_product(panel, jnp.conj(nstrip).T)
-                            if use_mxu else panel @ jnp.conj(nstrip).T)
-                ccur = _carry_window(acc, (0, k0 + nb), (m, nb))
-                cols1 = k0 + nb + jnp.arange(nb)
-                cmask = (rows[:, None] >= cols1[None, :]) & valid1
-                acc = jax.lax.dynamic_update_slice(
-                    acc, ccur - jnp.where(cmask, updc, 0), (0, k0 + nb))
+                with obs.named_span("cholesky.strip"):
+                    # eager next-column strip from THIS panel
+                    nstrip = jax.lax.dynamic_slice(panel, (k0 + nb, 0),
+                                                   (nb, nb))
+                    with oz.live_outputs(live["strip"]):
+                        updc = (_oz_product(panel, jnp.conj(nstrip).T)
+                                if use_mxu else panel @ jnp.conj(nstrip).T)
+                    ccur = _carry_window(acc, (0, k0 + nb), (m, nb))
+                    cols1 = k0 + nb + jnp.arange(nb)
+                    cmask = (rows[:, None] >= cols1[None, :]) & valid1
+                    acc = jax.lax.dynamic_update_slice(
+                        acc, ccur - jnp.where(cmask, updc, 0), (0, k0 + nb))
             else:
-                row = _carry_window(acc, (k0, 0), (nb, m))
-                if use_mixed:
-                    ppan.count_panel_kernel("xla", "solve")
-                    inv_t = jnp.conj(fac_inv).T
-                    with oz.live_outputs(live["panel"]):
-                        pfull = (tb.mm_mxu(inv_t, row) if use_mxu
-                                 else inv_t @ row)
-                elif step_fused:
-                    diag, pfull = ppan.fused_factor_solve(
-                        "U", blk, row, interpret=panel_interpret)
-                elif panel_fused:
-                    pfull = ppan.panel_solve("L", "U", "C", "N", diag, row,
-                                           fused=True,
-                                           interpret=panel_interpret)
-                else:
-                    ppan.count_panel_kernel("xla", "solve")
-                    pfull = tb.trsm("L", "U", "C", "N", diag, row)
-                panel = jnp.where(below[None, :], pfull, 0)
-                acc = jax.lax.dynamic_update_slice(
-                    acc, jnp.where(below[None, :], pfull, row), (k0, 0))
-                if step_fused:
-                    acc = jax.lax.dynamic_update_slice(acc, diag, (k0, k0))
-                ppt = jnp.conj(jnp.swapaxes(pp, -1, -2))
-                acc = bulk_update(acc, ppt, k0 + nb, rows, live["bulk"])
-                pt = jnp.conj(jnp.swapaxes(panel, -1, -2))
-                nstrip = jax.lax.dynamic_slice(pt, (k0 + nb, 0), (nb, nb))
-                # nstrip = conj(panel_block)^T, so nstrip @ panel IS the
-                # strip of conj(panel)^T @ panel (same dots as serial)
-                with oz.live_outputs(live["strip"]):
-                    updr = (_oz_product(nstrip, jnp.conj(pt).T) if use_mxu
-                            else nstrip @ panel)
-                rcur = _carry_window(acc, (k0 + nb, 0), (nb, m))
-                rows1 = k0 + nb + jnp.arange(nb)
-                rmask = (rows1[:, None] <= rows[None, :]) & valid1
-                acc = jax.lax.dynamic_update_slice(
-                    acc, rcur - jnp.where(rmask, updr, 0), (k0 + nb, 0))
+                with obs.named_span("cholesky.panel"):
+                    row = _carry_window(acc, (k0, 0), (nb, m))
+                    if use_mixed:
+                        ppan.count_panel_kernel("xla", "solve")
+                        inv_t = jnp.conj(fac_inv).T
+                        with oz.live_outputs(live["panel"]):
+                            pfull = (tb.mm_mxu(inv_t, row) if use_mxu
+                                     else inv_t @ row)
+                    elif step_fused:
+                        diag, pfull = ppan.fused_factor_solve(
+                            "U", blk, row, interpret=panel_interpret)
+                    elif panel_fused:
+                        pfull = ppan.panel_solve("L", "U", "C", "N", diag, row,
+                                               fused=True,
+                                               interpret=panel_interpret)
+                    else:
+                        ppan.count_panel_kernel("xla", "solve")
+                        pfull = tb.trsm("L", "U", "C", "N", diag, row)
+                    panel = jnp.where(below[None, :], pfull, 0)
+                    acc = jax.lax.dynamic_update_slice(
+                        acc, jnp.where(below[None, :], pfull, row), (k0, 0))
+                    if step_fused:
+                        acc = jax.lax.dynamic_update_slice(acc, diag, (k0, k0))
+                with obs.named_span("cholesky.bulk"):
+                    ppt = jnp.conj(jnp.swapaxes(pp, -1, -2))
+                    acc = bulk_update(acc, ppt, k0 + nb, rows, live["bulk"])
+                with obs.named_span("cholesky.strip"):
+                    pt = jnp.conj(jnp.swapaxes(panel, -1, -2))
+                    nstrip = jax.lax.dynamic_slice(pt, (k0 + nb, 0), (nb, nb))
+                    # nstrip = conj(panel_block)^T, so nstrip @ panel IS the
+                    # strip of conj(panel)^T @ panel (same dots as serial)
+                    with oz.live_outputs(live["strip"]):
+                        updr = (_oz_product(nstrip, jnp.conj(pt).T) if use_mxu
+                                else nstrip @ panel)
+                    rcur = _carry_window(acc, (k0 + nb, 0), (nb, m))
+                    rows1 = k0 + nb + jnp.arange(nb)
+                    rmask = (rows1[:, None] <= rows[None, :]) & valid1
+                    acc = jax.lax.dynamic_update_slice(
+                        acc, rcur - jnp.where(rmask, updr, 0), (k0 + nb, 0))
             return (acc, panel), None
 
         return step
@@ -1271,16 +1280,9 @@ def _build_dist_cholesky(dist, mesh, uplo, use_pallas, pallas_interpret,
             # Names carry no repeat index — identical across runs, so
             # histograms never fork. Counters are all trace-time.
             with obs.named_span(f"cholesky.step{k:03d}"):
-                if obs.metrics_active():
-                    obs.counter("dlaf_algo_tile_ops_total",
-                                algo="cholesky_dist", op="potrf").inc()
-                    obs.counter("dlaf_algo_tile_ops_total",
-                                algo="cholesky_dist", op="trailing_pairs"
-                                ).inc((ltr - max(0, -(-(k + 2 - Pr) // Pr)))
-                                      * (ltc - max(0, -(-(k + 2 - Qc) // Qc))))
-                    _count_step_modes(
-                        "cholesky_dist",
-                        *((1, 0) if lookahead and k + 1 < nt else (0, 1)))
+                _count_step_modes(
+                    "cholesky_dist",
+                    *((1, 0) if lookahead and k + 1 < nt else (0, 1)))
                 if comm_la:
                     # comm look-ahead (docs/comm_overlap.md): step k+1's
                     # panel chain — its bcast2d/bcast/all_gather included

@@ -260,53 +260,58 @@ def _build_dist_solve_scan(dist_a, dist_b, mesh, side, uplo, op, diag, dtype,
 
             def step(sub, i):
                 k = i if forward else nt - 1 - i
-                akk = bcast_diag_dyn(ctx_a, lta, k)
-                akk = pad_diag_identity_dyn(akk, jnp.minimum(mb, n - k * mb))
+                with obs.named_span("trsm.panel"):
+                    akk = bcast_diag_dyn(ctx_a, lta, k)
+                    akk = pad_diag_identity_dyn(akk, jnp.minimum(mb, n - k * mb))
                 if side == "L":
-                    bk = row_panel_dyn(ctx_b, sub, k, row_off=lu0)
-                    xk = ppan.panel_solve("L", uplo, op, diag, akk, bk,
+                    with obs.named_span("trsm.panel"):
+                        bk = row_panel_dyn(ctx_b, sub, k, row_off=lu0)
+                        xk = ppan.panel_solve("L", uplo, op, diag, akk, bk,
+                                              fused=panel_fused,
+                                              interpret=panel_interpret)
+                        own = ctx_b.rank_r == ctx_b.owner_r(k)
+                        row = ctx_b.kr(k) - lu0
+                        cur = jax.lax.dynamic_slice(
+                            sub, (row, 0, 0, 0), (1,) + sub.shape[1:])[0]
+                        sub = jax.lax.dynamic_update_slice(
+                            sub, jnp.where(own, xk, cur)[None], (row, 0, 0, 0))
+                    with obs.named_span("trsm.bulk"):
+                        g = ctx_b.g_rows(lu0, cnt)
+                        rem = ((g > k) if forward else (g < k)) & (g < nt)
+                        if op == "N":
+                            e = col_panel_dyn(ctx_a, lta, k, lu=lu0, count=cnt)
+                        else:
+                            rk = row_panel_dyn(ctx_a, lta, k, lu=lq0,
+                                               count=cnt_q)
+                            e = _tile_op(
+                                transpose_row_to_cols(ctx_a, rk, lq0, g), op)
+                        e = jnp.where(rem[:, None, None], e, jnp.zeros_like(e))
+                        upd = tb.contract("rab,cbd->rcad", e, xk)
+                        return sub - upd, None
+                with obs.named_span("trsm.panel"):
+                    bk = col_panel_dyn(ctx_b, sub, k, col_off=lu0)
+                    xk = ppan.panel_solve("R", uplo, op, diag, akk, bk,
                                           fused=panel_fused,
                                           interpret=panel_interpret)
-                    own = ctx_b.rank_r == ctx_b.owner_r(k)
-                    row = ctx_b.kr(k) - lu0
+                    own = ctx_b.rank_c == ctx_b.owner_c(k)
+                    col = ctx_b.kc(k) - lu0
                     cur = jax.lax.dynamic_slice(
-                        sub, (row, 0, 0, 0), (1,) + sub.shape[1:])[0]
+                        sub, (0, col, 0, 0),
+                        (sub.shape[0], 1) + sub.shape[2:])[:, 0]
                     sub = jax.lax.dynamic_update_slice(
-                        sub, jnp.where(own, xk, cur)[None], (row, 0, 0, 0))
-                    g = ctx_b.g_rows(lu0, cnt)
+                        sub, jnp.where(own, xk, cur)[:, None], (0, col, 0, 0))
+                with obs.named_span("trsm.bulk"):
+                    g = ctx_b.g_cols(lu0, cnt)
                     rem = ((g > k) if forward else (g < k)) & (g < nt)
                     if op == "N":
-                        e = col_panel_dyn(ctx_a, lta, k, lu=lu0, count=cnt)
+                        e = row_panel_dyn(ctx_a, lta, k, lu=lu0, count=cnt)
                     else:
-                        rk = row_panel_dyn(ctx_a, lta, k, lu=lq0,
-                                           count=cnt_q)
+                        ck = col_panel_dyn(ctx_a, lta, k, lu=lq0, count=cnt_q)
                         e = _tile_op(
-                            transpose_row_to_cols(ctx_a, rk, lq0, g), op)
+                            transpose_col_to_rows(ctx_a, ck, lq0, g), op)
                     e = jnp.where(rem[:, None, None], e, jnp.zeros_like(e))
-                    upd = tb.contract("rab,cbd->rcad", e, xk)
+                    upd = tb.contract("rab,cbd->rcad", xk, e)
                     return sub - upd, None
-                bk = col_panel_dyn(ctx_b, sub, k, col_off=lu0)
-                xk = ppan.panel_solve("R", uplo, op, diag, akk, bk,
-                                      fused=panel_fused,
-                                      interpret=panel_interpret)
-                own = ctx_b.rank_c == ctx_b.owner_c(k)
-                col = ctx_b.kc(k) - lu0
-                cur = jax.lax.dynamic_slice(
-                    sub, (0, col, 0, 0),
-                    (sub.shape[0], 1) + sub.shape[2:])[:, 0]
-                sub = jax.lax.dynamic_update_slice(
-                    sub, jnp.where(own, xk, cur)[:, None], (0, col, 0, 0))
-                g = ctx_b.g_cols(lu0, cnt)
-                rem = ((g > k) if forward else (g < k)) & (g < nt)
-                if op == "N":
-                    e = row_panel_dyn(ctx_a, lta, k, lu=lu0, count=cnt)
-                else:
-                    ck = col_panel_dyn(ctx_a, lta, k, lu=lq0, count=cnt_q)
-                    e = _tile_op(
-                        transpose_col_to_rows(ctx_a, ck, lq0, g), op)
-                e = jnp.where(rem[:, None, None], e, jnp.zeros_like(e))
-                upd = tb.contract("rab,cbd->rcad", xk, e)
-                return sub - upd, None
 
             return step
 
@@ -337,104 +342,113 @@ def _build_dist_solve_scan(dist_a, dist_b, mesh, side, uplo, op, diag, dtype,
                 sub, pe, pxk = carry
                 k = i if forward else nt - 1 - i
                 knext = k + 1 if forward else k - 1
-                akk = bcast_diag_dyn(ctx_a, lta, k)
-                akk = pad_diag_identity_dyn(akk, jnp.minimum(mb, n - k * mb))
+                with obs.named_span("trsm.panel"):
+                    akk = bcast_diag_dyn(ctx_a, lta, k)
+                    akk = pad_diag_identity_dyn(akk, jnp.minimum(mb, n - k * mb))
                 if side == "L":
-                    bk = row_panel_dyn(ctx_b, sub, k, row_off=lu0)
-                    xk = ppan.panel_solve("L", uplo, op, diag, akk, bk,
+                    with obs.named_span("trsm.panel"):
+                        bk = row_panel_dyn(ctx_b, sub, k, row_off=lu0)
+                        xk = ppan.panel_solve("L", uplo, op, diag, akk, bk,
+                                              fused=panel_fused,
+                                              interpret=panel_interpret)
+                        own = ctx_b.rank_r == ctx_b.owner_r(k)
+                        row = ctx_b.kr(k) - lu0
+                        cur = jax.lax.dynamic_slice(
+                            sub, (row, 0, 0, 0), (1,) + sub.shape[1:])[0]
+                        sub = jax.lax.dynamic_update_slice(
+                            sub, jnp.where(own, xk, cur)[None], (row, 0, 0, 0))
+                    with obs.named_span("trsm.bulk"):
+                        g = ctx_b.g_rows(lu0, cnt)
+                        rem = ((g > k) if forward else (g < k)) & (g < nt)
+
+                        def epanel():
+                            if op == "N":
+                                e = col_panel_dyn(ctx_a, lta, k, lu=lu0,
+                                                  count=cnt)
+                            else:
+                                rk = row_panel_dyn(ctx_a, lta, k, lu=lq0,
+                                                   count=cnt_q)
+                                e = _tile_op(
+                                    transpose_row_to_cols(ctx_a, rk, lq0, g), op)
+                            return jnp.where(rem[:, None, None], e,
+                                             jnp.zeros_like(e))
+
+                        if comm_la:
+                            # A-panel collectives emitted BEFORE the deferred
+                            # bulk of step k-1 (pe is pre-masked)
+                            e = epanel()
+                            sub = sub - tb.contract("rab,cbd->rcad", pe, pxk)
+                        else:
+                            sub = sub - tb.contract("rab,cbd->rcad", pe, pxk)
+                            e = epanel()
+                    with obs.named_span("trsm.strip"):
+                        # eager next-pivot-row strip (slot holds global row
+                        # knext only on its owner; gval-gating keeps every
+                        # other rank's slot in the pending set instead)
+                        rnext = ctx_b.kr(knext) - lu0
+                        gval = jax.lax.dynamic_slice(g, (rnext,), (1,))[0]
+                        hit = (gval == knext) & (knext >= 0) & (knext < nt)
+                        er = jax.lax.dynamic_slice(e, (rnext, 0, 0),
+                                                   (1, mb, mb))[0]
+                        updn = tb.contract("ab,cbd->cad", er, xk)
+                        rcur = jax.lax.dynamic_slice(
+                            sub, (rnext, 0, 0, 0), (1,) + sub.shape[1:])[0]
+                        sub = jax.lax.dynamic_update_slice(
+                            sub, (rcur - jnp.where(hit, updn, 0))[None],
+                            (rnext, 0, 0, 0))
+                    with obs.named_span("trsm.bulk"):
+                        pe_next = jnp.where((rem & (g != knext))[:, None, None],
+                                            e, jnp.zeros_like(e))
+                    return (sub, pe_next, xk), None
+                with obs.named_span("trsm.panel"):
+                    bk = col_panel_dyn(ctx_b, sub, k, col_off=lu0)
+                    xk = ppan.panel_solve("R", uplo, op, diag, akk, bk,
                                           fused=panel_fused,
                                           interpret=panel_interpret)
-                    own = ctx_b.rank_r == ctx_b.owner_r(k)
-                    row = ctx_b.kr(k) - lu0
+                    own = ctx_b.rank_c == ctx_b.owner_c(k)
+                    col = ctx_b.kc(k) - lu0
                     cur = jax.lax.dynamic_slice(
-                        sub, (row, 0, 0, 0), (1,) + sub.shape[1:])[0]
+                        sub, (0, col, 0, 0),
+                        (sub.shape[0], 1) + sub.shape[2:])[:, 0]
                     sub = jax.lax.dynamic_update_slice(
-                        sub, jnp.where(own, xk, cur)[None], (row, 0, 0, 0))
-                    g = ctx_b.g_rows(lu0, cnt)
+                        sub, jnp.where(own, xk, cur)[:, None], (0, col, 0, 0))
+                with obs.named_span("trsm.bulk"):
+                    g = ctx_b.g_cols(lu0, cnt)
                     rem = ((g > k) if forward else (g < k)) & (g < nt)
 
                     def epanel():
                         if op == "N":
-                            e = col_panel_dyn(ctx_a, lta, k, lu=lu0,
-                                              count=cnt)
+                            e = row_panel_dyn(ctx_a, lta, k, lu=lu0, count=cnt)
                         else:
-                            rk = row_panel_dyn(ctx_a, lta, k, lu=lq0,
+                            ck = col_panel_dyn(ctx_a, lta, k, lu=lq0,
                                                count=cnt_q)
                             e = _tile_op(
-                                transpose_row_to_cols(ctx_a, rk, lq0, g), op)
+                                transpose_col_to_rows(ctx_a, ck, lq0, g), op)
                         return jnp.where(rem[:, None, None], e,
                                          jnp.zeros_like(e))
 
                     if comm_la:
-                        # A-panel collectives emitted BEFORE the deferred
-                        # bulk of step k-1 (pe is pre-masked)
                         e = epanel()
-                        sub = sub - tb.contract("rab,cbd->rcad", pe, pxk)
+                        sub = sub - tb.contract("rab,cbd->rcad", pxk, pe)
                     else:
-                        sub = sub - tb.contract("rab,cbd->rcad", pe, pxk)
+                        sub = sub - tb.contract("rab,cbd->rcad", pxk, pe)
                         e = epanel()
-                    # eager next-pivot-row strip (slot holds global row
-                    # knext only on its owner; gval-gating keeps every
-                    # other rank's slot in the pending set instead)
-                    rnext = ctx_b.kr(knext) - lu0
-                    gval = jax.lax.dynamic_slice(g, (rnext,), (1,))[0]
+                with obs.named_span("trsm.strip"):
+                    cnext = ctx_b.kc(knext) - lu0
+                    gval = jax.lax.dynamic_slice(g, (cnext,), (1,))[0]
                     hit = (gval == knext) & (knext >= 0) & (knext < nt)
-                    er = jax.lax.dynamic_slice(e, (rnext, 0, 0),
+                    ec = jax.lax.dynamic_slice(e, (cnext, 0, 0),
                                                (1, mb, mb))[0]
-                    updn = tb.contract("ab,cbd->cad", er, xk)
-                    rcur = jax.lax.dynamic_slice(
-                        sub, (rnext, 0, 0, 0), (1,) + sub.shape[1:])[0]
+                    updn = tb.contract("rab,bd->rad", xk, ec)
+                    ccur = jax.lax.dynamic_slice(
+                        sub, (0, cnext, 0, 0),
+                        (sub.shape[0], 1) + sub.shape[2:])[:, 0]
                     sub = jax.lax.dynamic_update_slice(
-                        sub, (rcur - jnp.where(hit, updn, 0))[None],
-                        (rnext, 0, 0, 0))
+                        sub, (ccur - jnp.where(hit, updn, 0))[:, None],
+                        (0, cnext, 0, 0))
+                with obs.named_span("trsm.bulk"):
                     pe_next = jnp.where((rem & (g != knext))[:, None, None],
                                         e, jnp.zeros_like(e))
-                    return (sub, pe_next, xk), None
-                bk = col_panel_dyn(ctx_b, sub, k, col_off=lu0)
-                xk = ppan.panel_solve("R", uplo, op, diag, akk, bk,
-                                      fused=panel_fused,
-                                      interpret=panel_interpret)
-                own = ctx_b.rank_c == ctx_b.owner_c(k)
-                col = ctx_b.kc(k) - lu0
-                cur = jax.lax.dynamic_slice(
-                    sub, (0, col, 0, 0),
-                    (sub.shape[0], 1) + sub.shape[2:])[:, 0]
-                sub = jax.lax.dynamic_update_slice(
-                    sub, jnp.where(own, xk, cur)[:, None], (0, col, 0, 0))
-                g = ctx_b.g_cols(lu0, cnt)
-                rem = ((g > k) if forward else (g < k)) & (g < nt)
-
-                def epanel():
-                    if op == "N":
-                        e = row_panel_dyn(ctx_a, lta, k, lu=lu0, count=cnt)
-                    else:
-                        ck = col_panel_dyn(ctx_a, lta, k, lu=lq0,
-                                           count=cnt_q)
-                        e = _tile_op(
-                            transpose_col_to_rows(ctx_a, ck, lq0, g), op)
-                    return jnp.where(rem[:, None, None], e,
-                                     jnp.zeros_like(e))
-
-                if comm_la:
-                    e = epanel()
-                    sub = sub - tb.contract("rab,cbd->rcad", pxk, pe)
-                else:
-                    sub = sub - tb.contract("rab,cbd->rcad", pxk, pe)
-                    e = epanel()
-                cnext = ctx_b.kc(knext) - lu0
-                gval = jax.lax.dynamic_slice(g, (cnext,), (1,))[0]
-                hit = (gval == knext) & (knext >= 0) & (knext < nt)
-                ec = jax.lax.dynamic_slice(e, (cnext, 0, 0),
-                                           (1, mb, mb))[0]
-                updn = tb.contract("rab,bd->rad", xk, ec)
-                ccur = jax.lax.dynamic_slice(
-                    sub, (0, cnext, 0, 0),
-                    (sub.shape[0], 1) + sub.shape[2:])[:, 0]
-                sub = jax.lax.dynamic_update_slice(
-                    sub, (ccur - jnp.where(hit, updn, 0))[:, None],
-                    (0, cnext, 0, 0))
-                pe_next = jnp.where((rem & (g != knext))[:, None, None],
-                                    e, jnp.zeros_like(e))
                 return (sub, pe_next, xk), None
 
             return step

@@ -24,12 +24,13 @@ sub-jaxpr and reports the control-flow path it took to reach each eqn.
 from __future__ import annotations
 
 import dataclasses
-import re
 from typing import Callable, Iterator, Sequence, Tuple, Union
 
 import jax
 from jax import core as jax_core_legacy  # DropVar / Tracer still live here
 from jax.extend import core as jax_core
+
+from ..obs import scopes
 
 #: Cross-device collective primitives, as spelled in this jax line's
 #: jaxprs (``lax.psum`` -> ``psum``; ``bcast``'s mask+psum realization is
@@ -361,34 +362,23 @@ def dropped_outputs(scan_eqn) -> list:
 # Per-step scope structure (ISSUE 16)
 # ---------------------------------------------------------------------------
 
-#: The per-step ``named_scope`` convention every pipelined builder
-#: annotates with (``<algo>.step<k>.<phase>``, obs.named_span) and the
-#: index-free scan form (``<algo>.scanstep[.<phase>]``, obs.scoped_step).
-#: Kept textually identical to obs.critpath's HLO-side patterns — the
-#: jaxpr name stack and the compiled op_name metadata carry the same
-#: scopes, so the static structure here and the measured timeline there
-#: join on the same keys.
-STEP_SCOPE_RE = re.compile(
-    r"([A-Za-z0-9_]+)\.step(\d+)(?:\.(panel|strip|bulk))?")
-SCAN_SCOPE_RE = re.compile(
-    r"([A-Za-z0-9_]+)\.scanstep(?:\.(panel|strip|bulk))?")
-
-
 def step_scope_of(eqn) -> Tuple[str, int, str] | None:
     """``(algo, step, phase)`` of an eqn's innermost step scope, from its
-    traced name stack — or ``None`` for unscoped eqns.  Scan-body scopes
-    carry no index and report step ``-1``; phase defaults to ``other``
-    (the scope names only the step)."""
-    stack = str(getattr(eqn.source_info, "name_stack", "") or "")
-    hits = list(STEP_SCOPE_RE.finditer(stack))
-    if hits:
-        h = hits[-1]  # innermost scope wins (comm-lookahead hoisting)
-        return (h.group(1), int(h.group(2)), h.group(3) or "other")
-    hits = list(SCAN_SCOPE_RE.finditer(stack))
-    if hits:
-        h = hits[-1]
-        return (h.group(1), -1, h.group(2) or "other")
-    return None
+    traced name stack — or ``None`` for unscoped eqns (and for eqns under
+    a phase with no step marker).  The per-step ``named_scope`` convention
+    every pipelined builder annotates with (``<algo>.step<k>.<phase>``,
+    obs.named_span) and the index-free scan form (``<algo>.scanstep``,
+    obs.scoped_step) are parsed by :mod:`dlaf_tpu.obs.scopes`, as
+    obs.critpath's HLO side is: the jaxpr name stack and the compiled
+    op_name metadata carry the same scopes, so the static structure here
+    and the measured timeline there join on the same keys.  Scan-body
+    scopes carry no index and report step ``-1``; phase defaults to
+    ``other`` (the scope names only the step)."""
+    scope = scopes.parse(
+        str(getattr(eqn.source_info, "name_stack", "") or ""))
+    if scope is None or scope.step is None:
+        return None
+    return (scope.algo, scope.step, scope.phase or "other")
 
 
 def step_groups(eqns: Sequence) -> dict:

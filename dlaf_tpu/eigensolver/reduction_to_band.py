@@ -168,33 +168,39 @@ def _red2band_local(a, *, nb: int):
     for k in range(nt - 1):
         k0, k1 = k * nb, (k + 1) * nb
         m_p = n - k1
-        panel = a[k1:, k0:k1]
-        _count_form("unrolled", 1, min(m_p, nb))
-        vfull, taus = panel_qr(panel)
-        a = a.at[k1:, k0:k1].set(vfull)          # R in upper part, V below
-        ntau = taus.shape[0]
-        taus_out = taus_out.at[k, :ntau].set(taus)
-        v = jnp.tril(vfull, -1) + jnp.eye(m_p, nb, dtype=a.dtype)
-        if ntau < nb:
-            taus = jnp.pad(taus, (0, nb - ntau))
-        t = larft(v, taus)
-        trail = a[k1:, k1:]                       # full Hermitian
-        vt = v @ t
-        cw = _trail_chunk(m_p, nb, a.dtype)
-        if cw:
-            w = _map_row_chunks(lambda tr: tb.mm(tr, vt), cw, trail)
-        else:
-            w = tb.mm(trail, vt)                  # A V T
-        m = tb.mm(v.conj().T, w)                  # V^H W  (pw x pw)
-        x = w - 0.5 * v @ (t.conj().T @ m)
-        vh, xh = v.conj().T, x.conj().T
-        if cw:
-            new_trail = _map_row_chunks(
-                lambda tr, xr, vr: tr - tb.mm(xr, vh) - tb.mm(vr, xh),
-                cw, trail, x, v)
-        else:
-            new_trail = trail - tb.mm(x, vh) - tb.mm(v, xh)
-        a = a.at[k1:, k1:].set(new_trail)
+        # trace-time phase names, the scan form's (obs/scopes.py)
+        with obs.named_span("red2band.panel"):
+            panel = a[k1:, k0:k1]
+            _count_form("unrolled", 1, min(m_p, nb))
+            vfull, taus = panel_qr(panel)
+            a = a.at[k1:, k0:k1].set(vfull)      # R in upper part, V below
+            ntau = taus.shape[0]
+            taus_out = taus_out.at[k, :ntau].set(taus)
+            v = jnp.tril(vfull, -1) + jnp.eye(m_p, nb, dtype=a.dtype)
+            if ntau < nb:
+                taus = jnp.pad(taus, (0, nb - ntau))
+        with obs.named_span("red2band.larft"):
+            t = larft(v, taus)
+        with obs.named_span("red2band.w"):
+            trail = a[k1:, k1:]                   # full Hermitian
+            vt = v @ t
+            cw = _trail_chunk(m_p, nb, a.dtype)
+            if cw:
+                w = _map_row_chunks(lambda tr: tb.mm(tr, vt), cw, trail)
+            else:
+                w = tb.mm(trail, vt)              # A V T
+        with obs.named_span("red2band.x"):
+            m = tb.mm(v.conj().T, w)              # V^H W  (pw x pw)
+            x = w - 0.5 * v @ (t.conj().T @ m)
+        with obs.named_span("red2band.update"):
+            vh, xh = v.conj().T, x.conj().T
+            if cw:
+                new_trail = _map_row_chunks(
+                    lambda tr, xr, vr: tr - tb.mm(xr, vh) - tb.mm(vr, xh),
+                    cw, trail, x, v)
+            else:
+                new_trail = trail - tb.mm(x, vh) - tb.mm(v, xh)
+            a = a.at[k1:, k1:].set(new_trail)
     return a, taus_out
 
 
@@ -227,44 +233,54 @@ def _red2band_local_scan(a, *, nb: int):
 
         def step(carry, k):
             acc, taus_out = carry
-            k0 = (k - off) * nb            # panel column inside the slice
-            bdy = k0 + nb
-            below = rows >= bdy            # (m,)
-            raw = jax.lax.dynamic_slice(acc, (0, k0), (m, nb))
-            pan = jnp.roll(jnp.where(below[:, None], raw, 0), -bdy, axis=0)
-            # pan has m >= 2*nb rows whenever a step runs, so panel_qr
-            # returns exactly nb taus; dead columns masked below
-            vfull, taus = panel_qr(pan)
-            col_live = jnp.arange(nb) < (n - (k + 1) * nb)
-            taus = jnp.where(col_live, taus, jnp.zeros_like(taus))
-            taus_out = taus_out.at[k].set(taus)
-            vtop = jnp.tril(vfull, -1) + jnp.eye(m, nb, dtype=acc.dtype)
-            t = larft(vtop, taus)
-            v = jnp.where(below[:, None], jnp.roll(vtop, bdy, axis=0), 0)
-            vr = jnp.roll(vfull, bdy, axis=0)
-            newcol = jnp.where(below[:, None], vr, raw)
-            acc = jax.lax.dynamic_update_slice(acc, newcol, (0, k0))
-            vt = v @ t
-            if cw:
-                # mask fused into the chunk body: the full m x m masked
-                # trail temp is exactly the buffer this lever exists to
-                # avoid materializing
-                w = _map_row_chunks(
-                    lambda ar, br: tb.mm(
-                        jnp.where(br[:, None] & below[None, :], ar, 0), vt),
-                    cw, acc, below)
-            else:
-                trail = jnp.where(below[:, None] & below[None, :], acc, 0)
-                w = tb.mm(trail, vt)
-            mm = tb.mm(v.conj().T, w)
-            x = w - 0.5 * v @ (t.conj().T @ mm)
-            vh, xh = v.conj().T, x.conj().T
-            if cw:
-                acc = _map_row_chunks(
-                    lambda ar, xr, vr: ar - tb.mm(xr, vh) - tb.mm(vr, xh),
-                    cw, acc, x, v)
-            else:
-                acc = acc - tb.mm(x, vh) - tb.mm(v, xh)
+            # trace-time phase names (obs/scopes.py): what the benchmark's
+            # ``phase_ms.*`` split a call's device time by
+            with obs.named_span("red2band.panel"):
+                k0 = (k - off) * nb        # panel column inside the slice
+                bdy = k0 + nb
+                below = rows >= bdy        # (m,)
+                raw = jax.lax.dynamic_slice(acc, (0, k0), (m, nb))
+                pan = jnp.roll(jnp.where(below[:, None], raw, 0), -bdy,
+                               axis=0)
+                # pan has m >= 2*nb rows whenever a step runs, so panel_qr
+                # returns exactly nb taus; dead columns masked below
+                vfull, taus = panel_qr(pan)
+                col_live = jnp.arange(nb) < (n - (k + 1) * nb)
+                taus = jnp.where(col_live, taus, jnp.zeros_like(taus))
+                taus_out = taus_out.at[k].set(taus)
+                vtop = jnp.tril(vfull, -1) + jnp.eye(m, nb, dtype=acc.dtype)
+            with obs.named_span("red2band.larft"):
+                t = larft(vtop, taus)
+            with obs.named_span("red2band.panel"):
+                v = jnp.where(below[:, None], jnp.roll(vtop, bdy, axis=0), 0)
+                vr = jnp.roll(vfull, bdy, axis=0)
+                newcol = jnp.where(below[:, None], vr, raw)
+                acc = jax.lax.dynamic_update_slice(acc, newcol, (0, k0))
+            with obs.named_span("red2band.w"):
+                vt = v @ t
+                if cw:
+                    # mask fused into the chunk body: the full m x m masked
+                    # trail temp is exactly the buffer this lever exists to
+                    # avoid materializing
+                    w = _map_row_chunks(
+                        lambda ar, br: tb.mm(
+                            jnp.where(br[:, None] & below[None, :], ar, 0),
+                            vt),
+                        cw, acc, below)
+                else:
+                    trail = jnp.where(below[:, None] & below[None, :], acc, 0)
+                    w = tb.mm(trail, vt)
+            with obs.named_span("red2band.x"):
+                mm = tb.mm(v.conj().T, w)
+                x = w - 0.5 * v @ (t.conj().T @ mm)
+            with obs.named_span("red2band.update"):
+                vh, xh = v.conj().T, x.conj().T
+                if cw:
+                    acc = _map_row_chunks(
+                        lambda ar, xr, vr: ar - tb.mm(xr, vh) - tb.mm(vr, xh),
+                        cw, acc, x, v)
+                else:
+                    acc = acc - tb.mm(x, vh) - tb.mm(v, xh)
             return (acc, taus_out), None
 
         return step

@@ -42,6 +42,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..types import ceil_div
 from .distribution import Distribution
 from . import util_distribution as ud
@@ -153,10 +154,14 @@ def on_global(fn, dist: Distribution):
     first element is the global result; the rest (``info`` scalars) pass
     through."""
     def prog(storage):
-        out = fn(tiles_to_global(storage, dist))
-        if isinstance(out, tuple):
-            return (global_to_tiles(out[0], dist),) + out[1:]
-        return global_to_tiles(out, dist)
+        # trace-time phase name of the two layout moves (obs/scopes.py)
+        with obs.named_span("layout"):
+            a = tiles_to_global(storage, dist)
+        out = fn(a)
+        with obs.named_span("layout"):
+            tiles = global_to_tiles(
+                out[0] if isinstance(out, tuple) else out, dist)
+        return (tiles,) + out[1:] if isinstance(out, tuple) else tiles
 
     # the compiled program's name (``jit_<name>`` on a profiler timeline)
     prog.__name__ = f"{getattr(fn, '__name__', 'fn')}_on_tiles"

@@ -54,6 +54,7 @@ from .devtrace import (
     host_span_events,
     load_trace,
 )
+from . import scopes
 from .sinks import SCHEMA_VERSION
 
 PHASES = ("panel", "strip", "bulk", "other")
@@ -63,13 +64,10 @@ PHASES = ("panel", "strip", "bulk", "other")
 # collective/copy categories regardless of phase, "gap" is measured idle.
 BOUNDS = ("panel", "bulk", "comm", "copy", "gap")
 
-# op_name metadata scope patterns.  Innermost (last) match wins so a
-# comm-lookahead panel chain hoisted into step k's outer scope but tagged
+# op_name metadata scopes are parsed by :mod:`dlaf_tpu.obs.scopes`, the one
+# parser the library has: innermost scope wins, so a comm-lookahead panel
+# chain hoisted into step k's outer scope but tagged
 # ``<algo>.step<k+1>.panel`` is attributed to step k+1.
-_STEP_RE = re.compile(r"([A-Za-z0-9_]+)\.step(\d+)(?:\.(panel|strip|bulk))?")
-_SCAN_RE = re.compile(r"([A-Za-z0-9_]+)\.scanstep(?:\.(panel|strip|bulk))?")
-_OP_RE = re.compile(r'%?([\w.\-]+) = .*op_name="([^"]*)"')
-_MODULE_RE = re.compile(r"^HloModule ([\w.\-]+)", re.MULTILINE)
 
 
 # ---------------------------------------------------------------------------
@@ -83,26 +81,18 @@ def schedule_from_hlo(hlo_text: str) -> dict[str, Any]:
     where ``step`` is an int for unrolled builders and ``-1`` for scan
     bodies (a scan body is traced once for all iterations, so its ops
     carry no step index; the joiner reconstructs iterations from
-    occurrence order).  Instructions without a step scope are omitted.
+    occurrence order).  ``phase`` is whatever token the builder wrote
+    (``panel``, ``strip``, ``bulk``, the reduction's ``larft`` / ``w`` /
+    ``update``...; ``other`` for a step marker alone): the per-step
+    reports fold every token outside :data:`PHASES` into ``other``.
+    Instructions without a step scope are omitted.
     """
-    m = _MODULE_RE.search(hlo_text)
-    module = m.group(1) if m else ""
     ops: dict[str, list[Any]] = {}
-    for line in hlo_text.splitlines():
-        om = _OP_RE.search(line)
-        if om is None:
-            continue
-        name, op_name = om.group(1), om.group(2)
-        hits = list(_STEP_RE.finditer(op_name))
-        if hits:
-            h = hits[-1]  # innermost scope wins
-            ops[name] = [h.group(1), int(h.group(2)), h.group(3) or "other"]
-            continue
-        sm = list(_SCAN_RE.finditer(op_name))
-        if sm:
-            h = sm[-1]
-            ops[name] = [h.group(1), -1, h.group(2) or "other"]
-    return {"module": module, "ops": ops}
+    for name, op_name, _rest in scopes.instructions(hlo_text):
+        scope = scopes.parse(op_name) if op_name else None
+        if scope is not None and scope.step is not None:
+            ops[name] = [scope.algo, scope.step, scope.phase or "other"]
+    return {"module": scopes.module_name(hlo_text), "ops": ops}
 
 
 def schedule_record(site: str, hlo_text: str) -> dict[str, Any] | None:
@@ -208,7 +198,7 @@ def _scheduled_events(events: list[dict], records: list[dict]):
                 "hi": (ts + dur) * 1e-6,
                 "algo": entry[0],
                 "step": int(entry[1]),
-                "phase": entry[2],
+                "phase": entry[2] if entry[2] in PHASES else "other",
                 "cat": cat or "compute",
                 "name": e.get("name", ""),
                 "domain": pid if "/device:" in proc.lower() else (pid, e.get("tid")),

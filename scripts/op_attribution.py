@@ -1,6 +1,6 @@
 #!/usr/bin/env python3
 """Own device time per HLO instruction of one traced benchmark run, joined to
-the source lines that emitted it.
+the source lines and the builder's phases that emitted it.
 
     python3 scripts/op_attribution.py --workload chol_d_n4096_1x1 --seed 7 \\
         --out chiprun_out/attr [--root <checkout>] [--opcode copy] \
@@ -11,20 +11,24 @@ the chip), then reads the run's xplane once more with the benchmark's own
 readers: every ``XLA Ops`` event of the traced window is given to the ``XLA
 Modules`` event that encloses it, own
 time (``trace_reduce.self_times``) is summed per (module, instruction name),
-and the instruction name is looked up in the compiled text of the local
-entry's program (``SITE_PROGRAMS``: the Cholesky's one program,
-``jit_cholesky_local_on_tiles``, with the layout moves and the builder the
-entry took, ``_cholesky_local`` or, from 32 block steps on,
-``_cholesky_local_scan``; ``reduction_to_band``'s ``_red2band_local`` /
-``_red2band_local_scan``: ``--program`` names the builder at which a
-source chain stops, by default the one the run dispatched;
-``.lower(...).compile().as_text()``, which keeps
+and the instruction name is looked up in the compiled text of every program
+an entry dispatched through ``telemetry.call`` (``telemetry.programs()`` /
+``telemetry.compiled(site)``: the entries remember what they dispatched
+while the metrics sink is on, so the local Cholesky's
+``jit_cholesky_local_on_tiles``, the local reduction's
+``jit__red2band_local_scan`` and the four-chip solve's ``jit_run`` are all
+found the same way; ``compiled.as_text()`` keeps
 ``metadata={op_name=... stack_frame_id=...}`` and the tables that resolve a
-frame to file, line and function; the trace's event names do not, PERF.md
-section 3). Writes ``<out>/attribution.json`` (with own time by the source
-file of the innermost frame, ``by_file``, and by the builder's own line
-that emitted the instruction, ``by_site``) and the compiled text (gzipped), and prints the ``--opcode``
-rows by result shape and source line.
+frame to file, line and function; the trace's event names do not). Writes
+``<out>/attribution.json`` with own time by the source file of the
+innermost frame (``by_file``), by the builder's own line that emitted the
+instruction (``by_site``: the innermost frame in the file of the entry that
+called ``telemetry.call``; ``--program`` names a function at which a source
+chain stops instead) and by the builder's phase (``by_phase``:
+``telemetry.phase_table(site)`` placed by ``benchmark/phase_table.py``'s
+``resolve``, which is what the benchmark's ``phase_ms.*`` use), and the
+compiled texts (gzipped), and prints the ``--opcode`` rows by result shape
+and source line.
 """
 
 from __future__ import annotations
@@ -38,16 +42,11 @@ import os
 import re
 import sys
 
-_META = re.compile(r'op_name="([^"]*)"(?:[^}]*?stack_frame_id=(\d+))?')
+_FRAME = re.compile(r"stack_frame_id=(\d+)")
 _RESULT = re.compile(r" = \(?([a-z0-9]+\[[0-9,]*\](?:\{[^}]*\})?)")
-_OPERAND = re.compile(r"%([A-Za-z_][\w.\-]*)")
 _TABLE_ROW = re.compile(r"^(\d+) (.*)$")
-_MODULE = re.compile(r"HloModule ([\w.\-]+)")
-#: the local entries' telemetry sites and the builder each dispatches
-SITE_PROGRAMS = {"cholesky.local": "_cholesky_local",
-                 "cholesky.local_scan": "_cholesky_local_scan",
-                 "reduction_to_band.local": "_red2band_local",
-                 "reduction_to_band.local_scan": "_red2band_local_scan"}
+#: the library's frame that dispatches a program: its caller is the entry
+DISPATCH = ("telemetry.py", "call")
 
 
 def frame_tables(text: str) -> dict:
@@ -82,39 +81,55 @@ def frames(tables: dict, frame_id: int):
         frame_id = int(ids["parent_frame_id"]) - 1
 
 
-def frame_chain(tables: dict, frame_id: int, stop="_cholesky_local") -> str:
+def frame_chain(tables: dict, frame_id: int, stop="_cholesky_local",
+                home=None) -> str:
     """``file:line(function) < caller ...`` from the innermost frame out to
-    the first frame in ``stop``, eight frames at most."""
+    the first frame in function ``stop`` (or, with ``stop`` None, the first
+    in the file ``home``), eight frames at most."""
     out = []
     for path, line, fn in frames(tables, frame_id):
         out.append(f"{path}:{line}({fn})")
-        if fn == stop or len(out) >= 8:
+        if (fn == stop if stop else path == home) or len(out) >= 8:
             break
     return " < ".join(out)
 
 
-def builder_site(tables: dict, frame_id: int, stop: str) -> str:
+def entry_file(tables: dict):
+    """The source file of the entry that dispatched the program: the file
+    of the caller of ``telemetry.call``, taken from the frame tables (the
+    builder is a function of the same file: ``cholesky.py``,
+    ``triangular.py``, ``reduction_to_band.py``); None without such a
+    frame."""
+    for frame_id in sorted(tables.get("StackFrames", {})):
+        chain = list(frames(tables, frame_id))
+        if len(chain) > 1 and chain[0][0::2] == DISPATCH:
+            return chain[1][0]
+    return None
+
+
+def builder_site(tables: dict, frame_id: int, stop=None, home=None) -> str:
     """``file:line`` of the innermost frame in the builder's own file (the
-    file of the frame of function ``stop``): the line of the builder that
-    emitted the instruction, however deep the library calls under it."""
+    file of the frame of function ``stop``, else ``home``): the line of the
+    builder that emitted the instruction, however deep the library calls
+    under it."""
     chain = list(frames(tables, frame_id))
-    home = next((path for path, _line, fn in chain if fn == stop), None)
+    if stop:
+        home = next((path for path, _line, fn in chain if fn == stop), None)
     return next((f"{path}:{line}" for path, line, _fn in chain
                  if path == home), "")
 
 
 def instruction_metadata(text: str) -> dict:
     """``{instruction name: (op_name, stack frame id)}`` of a compiled
-    module's text, for the instructions that carry metadata."""
+    module's text, for the instructions that carry metadata
+    (``obs.scopes.instructions`` reads the lines)."""
+    from dlaf_tpu.obs import scopes
+
     out = {}
-    for line in text.splitlines():
-        head, sep, rest = line.strip().partition(" = ")
-        if not sep:
-            continue
-        m = _META.search(rest)
-        if m:
-            out[head.replace("ROOT ", "").lstrip("%")] = (
-                m.group(1), int(m.group(2) or 0))
+    for name, op_name, rest in scopes.instructions(text):
+        if op_name:
+            frame = _FRAME.search(rest)
+            out[name] = (op_name, int(frame.group(1)) if frame else 0)
     return out
 
 
@@ -142,36 +157,23 @@ def main() -> int:
                          "and a half); default: the whole window")
     ap.add_argument("--program", default=None,
                     help="builder function at which a source chain stops "
-                         "(default: the one the run dispatched)")
+                         "(default: the first frame in the file of the "
+                         "entry that dispatched the program)")
     args = ap.parse_args()
     root = os.path.abspath(args.root)
     os.makedirs(args.out, exist_ok=True)
     for p in (root, os.path.join(root, "benchmark")):
         sys.path.insert(0, p)
 
+    import phase_table
     import run as bench
     import span_reduce
     import trace_reduce
     from dlaf_tpu.obs import telemetry
 
-    # the factorization's program as the entry point asks for it
-    captured = {}
-    plain_call = telemetry.call
-
-    def capturing_call(site, fn, *a, **kw):
-        if site in SITE_PROGRAMS and not captured:
-            import jax
-
-            captured["lower"] = (fn, [jax.ShapeDtypeStruct(x.shape, x.dtype)
-                                      for x in a], kw)
-            captured["site"] = site
-        return plain_call(site, fn, *a, **kw)
-
-    telemetry.call = capturing_call
     rc = bench.main(["--workload", args.workload, "--seed", str(args.seed),
                      "--seconds", str(args.seconds), "--trace", "1"],
                     root=root)
-    telemetry.call = plain_call
     if rc:
         return rc
 
@@ -199,47 +201,58 @@ def main() -> int:
             [(s, e, module_of(programs, s) + "\t" + n)
              for s, e, n in trace_reduce.clip(events, window)]))
 
-    meta, tables, program = {}, {}, None
-    builder = args.program or SITE_PROGRAMS.get(captured.get("site"),
-                                                "_cholesky_local")
-    if captured:
-        fn, avals, kw = captured["lower"]
-        text = fn.lower(*avals, **kw).compile().as_text()
-        program = _MODULE.match(text).group(1)
-        with gzip.open(os.path.join(args.out,
-                                    builder.lstrip("_") + ".hlo.txt.gz"),
+    # every program an entry dispatched, as the entry remembered it
+    dispatched = {}
+    for site in telemetry.programs():
+        text = telemetry.compiled(site).as_text()
+        table = telemetry.phase_table(site)
+        tables = frame_tables(text)
+        dispatched[table["module"]] = {
+            "site": site, "meta": instruction_metadata(text),
+            "tables": tables, "table": table, "home": entry_file(tables)}
+        with gzip.open(os.path.join(args.out, site + ".hlo.txt.gz"),
                        "wt") as f:
             f.write(text)
-        meta, tables = instruction_metadata(text), frame_tables(text)
+        print(f"[program] site={site} module={table['module']} "
+              f"entry_file={dispatched[table['module']]['home']} "
+              f"phase_instructions={table['counts']} "
+              f"stale={table['stale']}")
 
     rows = collections.defaultdict(lambda: [0, 0])
     by_label = collections.defaultdict(int)
     by_file = collections.defaultdict(int)
     by_site = collections.defaultdict(int)
+    by_phase = collections.defaultdict(int)
     for key, ns in own.items():
         module, name = key.split("\t", 1)
         label, opcode, _stem = trace_reduce.parse_op(name)
         by_label[label] += ns
-        inst = name.partition(" = ")[0].lstrip("%")
+        inst = phase_table.instruction(name)
         shape = _RESULT.search(name)
-        op_name, frame, via = "", 0, ""
-        if program and module.startswith(program):
+        op_name, frame, via, tables, home = "", 0, "", {}, None
+        prog = next((d for m, d in dispatched.items()
+                     if module.startswith(m)), None)
+        if prog is not None:
+            tables, home = prog["tables"], prog["home"]
             # a copy the compiler put in has no metadata of its own: take
             # its first operand's
-            for cand in [inst] + _OPERAND.findall(name.partition(" = ")[2]):
-                if cand in meta:
-                    op_name, frame = meta[cand]
+            for cand in [inst] + prog["table"]["operands"].get(inst, []):
+                if cand in prog["meta"]:
+                    op_name, frame = prog["meta"][cand]
                     via = "" if cand == inst else "via operand: "
                     break
+            phase, how = phase_table.resolve(prog["table"], inst)
+            by_phase[f"{phase or phase_table.UNATTRIBUTED} "
+                     f"({prog['site']}{', via ' + how if how else ''})"] += ns
         # the op_name's tail (the primitive and its nearest scopes)
         tail = via + "/".join(op_name.split("/")[-3:])
-        chain = frame_chain(tables, frame, builder)
+        chain = frame_chain(tables, frame, args.program, home)
         # an instruction with an op_name and no frame (XLA's expansions:
         # triangular_solve) goes by its primitive
         bare = f"no frame: {op_name.rpartition('/')[2]} ({module})" \
             if op_name else f"no metadata ({module})"
         by_file[chain.split(":")[0] or bare] += ns
-        by_site[builder_site(tables, frame, builder) or bare] += ns
+        by_site[builder_site(tables, frame, args.program, home) or bare] += ns
         row = rows[(module, label, opcode, shape.group(1) if shape else "",
                     chain, tail)]
         row[0] += ns
@@ -249,7 +262,8 @@ def main() -> int:
     busy = sum(own.values()) / 1e9
     with open(os.path.join(args.out, "attribution.json"), "w") as f:
         json.dump({"workload": args.workload, "seed": args.seed,
-                   "program": builder,
+                   "programs": {m: d["site"]
+                                for m, d in dispatched.items()},
                    "calls": calls, "window_s": window_s,
                    "own_s_total": busy,
                    "by_label": sorted(([k, v / 1e9]
@@ -261,12 +275,18 @@ def main() -> int:
                    "by_site": sorted(([k, v / 1e9]
                                       for k, v in by_site.items()),
                                      key=lambda kv: -kv[1]),
+                   "by_phase": sorted(([k, v / 1e9]
+                                       for k, v in by_phase.items()),
+                                      key=lambda kv: -kv[1]),
                    "rows": table}, f, indent=1)
     print(f"[attribution] calls={calls} own_s_total={busy:.4f} "
           f"window_s={window_s:.4f}")
     for label, ns in sorted(by_label.items(), key=lambda kv: -kv[1])[:16]:
         print(f"[label] {ns / 1e9:9.5f} s  {100 * ns / 1e9 / busy:5.1f}%  "
               f"{label}")
+    for label, ns in sorted(by_phase.items(), key=lambda kv: -kv[1]):
+        print(f"[phase] {ns / 1e9:9.5f} s  {1e3 * ns / 1e9 / calls:9.4f} "
+              f"ms a call  {label}")
     print(f"[rows] opcode={args.opcode}: module, result, source, op_name "
           "tail, own s in window, per call ms, instructions")
     for module, label, opcode, shape, src, tail, sec, count in table:

@@ -75,35 +75,59 @@ def test_frame_chain_stops_at_the_program(attribution, frame, chain):
 
 def test_frame_chain_stops_at_the_named_program(attribution):
     """``--program``: the scan builder's chains stop at its own name (the
-    default stop is the unrolled builder's), and the two telemetry sites
-    of the local entry name their builders."""
+    default stop is the unrolled builder's); without a name a chain stops
+    at the first frame in the entry's file, and with neither it runs on."""
     hlo = HLO.replace('2 "_cholesky_local"', '2 "_cholesky_local_scan"')
     tables = attribution.frame_tables(hlo)
-    assert attribution.frame_chain(tables, 4, "_cholesky_local_scan") == (
-        "ozaki.py:289(_mirror) < ozaki.py:492(syrk_f64) "
-        "< cholesky.py:325(_cholesky_local_scan)")
-    # without the name the chain runs on to the outermost frame
+    want = ("ozaki.py:289(_mirror) < ozaki.py:492(syrk_f64) "
+            "< cholesky.py:325(_cholesky_local_scan)")
+    assert attribution.frame_chain(tables, 4, "_cholesky_local_scan") == want
+    assert attribution.frame_chain(tables, 4, None, "cholesky.py") == want
+    # without either the chain runs on to the outermost frame
     assert attribution.frame_chain(tables, 4).endswith(
         "< telemetry.py:240(call)")
-    assert attribution.SITE_PROGRAMS == {
-        "cholesky.local": "_cholesky_local",
-        "cholesky.local_scan": "_cholesky_local_scan",
-        "reduction_to_band.local": "_red2band_local",
-        "reduction_to_band.local_scan": "_red2band_local_scan"}
+    assert attribution.frame_chain(tables, 4, None, None).endswith(
+        "< telemetry.py:240(call)")
+    assert not hasattr(attribution, "SITE_PROGRAMS")
 
 
-def test_the_sites_are_the_entries_own(attribution):
-    """Every site of ``SITE_PROGRAMS`` is a telemetry site a local entry
-    dispatches its builder under, and the builder is a function of that
-    entry's module."""
+ENTRY_HLO = HLO.replace(
+    '4 "_mirror"', '4 "_mirror"\n5 "cholesky"').replace(
+    "4 {file_name_id=3 function_name_id=4 line=289 end_line=289 column=11 "
+    "end_column=42}",
+    "4 {file_name_id=3 function_name_id=4 line=289 end_line=289 column=11 "
+    "end_column=42}\n5 {file_name_id=2 function_name_id=5 line=2035 "
+    "end_line=2035 column=22 end_column=60}").replace(
+    "1 {file_location_id=1 parent_frame_id=1}",
+    "1 {file_location_id=1 parent_frame_id=6}").replace(
+    "4 {file_location_id=4 parent_frame_id=4}",
+    "4 {file_location_id=4 parent_frame_id=4}\n"
+    "5 {file_location_id=5 parent_frame_id=1}")
+
+
+def test_the_builders_file_is_the_file_of_the_entry_that_dispatched(
+        attribution):
+    """No table of sites: the caller of ``telemetry.call`` in the frame
+    tables is the entry, its file the builder's, and ``by_site`` is the
+    innermost frame in that file."""
+    tables = attribution.frame_tables(ENTRY_HLO)
+    assert list(attribution.frames(tables, 1)) == [
+        ("telemetry.py", 240, "call"), ("cholesky.py", 2035, "cholesky")]
+    assert attribution.entry_file(tables) == "cholesky.py"
+    assert attribution.entry_file(attribution.frame_tables(HLO)) is None
+    assert attribution.builder_site(tables, 4, None, "cholesky.py") \
+        == "cholesky.py:325"
+    assert attribution.builder_site(tables, 4, "syrk_f64") == "ozaki.py:289"
+    assert attribution.builder_site(tables, 4, None, None) == ""
+    # every entry that dispatches a program does so from its builder's file
     import importlib
 
-    modules = {"cholesky": "dlaf_tpu.algorithms.cholesky",
-               "reduction_to_band": "dlaf_tpu.eigensolver.reduction_to_band"}
-    for site, builder in attribution.SITE_PROGRAMS.items():
-        mod = importlib.import_module(modules[site.split(".")[0]])
-        assert callable(getattr(mod, builder))
-        with open(mod.__file__) as f:
+    for module, site in (
+            ("dlaf_tpu.algorithms.cholesky", "cholesky.local_scan"),
+            ("dlaf_tpu.algorithms.triangular", "triangular_solve.dist"),
+            ("dlaf_tpu.eigensolver.reduction_to_band",
+             "reduction_to_band.local_scan")):
+        with open(importlib.import_module(module).__file__) as f:
             assert f'"{site}"' in f.read()
 
 
