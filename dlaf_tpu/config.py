@@ -217,14 +217,18 @@ class Configuration:
     #: N=4096 local Cholesky at 2.9 times; PERF.md section 6, PR 28).
     #: "scan": the sequenced schedule — one partial + the f64
     #: accumulator live, O(1) in the slice count, which is what the
-    #: N=16384 local Cholesky needs to fit a chip. Bulk products (both
-    #: output dimensions wider than the contraction) run the same ragged
-    #: dots ordered by an optimization_barrier per group, no padding;
-    #: panel products and the syrk keep lax.scan over zero-padded uniform
-    #: groups (one body: the least program code, which is resident in
-    #: HBM; tile_ops/ozaki.py:_sequenced_ragged). "auto" (default): scan
-    #: on TPU, xla elsewhere (XLA:CPU schedules the straight line fine
-    #: and ignores the barrier's hint).
+    #: N=16384 local Cholesky needs to fit a chip. Three forms, chosen
+    #: from the product's shape (tile_ops/ozaki.py:_sequenced_form): bulk
+    #: products (both output dimensions wider than the contraction) run
+    #: the same ragged dots ordered by an optimization_barrier per group,
+    #: no padding; panel products one block wide and deep and the syrk
+    #: keep lax.scan over zero-padded uniform groups (one body: the least
+    #: program code, which is resident in HBM); deep products (the
+    #: contraction deeper than the narrower output side: the reduction
+    #: to band's W = A (V T)) scan the wide operand's slices as they were
+    #: peeled, so nothing of it is stacked or padded (PERF.md section 6,
+    #: PR 36). "auto" (default): scan on TPU, xla elsewhere (XLA:CPU
+    #: schedules the straight line fine and ignores the barrier's hint).
     ozaki_accum: str = "auto"
     #: Ozaki slice-reduction implementation: "jnp" (per-shift int32 groups +
     #: full-f64 combine — f64-grade dots at f64_gemm_slices >= 8) or

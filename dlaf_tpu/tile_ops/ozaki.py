@@ -179,15 +179,19 @@ def _accum_impl() -> str:
     (m, n) int32 group partials live at once on the TPU) or "scan": the
     sequenced schedule, one int32 partial + the f64 accumulator live,
     O(1) in the slice count (the bound the N=16384 local Cholesky
-    needs). It has two forms (:func:`_sequenced_ragged`): bulk products
-    run the same ragged group dots as "xla", ordered by an
-    ``optimization_barrier`` per group; panel products and the syrk keep
-    the ``lax.scan`` over zero-padded uniform groups, whose one body is
-    the least program code. Bit-identical results either way — zero int8
-    pad columns contribute exactly nothing on either dot route. "auto"
-    resolves scan on TPU and xla elsewhere. The "dots" group form
-    ignores this knob (its partials are per-pair and XLA fuses them
-    well)."""
+    needs). It has three forms, chosen from the product's shape
+    (:func:`_sequenced_form`): bulk products run the same ragged group
+    dots as "xla", ordered by an ``optimization_barrier`` per group;
+    panel products and the syrk keep the ``lax.scan`` over zero-padded
+    uniform groups, whose one body is the least program code; deep
+    products (the contraction deeper than the narrower output side)
+    scan the wide operand's slices instead, each as it was peeled, into
+    an int32 carry of all the groups at once (route label
+    ``scan_slices``). Bit-identical results whichever form — zero int8
+    pad blocks contribute exactly nothing on either dot route and the
+    groups fold in the same order with the same scales. "auto" resolves
+    scan on TPU and xla elsewhere. The "dots" group form ignores this
+    knob (its partials are per-pair and XLA fuses them well)."""
     from ..config import get_configuration, resolve_platform_auto
 
     return resolve_platform_auto(
@@ -231,17 +235,74 @@ def _pad_k(x, k_pad, axis):
     return jnp.pad(x, widths)
 
 
-def _sequenced_ragged(m: int, n: int, k: int) -> bool:
+def _exact_i32(s: int, k: int) -> bool:
+    """Do the int32 shift-group sums of ``s`` slices a side at depth ``k``
+    stay exact: ``(d + 1) k 2^12 < 2^31`` for every group ``d < s``."""
+    return (s * k) << (2 * SLICE_BITS - 2) < (1 << 31)
+
+
+def _sequenced_form(m: int, n: int, k: int, s: int) -> str:
     """Which form the sequenced schedule takes for an (m, k) x (k, n)
-    product. Ragged groups multiply no padding (the padded scan spends
-    ``s (s - 1) / 2`` of its ``s^2`` depth slots on zeros) but are ``s``
-    distinct dot kernels where the scan has one body, and a program's
-    code is resident in HBM: ~0.4 MiB a kernel, once per product
-    instance. Bulk products — both output dimensions wider than the
-    contraction, where the flops are — take the ragged form; panel
-    products (one block wide: lower-order flops, one instance per step
-    of an unrolled builder) keep the scan (PERF.md section 6, PR 28)."""
-    return min(m, n) > k
+    product of ``s`` slices a side: one rule on the shape, no knob.
+
+    * ``"ragged"``, ``min(m, n) > k``: bulk products, both output
+      dimensions wider than the contraction. Ragged groups multiply no
+      padding but are ``s`` distinct dot kernels where a scan has one
+      body, and a program's code is resident in HBM: ~0.4 MiB a kernel,
+      once per product instance (PERF.md section 6, PR 28).
+    * ``"groups"``, ``k == min(m, n)``: panel products one block wide and
+      one block deep (the Cholesky's panel and strip products, the
+      solve's pivot products). ``lax.scan`` over the ``s`` shift groups,
+      each zero-padded to the widest depth ``s k``: one body,
+      ``s (s - 1) / 2`` of its ``s^2`` depth slots zeros on BOTH
+      operands, both stacked ``s`` times.
+    * ``"slices"``, ``k > min(m, n)``: deep products, the contraction
+      deeper than the narrower output side (the reduction to band's ``W =
+      A (V T)``, (m, m) x (m, band), whose wide operand is the whole
+      trailing matrix and whose MACs are 46% of that program's; ``V^H
+      W``; ``bt_reduction_to_band``'s ``V^H C``). Stacking the wide
+      operand's padded groups writes and reads ``s^2`` times its size a
+      product; this form scans the wide operand's ``s`` slices as they
+      were peeled against the narrow operand's slices shifted into ``s``
+      blocks, so the zero slots sit on the narrow side only, and the
+      ``s`` groups add up in one int32 carry. It needs every group sum
+      exact in int32 (``s k 2^12 < 2^31``); deeper than that the product
+      keeps ``"groups"``, whose dots chunk into f64."""
+    if min(m, n) > k:
+        return "ragged"
+    return "slices" if k > min(m, n) and _exact_i32(s, k) else "groups"
+
+
+def _scan_slices(ia, ib, s: int):
+    """``[G_0 .. G_{s-1}]``, the int32 shift-group sums ``G_d = sum_t I_t
+    J_{d-t}`` of a deep product (:func:`_sequenced_form` ``"slices"``),
+    by one ``lax.scan`` over the WIDE operand's slices. With A (m, k) the
+    wide one (m >= n), step ``t`` multiplies ``I_t`` as it was peeled by
+    ``[0 x t | J_0 | ... | J_{s-1-t}]`` (k, s n): block ``d`` of the (m,
+    s n) product is ``I_t J_{d-t}`` for ``d >= t`` and exactly zero
+    before it, and the steps add into one int32 carry. With B the wide
+    one the roles are exchanged: step ``u`` multiplies ``[0 x u; I_0; ...;
+    I_{s-1-u}]`` (s m, k) by ``J_u``. The wide operand is stacked once
+    (``s`` times its size in int8), nothing of it is concatenated or
+    padded."""
+    m, n = ia[0].shape[-2], ib[0].shape[-1]
+    a_wide = m >= n
+    narrow, axis = (ib, -1) if a_wide else (ia, -2)
+    zero = jnp.zeros_like(narrow[0])
+    shifted = jnp.stack([jnp.concatenate([zero] * t + narrow[:s - t],
+                                         axis=axis) for t in range(s)])
+
+    def body(g, xs):
+        wide_t, shifted_t = xs
+        return g + (_dot_i8(wide_t, shifted_t) if a_wide
+                    else _dot_i8(shifted_t, wide_t)), None
+
+    g, _ = lax.scan(body,
+                    jnp.zeros((m, s * n) if a_wide else (s * m, n),
+                              jnp.int32),
+                    (jnp.stack(ia if a_wide else ib), shifted))
+    return [g[:, d * n:(d + 1) * n] if a_wide else g[d * m:(d + 1) * m]
+            for d in range(s)]
 
 
 def _dot_bf16(ia, ib):
@@ -435,8 +496,6 @@ def _matmul_f64_2d(a, b, *, slices=DEFAULT_SLICES):
                                      dot=_slice_dot_impl())
         acc = hi.astype(jnp.float64) + lo.astype(jnp.float64)
         return _apply_scales(acc, sa, sb)
-    # int32 group sums stay exact while (d+1) * k * 2^12 < 2^31
-    exact_i32 = (s * k) << (2 * SLICE_BITS - 2) < (1 << 31)
     acc = None
     if _group_impl() == "concat":
         # one dot per shift group over k-concatenated operands: the d+1
@@ -447,7 +506,16 @@ def _matmul_f64_2d(a, b, *, slices=DEFAULT_SLICES):
         # at depths far above s*k for every supported shape)
         route = _concat_route()
         m, n = a.shape[-2], b.shape[-1]
-        if route == "scan" and not _sequenced_ragged(m, n, k):
+        form = _sequenced_form(m, n, k, s) if route == "scan" else "ragged"
+        if form == "slices":
+            # deep product: the s^2 slots of the padded scan (zeros among
+            # them, on the narrow operand's side), emitted as s dots
+            _count_macs("scan_slices", m * n, s * (s + 1) // 2 * k,
+                        s * s * k)
+            for d, g_d in enumerate(_scan_slices(ia, ib, s)):
+                acc = _fold_group(acc, d, g_d)
+            return _apply_scales(acc, sa, sb)
+        if form == "groups":
             # panel product: uniform zero-padded groups scanned with an
             # f64 carry (one body; s (s - 1) / 2 of its s^2 slots are
             # zero columns)
@@ -484,7 +552,7 @@ def _matmul_f64_2d(a, b, *, slices=DEFAULT_SLICES):
     for d in range(s):
         terms = [_group_dot("dots", ia[t], ib[d - t], 1, k)
                  for t in range(d + 1)]
-        if exact_i32:
+        if _exact_i32(s, k):
             p = terms[0]
             for t in terms[1:]:
                 p = p + t
@@ -528,8 +596,8 @@ def _syrk_f64_2d(a, *, slices=DEFAULT_SLICES):
         _count_mirror("pallas")
         acc = jnp.tril(acc) + jnp.swapaxes(jnp.tril(acc, -1), -1, -2)
         return _apply_scales(acc, sa, jnp.swapaxes(sa, -1, -2))
-    exact_i32 = (s * k) << (2 * SLICE_BITS - 2) < (1 << 31)
-    cast = (lambda x: x) if exact_i32 else (lambda x: x.astype(jnp.float64))
+    cast = (lambda x: x) if _exact_i32(s, k) \
+        else (lambda x: x.astype(jnp.float64))
     acc = None
     if _group_impl() == "concat":
         # one dot for the strict-upper pair half of each shift group

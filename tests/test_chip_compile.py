@@ -279,6 +279,39 @@ def test_f64_product_schedules_on_the_tpu_compiler(one_chip, as_on_tpu,
         assert temp_mib > 2 * PADDED_SCAN_TEMP_MIB[shape]
 
 
+#: temporaries the TPU compiler gave the parent of PR 36 (67110eb) for the
+#: product below, MiB: its padded group scan stacked the (4096, 8192) operand
+#: 49 times, ``s8[7,4096,57344]`` (``memory_analysis()``; not a device number)
+DEEP_PRODUCT_PARENT_TEMP_MIB = 3059.4
+
+
+def test_deep_f64_product_scans_the_wide_operands_slices(one_chip,
+                                                         as_on_tpu):
+    """The reduction to band's ``W = A (V T)`` by row chunk, (4096, 8192) x
+    (8192, 128) at s = 7 on the bf16 route: the contraction is deeper than
+    the narrower output side, so the sequenced schedule scans the wide
+    operand's seven slices as they were peeled, against the narrow
+    operand's slices shifted into seven blocks. One loop, whose body is
+    one slice's product (two dots: the bf16 route's 4096-deep chunks) into
+    an int32 carry 896 wide; no operand of depth 7 k anywhere; at most a
+    third of the parent's temporaries (742.1 MiB when this was written)."""
+    from dlaf_tpu.tile_ops import ozaki
+
+    m, k, n, s = 4096, 8192, 128, 7
+    assert ozaki._sequenced_form(m, n, k, s) == "slices"
+    compiled = jax.jit(lambda a, b: ozaki.matmul_f64(a, b, slices=s)).lower(
+        jax.ShapeDtypeStruct((m, k), jnp.float64, sharding=one_chip),
+        jax.ShapeDtypeStruct((k, n), jnp.float64, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") == 1 and " conditional(" not in text
+    assert text.count(" convolution(") == k // ozaki._K_F32_EXACT
+    assert not re.search(rf"\[[0-9,]*\b{s * k}\b[0-9,]*\]", text)  # 57344
+    assert f"s8[{s},{m},{k}]" in text and f"s32[{m},{s * n}]" in text
+    temp_mib = compiled.memory_analysis().temp_size_in_bytes / 2 ** 20
+    assert temp_mib <= DEEP_PRODUCT_PARENT_TEMP_MIB / 3, temp_mib
+
+
 # ---------------------------------------------------------------------------
 # the local scan Cholesky's chunked segment (algorithms/cholesky.py)
 # ---------------------------------------------------------------------------

@@ -938,9 +938,10 @@ class TestConcatGroupRoute:
 class TestScanAccumRoute:
     """ozaki_accum="scan" (the sequenced schedule, O(1) live partials:
     barriers between the ragged groups of a bulk product, lax.scan'd
-    zero-padded groups for panel products and the syrk) must be
-    BIT-IDENTICAL to the straight-line "xla" schedule under the concat
-    group form — padded columns are int8 zeros, which contribute exactly
+    zero-padded groups for panel products and the syrk, a scan over the
+    wide operand's slices for deep products) must be BIT-IDENTICAL to
+    the straight-line "xla" schedule under the concat group form —
+    padded columns and blocks are int8 zeros, which contribute exactly
     nothing on either dot route, and the groups fold in the same order
     with the same scales."""
 
@@ -972,6 +973,50 @@ class TestScanAccumRoute:
         b = rng.standard_normal((k, m)) * 10.0 ** rng.integers(-6, 6, (1, m))
         self._ab(monkeypatch, lambda x, y: matmul_f64(x, y, slices=s),
                  jnp.asarray(a), jnp.asarray(b), dot=dot)
+
+    #: deep products, ``k > min(m, n)`` (ISSUE 36): A the wide operand, B
+    #: the wide operand, square, and a batch under ``jnp.vectorize``
+    DEEP = [(96, 160, 8, ()), (8, 160, 96, ()), (24, 200, 24, ()),
+            (72, 300, 16, (3,))]
+
+    @pytest.mark.parametrize("dot", ["int8", "bf16"])
+    @pytest.mark.parametrize("s", [7, 8])
+    @pytest.mark.parametrize("m,k,n,batch", DEEP)
+    def test_deep_matmul_bitwise_equal(self, m, k, n, batch, s, dot,
+                                       monkeypatch):
+        """The scan over the wide operand's slices (``I_t`` against the
+        narrow operand's slices shifted into ``s`` blocks, summed in one
+        int32 carry) keeps the bits of the straight line."""
+        from dlaf_tpu.tile_ops.ozaki import _sequenced_form
+
+        assert _sequenced_form(m, n, k, s) == "slices"
+        rng = np.random.default_rng(36)
+        a = rng.standard_normal(batch + (m, k)) \
+            * 10.0 ** rng.integers(-6, 6, batch + (m, 1))
+        b = rng.standard_normal((k, n)) * 10.0 ** rng.integers(-6, 6, (1, n))
+        self._ab(monkeypatch, lambda x, y: matmul_f64(x, y, slices=s),
+                 jnp.asarray(a), jnp.asarray(b), dot=dot)
+
+    def test_deep_matmul_past_int32_keeps_the_group_scan(self, monkeypatch):
+        """Where a group sum could pass int32 (``s k 2^12 >= 2^31``) the
+        slices form's int32 carry is not exact: the product keeps the
+        group scan, whose dots chunk into f64, and the straight line's
+        bits. The adversarial rows of ``test_concat_syrk_int32_wrap_window``
+        put the last group's sum past ``INT32_MIN``."""
+        from dlaf_tpu.tile_ops import ozaki
+
+        m, k, n, s = 8, (1 << 16) + 8, 8, 8
+        assert ozaki._sequenced_form(m, n, k, s) == "groups"
+        assert ozaki._sequenced_form(m, n, k - 16, s) == "slices"
+
+        def refuse(*args, **kw):
+            raise AssertionError("the slices form ran past int32")
+
+        monkeypatch.setattr(ozaki, "_scan_slices", refuse)
+        a = np.ones((m, k))
+        a[:, 0] = 129.0 / 128.0
+        self._ab(monkeypatch, lambda x, y: matmul_f64(x, y, slices=s),
+                 jnp.asarray(a), jnp.asarray(-a.T), dot="int8")
 
     @pytest.mark.parametrize("dot", ["int8", "bf16"])
     @pytest.mark.parametrize("s", [7, 8])
@@ -1083,6 +1128,45 @@ def test_concat_syrk_int32_wrap_window(accum, monkeypatch):
         config.initialize()
 
 
+#: the four cells' own products as ``(m, k, n)`` and the form the sequenced
+#: schedule gives each (``ozaki.py:_sequenced_form``; s = 7 on a TPU)
+CELL_PRODUCTS = [
+    # chol_d_n4096_1x1: panel / strip products one block wide and deep
+    ((3840, 256, 256), "groups"), ((256, 256, 3840), "groups"),
+    ((256, 256, 256), "groups"),
+    # chol_d_n16384_1x1: the same at nb = 512; its chunked bulk products
+    ((15872, 512, 512), "groups"), ((512, 512, 512), "groups"),
+    ((8192, 512, 4096), "ragged"),
+    # trsm_d_n8192_2x2: pivot products and the deferred bulk product
+    ((4096, 256, 256), "groups"), ((4096, 256, 4096), "ragged"),
+    ((3840, 256, 4096), "ragged"),
+    # red2band_d_n8192_1x1: the rank-2b update's X V^H and V X^H ...
+    ((8192, 128, 8192), "ragged"), ((4096, 128, 8192), "ragged"),
+    ((1024, 128, 1024), "ragged"),
+    # ... and the deep ones: W = A (V T) by row chunk and whole, M = V^H W
+    ((4096, 8192, 128), "slices"), ((7168, 7168, 128), "slices"),
+    ((1024, 1024, 128), "slices"), ((128, 8192, 128), "slices"),
+    ((128, 1024, 128), "slices"),
+]
+
+
+@pytest.mark.quick
+@pytest.mark.parametrize("shape,form", CELL_PRODUCTS,
+                         ids=["x".join(map(str, sh)) for sh, _ in
+                              CELL_PRODUCTS])
+def test_sequenced_form_of_the_cells_products(shape, form):
+    """One rule on the shape: bulk products (``min(m, n) > k``) ragged,
+    products one block wide and one block deep (``k == min(m, n)``) the
+    scan over padded groups, deep products (``k > min(m, n)``) the scan
+    over the wide operand's slices. Only the reduction's W and M are
+    deep: the other three cells' programs keep every form they had."""
+    from dlaf_tpu.tile_ops.ozaki import _sequenced_form
+
+    m, k, n = shape
+    assert _sequenced_form(m, n, k, 7) == form
+    assert _sequenced_form(n, m, k, 7) == form      # symmetric in (m, n)
+
+
 #: ``(ozaki_group, ozaki_accum)`` of the three jnp syrk routes, by the
 #: ``route`` label ``dlaf_ozaki_mirror_total`` counts them under
 SYRK_ROUTES = {"scan": ("concat", "scan"), "concat": ("concat", "xla"),
@@ -1137,11 +1221,15 @@ class TestRaggedGroups:
     the contraction) and keeps the zero-padded ``lax.scan`` for panel
     products and the syrk, where every group has the widest depth:
     ``s * s * k`` deep in all for the product (49 k at s = 7 for 28 k
-    real), ``s (h + 1) k`` for the syrk (28 k for 16 k real)."""
+    real), ``s (h + 1) k`` for the syrk (28 k for 16 k real). Deep
+    products (ISSUE 36: the contraction deeper than the narrower output
+    side) scan ONE dot of depth ``k`` whose narrow side is ``s`` blocks
+    wide: the same 49 k slots, seven steps."""
 
     K = 40
     BULK = (48, 56)         # (m, n): both wider than K
-    PANELS = [(24, 56), (56, 40), (24, 24)]
+    PANELS = [(40, 56), (56, 40), (40, 40)]     # one side K wide
+    DEEP = [(24, 56), (56, 24), (24, 24)]       # one side narrower than K
 
     @staticmethod
     def _matmul_depths(m, k, n, s):
@@ -1189,6 +1277,46 @@ class TestRaggedGroups:
             assert scans == 0
 
     @pytest.mark.quick
+    @pytest.mark.parametrize("dot", ["int8", "bf16"])
+    @pytest.mark.parametrize("m,n", DEEP)
+    @pytest.mark.parametrize("route", ["scan", "concat"], indirect=True)
+    def test_deep_matmul_scans_the_wide_operands_slices(self, route, m, n,
+                                                        dot, monkeypatch):
+        """A deep product's one scan body holds ONE dot ``K`` deep: a slice
+        of the wide operand as it was peeled, (m, K), against the narrow
+        operand's ``s`` shifted blocks, (K, s n), or with B the wide one
+        (s m, K) against (K, n). No operand of depth ``s K`` exists."""
+        import re
+
+        import jax
+
+        from dlaf_tpu import config
+        from dlaf_tpu.analysis import depgraph
+
+        monkeypatch.setenv("DLAF_OZAKI_DOT", dot)
+        config.initialize()
+        try:
+            def fn(x, y):
+                return matmul_f64(x, y, slices=7)
+
+            args = jnp.zeros((m, self.K)), jnp.zeros((self.K, n))
+            depths = _dot_depths(fn, *args)
+            text = jax.jit(fn).lower(*args).as_text()
+            jaxpr = jax.make_jaxpr(fn)(*args)
+        finally:    # before the route fixture re-initializes the config
+            monkeypatch.delenv("DLAF_OZAKI_DOT")
+        scans = sum(eqn.primitive.name == "scan"
+                    for _, eqn in depgraph.iter_eqns(jaxpr))
+        if route == "scan":
+            assert depths == [self.K] and scans == 1
+            assert not re.search(rf"tensor<[0-9x]*{7 * self.K}x", text)
+            out = (m, 7 * n) if m >= n else (7 * m, n)
+            assert f"-> tensor<{out[0]}x{out[1]}x" in text
+        else:
+            assert sorted(depths) == [(d + 1) * self.K for d in range(7)]
+            assert scans == 0
+
+    @pytest.mark.quick
     @pytest.mark.parametrize("s", [6, 7, 8])
     @pytest.mark.parametrize("route", ["scan", "concat"], indirect=True)
     def test_syrk_dot_depths(self, route, s):
@@ -1224,7 +1352,7 @@ class TestRaggedGroups:
 
     @pytest.mark.quick
     @pytest.mark.parametrize("s", [7, 8])
-    @pytest.mark.parametrize("which", ["bulk", "panel", "syrk"])
+    @pytest.mark.parametrize("which", ["bulk", "panel", "syrk", "deep"])
     @pytest.mark.parametrize("route", list(SYRK_ROUTES), indirect=True)
     def test_mac_counter_real_and_zero_by_hand(self, route, which, s,
                                                tmp_path):
@@ -1233,7 +1361,9 @@ class TestRaggedGroups:
         (product: s (s + 1) / 2; syrk: the half pairs and the diagonal
         pairs); ``zero`` is the padding of the two padded scans (product:
         s (s - 1) / 2 slots; syrk: ``s (s // 2 + 1)`` emitted less the
-        real pairs) and 0 everywhere else."""
+        real pairs) and 0 everywhere else. A deep product under the
+        sequenced schedule counts the same slots under a route label of
+        its own, ``scan_slices``."""
         import os
 
         from dlaf_tpu import config, obs
@@ -1242,12 +1372,15 @@ class TestRaggedGroups:
             metrics_path=str(tmp_path / "macs.jsonl"),
             ozaki_group=os.environ["DLAF_OZAKI_GROUP"],
             ozaki_accum=os.environ["DLAF_OZAKI_ACCUM"]))
+        label = "scan_slices" if (route, which) == ("scan", "deep") \
+            else route
         real, zero = (obs.registry().counter("dlaf_ozaki_macs_total",
-                                             route=route, kind=kind)
+                                             route=label, kind=kind)
                       for kind in ("real", "zero"))
         base = real.snapshot()["value"], zero.snapshot()["value"]
         k = self.K
-        m, n = self.PANELS[0] if which == "panel" else self.BULK
+        m, n = {"panel": self.PANELS[0], "deep": self.DEEP[0]}.get(
+            which, self.BULK)
         a = jnp.asarray(np.random.default_rng(28).standard_normal((m, k)))
         if which == "syrk":
             syrk_f64(a, slices=s)

@@ -193,28 +193,39 @@ def _case_unrolled_below_32(n, route, tmp_path):
 def _hand_macs(n, band, s, chunk=0, chunk_at=0):
     """``(real, zero)`` multiply-accumulates of the slice dots of one call
     of the scan form, per EXECUTED step and chunk. A step on a trailing
-    block of m rows makes W = A (V T) ((m, m) x (m, band): one block wide,
-    so a padded scan of s groups at depth s m: s (s + 1) / 2 m real), M =
-    V^H W ((band, m) x (m, band), padded likewise) and X V^H, V X^H ((m,
-    band) x (band, m): both outputs wider than the depth, so ragged groups,
-    s (s + 1) / 2 band and no padding). In row chunks of ``chunk`` (where
-    shorter than m, and from ``chunk_at`` rows on: the auto rule's 8192) W
-    and the two updates have ``ceil(m / chunk)`` chunks of ``chunk`` rows
-    each: the ragged last one starts early and recomputes rows."""
+    block of m rows makes W = A (V T) ((m, m) x (m, band): m deep and one
+    band wide, so s^2 slice-pair slots of depth m are emitted for the s (s
+    + 1) / 2 real ones), M = V^H W ((band, m) x (m, band), likewise) and X
+    V^H, V X^H ((m, band) x (band, m): both outputs wider than the depth,
+    so ragged groups, s (s + 1) / 2 band and no padding). In row chunks of
+    ``chunk`` (where shorter than m, and from ``chunk_at`` rows on: the auto
+    rule's 8192) W and the two updates have ``ceil(m / chunk)`` chunks of
+    ``chunk`` rows each: the ragged last one starts early and recomputes
+    rows. Summed over the route labels of :func:`_hand_macs_by_route`."""
+    return tuple(map(sum, zip(*_hand_macs_by_route(n, band, s, chunk,
+                                                   chunk_at).values())))
+
+
+def _hand_macs_by_route(n, band, s, chunk=0, chunk_at=0):
+    """``{route label: (real, zero)}`` of :func:`_hand_macs`' products. W
+    and M, whose contraction (m) is deeper than their narrower output side
+    (band), scan the wide operand's slices since ISSUE 36 and count under
+    ``scan_slices``: the padded scan's 28 real and 21 zero slots of m an
+    output element. The rank-2b update's two products stay under ``scan``
+    (ragged groups, no padding)."""
     pairs = s * (s + 1) // 2
     panels = -(-n // band) - 1
-    real = zero = 0
+    deep_real = deep_zero = bulk_real = 0
     off = 0
     for seg in telescope_segments(panels):
         m = (-(-n // band) - off) * band
         chunked = 0 < chunk < m and m >= chunk_at
         rows = -(-m // chunk) * chunk if chunked else m
-        real += seg * (rows * band * pairs * m            # W
-                       + band * band * pairs * m          # M
-                       + 2 * rows * m * pairs * band)     # X V^H, V X^H
-        zero += seg * (rows * band + band * band) * (s * s - pairs) * m
+        deep_real += seg * (rows * band + band * band) * pairs * m   # W, M
+        deep_zero += seg * (rows * band + band * band) * (s * s - pairs) * m
+        bulk_real += seg * 2 * rows * m * pairs * band    # X V^H, V X^H
         off += seg
-    return real, zero
+    return {"scan_slices": (deep_real, deep_zero), "scan": (bulk_real, 0)}
 
 
 def _counters(name, **labels):
@@ -235,6 +246,14 @@ def _case_counters(n, route, tmp_path, chunk=0):
     real, zero = _hand_macs(n, BAND, SLICES, chunk)
     assert _counters("dlaf_ozaki_macs_total", kind="real") == real
     assert _counters("dlaf_ozaki_macs_total", kind="zero") == zero
+    # W and M scan the wide operand's slices, the rank-2b update stays
+    # ragged: each route label reads its own products' hand count
+    for label, (r, z) in _hand_macs_by_route(n, BAND, SLICES,
+                                             chunk).items():
+        assert _counters("dlaf_ozaki_macs_total", route=label,
+                         kind="real") == r, label
+        assert _counters("dlaf_ozaki_macs_total", route=label,
+                         kind="zero") == z, label
     assert _counters("dlaf_ozaki_masked_macs_total") == 0
     assert _counters("dlaf_red2band_steps_total", form="scan") == panels
     assert _counters("dlaf_red2band_bodies_total", form="scan") \
@@ -303,6 +322,22 @@ def test_hand_count_of_the_cells_shape():
     real, zero = _hand_macs(8192, 128, 7, chunk=4096, chunk_at=8192)
     assert (real, zero) == _hand_macs(8192, 128, 7)
     assert (real, zero) == (18523187314688, 4698207682560)
+
+
+def test_hand_count_of_the_cells_deep_products():
+    """What ``dlaf_ozaki_macs_total{route="scan_slices"}`` has to read on
+    the cell: W = A (V T) and M = V^H W of every executed step, ``sum seg
+    (m^2 + 128 m) x 128 x 28`` real and the same with 21 zero: all of the
+    call's zeros (the rank-2b update is ragged) and 47% of its emitted
+    multiply-accumulates."""
+    by_route = _hand_macs_by_route(8192, 128, 7, chunk=4096, chunk_at=8192)
+    segs = zip((8,) * 7 + (7,), range(8192, 0, -1024))
+    slots = sum(seg * (m * m + 128 * m) * 128 for seg, m in segs)
+    assert by_route["scan_slices"] == (28 * slots, 21 * slots)
+    assert by_route["scan_slices"] == (6264276910080, 4698207682560)
+    assert by_route["scan"] == (18523187314688 - 6264276910080, 0)
+    emitted = 18523187314688 + 4698207682560
+    assert round(100 * 49 * slots / emitted, 1) == 47.2
 
 
 def test_the_reference_reduces_and_reconstructs():
