@@ -963,9 +963,14 @@ def _build_dist_cholesky(dist, mesh, uplo, use_pallas, pallas_interpret,
 
         # -- diag tile -> everyone (reference: col bcast impl.h:215-219);
         # uplo='U' carries a block ROW, indexed by column slots
-        cand = lt[kr, kc] if la is None \
-            else la[0][(kr if uplo == "L" else kc) - la[1]]
-        diag = cc.bcast2d(cand, owner_r, owner_c)
+        # the chain's collectives, with the selects and gathers around
+        # them, are the phase ``cholesky.comm`` (innermost phase wins,
+        # obs/scopes.py): ``panel`` keeps the potrf and the panel solve, so
+        # the chain's device time splits into arithmetic and communication
+        with obs.named_span("cholesky.comm"):
+            cand = lt[kr, kc] if la is None \
+                else la[0][(kr if uplo == "L" else kc) - la[1]]
+            diag = cc.bcast2d(cand, owner_r, owner_c)
         ts = min(mb, n - k * mb)
         if ts < mb:  # pad short edge tile with identity to keep potrf defined
             pad = (jnp.arange(mb) >= ts)
@@ -1020,11 +1025,13 @@ def _build_dist_cholesky(dist, mesh, uplo, use_pallas, pallas_interpret,
                                      fused=panel_fused,
                                      interpret=pallas_interpret,
                                      inv_a=lkk_inv)
-            pan = jnp.where(row_valid[:, None, None], pan,
-                            jnp.zeros_like(pan))
-            # -- panel broadcast (reference broadcast_panel.h:101-193) ---
-            # row-wise: every rank gets the panel tiles for its local rows
-            vr = cc.bcast(pan, COL_AXIS, owner_c)
+            with obs.named_span("cholesky.comm"):
+                pan = jnp.where(row_valid[:, None, None], pan,
+                                jnp.zeros_like(pan))
+                # -- panel broadcast (reference broadcast_panel.h:101-193)
+                # row-wise: every rank gets the panel tiles for its local
+                # rows
+                vr = cc.bcast(pan, COL_AXIS, owner_c)
             ncols = ltc - lu_c
             if ncols == 0:
                 return lkk, pan, vr, None
@@ -1032,8 +1039,11 @@ def _build_dist_cholesky(dist, mesh, uplo, use_pallas, pallas_interpret,
             col_valid = (g_cols > k) & (g_cols < nt)
             # transposed panel: all_gather along 'row' -> all panel tiles,
             # then gather the tiles matching my local trailing columns
-            vc = transpose_col_to_rows(DistContext(dist), vr, lu_r, g_cols)
-            vc = jnp.where(col_valid[:, None, None], vc, jnp.zeros_like(vc))
+            with obs.named_span("cholesky.comm"):
+                vc = transpose_col_to_rows(DistContext(dist), vr, lu_r,
+                                           g_cols)
+                vc = jnp.where(col_valid[:, None, None], vc,
+                               jnp.zeros_like(vc))
             return lkk, pan, vr, vc
 
         # uplo='U': panel is the block row k (reference ``call_U``)
@@ -1052,17 +1062,21 @@ def _build_dist_cholesky(dist, mesh, uplo, use_pallas, pallas_interpret,
                                  fused=panel_fused,
                                  interpret=pallas_interpret,
                                  inv_a=lkk_inv)
-        pan = jnp.where(col_valid[:, None, None], pan, jnp.zeros_like(pan))
         # col-wise down the mesh, then all_gather along the column axis
         # to index the transposed panel by local rows
-        vcp = cc.bcast(pan, ROW_AXIS, owner_r)
+        with obs.named_span("cholesky.comm"):
+            pan = jnp.where(col_valid[:, None, None], pan,
+                            jnp.zeros_like(pan))
+            vcp = cc.bcast(pan, ROW_AXIS, owner_r)
         nrows = ltr - lu_r
         if nrows == 0:
             return lkk, pan, vcp, None
         g_rows = local_rows_global(lu_r, rr, nrows)
         row_valid = (g_rows > k) & (g_rows < nt)
-        vrp = transpose_row_to_cols(DistContext(dist), vcp, lu_c, g_rows)
-        vrp = jnp.where(row_valid[:, None, None], vrp, jnp.zeros_like(vrp))
+        with obs.named_span("cholesky.comm"):
+            vrp = transpose_row_to_cols(DistContext(dist), vcp, lu_c, g_rows)
+            vrp = jnp.where(row_valid[:, None, None], vrp,
+                            jnp.zeros_like(vrp))
         return lkk, pan, vcp, vrp
 
     def step_pre(lt, k, ch):
@@ -1394,9 +1408,10 @@ def _build_dist_cholesky_scan(dist, mesh, uplo, use_mxu=False,
             is_owner_c = ctx.rank_c == owner_c
 
             # -- diag tile -> everyone (one fused 2D collective) --------
-            cand = jax.lax.dynamic_slice(lt, (kr, kc, 0, 0),
-                                         (1, 1, mb, mb))[0, 0]
-            diag = cc.bcast2d(cand, owner_r, owner_c)
+            with obs.named_span("cholesky.comm"):
+                cand = jax.lax.dynamic_slice(lt, (kr, kc, 0, 0),
+                                             (1, 1, mb, mb))[0, 0]
+                diag = cc.bcast2d(cand, owner_r, owner_c)
             ts = jnp.minimum(mb, n - k * mb)
             pad = jnp.arange(mb) >= ts   # short-edge mask
             diag = pad_diag_identity_dyn(diag, ts)
@@ -1463,10 +1478,11 @@ def _build_dist_cholesky_scan(dist, mesh, uplo, use_mxu=False,
                     lt = write_diag(lt, lkk, fallback=pivot_tile(lt))
 
                 # -- panel broadcast + transposed panel ------------------
-                vr = cc.bcast(pan, COL_AXIS, owner_c)
-                vc = transpose_col_to_rows(DistContext(dist), vr, lu_r0,
-                                           g_cols)
-                vc = jnp.where(col_valid[:, None, None], vc, 0)
+                with obs.named_span("cholesky.comm"):
+                    vr = cc.bcast(pan, COL_AXIS, owner_c)
+                    vc = transpose_col_to_rows(DistContext(dist), vr, lu_r0,
+                                               g_cols)
+                    vc = jnp.where(col_valid[:, None, None], vc, 0)
 
                 # -- trailing update over the segment's pair grid --------
                 pair = row_valid[:, None] & col_valid[None, :]
@@ -1507,10 +1523,11 @@ def _build_dist_cholesky_scan(dist, mesh, uplo, use_mxu=False,
                 if fuse_step:
                     lt = write_diag(lt, lkk, fallback=pivot_tile(lt))
 
-                vcp = cc.bcast(pan, ROW_AXIS, owner_r)
-                vrp = transpose_row_to_cols(DistContext(dist), vcp, lu_c0,
-                                            g_rows)
-                vrp = jnp.where(row_valid[:, None, None], vrp, 0)
+                with obs.named_span("cholesky.comm"):
+                    vcp = cc.bcast(pan, ROW_AXIS, owner_r)
+                    vrp = transpose_row_to_cols(DistContext(dist), vcp,
+                                                lu_c0, g_rows)
+                    vrp = jnp.where(row_valid[:, None, None], vrp, 0)
 
                 pair = row_valid[:, None] & col_valid[None, :]
                 below = pair & (g_rows[:, None] < g_cols[None, :])
@@ -1582,9 +1599,10 @@ def _build_dist_cholesky_scan(dist, mesh, uplo, use_mxu=False,
             # bcast/all_gather below — BEFORE the deferred bulk of step
             # k-1, so the scan form's collectives overlap the bulk MXU
             # product by construction (docs/comm_overlap.md).
-            cand = jax.lax.dynamic_slice(lt, (kr, kc, 0, 0),
-                                         (1, 1, mb, mb))[0, 0]
-            diag = cc.bcast2d(cand, owner_r, owner_c)
+            with obs.named_span("cholesky.comm"):
+                cand = jax.lax.dynamic_slice(lt, (kr, kc, 0, 0),
+                                             (1, 1, mb, mb))[0, 0]
+                diag = cc.bcast2d(cand, owner_r, owner_c)
             ts = jnp.minimum(mb, n - k * mb)
             pad = jnp.arange(mb) >= ts
             diag = pad_diag_identity_dyn(diag, ts)
@@ -1640,10 +1658,11 @@ def _build_dist_cholesky_scan(dist, mesh, uplo, use_mxu=False,
                     lt, jnp.where(keep, pan, colk)[:, None], (0, kc, 0, 0))
                 if fuse_step:
                     lt = write_diag(lt, lkk, fallback=pivot_tile(lt))
-                vr = cc.bcast(pan, COL_AXIS, owner_c)
-                vc = transpose_col_to_rows(DistContext(dist), vr, lu_r0,
-                                           g_cols)
-                vc = jnp.where(col_valid[:, None, None], vc, 0)
+                with obs.named_span("cholesky.comm"):
+                    vr = cc.bcast(pan, COL_AXIS, owner_c)
+                    vc = transpose_col_to_rows(DistContext(dist), vr, lu_r0,
+                                               g_cols)
+                    vc = jnp.where(col_valid[:, None, None], vc, 0)
 
                 # -- deferred bulk of step k-1 (its column-k strip was
                 # applied eagerly in body k-1, so exclude column k) ------
@@ -1708,10 +1727,11 @@ def _build_dist_cholesky_scan(dist, mesh, uplo, use_mxu=False,
                 lt, jnp.where(keep, pan, rowk)[None], (kr, 0, 0, 0))
             if fuse_step:
                 lt = write_diag(lt, lkk, fallback=pivot_tile(lt))
-            vcp = cc.bcast(pan, ROW_AXIS, owner_r)
-            vrp = transpose_row_to_cols(DistContext(dist), vcp, lu_c0,
-                                        g_rows)
-            vrp = jnp.where(row_valid[:, None, None], vrp, 0)
+            with obs.named_span("cholesky.comm"):
+                vcp = cc.bcast(pan, ROW_AXIS, owner_r)
+                vrp = transpose_row_to_cols(DistContext(dist), vcp, lu_c0,
+                                            g_rows)
+                vrp = jnp.where(row_valid[:, None, None], vrp, 0)
 
             # deferred bulk of step k-1 (row-k strip applied in body k-1)
             rv_p = (g_rows > k - 1) & (g_rows < nt) & (g_rows != k)
@@ -2083,9 +2103,15 @@ def cholesky(uplo: str, mat: Matrix, *, donate: bool = False,
                                panel_fused=panel_fused,
                                step_fused=step_fused)
     with entry_span, quiet_donation():
+        # ONE program a call on every device of the grid; the host phase
+        # (unfenced: the wall of the enqueue, completion is the caller's
+        # fence) names the device's idle gap before it, and the program is
+        # counted as the entry's, as on the local branch
+        with obs.span("stage.cholesky.dispatch", fenced=False):
+            if obs.metrics_active():
+                obs.counter("dlaf_entry_programs_total",
+                            entry="cholesky").inc()
+            out = obs.telemetry.call("cholesky.dist", fn, mat.storage)
         if with_info:
-            storage, info = obs.telemetry.call("cholesky.dist", fn,
-                                               mat.storage)
-            return mat.with_storage(storage), info
-        return mat.with_storage(
-            obs.telemetry.call("cholesky.dist", fn, mat.storage))
+            return mat.with_storage(out[0]), out[1]
+        return mat.with_storage(out)

@@ -57,11 +57,19 @@ from .devtrace import (
 from . import scopes
 from .sinks import SCHEMA_VERSION
 
-PHASES = ("panel", "strip", "bulk", "other")
+PHASES = ("panel", "comm", "strip", "bulk", "other")
 
-# Bound classes, in reporting order.  "panel" folds in the strip phase
-# (both sit on the panel-chain critical path), "comm"/"copy" are the
-# collective/copy categories regardless of phase, "gap" is measured idle.
+# The phases on the panel-chain critical path, in chain order: the potrf
+# and panel solve, the chain's collectives with the selects and gathers
+# around them (``cholesky.comm``, the distributed Cholesky builders), the
+# eager next-column strip.
+PANEL_CHAIN = ("panel", "comm", "strip")
+
+# Bound classes, in reporting order.  "panel" folds in the rest of the
+# panel chain (all of it sits on the critical path), "comm"/"copy" are the
+# collective/copy CATEGORIES of an operation regardless of its phase (the
+# ``comm`` phase also holds the masks and gathers around a collective),
+# "gap" is measured idle.
 BOUNDS = ("panel", "bulk", "comm", "copy", "gap")
 
 # op_name metadata scopes are parsed by :mod:`dlaf_tpu.obs.scopes`, the one
@@ -82,7 +90,7 @@ def schedule_from_hlo(hlo_text: str) -> dict[str, Any]:
     bodies (a scan body is traced once for all iterations, so its ops
     carry no step index; the joiner reconstructs iterations from
     occurrence order).  ``phase`` is whatever token the builder wrote
-    (``panel``, ``strip``, ``bulk``, the reduction's ``larft`` / ``w`` /
+    (``panel``, ``comm``, ``strip``, ``bulk``, the reduction's ``larft`` / ``w`` /
     ``update``...; ``other`` for a step marker alone): the per-step
     reports fold every token outside :data:`PHASES` into ``other``.
     Instructions without a step scope are omitted.
@@ -460,7 +468,7 @@ def _step_table(evs: list[dict], n_steps: int) -> list[dict]:
 def _bound_of(step: dict) -> str:
     """Classify what bounds a step: argmax over exposure per category."""
     ph = step.get("phases", {})
-    panel = ph.get("panel", 0.0) + ph.get("strip", 0.0)
+    panel = sum(ph.get(p, 0.0) for p in PANEL_CHAIN)
     bulk = ph.get("bulk", 0.0) + ph.get("other", 0.0)
     comm = step.get("comm_exposed_s", 0.0)
     copy = step.get("copy_s", 0.0)
@@ -474,7 +482,7 @@ def _critical_path(steps: list[dict], lookahead: bool) -> dict:
     """Longest path through the step DAG.
 
     Nodes are (step, phase) with measured walls; edges are
-    panel_k -> strip_k -> bulk_k within a step, bulk_k -> bulk_{k+1}
+    panel_k -> comm_k -> strip_k -> bulk_k within a step, bulk_k -> bulk_{k+1}
     (trailing updates serialize on the matrix), and the next panel hangs
     off strip_k when lookahead overlaps it with bulk_k, else off bulk_k.
     Boundary gaps ride the cross-step edges.
@@ -493,7 +501,7 @@ def _critical_path(steps: list[dict], lookahead: bool) -> dict:
         k = st["step"]
         ph = st.get("phases", {})
         gap = steps[k - 1].get("gap_after_s", 0.0) if 0 < k <= len(steps) else 0.0
-        chain = [p for p in ("panel", "strip", "bulk", "other") if p in ph]
+        chain = [p for p in PHASES if p in ph]
         for i, p in enumerate(chain):
             w = ph[p]
             node = (k, p)
@@ -504,7 +512,8 @@ def _critical_path(steps: list[dict], lookahead: bool) -> dict:
             if i == 0:
                 # the panel hangs off strip_{k-1} (lookahead overlap) or the
                 # end of step k-1 entirely (serial)
-                srcs = ("strip", "panel") if lookahead else ("bulk", "other", "strip", "panel")
+                srcs = PANEL_CHAIN[::-1] if lookahead \
+                    else ("bulk", "other") + PANEL_CHAIN[::-1]
             elif p in ("bulk", "other"):
                 srcs = ("bulk", "other")  # trailing updates serialize
             else:
@@ -618,7 +627,7 @@ def attribute(
                 max(0.0, sum(b - a for a, b in comm_u) - _intersect_len(comm_u, comp_u))
             )
             pan_u = _union(
-                [(e["lo"], e["hi"]) for e in revs if e["phase"] in ("panel", "strip")]
+                [(e["lo"], e["hi"]) for e in revs if e["phase"] in PANEL_CHAIN]
             )
             blk_u = _union([(e["lo"], e["hi"]) for e in revs if e["phase"] in ("bulk", "other")])
             panel_exposed_run.append(
@@ -795,7 +804,7 @@ def records_from_report(report: dict, trace: str) -> list[dict]:
                     "step": s["step"],
                     "wall_s": round(s.get("wall_s", 0.0), 9),
                     "panel_s": round(
-                        s["phases"].get("panel", 0.0) + s["phases"].get("strip", 0.0), 9),
+                        sum(s["phases"].get(p, 0.0) for p in PANEL_CHAIN), 9),
                     "bulk_s": round(
                         s["phases"].get("bulk", 0.0) + s["phases"].get("other", 0.0), 9),
                     "comm_s": round(s.get("comm_s", 0.0), 9),
@@ -878,7 +887,7 @@ def format_report(report: dict, top_n: int = 32) -> str:
                 lines.append(f"  {s['step']:4d}  (no device events)")
                 continue
             ph = s.get("phases", {})
-            panel = ph.get("panel", 0.0) + ph.get("strip", 0.0)
+            panel = sum(ph.get(p, 0.0) for p in PANEL_CHAIN)
             bulk = ph.get("bulk", 0.0) + ph.get("other", 0.0)
             lines.append(
                 f"  {s['step']:4d}  {_fmt_ms(s.get('wall_s', 0.0))}  {_fmt_ms(panel)}"

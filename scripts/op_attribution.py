@@ -12,7 +12,9 @@ readers: every ``XLA Ops`` event of the traced window is given to the ``XLA
 Modules`` event that encloses it, own
 time (``trace_reduce.self_times``) is summed per (module, instruction name),
 and the instruction name is looked up in the compiled text of every program
-an entry dispatched through ``telemetry.call`` (``telemetry.programs()`` /
+an entry dispatched through ``telemetry.call`` (on a four-chip cell the
+least busy device's events only, the plane the benchmark's readers read;
+``telemetry.programs()`` /
 ``telemetry.compiled(site)``: the entries remember what they dispatched
 while the metrics sink is on, so the local Cholesky's
 ``jit_cholesky_local_on_tiles``, the local reduction's
@@ -141,6 +143,16 @@ def module_of(modules, start):
     return "?"
 
 
+def least_busy(devices: dict, reduced) -> dict:
+    """``devices`` cut to the plane the benchmark's readers read: the
+    traced run's least busy device (``trace_reduced.json``'s
+    ``worst_device``), so that a four-chip cell's times are ONE chip's, as
+    its ``phase_ms.*`` and ``device_busy_s`` are, and not the sum of four.
+    All of them where the reduced trace names none (a one-plane trace)."""
+    worst = (reduced or {}).get("worst_device")
+    return {worst: devices[worst]} if worst in devices else devices
+
+
 def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", default="chol_d_n4096_1x1")
@@ -180,6 +192,9 @@ def main() -> int:
     out_dir = os.path.join(root, "benchmark", "out", args.workload)
     path = trace_reduce.newest_xplane(os.path.join(out_dir, "trace"))
     devices, _spans, _listing = trace_reduce.read_xplane(path)
+    with open(os.path.join(out_dir, "trace_reduced.json")) as f:
+        devices = least_busy(devices, json.load(f))
+    print(f"[attribution] device planes read: {sorted(devices)}")
     modules, host_spans = span_reduce.load(path)
     window = next((s, e) for s, e, n in host_spans
                   if n == span_reduce.WINDOW)

@@ -153,10 +153,12 @@ def test_counters_are_silent_without_the_metrics_sink():
     assert _counter("dlaf_entry_calls_total") == 0
 
 
-def test_every_entry_counts_its_calls_and_the_distributed_branch_no_program(
+def test_every_entry_counts_its_calls_and_the_distributed_cholesky_a_program(
         tmp_path, devices8):
     """``dlaf_entry_calls_total`` comes from ``obs.entry_span``, so every
-    entry has it; ``dlaf_entry_programs_total`` is the local branch's."""
+    entry has it; ``dlaf_entry_programs_total`` is counted where an entry
+    dispatches a program it says so of: the local branches and, since
+    ISSUE 37, the distributed Cholesky's one program a call."""
     from dlaf_tpu.algorithms.triangular import triangular_solve
     from dlaf_tpu.comm.grid import Grid
 
@@ -165,7 +167,7 @@ def test_every_entry_counts_its_calls_and_the_distributed_branch_no_program(
     size = TileElementSize(8, 8)
     cholesky("L", Matrix.from_global(a, size, grid=Grid(2, 2)))
     assert _counter("dlaf_entry_calls_total") == 1
-    assert _counter("dlaf_entry_programs_total") == 0
+    assert _counter("dlaf_entry_programs_total") == 1
     triangular_solve("L", "L", "N", "N", 1.0,
                      Matrix.from_global(np.tril(a), size),
                      Matrix.from_global(a, size))

@@ -124,6 +124,7 @@ def test_the_builders_file_is_the_file_of_the_entry_that_dispatched(
 
     for module, site in (
             ("dlaf_tpu.algorithms.cholesky", "cholesky.local_scan"),
+            ("dlaf_tpu.algorithms.cholesky", "cholesky.dist"),
             ("dlaf_tpu.algorithms.triangular", "triangular_solve.dist"),
             ("dlaf_tpu.eigensolver.reduction_to_band",
              "reduction_to_band.local_scan")):
@@ -135,3 +136,20 @@ def test_module_of(attribution):
     modules = [(0, 10, "jit_a"), (20, 30, "jit_b")]
     assert [attribution.module_of(modules, t) for t in (0, 9, 10, 25, 40)] \
         == ["jit_a", "jit_a", "?", "jit_b", "?"]
+
+
+@pytest.mark.parametrize("reduced, planes", [
+    # a four-chip cell: the readers' least busy device, not the sum of four
+    ({"worst_device": "/device:TPU:2"}, ["/device:TPU:2"]),
+    # one chip: its one plane
+    ({"worst_device": "/device:TPU:0"}, ["/device:TPU:0"]),
+    # no reduced trace, or a plane it does not know: everything, as before
+    (None, ["/device:TPU:0", "/device:TPU:2"]),
+    ({"worst_device": "/device:TPU:9"}, ["/device:TPU:0", "/device:TPU:2"]),
+])
+def test_least_busy_device_of_a_four_chip_workload(attribution, reduced,
+                                                   planes):
+    devices = {"/device:TPU:0": [(0, 5, "a")], "/device:TPU:2": [(1, 2, "b")]}
+    got = attribution.least_busy(devices, reduced)
+    assert sorted(got) == planes
+    assert all(got[p] is devices[p] for p in planes)

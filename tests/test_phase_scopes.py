@@ -68,6 +68,15 @@ def _sink_on(tmp_path):
     ("jit(f)/cholesky.bulk/cholesky.strip/add", ("cholesky", None, "strip")),
     # the bare layout phase
     ("jit(cholesky_local_on_tiles)/layout/transpose", ("", None, "layout")),
+    # the distributed Cholesky's collectives inside a panel chain: the
+    # innermost phase is ``comm``; a chain hoisted into step 3's scope and
+    # tagged step 4's panel is step 4's ``comm``; a scan body's is step -1
+    ("jit(run)/cholesky.step000/cholesky.step000.panel/cholesky.comm/psum",
+     ("cholesky", 0, "comm")),
+    ("jit(run)/cholesky.step003/cholesky.step004.panel/cholesky.comm/psum",
+     ("cholesky", 4, "comm")),
+    ("jit(run)/while/body/cholesky.scanstep/cholesky.comm/all_gather",
+     ("cholesky", -1, "comm")),
 ])
 def test_parse_innermost_phase_wins(path, want):
     assert tuple(scopes.parse(path)) == want
@@ -132,6 +141,35 @@ def test_schedule_from_hlo_reads_through_the_parser():
     assert "larft" not in critpath.PHASES
 
 
+@pytest.mark.parametrize("phase, chain", [
+    ("panel", True), ("comm", True), ("strip", True), ("bulk", False),
+    ("other", False)])
+def test_critpath_enumerates_the_panel_chain(phase, chain):
+    """``comm`` (the distributed Cholesky's collectives) is a phase of the
+    reports and part of the panel chain, not folded into ``other`` (which
+    counts with the bulk): a step whose chain time is all collectives is
+    still bound by its panel chain."""
+    assert phase in critpath.PHASES
+    assert (phase in critpath.PANEL_CHAIN) == chain
+    step = {"phases": {phase: 1.0, "bulk": 0.5}, "comm_exposed_s": 0.0,
+            "copy_s": 0.0}
+    assert critpath._bound_of(step) == ("panel" if chain else "bulk")
+    path = critpath._critical_path(
+        [{"step": 0, "phases": {"panel": 1.0, phase: 2.0, "bulk": 1.0}}],
+        lookahead=True)
+    assert f"step000.{phase}" in path["nodes"]
+
+
+def test_a_hoisted_chains_comm_is_the_next_steps():
+    """A chain hoisted into step 3's scope, tagged step 4's panel, with its
+    collective under ``cholesky.comm``: the schedule gives it to step 4's
+    ``comm``."""
+    hlo = HLO.replace("jit(toy)/toy.step002.bulk/add",
+                      "jit(toy)/toy.step003/toy.step004.panel/toy.comm/psum")
+    assert critpath.schedule_from_hlo(hlo)["ops"]["add.4"] \
+        == ["toy", 4, "comm"]
+
+
 # ---------------------------------------------------------------------------
 # the four builders, through their entries
 # ---------------------------------------------------------------------------
@@ -176,6 +214,17 @@ def _trsm(devices):
     return dispatch
 
 
+def _cholesky_dist(devices):
+    from dlaf_tpu.algorithms import cholesky
+
+    def dispatch():
+        grid = Grid(2, 2, devices=list(devices[:4]))
+        mat = Matrix.from_global(_hpd(128), TileElementSize(16, 16),
+                                 grid=grid)
+        return cholesky("L", mat, donate=True).storage
+    return dispatch
+
+
 BUILDERS = {
     # site, dispatch, phases the program must carry
     "cholesky_unrolled": ("cholesky.local", lambda d: _cholesky(128, 32),
@@ -185,6 +234,10 @@ BUILDERS = {
     "red2band_scan": ("reduction_to_band.local_scan", lambda d: _red2band(),
                       {"panel", "larft", "w", "x", "update"}),
     "trsm_dist_scan": ("triangular_solve.dist", _trsm, {"panel", "bulk"}),
+    # ISSUE 37: the unrolled distributed Cholesky (8 steps, look-ahead and
+    # the hoisted chains as on a TPU), its collectives the phase ``comm``
+    "cholesky_dist": ("cholesky.dist", _cholesky_dist,
+                      {"panel", "comm", "strip", "bulk"}),
 }
 
 
