@@ -17,7 +17,8 @@ dense MXU primitives:
 * trailing two-sided update: W = A (V T); M = V^H W; X = W - 1/2 V (T^H M);
   A <- A - X V^H - V X^H — three big gemms (the reference's hemmComputeX /
   gemmComputeW2 / gemmUpdateX / her2kUpdateTrailingMatrix fused into batched
-  einsums).
+  einsums; the local builders' rank-2b term is ONE product 2 band deep,
+  ``_rank2b_update``).
 * distributed: the panel is all-gathered along the row axis (nb columns —
   cheap), factored redundantly on every rank, and the update runs as local
   einsums + psum partial sums over the mesh axes.
@@ -156,6 +157,79 @@ def _map_row_chunks(fn, cw: int, *arrs):
     return jnp.concatenate([head, out[-1, cw - tail:]], axis=0)
 
 
+def _balance(x, v):
+    """``(alpha, 1 / alpha)`` for :func:`_rank2b_update`: the power of two
+    nearest the ratio of the operands' mean row maxima, both exact, in the
+    operands' real dtype; ``(1, 1)`` where either operand is all zero (a
+    dead step under the masks) or the ratio is not finite in f32. Row
+    maxima and not the largest entries: V is a unit diagonal over tails of
+    ``1 / sqrt(m)``, its largest entry says nothing of the rows the update
+    is made of, and balanced on it every row but ``band`` holds its V half
+    four bits under its X half (0.05 digits of the reduction's similarity
+    residual at N=8192 on the chip; PERF.md section 6, PR 38). Through
+    f32 ``log2`` / ``round`` and the f32 exponent field: the TPU's
+    emulated-f64 pipeline has no ``frexp`` / ``ldexp``
+    (``ozaki.py:_scale``), and ``exp2`` promises no exact powers of two,
+    which building the exponent bits does. The exponent is held to +-126,
+    so both factors are normal f32 numbers."""
+    mx = jnp.sum(jnp.max(jnp.abs(x), axis=1)).astype(jnp.float32)
+    mv = jnp.sum(jnp.max(jnp.abs(v), axis=1)).astype(jnp.float32)
+    e = jnp.round(jnp.log2(mx) - jnp.log2(mv))     # a zero operand: inf, nan
+    e = jnp.where(jnp.isfinite(e), e, 0)
+    e = jnp.clip(e, -126, 126).astype(jnp.int32)
+    real = jnp.finfo(x.dtype).dtype
+
+    def pow2(k):
+        return jax.lax.bitcast_convert_type((k + 127) << 23,
+                                            jnp.float32).astype(real)
+
+    return pow2(e), pow2(-e)
+
+
+def _rank2b_update(acc, x, v, *, cw: int, form: str):
+    """``acc - (X V^H + V X^H)``, the two-sided update's rank-2b term, as
+    ONE product ``2 band`` deep: ``[X / alpha | V] [alpha V | X]^H``. Two
+    products ``band`` deep fold fourteen shift groups a step into an (m, m)
+    f64 accumulator where one folds seven, and those folds, not the MXU,
+    are what the slice route's bulk dots wait for (PERF.md section 6, PR
+    38); the multiply-accumulates are the same.
+
+    ``alpha`` (:func:`_balance`): the slice route normalizes each row of
+    the left operand and each column of the right one by its largest entry,
+    so side by side the smaller half of a row would lose the bits by which
+    it is smaller (measured: seven orders at ``|X| = 2^20 |V|``). A power
+    of two makes both scalings exact, so the term is unchanged in exact
+    arithmetic. It is computed once a step, outside the chunk body.
+
+    The route is decided on ``band``, as the two products it replaces were
+    (``blas._mxu_f64``'s gate reads the smallest dimension): a band under
+    ``f64_gemm_min_dim`` keeps a native product even where ``2 band``
+    reaches it, and a native product is not balanced (floating point
+    carries each entry's own scale; the balance's f32 exponent arithmetic
+    stays off the native route, ``analysis/graphcheck.py``). ``cw``:
+    row-chunk width (:func:`_trail_chunk`, 0 = unchunked); the row-local
+    operand is ``P``'s chunk, ``Q`` is whole.
+    Counts ``dlaf_red2band_update_products_total{form}``: products the
+    update emits, per EXECUTED step."""
+    m, band = x.shape
+    routed = tb._mxu_f64(x, v, dims=(cw or m, band, m))
+    if routed:
+        alpha, inv_alpha = _balance(x, v)
+        p = jnp.concatenate([x * inv_alpha, v], axis=1)        # (m, 2b)
+        q = jnp.concatenate([alpha * v, x], axis=1).conj().T   # (2b, m)
+    else:                  # native floating point needs no common scale
+        p = jnp.concatenate([x, v], axis=1)
+        q = jnp.concatenate([v, x], axis=1).conj().T
+    product = tb.mm_mxu if routed else jnp.matmul
+    if obs.metrics_active():
+        obs.counter("dlaf_red2band_update_products_total",
+                    form=form).inc(obs.traced_step_count())
+    if cw:
+        return _map_row_chunks(lambda ar, pr: ar - product(pr, q), cw, acc,
+                               p)
+    return acc - product(p, q)
+
+
 @register_program_cache
 @functools.partial(jax.jit, static_argnames=("nb",), donate_argnums=0)
 def _red2band_local(a, *, nb: int):
@@ -193,14 +267,8 @@ def _red2band_local(a, *, nb: int):
             m = tb.mm(v.conj().T, w)              # V^H W  (pw x pw)
             x = w - 0.5 * v @ (t.conj().T @ m)
         with obs.named_span("red2band.update"):
-            vh, xh = v.conj().T, x.conj().T
-            if cw:
-                new_trail = _map_row_chunks(
-                    lambda tr, xr, vr: tr - tb.mm(xr, vh) - tb.mm(vr, xh),
-                    cw, trail, x, v)
-            else:
-                new_trail = trail - tb.mm(x, vh) - tb.mm(v, xh)
-            a = a.at[k1:, k1:].set(new_trail)
+            a = a.at[k1:, k1:].set(
+                _rank2b_update(trail, x, v, cw=cw, form="unrolled"))
     return a, taus_out
 
 
@@ -213,7 +281,11 @@ def _red2band_local_scan(a, *, nb: int):
     panels (docs/DESIGN.md). Uniform scheme: the full-height masked panel
     column is top-aligned with a traced roll (zero rows below a
     Householder panel leave its reflectors unchanged), and the two-sided
-    update is full-size under traced masks (~2-3x flops)."""
+    update is full-size under traced masks (~2-3x flops). A step emits
+    three routed products (``tile_ops/blas.py``): W = A (V T) and M = V^H
+    W, m deep, and the rank-2b update as ONE product ``2 band`` deep,
+    ``[X / alpha | V] [alpha V | X]^H`` (:func:`_rank2b_update`: why the
+    power of two ``alpha``, and why its route reads ``band``)."""
     n = a.shape[0]
     if n == 0:
         return a, jnp.zeros((0, nb), dtype=a.dtype)
@@ -274,13 +346,7 @@ def _red2band_local_scan(a, *, nb: int):
                 mm = tb.mm(v.conj().T, w)
                 x = w - 0.5 * v @ (t.conj().T @ mm)
             with obs.named_span("red2band.update"):
-                vh, xh = v.conj().T, x.conj().T
-                if cw:
-                    acc = _map_row_chunks(
-                        lambda ar, xr, vr: ar - tb.mm(xr, vh) - tb.mm(vr, xh),
-                        cw, acc, x, v)
-                else:
-                    acc = acc - tb.mm(x, vh) - tb.mm(v, xh)
+                acc = _rank2b_update(acc, x, v, cw=cw, form="scan")
             return (acc, taus_out), None
 
         return step

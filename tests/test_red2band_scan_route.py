@@ -35,6 +35,7 @@ from dlaf_tpu import obs
 from dlaf_tpu.common.index2d import TileElementSize
 from dlaf_tpu.eigensolver import reduction_to_band
 from dlaf_tpu.matrix.matrix import Matrix
+from dlaf_tpu.tile_ops import blas as tb
 from dlaf_tpu.tile_ops import ozaki as oz
 from dlaf_tpu.types import telescope_segments
 
@@ -169,13 +170,20 @@ def _case_chunked(n, route, tmp_path):
     assert 96 in route["chunks"], route
 
 
-def _case_native_products(n, route, tmp_path):
+def _case_native_products(n, route, tmp_path, gate=128):
     """The default gate (128): at band 16 the products stay native f64; the
     scan form, its roll and its masks are the same."""
-    C.initialize()
+    C.initialize(C.Configuration(f64_gemm_min_dim=gate))
     _compare(n, *_reduce(n))
     assert route["builders"] == ["scan"], route
     assert route["slices"] == set(), route
+
+
+def _case_gate_reads_band(n, route, tmp_path):
+    """The gate between ``band`` and ``2 band``: the update's one product
+    is ``2 band`` deep and keeps the route of the two ``band`` deep ones
+    it replaced (native: nothing peels), as W and M do."""
+    _case_native_products(n, route, tmp_path, gate=2 * BAND)
 
 
 def _case_unrolled_below_32(n, route, tmp_path):
@@ -195,11 +203,13 @@ def _hand_macs(n, band, s, chunk=0, chunk_at=0):
     of the scan form, per EXECUTED step and chunk. A step on a trailing
     block of m rows makes W = A (V T) ((m, m) x (m, band): m deep and one
     band wide, so s^2 slice-pair slots of depth m are emitted for the s (s
-    + 1) / 2 real ones), M = V^H W ((band, m) x (m, band), likewise) and X
-    V^H, V X^H ((m, band) x (band, m): both outputs wider than the depth,
-    so ragged groups, s (s + 1) / 2 band and no padding). In row chunks of
+    + 1) / 2 real ones), M = V^H W ((band, m) x (m, band), likewise) and
+    the rank-2b update [X / alpha | V] [alpha V | X]^H ((m, 2 band) x (2
+    band, m), ONE product since ISSUE 38 with the multiply-accumulates of
+    the two it replaced: both outputs wider than the depth, so ragged
+    groups, s (s + 1) / 2 times 2 band and no padding). In row chunks of
     ``chunk`` (where shorter than m, and from ``chunk_at`` rows on: the auto
-    rule's 8192) W and the two updates have ``ceil(m / chunk)`` chunks of
+    rule's 8192) W and the update have ``ceil(m / chunk)`` chunks of
     ``chunk`` rows each: the ragged last one starts early and recomputes
     rows. Summed over the route labels of :func:`_hand_macs_by_route`."""
     return tuple(map(sum, zip(*_hand_macs_by_route(n, band, s, chunk,
@@ -211,8 +221,8 @@ def _hand_macs_by_route(n, band, s, chunk=0, chunk_at=0):
     and M, whose contraction (m) is deeper than their narrower output side
     (band), scan the wide operand's slices since ISSUE 36 and count under
     ``scan_slices``: the padded scan's 28 real and 21 zero slots of m an
-    output element. The rank-2b update's two products stay under ``scan``
-    (ragged groups, no padding)."""
+    output element. The rank-2b update's one product ``2 band`` deep stays
+    under ``scan`` (ragged groups, no padding)."""
     pairs = s * (s + 1) // 2
     panels = -(-n // band) - 1
     deep_real = deep_zero = bulk_real = 0
@@ -223,7 +233,7 @@ def _hand_macs_by_route(n, band, s, chunk=0, chunk_at=0):
         rows = -(-m // chunk) * chunk if chunked else m
         deep_real += seg * (rows * band + band * band) * pairs * m   # W, M
         deep_zero += seg * (rows * band + band * band) * (s * s - pairs) * m
-        bulk_real += seg * 2 * rows * m * pairs * band    # X V^H, V X^H
+        bulk_real += seg * rows * m * pairs * 2 * band    # the update
         off += seg
     return {"scan_slices": (deep_real, deep_zero), "scan": (bulk_real, 0)}
 
@@ -261,6 +271,12 @@ def _case_counters(n, route, tmp_path, chunk=0):
     assert _counters("dlaf_red2band_panel_columns_total", form="scan") \
         == panels * BAND
     assert _counters("dlaf_red2band_steps_total", form="unrolled") == 0
+    # the rank-2b update is ONE product a step (two before ISSUE 38),
+    # whatever the row chunks
+    assert _counters("dlaf_red2band_update_products_total",
+                     form="scan") == panels
+    assert _counters("dlaf_red2band_update_products_total",
+                     form="unrolled") == 0
     assert _counters("dlaf_entry_calls_total",
                      entry="reduction_to_band") == 2
     assert _counters("dlaf_entry_programs_total",
@@ -283,6 +299,8 @@ def _case_counters_unrolled(n, route, tmp_path):
     assert _counters("dlaf_red2band_panel_columns_total",
                      form="unrolled") == panels * BAND
     assert _counters("dlaf_red2band_bodies_total", form="scan") == 0
+    assert _counters("dlaf_red2band_update_products_total",
+                     form="unrolled") == panels
     assert _counters("dlaf_entry_programs_total",
                      entry="reduction_to_band") == 3
 
@@ -295,6 +313,8 @@ CASES = [
                  id="chunked-33panels-ragged"),
     pytest.param(_case_native_products, 33 * BAND,
                  id="scan-32panels-native-products"),
+    pytest.param(_case_gate_reads_band, 33 * BAND,
+                 id="scan-32panels-gate-reads-band"),
     pytest.param(_case_unrolled_below_32, 32 * BAND,
                  id="unrolled-31panels"),
     pytest.param(_case_counters, 33 * BAND, id="counters-hand-count"),
@@ -322,6 +342,9 @@ def test_hand_count_of_the_cells_shape():
     real, zero = _hand_macs(8192, 128, 7, chunk=4096, chunk_at=8192)
     assert (real, zero) == _hand_macs(8192, 128, 7)
     assert (real, zero) == (18523187314688, 4698207682560)
+    # dlaf_red2band_update_products_total{form="scan"}: one a step (the
+    # counters case reads it equal to the steps), 126 before ISSUE 38
+    assert sum(telescope_segments(panels)) == 63
 
 
 def test_hand_count_of_the_cells_deep_products():
@@ -338,6 +361,128 @@ def test_hand_count_of_the_cells_deep_products():
     assert by_route["scan"] == (18523187314688 - 6264276910080, 0)
     emitted = 18523187314688 + 4698207682560
     assert round(100 * 49 * slots / emitted, 1) == 47.2
+
+
+# ---------------------------------------------------------------------------
+# the rank-2b update alone (ISSUE 38)
+# ---------------------------------------------------------------------------
+
+def _update_operands(m, band, log2_ratio, seed, dtype=np.float64):
+    """(acc, X, V): V as a step's reflector block (unit lower trapezoid,
+    entries within 1), X normal at ``2^log2_ratio`` times that, or all
+    zero for ``None`` (a dead step under the masks)."""
+    rng = np.random.default_rng(seed)
+
+    def normal(*shape):
+        g = rng.standard_normal(shape)
+        if np.issubdtype(dtype, np.complexfloating):
+            g = g + 1j * rng.standard_normal(shape)
+        return g.astype(dtype)
+
+    v = normal(m, band)
+    v = np.tril(v / np.abs(v).max(), -1) + np.eye(m, band)
+    x = normal(m, band) * (0.0 if log2_ratio is None else 2.0 ** log2_ratio)
+    acc = normal(m, m)
+    return acc + acc.conj().T, x, v
+
+
+@pytest.mark.parametrize("cw", [0, 48], ids=["whole", "row-chunks"])
+@pytest.mark.parametrize("log2_ratio", [-20, 0, 10, 20, None],
+                         ids=["2^-20", "1", "2^10", "2^20", "zero-X"])
+def test_the_merged_update_against_longdouble(log2_ratio, cw, route):
+    """``[X / alpha | V] [alpha V | X]^H`` on the chip's route (seven
+    slices, ragged groups 2 band deep) against numpy's ``longdouble`` ``X
+    V^H + V X^H``: within twice the error of the two ``band`` deep
+    products on the same data whatever ``|X| / |V|`` (unbalanced, the
+    smaller half would lose its low slices: seven orders at 2^20), and
+    finite and exact on an all-zero X. The last row chunk starts early
+    (160 = 3 x 48 + 16)."""
+    _configure()
+    m = 160
+    acc, x, v = _update_operands(m, BAND, log2_ratio, seed=7)
+    want = (x.astype(np.longdouble) @ v.astype(np.longdouble).T
+            + v.astype(np.longdouble) @ x.astype(np.longdouble).T)
+    zero = np.zeros_like(acc)
+    merged = -np.asarray(r2b._rank2b_update(zero, x, v, cw=cw, form="scan"))
+    two = np.asarray(tb.mm(x, v.T) + tb.mm(v, x.T))
+    assert route["slices"] == {SLICES}, route
+    assert np.isfinite(merged).all()
+    err_two = np.abs(two - want).max()
+    assert np.abs(merged - want).max() <= 2 * err_two
+    if log2_ratio is None:
+        assert not merged.any()
+    else:
+        # seven slices: 2^-49 of the operands' row and column scales
+        assert err_two <= 64 * 2.0 ** -49 * np.abs(want).max()
+    # and the subtraction from a live accumulator
+    out = np.asarray(r2b._rank2b_update(acc, x, v, cw=cw, form="scan"))
+    assert np.abs(out - (acc - want)).max() <= 2 * err_two + \
+        2.0 ** -52 * np.abs(acc).max()
+
+
+@pytest.mark.parametrize("cw", [0, 48], ids=["whole", "row-chunks"])
+def test_the_merged_update_complex_on_the_native_route(cw):
+    """complex128 where it runs (the CPU's native products; it does not
+    compile for the TPU, PERF.md PR 34): ``conj`` is on ``Q``'s halves, so
+    the merged update equals ``acc - X V^H - V X^H`` to rounding, at a
+    ratio that needs the balance to be exact."""
+    C.initialize()
+    acc, x, v = _update_operands(160, BAND, 10, seed=11,
+                                 dtype=np.complex128)
+    want = acc - x @ v.conj().T - v @ x.conj().T
+    out = np.asarray(r2b._rank2b_update(acc, x, v, cw=cw, form="scan"))
+    assert out.dtype == np.complex128
+    assert np.abs(out - want).max() <= 1e-13 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("band, peels", [(64, False), (127, False),
+                                         (128, True)],
+                         ids=["band64", "band127", "band128"])
+def test_the_updates_route_is_decided_on_band(band, peels, route):
+    """Under the default gate (``f64_gemm_min_dim`` 128) a band of 64..127
+    keeps native products though the merged depth ``2 band`` reaches the
+    gate; the cell's band, 128, takes the slice route as before."""
+    C.initialize()
+    acc, x, v = _update_operands(384, band, 0, seed=band)
+    out = np.asarray(r2b._rank2b_update(acc, x, v, cw=0, form="scan"))
+    assert route["slices"] == ({SLICES} if peels else set()), route
+    want = acc - x @ v.T - v @ x.T
+    assert np.abs(out - want).max() <= 1e-12 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("x_scale, v_scale, want", [
+    (1.0, 1.0, 1.0), (3.0 * 2 ** 20, 1.0, 2.0 ** 21),
+    (2.0 ** -20, 1.0, 2.0 ** -20), (1.0, 2.0 ** 10, 2.0 ** -10),
+    (0.0, 1.0, 1.0), (1.0, 0.0, 1.0), (0.0, 0.0, 1.0),
+    (np.inf, 1.0, 1.0), (1e300, 1e-300, 1.0)],
+    ids=["equal", "3x2^20", "2^-20", "v-2^10", "zero-x", "zero-v",
+         "both-zero", "inf", "beyond-f32"])
+def test_the_balance_is_an_exact_power_of_two(x_scale, v_scale, want):
+    """``_balance``: the power of two nearest the ratio of the operands'
+    mean row maxima (here 0.75 ``x_scale / v_scale``) with its exact
+    inverse; 1 where an operand is all zero or the ratio is not finite in
+    f32 (what the TPU's f64 holds), never inf or nan."""
+    x = np.array([[0.25, -1.0], [0.5, 0.125]]) * x_scale
+    v = np.array([[1.0, 0.0], [-0.5, 1.0]]) * v_scale
+    alpha, inv = (np.asarray(t) for t in r2b._balance(x, v))
+    assert alpha.dtype == inv.dtype == np.float64
+    assert (float(alpha), float(inv)) == (want, 1.0 / want)
+
+
+def test_the_balance_reads_rows_not_the_unit_diagonal():
+    """A step's V is a unit diagonal over tails of ``1 / sqrt(m)``: its
+    largest entry is 1 in ``band`` rows of m, and a balance on the largest
+    entries would set every other row's V half four bits under its X half
+    (PERF.md section 6, PR 38: 0.05 digits of the chip's residual)."""
+    m, band = 4096, 16
+    rng = np.random.default_rng(5)
+    v = np.tril(rng.standard_normal((m, band)) / np.sqrt(m), -1) \
+        + np.eye(m, band)
+    x = rng.standard_normal((m, band))
+    alpha = float(np.asarray(r2b._balance(x, v)[0]))
+    rows = np.abs(x).max(axis=1).mean() / np.abs(v).max(axis=1).mean()
+    assert alpha == 2.0 ** np.round(np.log2(rows))
+    assert alpha >= 8 * np.abs(x).max() / np.abs(v).max()
 
 
 def test_the_reference_reduces_and_reconstructs():
