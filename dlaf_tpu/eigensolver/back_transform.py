@@ -65,6 +65,38 @@ from .band_to_tridiag import TridiagResult
 from .reduction_to_band import BandReduction
 
 
+def chase_reflector_slots(n: int, b: int, n_sweeps: int, n_steps: int,
+                          group: int) -> tuple[int, int, int]:
+    """``(levels, live, null)`` of one application of the chase reflectors
+    in the uniform ``(n_sweeps, n_steps, b)`` layout, from shapes alone:
+    ``levels`` the sequential scan steps (``ceil(n_sweeps / group) *
+    n_steps`` blocked, ``n_sweeps`` for the sweeps form: ``group=0``),
+    ``live`` the ``(s, t)`` slots whose rows ``s + 1 + t b ..`` start inside
+    the matrix (``ceil((n - 1 - s) / b)`` a sweep), ``null`` the slots the
+    program multiplies all the same: the steps past the matrix end every
+    sweep is padded with, and the sweeps that pad the last group."""
+    live = sum(min(n_steps, ceil_div(n - 1 - s, b)) for s in range(n_sweeps))
+    if group <= 0:
+        return n_sweeps, live, n_sweeps * n_steps - live
+    nblk = ceil_div(n_sweeps, group)
+    return nblk * n_steps, live, nblk * group * n_steps - live
+
+
+def _count_slots(impl: str, slots: tuple[int, int, int]) -> None:
+    """Trace-time accounting of one traced application (a program is
+    traced once a process, so the sums are one call's):
+    ``dlaf_bt_b2t_levels_total{impl}`` and
+    ``dlaf_bt_b2t_reflectors_total{impl, kind=live|null}``
+    (:func:`chase_reflector_slots`)."""
+    if obs.metrics_active():
+        levels, live, null = slots
+        obs.counter("dlaf_bt_b2t_levels_total", impl=impl).inc(levels)
+        obs.counter("dlaf_bt_b2t_reflectors_total", impl=impl,
+                    kind="live").inc(live)
+        obs.counter("dlaf_bt_b2t_reflectors_total", impl=impl,
+                    kind="null").inc(null)
+
+
 @register_program_cache
 @functools.partial(jax.jit, static_argnames=("b", "n", "group"))
 def _bt_b2t_blocked(v_all, tau_all, e, *, b: int, n: int, group: int):
@@ -90,6 +122,7 @@ def _bt_b2t_blocked(v_all, tau_all, e, *, b: int, n: int, group: int):
     G = group
     nblk = ceil_div(n_sweeps, G)
     S = nblk * G
+    _count_slots("blocked", chase_reflector_slots(n, b, n_sweeps, n_steps, G))
     v_all = jnp.pad(v_all, ((0, S - n_sweeps), (0, 0), (0, 0)))
     tau_all = jnp.pad(tau_all, ((0, S - n_sweeps), (0, 0)))
     L = b + G - 1
@@ -107,18 +140,30 @@ def _bt_b2t_blocked(v_all, tau_all, e, *, b: int, n: int, group: int):
     base_seq = blk_idx * G + 1 + t_idx * b
     col_off = jnp.arange(G)
 
+    # phase scopes (`bt_b2t.<phase>`, read by telemetry.phase_table): the
+    # staircase assembly, the T factor, W = T (V^H seg) with the segment's
+    # read, and seg - V W with the write-back
     def body(e_pad, xs):
         vcols, taus, base = xs
-        stair = jax.vmap(
-            lambda vj, j: lax.dynamic_update_slice(
-                jnp.zeros((L,), vcols.dtype), vj, (j,)))(vcols, col_off).T
-        t_mat = larft(stair, jnp.conj(taus))
-        seg = lax.dynamic_slice(e_pad, (base, 0), (L, m))
-        w = t_mat @ tb.mm(jnp.conj(stair).T, seg)
-        seg = seg - tb.mm(stair, w)
-        return lax.dynamic_update_slice(e_pad, seg, (base, 0)), None
+        with obs.named_span("bt_b2t.stair"):
+            stair = jax.vmap(
+                lambda vj, j: lax.dynamic_update_slice(
+                    jnp.zeros((L,), vcols.dtype), vj, (j,)))(vcols, col_off).T
+        with obs.named_span("bt_b2t.tfactor"):
+            t_mat = larft(stair, jnp.conj(taus))
+        with obs.named_span("bt_b2t.project"):
+            seg = lax.dynamic_slice(e_pad, (base, 0), (L, m))
+            w = t_mat @ tb.mm(jnp.conj(stair).T, seg)
+        with obs.named_span("bt_b2t.apply"):
+            seg = seg - tb.mm(stair, w)
+            e_pad = lax.dynamic_update_slice(e_pad, seg, (base, 0))
+        return e_pad, None
 
-    e_pad, _ = lax.scan(body, e_pad, (v_seq, tau_seq, base_seq))
+    # one traced body serves every level: scoped_step hands trace-time
+    # counters (the slice products' MACs) the trip count
+    e_pad, _ = lax.scan(
+        obs.scoped_step("bt_b2t.scanstep", body, steps=nblk * n_steps),
+        e_pad, (v_seq, tau_seq, base_seq))
     return e_pad[:n]
 
 
@@ -131,20 +176,24 @@ def _bt_b2t_scan(v_all, tau_all, e, *, b: int, n: int):
     seg_len = n_steps * b
     pad = seg_len + 1
     e_pad = jnp.pad(e, ((0, pad), (0, 0)))
+    _count_slots("sweeps", chase_reflector_slots(n, b, n_sweeps, n_steps, 0))
 
     def body(e_pad, xs):
         s, v_s, tau_s = xs
         start = s + 1
-        seg = lax.dynamic_slice(e_pad, (start, 0), (seg_len, m))
-        seg = seg.reshape(n_steps, b, m)
-        w = tb.contract("tb,tbm->tm", jnp.conj(v_s), seg)
-        seg = seg - jnp.conj(tau_s)[:, None, None] * v_s[..., None] * w[:, None, :]
-        e_pad = lax.dynamic_update_slice(e_pad, seg.reshape(seg_len, m), (start, 0))
+        with obs.named_span("bt_b2t.project"):
+            seg = lax.dynamic_slice(e_pad, (start, 0), (seg_len, m))
+            seg = seg.reshape(n_steps, b, m)
+            w = tb.contract("tb,tbm->tm", jnp.conj(v_s), seg)
+        with obs.named_span("bt_b2t.apply"):
+            seg = seg - jnp.conj(tau_s)[:, None, None] * v_s[..., None] * w[:, None, :]
+            e_pad = lax.dynamic_update_slice(e_pad, seg.reshape(seg_len, m), (start, 0))
         return e_pad, None
 
     xs = (jnp.arange(n_sweeps - 1, -1, -1),
           v_all[::-1], tau_all[::-1])
-    e_pad, _ = lax.scan(body, e_pad, xs)
+    e_pad, _ = lax.scan(
+        obs.scoped_step("bt_b2t.scanstep", body, steps=n_sweeps), e_pad, xs)
     return e_pad[:n]
 
 
@@ -176,12 +225,18 @@ def _effective_group(b: int, n_sweeps: int, group: int) -> int:
     return max(1, min(group, b + 1, n_sweeps))
 
 
-def _apply_chase_reflectors(v_all, tau_all, e, *, b: int, n: int,
-                            impl: str, group: int):
+def _chase_program(v_all, *, b: int, n: int, impl: str, group: int):
+    """``(jitted program, its static kwargs)`` for ``impl``."""
     if impl == "blocked":
         g = _effective_group(b, int(v_all.shape[0]), group)
-        return _bt_b2t_blocked(v_all, tau_all, e, b=b, n=n, group=g)
-    return _bt_b2t_scan(v_all, tau_all, e, b=b, n=n)
+        return _bt_b2t_blocked, dict(b=b, n=n, group=g)
+    return _bt_b2t_scan, dict(b=b, n=n)
+
+
+def _apply_chase_reflectors(v_all, tau_all, e, *, b: int, n: int,
+                            impl: str, group: int):
+    fn, static = _chase_program(v_all, b=b, n=n, impl=impl, group=group)
+    return fn(v_all, tau_all, e, **static)
 
 
 def _build_dist_bt_b2t(dist, mesh, *, b: int, cplx: bool, n_sweeps: int,
@@ -242,26 +297,44 @@ def _dist_bt_b2t_cached(dist, mesh, b, cplx, n_sweeps, impl, group):
                                       group=group))
 
 
+def _local_phase(name: str, program: bool = True):
+    """Host phase of the local branch (``stage.bt_band_to_tridiag.<name>``,
+    unfenced: the wall of an async dispatch or of the hand-off's upload);
+    one around a dispatched ``program`` counts it as the entry's
+    (``dlaf_entry_programs_total``), as ``reduction_to_band._local_phase``
+    does."""
+    if program and obs.metrics_active():
+        obs.counter("dlaf_entry_programs_total",
+                    entry="bt_band_to_tridiag").inc()
+    return obs.span(f"stage.bt_band_to_tridiag.{name}", fenced=False)
+
+
 def _bt_b2t_local_array(tri: TridiagResult, e) -> jax.Array:
     n = tri.d.shape[0]
     cplx = np.issubdtype(tri.v.dtype, np.complexfloating)
-    e = memory.as_device(e)
+    # the chase ran on the host: its reflectors are uploaded in every call
+    with _local_phase("upload", program=False):
+        e = memory.as_device(e)
+        v_all, tau_all = memory.as_device(tri.v), memory.as_device(tri.tau)
+        phase = memory.as_device(tri.phase) if cplx else None
     if cplx:
-        e = e.astype(tri.v.dtype) * memory.as_device(tri.phase)[:, None]
+        e = e.astype(tri.v.dtype) * phase[:, None]
     if tri.v.shape[0] == 0:
         return e
     impl, group = _bt_b2t_params()
-    return _apply_chase_reflectors(memory.as_device(tri.v),
-                                   memory.as_device(tri.tau),
-                                   e, b=tri.band, n=n, impl=impl, group=group)
+    fn, static = _chase_program(v_all, b=tri.band, n=n, impl=impl,
+                                group=group)
+    with _local_phase("apply"):
+        # program telemetry (DLAF_PROGRAM_TELEMETRY): off = passthrough
+        return obs.telemetry.call("bt_band_to_tridiag.local", fn, v_all,
+                                  tau_all, e, **static)
 
 
 def _bt_b2t_entry_span(tri: TridiagResult, m: int, impl: str, group: int,
                        grid: str):
     """Entry span: chase back-transform flop model n^2*m muls + n^2*m
-    adds (one rank-1 segment update per reflector;
-    docs/eigensolver_perf.md)."""
-    from .. import obs
+    adds (one rank-1 segment update per live reflector: ~n^2/(2b) of them
+    at 4bm real operations each; docs/eigensolver_perf.md)."""
     from ..types import total_ops
 
     n = tri.d.shape[0]
@@ -291,10 +364,12 @@ def bt_band_to_tridiag(tri: TridiagResult, evecs):
             return _bt_b2t_local_array(tri, evecs)
     if evecs.grid is None or evecs.grid.num_devices == 1:
         with _bt_b2t_entry_span(tri, evecs.size.col, impl_l, group_l, "1x1"):
-            out = _bt_b2t_local_array(tri,
-                                      tiles_to_global(evecs.storage,
-                                                      evecs.dist))
-        return Matrix(evecs.dist, global_to_tiles(out, evecs.dist), evecs.grid)
+            with _local_phase("to_global"):
+                arr = tiles_to_global(evecs.storage, evecs.dist)
+            out = _bt_b2t_local_array(tri, arr)
+            with _local_phase("to_tiles"):
+                storage = global_to_tiles(out, evecs.dist)
+        return Matrix(evecs.dist, storage, evecs.grid)
     dlaf_assert(evecs.size.row == tri.d.shape[0],
                 "bt_band_to_tridiag: eigenvector rows != n")
     dlaf_assert(evecs.block_size.row == evecs.block_size.col,

@@ -4,8 +4,9 @@ TPU-native counterpart of the reference's
 ``miniapp/miniapp_bt_band_to_tridiag.cpp`` (195 LoC): times the application
 of the bulge-chasing Householder vectors to an eigenvector matrix
 (``bt_band_to_tridiag``), with the chase itself as untimed setup. Flop
-model: ~n^2/b reflectors of length b applied to m columns at 4bm real ops
-each -> muls = adds = 2 n^2 m.
+model: sum_s ceil((n-1-s)/b) ~ n^2/(2b) live reflectors of length b applied
+to m columns at 4bm real ops each -> muls = adds = n^2 m (the entry span's
+model, ``back_transform._bt_b2t_entry_span``).
 
 Run:  python -m dlaf_tpu.miniapp.miniapp_bt_band_to_tridiag -m 4096 -b 128
 """
@@ -69,7 +70,7 @@ def run(argv=None) -> list[dict]:
         out = bt_band_to_tridiag(tri, e_in)
         hard_fence(out.storage)
         t = time.perf_counter() - t0
-        gflops = total_ops(opts.dtype, 2.0 * n * n * m, 2.0 * n * n * m) / t / 1e9
+        gflops = total_ops(opts.dtype, n**2 * m, n**2 * m) / t / 1e9
         if run_i < 0:
             continue
         print(f"[{run_i}] {t:.6f}s {gflops:.2f}GFlop/s "
