@@ -12,8 +12,8 @@ exactly as the reference keeps it on CPU (``eigensolver/impl.h:46-72``).
 ``larft`` replaces the reference's gemv-loop T-factor accumulation
 (``factorization/qr/t_factor_impl.h``) with a closed form: for forward
 columnwise reflectors, ``T^{-1} = diag(1/tau) + strict_upper(V^H V)``, so T
-comes from ONE gemm (MXU) plus one small triangular solve — the TPU-idiomatic
-formulation.
+comes from ONE gemm (MXU) plus ``ceil(log2 k)`` masked doubling steps of two
+small products each for its inverse — the TPU-idiomatic formulation.
 """
 
 from __future__ import annotations
@@ -23,6 +23,7 @@ import numpy as np
 import jax.numpy as jnp
 from jax import lax
 
+from .. import obs
 from .blas import _diag_of, _embed_diag, hermitian_from, tri_mask, trsm
 
 
@@ -157,6 +158,18 @@ def larft(v, tau):
     column's stored sub-diagonal is ignored (treated as the null reflector
     it represents) so the closed form matches LAPACK dlarft even when the
     caller left stale data in that column.
+
+    ``T^{-1}`` is inverted by recursive 2x2 block doubling (the recursive
+    ``trtri`` scheme) on the whole (k, k) matrix, with masks instead of
+    slices: ``X`` starts as the inverse of the diagonal, ``diag(tau)``,
+    and is block diagonal with blocks of size ``w``; for each diagonal
+    block ``[A1 C; 0 A2]`` of size ``2 w`` the inverse is
+    ``[X1, -X1 C X2; 0, X2]``, and ``X - X B X``, with ``B`` the ``C``
+    blocks masked out of ``V^H V``, forms exactly that. ``ceil(log2 k)``
+    steps of two (k, k) products (7 at k = 128) where a substitution is k
+    sequential steps; ragged last blocks and leading batch dimensions need
+    nothing else. Counts ``dlaf_larft_doublings_total{k}``: the steps
+    emitted, per EXECUTED call (``obs.traced_step_count()``).
     """
     k = tau.shape[-1]
     vlow = tri_mask(v, "L", k=-1)
@@ -166,9 +179,19 @@ def larft(v, tau):
     vv = vlow + jnp.eye(v.shape[-2], k, dtype=v.dtype)
     s = jnp.conj(jnp.swapaxes(vv, -1, -2)) @ vv            # V^H V, one gemm
     tau_safe = jnp.where(tau == 0, jnp.ones_like(tau), tau)
-    tinv = tri_mask(s, "U", k=-1) + _embed_diag(1.0 / tau_safe, s.shape, s.dtype)
-    eye = jnp.broadcast_to(jnp.eye(k, dtype=v.dtype), s.shape)
-    t = lax.linalg.triangular_solve(tinv, eye, left_side=True, lower=False)
+    t = _embed_diag(tau_safe, s.shape, s.dtype)
+    i = np.arange(k)
+    w, steps = 1, 0
+    while w < k:
+        blk = i // (2 * w)
+        upper = (blk[:, None] == blk[None, :]) & (i[:, None] % (2 * w) < w) \
+            & (i[None, :] % (2 * w) >= w)
+        b = jnp.where(upper, s, jnp.zeros_like(s))     # strictly upper
+        t = t - t @ (b @ t)
+        w, steps = 2 * w, steps + 1
+    if obs.metrics_active():
+        obs.counter("dlaf_larft_doublings_total", k=str(k)).inc(
+            steps * obs.traced_step_count())
     nz = (tau != 0)
     mask = nz[..., :, None] & nz[..., None, :]
     return jnp.where(mask, t, jnp.zeros_like(t))

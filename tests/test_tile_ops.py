@@ -305,6 +305,76 @@ def test_larft_zero_tau_stale_column_wy_identity():
     np.testing.assert_allclose(q_block, q_prod, rtol=1e-12, atol=1e-12)
 
 
+def _larft_case(rng, m, k, dtype, nulls):
+    """Reflectors as a QR panel leaves them (unit diagonal, stale data in
+    a null reflector's column) with proper taus, ``2 / (v^H v)``; ``nulls``
+    zeroes none, every third from the second (interior), or the back half
+    (the chase's trailing block of null reflectors)."""
+    v = rand(rng, (m, k), dtype)
+    v = np.tril(v, -1) + np.eye(m, k, dtype=dtype)
+    taus = np.array([2.0 / np.real(np.vdot(v[:, i], v[:, i]))
+                     for i in range(k)], dtype=dtype)
+    if nulls == "interior":
+        taus[1::3] = 0
+    elif nulls == "trailing":
+        taus[k // 2:] = 0
+    return v, taus
+
+
+def _larft_by_substitution(v, taus):
+    """T by scipy's triangular solve of the same ``T^-1 = diag(1/tau) +
+    strict_upper(V^H V)``, null reflectors' columns ignored, their rows and
+    columns of T zero."""
+    import scipy.linalg as sla
+
+    m, k = v.shape
+    vv = np.where(taus == 0, 0, np.tril(v, -1)) + np.eye(m, k)
+    tinv = np.triu(vv.conj().T @ vv, 1) \
+        + np.diag(1.0 / np.where(taus == 0, 1, taus))
+    t = sla.solve_triangular(tinv, np.eye(k), lower=False)
+    nz = taus != 0
+    return np.where(nz[:, None] & nz[None, :], t, 0)
+
+
+@pytest.mark.parametrize("nulls", ["none", "interior", "trailing"])
+@pytest.mark.parametrize("dtype", [np.float64, np.complex128])
+@pytest.mark.parametrize("k", [1, 2, 3, 5, 64, 127, 128])
+def test_larft_doubling_inverse(k, dtype, nulls):
+    """The masked doubling inverse (ISSUE 40) at every depth of its
+    recursion, ragged last blocks included: the WY identity against the
+    explicit reflector product, and T against a substitution of the same
+    ``T^-1`` to 1e-13 relative. k = 128 at the chase's (255, 128)."""
+    rng = np.random.default_rng(40 * k + len(nulls))
+    m = 255 if k == 128 else k + 9
+    v, taus = _larft_case(rng, m, k, dtype, nulls)
+    t = np.asarray(tl.larft(jnp.asarray(v), jnp.asarray(taus)))
+    want = _larft_by_substitution(v, taus)
+    assert np.abs(t - want).max() <= 1e-13 * np.abs(want).max()
+    assert np.all(np.tril(t, -1) == 0)
+    q_prod = np.eye(m, dtype=dtype)
+    for i in range(k):
+        q_prod = q_prod - taus[i] * np.outer(q_prod @ v[:, i], v[:, i].conj())
+    vv = np.where(taus == 0, 0, np.tril(v, -1)) + np.eye(m, k)
+    np.testing.assert_allclose(np.eye(m) - vv @ t @ vv.conj().T, q_prod,
+                               rtol=1e-12, atol=1e-12)
+
+
+def test_larft_batched_matches_per_item():
+    """A leading batch dimension: the products broadcast, so each item's T
+    is the one a call of its own returns."""
+    rng = np.random.default_rng(401)
+    cases = [_larft_case(rng, 40, 24, np.float64, nulls)
+             for nulls in ("none", "interior", "trailing")]
+    v = np.stack([c[0] for c in cases])
+    taus = np.stack([c[1] for c in cases])
+    t = np.asarray(tl.larft(jnp.asarray(v), jnp.asarray(taus)))
+    for j, (vj, tj) in enumerate(cases):
+        one = np.asarray(tl.larft(jnp.asarray(vj), jnp.asarray(tj)))
+        np.testing.assert_allclose(t[j], one, rtol=1e-14, atol=1e-14)
+        want = _larft_by_substitution(vj, tj)
+        assert np.abs(t[j] - want).max() <= 1e-13 * np.abs(want).max()
+
+
 def test_stedc_vs_scipy():
     rng = np.random.default_rng(14)
     n = 12
