@@ -82,6 +82,17 @@ def chase_reflector_slots(n: int, b: int, n_sweeps: int, n_steps: int,
     return nblk * n_steps, live, nblk * group * n_steps - live
 
 
+def _staircase(vcols, L: int):
+    """The (L, G) staircase of G reflectors ``vcols`` (G, b), L >= b + G - 1:
+    column j is reflector j shifted down j rows, zeros elsewhere. A skew:
+    each row padded with zeros to L + 1 and the whole read back as (G, L),
+    so row j starts at flat index j (L + 1) = j L + j. Pad, reshape, slice
+    and transpose only: no loop, no scatter, no gather."""
+    G, b = vcols.shape
+    flat = jnp.pad(vcols, ((0, 0), (0, L + 1 - b))).reshape(-1)
+    return flat[:G * L].reshape(G, L).T
+
+
 def _count_slots(impl: str, slots: tuple[int, int, int]) -> None:
     """Trace-time accounting of one traced application (a program is
     traced once a process, so the sums are one call's):
@@ -138,17 +149,14 @@ def _bt_b2t_blocked(v_all, tau_all, e, *, b: int, n: int, group: int):
     blk_idx = jnp.repeat(jnp.arange(nblk - 1, -1, -1), n_steps)
     t_idx = jnp.tile(jnp.arange(n_steps), nblk)
     base_seq = blk_idx * G + 1 + t_idx * b
-    col_off = jnp.arange(G)
 
     # phase scopes (`bt_b2t.<phase>`, read by telemetry.phase_table): the
-    # staircase assembly, the T factor, W = T (V^H seg) with the segment's
-    # read, and seg - V W with the write-back
+    # staircase's skew (_staircase), the T factor, W = T (V^H seg) with the
+    # segment's read, and seg - V W with the write-back
     def body(e_pad, xs):
         vcols, taus, base = xs
         with obs.named_span("bt_b2t.stair"):
-            stair = jax.vmap(
-                lambda vj, j: lax.dynamic_update_slice(
-                    jnp.zeros((L,), vcols.dtype), vj, (j,)))(vcols, col_off).T
+            stair = _staircase(vcols, L)
         with obs.named_span("bt_b2t.tfactor"):
             t_mat = larft(stair, jnp.conj(taus))
         with obs.named_span("bt_b2t.project"):
