@@ -750,17 +750,7 @@ def run_variant() -> None:
     # red tier-1)
     platform = require_platform()
     os.environ.setdefault("DLAF_CHOLESKY_LOOKAHEAD", la or "0")
-    # "ozaki_concat"/"ozaki_dots" = the ozaki trailing with the group form
-    # pinned (config ozaki_group) — labeled separately so the sweep A/Bs
-    # the two group forms against the auto default (concat on TPU since
-    # the 2026-08-01 dot_ab session) and the headline picks whichever
-    # silicon prefers
-    if base in ("ozaki_concat", "ozaki_dots"):
-        os.environ["DLAF_CHOLESKY_TRAILING"] = "ozaki"
-        os.environ.setdefault("DLAF_OZAKI_GROUP",
-                              base.removeprefix("ozaki_"))
-    else:
-        os.environ["DLAF_CHOLESKY_TRAILING"] = base
+    os.environ["DLAF_CHOLESKY_TRAILING"] = base
 
     import dlaf_tpu.config as config
 
@@ -906,10 +896,7 @@ def sweep(platform: str) -> None:
     pinned = os.environ.get("DLAF_BENCH_TRAILING")
     # measured winner first (ozaki 91-99 GF/s vs xla 37-47 on one v5e
     # chip, 2026-08, hard_fence timing): if the time budget runs out or a
-    # later variant hangs, the best measurement has already landed
-    # the group-form A/B arm pins whichever form ozaki_group=auto does
-    # NOT resolve to on this platform (concat on TPU, dots elsewhere),
-    # so "ozaki" (the auto default) vs the pinned arm is a real A/B.
+    # later variant hangs, the best measurement has already landed.
     # "+la1" arms re-run a form under the pipelined step order
     # (cholesky_lookahead=1) against the plain serialized arm — the
     # look-ahead A/B the bench artifact must carry on every run.
@@ -919,10 +906,9 @@ def sweep(platform: str) -> None:
     # the eigensolver stage A/B arms (tridiag dc_level_batch, btr2b
     # bt_lookahead — ISSUE 6) run LAST: the headline cholesky sweep owns
     # the budget, and the stage pairs are informational artifact rows
-    ab_arm = "ozaki_dots" if platform == "tpu" else "ozaki_concat"
     # the fused-panel pair (ISSUE 10) rides after the stage arms: f32,
     # its own workload label, plain arm pinned to panel_impl=xla
-    order = ["ozaki", "ozaki+la1", ab_arm, "xla", "scan", "scan+la1",
+    order = ["ozaki", "ozaki+la1", "xla", "scan", "scan+la1",
              "loop", "loop+la1", "biggemm", "biggemm+la1", "invgemm",
              "tridiag", "tridiag+dcb1", "btr2b", "btr2b+btla1", "btb2t",
              "fpanel", "fpanel+fp1", "fstep", "fstep+fs1", "serve",
@@ -930,8 +916,7 @@ def sweep(platform: str) -> None:
 
     def _known(v):
         b = v[: -len("+la1")] if v.endswith("+la1") else v
-        return b in VALID_TRAILING or v == ab_arm \
-            or v.split("+")[0] in STAGE_BASES
+        return b in VALID_TRAILING or v.split("+")[0] in STAGE_BASES
 
     variants = [pinned] if pinned else \
         [v for v in order if _known(v)] + \

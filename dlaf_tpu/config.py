@@ -179,8 +179,7 @@ class Configuration:
     #: ~22% of the MXU work; measured 103.9 vs 95.5 GF/s on config #1,
     #: 2026-07-31 v5e session), 8 where f64 is native (f64-grade dots).
     f64_gemm_slices: int = 0
-    #: Slice contraction route of the ozaki paths (jnp AND the fused
-    #: pallas kernels): "int8" (s8 x s8 ->
+    #: Slice contraction route of the ozaki products: "int8" (s8 x s8 ->
     #: s32 dot), "bf16" (slices cast to bf16 — exact for 7-bit integers —
     #: contracted on the MXU's native bf16 path with f32 accumulation,
     #: integer-exact while k*2^12 <= 2^24, chunked beyond; bit-identical
@@ -188,56 +187,10 @@ class Configuration:
     #: 2026-08-01 dot_ab session settled the routes on silicon:
     #: bit-identical on device (0/65536 mismatches at k up to 4096) and
     #: at performance parity at the pipeline level (within 1% on full
-    #: config #1 under either group form — the jnp path is HBM-bound, so
+    #: config #1 — the slice product is HBM-bound, so
     #: the raw s8-dot lowering deficit never binds); bf16 stays the TPU
     #: default as the hardware's first-class MXU path.
     ozaki_dot: str = "auto"
-    #: Shape of the jnp path's per-shift group sums: "dots" (one MXU dot
-    #: per slice pair, group summed elementwise in HBM — the original
-    #: form) or "concat" (ONE dot per shift group over k-concatenated
-    #: slice operands: the d+1 pair sums ride the MXU accumulator instead
-    #: of materializing d+1 (m, n) int32 buffers; "concat" trades more
-    #: int8 operand reads, 1 B/elt, for fewer int32 intermediates,
-    #: 4 B/elt). Outside the padded scans of ozaki_accum="scan", group
-    #: d's operands are static slices of one concatenation per operand,
-    #: at the group's real depth (d+1) k.
-    #: Bit-identical integer math either way (tests/test_ozaki.py).
-    #: "auto" (default) resolves concat on TPU and dots elsewhere; the
-    #: two forms have not been compared through benchmark/run.py
-    #: (ROADMAP S9: not measured). Syrk's even-shift groups keep their
-    #: diagonal pair as a second dot to preserve the transpose-mirroring
-    #: MAC saving.
-    ozaki_group: str = "auto"
-    #: Schedule of the concat group form's per-shift accumulation;
-    #: bit-identical results either way (tests/test_ozaki.py
-    #: TestScanAccumRoute). "xla": a straight-line trace of the ragged
-    #: group dots — XLA owns the schedule and keeps several (m, n) int32
-    #: group partials live at once (the TPU compiler sizes the solve's
-    #: 4096 x 256 x 4096 product at 2.6 times the temporaries, the
-    #: N=4096 local Cholesky at 2.9 times; PERF.md section 6, PR 28).
-    #: "scan": the sequenced schedule — one partial + the f64
-    #: accumulator live, O(1) in the slice count, which is what the
-    #: N=16384 local Cholesky needs to fit a chip. Three forms, chosen
-    #: from the product's shape (tile_ops/ozaki.py:_sequenced_form): bulk
-    #: products (both output dimensions wider than the contraction) run
-    #: the same ragged dots ordered by an optimization_barrier per group,
-    #: no padding; panel products one block wide and deep and the syrk
-    #: keep lax.scan over zero-padded uniform groups (one body: the least
-    #: program code, which is resident in HBM); deep products (the
-    #: contraction deeper than the narrower output side: the reduction
-    #: to band's W = A (V T)) scan the wide operand's slices as they were
-    #: peeled, so nothing of it is stacked or padded (PERF.md section 6,
-    #: PR 36). "auto" (default): scan on TPU, xla elsewhere (XLA:CPU
-    #: schedules the straight line fine and ignores the barrier's hint).
-    ozaki_accum: str = "auto"
-    #: Ozaki slice-reduction implementation: "jnp" (per-shift int32 groups +
-    #: full-f64 combine — f64-grade dots at f64_gemm_slices >= 8) or
-    #: "pallas" (fused per-tile kernel, double-f32 fold: ~48 mantissa bits,
-    #: no intermediate HBM traffic; see tile_ops/pallas_ozaki.py).
-    #: EXPERIMENTAL: the three kernels compile for the v5e
-    #: (tests/test_chip_compile.py) and are validated in interpret mode;
-    #: they have not run on a chip.
-    ozaki_impl: str = "jnp"
     #: Panel factorization kernels for the blocked algorithms' per-step
     #: potrf + panel-TRSM chain (tile_ops/pallas_panel.py,
     #: docs/pallas_panel.md): "xla" (the generic route — XLA's blocked
@@ -679,10 +632,7 @@ _VALID_CHOICES = {
     "f64_trsm": ("native", "mixed", "auto"),
     "panel_impl": ("fused", "xla", "auto"),
     "step_impl": ("fused", "xla", "auto"),
-    "ozaki_impl": ("jnp", "pallas"),
     "ozaki_dot": ("int8", "bf16", "auto"),
-    "ozaki_group": ("dots", "concat", "auto"),
-    "ozaki_accum": ("xla", "scan", "auto"),
     "qr_panel": ("geqrf", "householder", "auto"),
     "mixed_seed": ("xla", "recursive"),
     "dist_step_mode": ("unrolled", "scan", "auto"),
@@ -891,8 +841,8 @@ def get_configuration() -> Configuration:
 def resolve_platform_auto(value: str, *, knob: str, tpu_choice: str,
                           other_choice: str, detail: str) -> str:
     """Shared resolve-and-announce for the platform-keyed "auto" knobs
-    (ozaki_dot, ozaki_group, ozaki_accum, qr_panel, f64_gemm, f64_trsm,
-    cholesky_trailing — grep for callers rather than trusting this list):
+    (ozaki_dot, qr_panel, f64_gemm, f64_trsm, cholesky_trailing — grep
+    for callers rather than trusting this list):
     pick per the PROCESS
     default jax backend — a trace explicitly placed on a non-default
     backend inherits the process choice; set the knob explicitly for

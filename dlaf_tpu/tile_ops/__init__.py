@@ -6,7 +6,7 @@
 * :mod:`.lapack` — potrf(+info), hegst, laset/lacpy, lange/lantr, larft,
   laed4, stedc (host), and friends.
 * :mod:`.ozaki` — emulated-f64/c128 gemm on the int8 MXU (error-free
-  slicing); :mod:`.pallas_ozaki` is its fused-kernel variant.
+  slicing).
 * :mod:`.mixed` — mixed-precision panel potrf / triangular inverse
   (half-precision seed + Newton).
 * :mod:`.pallas_kernels` — predicated trailing-update Pallas kernel.

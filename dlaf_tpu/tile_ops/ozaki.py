@@ -155,59 +155,6 @@ def _slice_dot_impl() -> str:
                "at the pipeline level — dot_ab, one v5e chip, 2026-08-01")
 
 
-def _group_impl() -> str:
-    """Per-shift group summation shape (config ``ozaki_group``): "dots"
-    (one dot per slice pair + elementwise group sums) or "concat" (one
-    dot per group over k-concatenated operands). Trace-time knob like
-    :func:`_slice_dot_impl`; bit-identical results (tests/test_ozaki.py
-    TestConcatGroupRoute). "auto" resolves concat on TPU (fewer (m, n)
-    int32 intermediates through HBM) and dots elsewhere."""
-    from ..config import get_configuration, resolve_platform_auto
-
-    return resolve_platform_auto(
-        get_configuration().ozaki_group, knob="ozaki_group",
-        tpu_choice="concat", other_choice="dots",
-        detail="one dot per shift group: the pair sums ride the MXU "
-               "accumulator instead of (m, n) int32 buffers; "
-               "bit-identical results")
-
-
-def _accum_impl() -> str:
-    """Schedule of the per-shift group accumulation under the concat
-    group form (config ``ozaki_accum``): "xla" (straight-line trace of
-    the ragged group dots; XLA owns the schedule and DOES keep several
-    (m, n) int32 group partials live at once on the TPU) or "scan": the
-    sequenced schedule, one int32 partial + the f64 accumulator live,
-    O(1) in the slice count (the bound the N=16384 local Cholesky
-    needs). It has three forms, chosen from the product's shape
-    (:func:`_sequenced_form`): bulk products run the same ragged group
-    dots as "xla", ordered by an ``optimization_barrier`` per group;
-    panel products and the syrk keep the ``lax.scan`` over zero-padded
-    uniform groups, whose one body is the least program code; deep
-    products (the contraction deeper than the narrower output side)
-    scan the wide operand's slices instead, each as it was peeled, into
-    an int32 carry of all the groups at once (route label
-    ``scan_slices``). Bit-identical results whichever form — zero int8
-    pad blocks contribute exactly nothing on either dot route and the
-    groups fold in the same order with the same scales. "auto" resolves
-    scan on TPU and xla elsewhere. The "dots" group form ignores this
-    knob (its partials are per-pair and XLA fuses them well)."""
-    from ..config import get_configuration, resolve_platform_auto
-
-    return resolve_platform_auto(
-        get_configuration().ozaki_accum, knob="ozaki_accum",
-        tpu_choice="scan", other_choice="xla",
-        detail="scan keeps one int32 group partial plus the f64 "
-               "accumulator live whatever the slice count; bit-identical "
-               "results")
-
-
-def _concat_route() -> str:
-    """The concat group form's ``route`` label on the ``dlaf_ozaki_*``
-    counters: "scan" or, under the straight-line schedule, "concat"."""
-    return "scan" if _accum_impl() == "scan" else "concat"
-
-
 def _group_scale(d: int, half: bool = False) -> float:
     """Fold scale ``2^-q(d+2)`` of shift group ``d`` (``half``: half of it,
     for the syrk's un-mirrored groups, :func:`_mirror`); a power of two, so
@@ -242,8 +189,13 @@ def _exact_i32(s: int, k: int) -> bool:
 
 
 def _sequenced_form(m: int, n: int, k: int, s: int) -> str:
-    """Which form the sequenced schedule takes for an (m, k) x (k, n)
-    product of ``s`` slices a side: one rule on the shape, no knob.
+    """Which form the slice product takes for an (m, k) x (k, n) product
+    of ``s`` slices a side: one rule on the shape, no knob. Every form
+    keeps one int32 group partial plus the f64 accumulator live, whatever
+    the slice count (the bound the N=16384 local Cholesky needs), folds
+    the groups in the order ``d = 0..s-1`` with the same scales, and
+    gives the same bits (zero int8 pad blocks contribute exactly nothing
+    on either dot route).
 
     * ``"ragged"``, ``min(m, n) > k``: bulk products, both output
       dimensions wider than the contraction. Ragged groups multiply no
@@ -347,7 +299,7 @@ def _dot_i8(ia, ib):
     return acc
 
 
-def _fold_group(acc, d, p, half: bool = False):
+def _fold_group(acc, d, p):
     """Fold one per-shift group into the running f64 accumulator:
     ``acc + P_d 2^-q(d+2)`` (:func:`_group_scale`). The power-of-two
     constant multiply is exact and avoids ldexp (s64 ops). Folding each
@@ -356,27 +308,25 @@ def _fold_group(acc, d, p, half: bool = False):
     the accumulator live, which is what lets the unrolled N=16384
     factorization fit HBM (the collect-then-combine form compiled to a
     22.7 GB peak on a 16 GB v5e)."""
-    term = p.astype(jnp.float64) * _group_scale(d, half)
+    term = p.astype(jnp.float64) * _group_scale(d)
     return term if acc is None else acc + term
 
 
-def _fold_groups(s, group, cats, half: bool = False):
+def _fold_groups(s, group, cats):
     """``sum_d group(d, *cats) 2^-q(d+2)`` folded in the order ``d =
     0..s-1`` (:func:`_fold_group`). ``group(d, *cats)`` builds shift
     group ``d``'s integer partial from static slices of the operands'
-    concatenations ``cats``, at the group's real depth. Under the
-    sequenced schedule (:func:`_accum_impl` "scan") a barrier per group
-    makes group ``d``'s operands available only with the accumulator
-    group ``d - 1`` folded into, so the compiler cannot run the dots
-    ahead of the folds and hold their (m, n) partials: the live set of
-    the padded scan's carry, without the scan (on the TPU compiler;
-    XLA:CPU ignores the hint, tests/test_chip_compile.py)."""
-    sequenced = _accum_impl() == "scan"
+    concatenations ``cats``, at the group's real depth. A barrier between
+    groups makes group ``d``'s operands available only with the
+    accumulator group ``d - 1`` folded into, so the compiler cannot run
+    the dots ahead of the folds and hold their (m, n) partials: the live
+    set of the padded scan's carry, without the scan (on the TPU
+    compiler; XLA:CPU ignores the hint, tests/test_chip_compile.py)."""
     acc = None
     for d in range(s):
-        if sequenced and d:
+        if d:
             acc, cats = lax.optimization_barrier((acc, cats))
-        acc = _fold_group(acc, d, group(d, *cats), half)
+        acc = _fold_group(acc, d, group(d, *cats))
     return acc
 
 
@@ -426,33 +376,28 @@ def _count_macs(route: str, mn: int, real: int, emitted: int) -> None:
                         route=route).inc((mn - live) * emitted)
 
 
-def _group_dot(route: str, ga, gb, pairs: int, k: int):
+def _group_dot(ga, gb, pairs: int, k: int):
     """``ga @ gb``: ``pairs`` slice-pair products of depth ``k`` summed on
     the MXU accumulator by one exact dot that runs once per call, counted
     (:func:`_count_macs`) at the depth of the operands it was handed."""
-    _count_macs(route, ga.shape[-2] * gb.shape[-1], pairs * k, ga.shape[-1])
+    _count_macs("scan", ga.shape[-2] * gb.shape[-1], pairs * k, ga.shape[-1])
     return _dot_i8(ga, gb)
 
 
-def _count_mirror(route: str) -> None:
-    """Trace-time accounting, once per emitted (m, m) mirror of a syrk:
-    ``dlaf_ozaki_mirror_total{route}``."""
-    from .. import obs
-
-    if obs.metrics_active():
-        obs.counter("dlaf_ozaki_mirror_total", route=route).inc()
-
-
-def _mirror(acc, route: str):
+def _mirror(acc):
     """``acc + acc^T``: the syrk's one (m, m) transpose. The slice-pair
     half-products ``g_d = sum_{t<u, t+u=d} I_t I_u^T`` and the symmetric
     diagonal pairs ``D_d`` make the product ``sum_d s_d (g_d + g_d^T +
     D_d)``; the mirror is linear and the group scales are scalars, so that
-    is ``C + C^T`` with ``C = sum_d s_d (g_d + D_d / 2)``: every branch
-    of :func:`_syrk_f64_2d` folds the integers ``2 g_d + D_d`` at
-    ``s_d / 2`` (exact: a power of two) and mirrors the f64 accumulator
-    here once, instead of each group's int32 partial."""
-    _count_mirror(route)
+    is ``C + C^T`` with ``C = sum_d s_d (g_d + D_d / 2)``:
+    :func:`_syrk_f64_2d` folds the integers ``2 g_d + D_d`` at ``s_d / 2``
+    (exact: a power of two) and mirrors the f64 accumulator here once,
+    instead of each group's int32 partial. Counted at trace time, once
+    per emitted mirror: ``dlaf_ozaki_mirror_total{route="scan"}``."""
+    from .. import obs
+
+    if obs.metrics_active():
+        obs.counter("dlaf_ozaki_mirror_total", route="scan").inc()
     return acc + jnp.swapaxes(acc, -1, -2)
 
 
@@ -461,20 +406,6 @@ def _apply_scales(acc, sa, sb):
     :func:`_normalize`; the scales multiply in last so nothing overflows
     unless the true result does."""
     return ((acc * 4.0) * sa) * sb
-
-
-def _use_fused_pallas(k: int) -> bool:
-    """Trace-time: route the slice reduction through the fused Pallas kernel
-    (config ``ozaki_impl="pallas"``)? Interpret mode keeps it testable off
-    TPU; contraction depth is VMEM-bounded. The config check comes first so
-    the default jnp path never imports pallas at all."""
-    from ..config import get_configuration
-
-    if get_configuration().ozaki_impl != "pallas":
-        return False
-    from .pallas_ozaki import K_MAX
-
-    return k <= K_MAX
 
 
 @functools.partial(jnp.vectorize, signature="(m,k),(k,n)->(m,n)",
@@ -486,81 +417,51 @@ def _matmul_f64_2d(a, b, *, slices=DEFAULT_SLICES):
     sb = _scale(b, axis=-2)           # (1, n)
     ia = _peel_slices(_normalize(a, sa), s)
     ib = _peel_slices(_normalize(b, sb), s)
-    if _use_fused_pallas(k):
-        import jax
-
-        from .pallas_ozaki import fused_slice_product
-
-        hi, lo = fused_slice_product(jnp.stack(ia), jnp.stack(ib),
-                                     interpret=jax.default_backend() == "cpu",
-                                     dot=_slice_dot_impl())
-        acc = hi.astype(jnp.float64) + lo.astype(jnp.float64)
+    m, n = a.shape[-2], b.shape[-1]
+    form = _sequenced_form(m, n, k, s)
+    if form == "slices":
+        # deep product: the s^2 slots of the padded scan (zeros among
+        # them, on the narrow operand's side), emitted as s dots
+        _count_macs("scan_slices", m * n, s * (s + 1) // 2 * k, s * s * k)
+        acc = None
+        for d, g_d in enumerate(_scan_slices(ia, ib, s)):
+            acc = _fold_group(acc, d, g_d)
         return _apply_scales(acc, sa, sb)
-    acc = None
-    if _group_impl() == "concat":
-        # one dot per shift group over k-concatenated operands: the d+1
-        # pair sums ride the MXU accumulator (same integer math as the
-        # "dots" form — the concatenated contraction is exactly the sum
-        # of the per-pair contractions — so chunking/exactness bounds in
-        # _dot_i8/_dot_bf16 apply to (d+1)*k unchanged, and they chunk
-        # at depths far above s*k for every supported shape)
-        route = _concat_route()
-        m, n = a.shape[-2], b.shape[-1]
-        form = _sequenced_form(m, n, k, s) if route == "scan" else "ragged"
-        if form == "slices":
-            # deep product: the s^2 slots of the padded scan (zeros among
-            # them, on the narrow operand's side), emitted as s dots
-            _count_macs("scan_slices", m * n, s * (s + 1) // 2 * k,
-                        s * s * k)
-            for d, g_d in enumerate(_scan_slices(ia, ib, s)):
-                acc = _fold_group(acc, d, g_d)
-            return _apply_scales(acc, sa, sb)
-        if form == "groups":
-            # panel product: uniform zero-padded groups scanned with an
-            # f64 carry (one body; s (s - 1) / 2 of its s^2 slots are
-            # zero columns)
-            k_pad = s * k
-            ga = jnp.stack([_pad_k(jnp.concatenate(
-                [ia[t] for t in range(d + 1)], axis=-1), k_pad, -1)
-                for d in range(s)])
-            gb = jnp.stack([_pad_k(jnp.concatenate(
-                [ib[d - t] for t in range(d + 1)], axis=-2), k_pad, -2)
-                for d in range(s)])
-            _count_macs(route, m * n, s * (s + 1) // 2 * k,
-                        s * ga.shape[-1])
+    if form == "groups":
+        # panel product: uniform zero-padded groups scanned with an f64
+        # carry (one body; s (s - 1) / 2 of its s^2 slots are zero columns)
+        k_pad = s * k
+        ga = jnp.stack([_pad_k(jnp.concatenate(
+            [ia[t] for t in range(d + 1)], axis=-1), k_pad, -1)
+            for d in range(s)])
+        gb = jnp.stack([_pad_k(jnp.concatenate(
+            [ib[d - t] for t in range(d + 1)], axis=-2), k_pad, -2)
+            for d in range(s)])
+        _count_macs("scan", m * n, s * (s + 1) // 2 * k, s * ga.shape[-1])
 
-            def body(carry, xs):
-                a_d, b_d, scale = xs
-                p = _dot_i8(a_d, b_d)
-                return carry + p.astype(jnp.float64) * scale, None
+        def body(carry, xs):
+            a_d, b_d, scale = xs
+            p = _dot_i8(a_d, b_d)
+            return carry + p.astype(jnp.float64) * scale, None
 
-            acc, _ = lax.scan(body, jnp.zeros((m, n), jnp.float64),
-                              (ga, gb, _group_scales(s)))
-            return _apply_scales(acc, sa, sb)
-        # ragged groups: group d's operands [I_0 | ... | I_d] and
-        # [J_d; ...; J_0] are contiguous slices of ONE concatenation per
-        # operand, at their real depth (d + 1) k
-        a_cat = jnp.concatenate(ia, axis=-1)          # [I_0 | ... | I_{s-1}]
-        b_rev = jnp.concatenate(ib[::-1], axis=-2)    # [J_{s-1}; ...; J_0]
-
-        def group(d, a_cat, b_rev):
-            return _group_dot(route, a_cat[..., :(d + 1) * k],
-                              b_rev[..., (s - 1 - d) * k:, :], d + 1, k)
-
-        acc = _fold_groups(s, group, (a_cat, b_rev))
+        acc, _ = lax.scan(body, jnp.zeros((m, n), jnp.float64),
+                          (ga, gb, _group_scales(s)))
         return _apply_scales(acc, sa, sb)
-    for d in range(s):
-        terms = [_group_dot("dots", ia[t], ib[d - t], 1, k)
-                 for t in range(d + 1)]
-        if _exact_i32(s, k):
-            p = terms[0]
-            for t in terms[1:]:
-                p = p + t
-        else:
-            p = terms[0].astype(jnp.float64)
-            for t in terms[1:]:
-                p = p + t.astype(jnp.float64)
-        acc = _fold_group(acc, d, p)
+    # ragged groups: one dot per shift group over k-concatenated operands,
+    # the d + 1 pair sums riding the MXU accumulator (the concatenated
+    # contraction is exactly the sum of the per-pair ones, so the
+    # exactness bounds of _dot_i8/_dot_bf16 apply to (d + 1) k unchanged).
+    # Group d's operands [I_0 | ... | I_d] and [J_d; ...; J_0] are
+    # contiguous slices of ONE concatenation per operand, at their real
+    # depth (d + 1) k
+    a_cat = jnp.concatenate(ia, axis=-1)          # [I_0 | ... | I_{s-1}]
+    b_rev = jnp.concatenate(ib[::-1], axis=-2)    # [J_{s-1}; ...; J_0]
+
+    def group(d, a_cat, b_rev):
+        return _group_dot(a_cat[..., :(d + 1) * k],
+                          b_rev[..., (s - 1 - d) * k:, :], d + 1, k)
+
+    acc = _fold_groups(s, group, (a_cat, b_rev))
     return _apply_scales(acc, sa, sb)
 
 
@@ -571,6 +472,12 @@ def matmul_f64(a, b, *, slices: int = DEFAULT_SLICES):
     mantissa coverage: gemm count is ``slices*(slices+1)/2``; accuracy is
     ``~2^(-7*slices)`` relative to ``rowmax(a)*colmax(b)`` (8 -> f64-grade,
     6 -> ~f64 with 3 fewer mantissa digits at half the gemms).
+
+    One schedule: the shift groups fold into an f64 accumulator in the
+    order ``d = 0..s-1``, one int32 group partial live at a time, in the
+    form the operands' shape picks (:func:`_sequenced_form`: ragged
+    groups, a scan over padded groups, or a scan over the wide operand's
+    slices); every form gives the same bits.
     """
     return _matmul_f64_2d(a, b, slices=slices)
 
@@ -582,114 +489,45 @@ def _syrk_f64_2d(a, *, slices=DEFAULT_SLICES):
     k = a.shape[-1]
     sa = _scale(a, axis=-1)           # (m, 1)
     ia = _peel_slices(_normalize(a, sa), s)
-    if _use_fused_pallas(k):
-        import jax
-
-        from .pallas_ozaki import fused_slice_syrk
-
-        # predicated square grid: strictly-upper tiles skip their MXU
-        # dots, mirrored here (halves the MXU work vs the general kernel)
-        hi, lo = fused_slice_syrk(jnp.stack(ia),
-                                  interpret=jax.default_backend() == "cpu",
-                                  dot=_slice_dot_impl())
-        acc = hi.astype(jnp.float64) + lo.astype(jnp.float64)
-        _count_mirror("pallas")
-        acc = jnp.tril(acc) + jnp.swapaxes(jnp.tril(acc, -1), -1, -2)
-        return _apply_scales(acc, sa, jnp.swapaxes(sa, -1, -2))
     cast = (lambda x: x) if _exact_i32(s, k) \
         else (lambda x: x.astype(jnp.float64))
-    acc = None
-    if _group_impl() == "concat":
-        # one dot for the strict-upper pair half of each shift group
-        # (mirrored once), plus the even-shift diagonal pair separately —
-        # keeps the syrk MAC halving while the pair sums ride the MXU
-        # accumulator; exactness as in _matmul_f64_2d's concat branch
-        route = _concat_route()
-        m = a.shape[-2]
-        halves = [[t for t in range(d // 2 + 1) if t != d - t]
-                  for d in range(s)]
-        if route == "scan":
-            # the sequenced schedule keeps the padded scan here: its
-            # only caller of size is the unrolled local Cholesky, where
-            # the ragged form's s + s // 2 kernels a product (one body
-            # here) are resident code, +95 MiB at N=4096 (PERF.md
-            # section 6, PR 28). Half-pair concats zero-padded to the
-            # widest group, the diagonal pair as a zeroed operand on odd
-            # shifts (its dot is then exactly zero)
-            h_pad = max(max((len(h) for h in halves), default=0), 1) * k
-            zero = jnp.zeros_like(ia[0])
+    # one dot for the strict-upper pair half of each shift group (mirrored
+    # once), plus the even-shift diagonal pair separately: the syrk MAC
+    # halving with the pair sums on the MXU accumulator. A padded scan:
+    # ragged groups would be s + s // 2 kernels a product where the scan
+    # has one body, and its only caller of size is the unrolled local
+    # Cholesky, where they are resident code, +95 MiB at N=4096 (PERF.md
+    # section 6, PR 28). Half-pair concats zero-padded to the widest
+    # group, the diagonal pair as a zeroed operand on odd shifts (its dot
+    # is then exactly zero)
+    m = a.shape[-2]
+    halves = [[t for t in range(d // 2 + 1) if t != d - t] for d in range(s)]
+    h_pad = max(max((len(h) for h in halves), default=0), 1) * k
+    zero = jnp.zeros_like(ia[0])
 
-            def half_cat(idx):
-                return _pad_k(jnp.concatenate([ia[t] for t in idx], axis=-1)
-                              if idx else zero, h_pad, -1)
+    def half_cat(idx):
+        return _pad_k(jnp.concatenate([ia[t] for t in idx], axis=-1)
+                      if idx else zero, h_pad, -1)
 
-            ga = jnp.stack([half_cat(halves[d]) for d in range(s)])
-            gb = jnp.stack([half_cat([d - t for t in halves[d]])
-                            for d in range(s)])
-            gd = jnp.stack([ia[d // 2] if d % 2 == 0 else zero
-                            for d in range(s)])
-            _count_macs(route, m * m,
-                        (sum(map(len, halves)) + (s + 1) // 2) * k,
-                        s * (ga.shape[-1] + gd.shape[-1]))
+    ga = jnp.stack([half_cat(halves[d]) for d in range(s)])
+    gb = jnp.stack([half_cat([d - t for t in halves[d]]) for d in range(s)])
+    gd = jnp.stack([ia[d // 2] if d % 2 == 0 else zero for d in range(s)])
+    _count_macs("scan", m * m, (sum(map(len, halves)) + (s + 1) // 2) * k,
+                s * (ga.shape[-1] + gd.shape[-1]))
 
-            def body(carry, xs):
-                a_d, b_d, d_d, scale = xs
-                # cast BEFORE the elementwise pair sum when the group
-                # magnitude bound exceeds int32 (same guard as the
-                # "dots" branch): 2 g + diag can wrap in the window
-                # where s*k*2^12 >= 2^31 but the half-concat depth is
-                # still below _dot_i8's own f64-chunking threshold
-                p = 2 * cast(_dot_i8(a_d, jnp.swapaxes(b_d, -1, -2))) \
-                    + cast(_dot_i8(d_d, jnp.swapaxes(d_d, -1, -2)))
-                return carry + p.astype(jnp.float64) * scale, None
+    def body(carry, xs):
+        a_d, b_d, d_d, scale = xs
+        # cast BEFORE the elementwise pair sum when the group magnitude
+        # bound exceeds int32: 2 g + diag can wrap in the window where
+        # s*k*2^12 >= 2^31 but the half-concat depth is still below
+        # _dot_i8's own f64-chunking threshold
+        p = 2 * cast(_dot_i8(a_d, jnp.swapaxes(b_d, -1, -2))) \
+            + cast(_dot_i8(d_d, jnp.swapaxes(d_d, -1, -2)))
+        return carry + p.astype(jnp.float64) * scale, None
 
-            acc, _ = lax.scan(body, jnp.zeros((m, m), jnp.float64),
-                              (ga, gb, gd, _group_scales(s, half=True)))
-            return _apply_scales(_mirror(acc, route), sa,
-                                 jnp.swapaxes(sa, -1, -2))
-        # straight line over ragged groups: the half pairs t < d - t of
-        # group d are [I_0 | ... | I_{h-1}] against [I_d | ... |
-        # I_{d-h+1}], contiguous slices of the concatenation and of its
-        # block-reversed twin (d = 0 has no half pair, an odd shift no
-        # diagonal pair)
-        a_cat = jnp.concatenate(ia, axis=-1)          # [I_0 | ... | I_{s-1}]
-        a_rev = jnp.concatenate(ia[::-1], axis=-1)    # [I_{s-1} | ... | I_0]
-
-        def group(d, a_cat, a_rev):
-            h = len(halves[d])
-            p = None
-            if h:
-                lo = (s - 1 - d) * k
-                # cast before the elementwise pair sum (see the scan
-                # body above): int32 2 g + diag can wrap where
-                # s*k*2^12 >= 2^31 but _dot_i8 still returns int32
-                p = 2 * cast(_group_dot(
-                    route, a_cat[..., :h * k],
-                    jnp.swapaxes(a_rev[..., lo:lo + h * k], -1, -2), h, k))
-            if d % 2 == 0:
-                i_d = a_cat[..., d // 2 * k:(d // 2 + 1) * k]
-                g = cast(_group_dot(route, i_d, jnp.swapaxes(i_d, -1, -2),
-                                    1, k))
-                p = g if p is None else p + g
-            return p
-
-        acc = _fold_groups(s, group, (a_cat, a_rev), half=True)
-        return _apply_scales(_mirror(acc, route), sa,
-                             jnp.swapaxes(sa, -1, -2))
-    for d in range(s):
-        # G_{t,u} with t+u=d: pair (t,u) and (u,t) are mutual transposes —
-        # compute the strict-upper half once, counted twice, and leave the
-        # transpose to the one mirror (the syrk symmetry saving: ~s^2/4
-        # gemms instead of s^2/2)
-        p = None
-        for t in range(d // 2 + 1):
-            u = d - t
-            g = cast(_group_dot("dots", ia[t],
-                                jnp.swapaxes(ia[u], -1, -2), 1, k))
-            term = g if t == u else 2 * g
-            p = term if p is None else p + term
-        acc = _fold_group(acc, d, p, half=True)
-    return _apply_scales(_mirror(acc, "dots"), sa, jnp.swapaxes(sa, -1, -2))
+    acc, _ = lax.scan(body, jnp.zeros((m, m), jnp.float64),
+                      (ga, gb, gd, _group_scales(s, half=True)))
+    return _apply_scales(_mirror(acc), sa, jnp.swapaxes(sa, -1, -2))
 
 
 def syrk_f64(a, *, slices: int = DEFAULT_SLICES):

@@ -40,8 +40,7 @@ I0 = np.int32(0)
 def _update_kernel(mode_ref, vr_ref, vc_ref, a_ref, out_ref):
     # whole (R, C) mode table in SMEM, indexed by the grid step in the
     # kernel body (TPU lowering rejects sub-(8, 128) SMEM blocks and
-    # loads inside the index map) — same form as
-    # pallas_ozaki._make_masked_kernel
+    # loads inside the index map)
     mode = mode_ref[pl.program_id(0), pl.program_id(1)]
 
     @pl.when(mode == 0)

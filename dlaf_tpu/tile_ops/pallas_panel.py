@@ -87,7 +87,7 @@ from .pallas_kernels import I0
 MICRO = 8
 
 #: Largest diagonal-tile edge the fused panel route accepts (route
-#: policy, like pallas_ozaki.MASKED_MB_MAX): the potrf ladder and the
+#: policy): the potrf ladder and the
 #: solve's scratch inverse hold O(nb^2) f32 working values in VMEM —
 #: ~0.75 MiB at nb=256 plus the strip tile being solved; 512 would put
 #: the solve step's live set past comfortable double-buffering.

@@ -133,14 +133,14 @@ def peel(x, s: int):
     return jnp.stack(oz._peel_slices(oz._normalize(x, sa), s)), sa
 
 
-def cholesky_arm(impl: str, slices: int, dot: str, *, n: int = 4096,
+def cholesky_arm(slices: int, dot: str, *, n: int = 4096,
                  nb: int = 256, source: str, extra_env: dict = None):
     """One config-#1 Cholesky measurement under the given ozaki knobs,
     with the miniapp-grade residual check — THE shared protocol for every
     script's full-cholesky arm (probe-identical by construction, per this
     module's no-copy contract). Returns ``{t, gflops, residual, tol,
     check}``; on a passing TPU run the result is appended to the durable
-    history as ``"<source> impl=...,slices=...,dot=..."``. Knobs are
+    history as ``"<source> slices=...,dot=..."``. Knobs are
     restored and config re-initialized on exit."""
     import jax
     import numpy as np
@@ -154,13 +154,12 @@ def cholesky_arm(impl: str, slices: int, dot: str, *, n: int = 4096,
     from dlaf_tpu.types import total_ops
 
     extra_env = dict(extra_env or {})
-    key = f"impl={impl},slices={slices},dot={dot}" + "".join(
+    key = f"slices={slices},dot={dot}" + "".join(
         f",{k.removeprefix('DLAF_').lower()}={v}"
         for k, v in sorted(extra_env.items()))
     for k, v in extra_env.items():
         os.environ[k] = v
     os.environ["DLAF_CHOLESKY_TRAILING"] = "ozaki"
-    os.environ["DLAF_OZAKI_IMPL"] = impl
     os.environ["DLAF_F64_GEMM_SLICES"] = str(slices)
     os.environ["DLAF_OZAKI_DOT"] = dot
     config.initialize()
@@ -198,8 +197,8 @@ def cholesky_arm(impl: str, slices: int, dot: str, *, n: int = 4096,
                                     resid / tol, f"{source} {key}")
         return out
     finally:
-        for k_ in ("DLAF_CHOLESKY_TRAILING", "DLAF_OZAKI_IMPL",
-                   "DLAF_F64_GEMM_SLICES", "DLAF_OZAKI_DOT",
+        for k_ in ("DLAF_CHOLESKY_TRAILING", "DLAF_F64_GEMM_SLICES",
+                   "DLAF_OZAKI_DOT",
                    *extra_env):
             os.environ.pop(k_, None)
         config.initialize()

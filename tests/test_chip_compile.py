@@ -33,7 +33,6 @@ from jax.sharding import SingleDeviceSharding
 import dlaf_tpu.config as C
 from dlaf_tpu.tile_ops import mixed
 from dlaf_tpu.tile_ops import pallas_kernels as pk
-from dlaf_tpu.tile_ops import pallas_ozaki as po
 from dlaf_tpu.tile_ops import pallas_panel as pp
 
 DTYPES = [jnp.float32, jnp.bfloat16]
@@ -129,7 +128,7 @@ def test_fused_step_compiles(one_chip, dtype, nb, uplo):
 
 
 # ---------------------------------------------------------------------------
-# pallas_kernels / pallas_ozaki
+# pallas_kernels
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("nb", BLOCKS)
@@ -143,30 +142,6 @@ def test_masked_trailing_update_compiles(one_chip, dtype, nb):
         jax.ShapeDtypeStruct((c, nb, nb), dtype, sharding=one_chip),
         jax.ShapeDtypeStruct((r, c), jnp.int32, sharding=one_chip))
     assert _kernels_in(text) == 1
-
-
-@pytest.mark.parametrize("dot", ["int8", "bf16"])
-@pytest.mark.parametrize("kernel", ["product", "syrk", "masked128",
-                                    "masked256"])
-def test_ozaki_slice_kernels_compile(one_chip, kernel, dot):
-    s = 7       # the slice count f64_gemm_slices=auto picks on TPU
-
-    def i8(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int8, sharding=one_chip)
-
-    if kernel == "product":
-        text = _compile(lambda a, b: po.fused_slice_product(a, b, dot=dot),
-                        i8(s, 1024, 256), i8(s, 256, 1024))
-    elif kernel == "syrk":
-        text = _compile(lambda a: po.fused_slice_syrk(a, dot=dot),
-                        i8(s, 1024, 256))
-    else:
-        mb = int(kernel[len("masked"):])
-        text = _compile(
-            lambda a, b, m: po.masked_slice_product(a, b, m, dot=dot),
-            i8(s, 6, mb, mb), i8(s, 6, mb, mb),
-            jax.ShapeDtypeStruct((6, 6), jnp.int32, sharding=one_chip))
-    assert _kernels_in(text) >= 1
 
 
 # ---------------------------------------------------------------------------
@@ -244,22 +219,18 @@ def test_mixed_f64_panel_compiles_partitioned(mesh22):
 PADDED_SCAN_TEMP_MIB = {"bulk": 129.6, "panel": 1.0}
 
 
-@pytest.mark.parametrize("accum", ["scan", "xla"])
 @pytest.mark.parametrize("shape", ["bulk", "panel"])
 def test_f64_product_schedules_on_the_tpu_compiler(one_chip, as_on_tpu,
-                                                   monkeypatch, shape,
-                                                   accum):
+                                                   shape):
     """The distributed solve's bulk product (4096 x 256 x 4096, s = 7,
-    bf16 route) and a panel product (1024 x 256 x 256) under both
-    ``ozaki_accum`` values. Sequenced, the bulk product is seven ragged
-    dots, no loop and no conditional, and the barriers between its groups
-    hold the compiler to the live set of the padded scan's carry (one
-    partial + the accumulator); the straight line keeps the partials live
-    (2.6 times the temporaries). The panel product stays one scan body."""
+    bf16 route) and a panel product (1024 x 256 x 256). The bulk product
+    is seven ragged dots, no loop and no conditional, and the barriers
+    between its groups hold the compiler to the live set of the padded
+    scan's carry (one partial + the accumulator; without them the
+    compiler kept the partials live: 2.6 times the temporaries). The
+    panel product stays one scan body."""
     from dlaf_tpu.tile_ops import ozaki
 
-    monkeypatch.setenv("DLAF_OZAKI_ACCUM", accum)
-    C.initialize()
     m, n = (4096, 4096) if shape == "bulk" else (1024, 256)
     compiled = jax.jit(lambda a, b: ozaki.matmul_f64(a, b, slices=7)).lower(
         jax.ShapeDtypeStruct((m, 256), jnp.float64, sharding=one_chip),
@@ -269,14 +240,8 @@ def test_f64_product_schedules_on_the_tpu_compiler(one_chip, as_on_tpu,
     temp_mib = compiled.memory_analysis().temp_size_in_bytes / 2 ** 20
     dots, loops = text.count(" convolution("), text.count(" while(")
     assert " conditional(" not in text
-    if accum == "scan" and shape == "panel":
-        assert (dots, loops) == (1, 1)
-    else:
-        assert (dots, loops) == (7, 0)
-    if accum == "scan":
-        assert temp_mib <= PADDED_SCAN_TEMP_MIB[shape] + 0.5
-    elif shape == "bulk":
-        assert temp_mib > 2 * PADDED_SCAN_TEMP_MIB[shape]
+    assert (dots, loops) == ((1, 1) if shape == "panel" else (7, 0))
+    assert temp_mib <= PADDED_SCAN_TEMP_MIB[shape] + 0.5
 
 
 #: temporaries the TPU compiler gave the parent of PR 36 (67110eb) for the

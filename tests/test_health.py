@@ -551,18 +551,6 @@ def test_strict_ozaki_gemm_site_raises(tmp_path):
     assert ei.value.site == "ozaki_gemm"
 
 
-def test_strict_ozaki_pallas_site_raises(tmp_path, devices8):
-    path = str(tmp_path / "strict_ozp.jsonl")
-    C.initialize(C.Configuration(metrics_path=path, strict=True,
-                                 ozaki_impl="pallas", f64_gemm="mxu",
-                                 f64_gemm_min_dim=4))
-    a = hpd_matrix(16)
-    with inject.disable_pallas():
-        with pytest.raises(health.DegradationError) as ei:
-            cholesky("L", Matrix_from(a, 4, Grid(2, 2)))
-    assert ei.value.site == "ozaki_pallas"
-
-
 def test_strict_panel_site_raises(tmp_path):
     path = str(tmp_path / "strict_panel.jsonl")
     C.initialize(C.Configuration(metrics_path=path, strict=True,
@@ -593,7 +581,7 @@ def test_strict_coverage_audit_no_unlisted_site():
     import re
 
     covered = {"secular", "deflate", "band_to_tridiag", "pallas_update",
-               "ozaki_gemm", "ozaki_pallas", "panel", "step"}
+               "ozaki_gemm", "panel", "step"}
     root = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "dlaf_tpu")
     found = set()

@@ -93,249 +93,182 @@ class TestOzakiMatmul:
         assert np.allclose(got, got.T)  # symmetry by construction
 
 
-class TestPallasFused:
-    """ozaki_impl="pallas": the fused per-tile slice reduction (interpret
-    mode on CPU) must agree with the jnp path to the double-f32 fold's
-    documented accuracy (~2^-48 relative to row/col scales)."""
+# ---------------------------------------------------------------------------
+# the plain reference: numpy integers, one f64 fold in the product's order
+# ---------------------------------------------------------------------------
 
-    def _knob(self, monkeypatch):
-        monkeypatch.setenv("DLAF_OZAKI_IMPL", "pallas")
-        import dlaf_tpu.config as config
+def _peeled(x, axis, s):
+    """Row (``axis=-1``) or column (``axis=-2``) scales of ``x`` and its
+    ``s`` slices as int64 numpy arrays (the library's own peel: what is
+    under test is how the slices are multiplied and folded)."""
+    from dlaf_tpu.tile_ops import ozaki as oz
+
+    sc = oz._scale(jnp.asarray(x), axis=axis)
+    return np.asarray(sc), [np.asarray(t, np.int64) for t in
+                            oz._peel_slices(oz._normalize(jnp.asarray(x),
+                                                          sc), s)]
+
+
+def reference_matmul(a, b, s):
+    """``a @ b`` as the slice product defines it: the shift-group sums
+    ``G_d = sum_t I_t J_{d-t}`` as exact int64 products, folded in float64
+    in the order ``d = 0..s-1`` at ``2^-7(d+2)`` (exact: a power of two),
+    then scaled back. The fold is the only rounding, so every form of the
+    product must give these bits."""
+    from dlaf_tpu.tile_ops import ozaki as oz
+
+    sa, ia = _peeled(a, -1, s)
+    sb, ib = _peeled(b, -2, s)
+    acc = None
+    for d in range(s):
+        g = sum(ia[t] @ ib[d - t] for t in range(d + 1))
+        term = g.astype(np.float64) * oz._group_scale(d)
+        acc = term if acc is None else acc + term
+    return oz._apply_scales(acc, sa, sb)
+
+
+def reference_syrk(a, s):
+    """``a @ a^T`` in the syrk's own algebra (``ozaki._mirror``): the
+    integers ``2 g_d + D_d`` (half pairs ``t < d - t``, the diagonal pair
+    on even shifts) folded at half the group scale, the f64 accumulator
+    mirrored once, then scaled."""
+    from dlaf_tpu.tile_ops import ozaki as oz
+
+    sa, ia = _peeled(a, -1, s)
+    acc = None
+    for d in range(s):
+        p = sum(2 * (ia[t] @ np.swapaxes(ia[d - t], -1, -2))
+                for t in range(d // 2 + 1) if t != d - t)
+        if d % 2 == 0:
+            p = p + ia[d // 2] @ np.swapaxes(ia[d // 2], -1, -2)
+        term = p.astype(np.float64) * oz._group_scale(d, half=True)
+        acc = term if acc is None else acc + term
+    acc = acc + np.swapaxes(acc, -1, -2)
+    return oz._apply_scales(acc, sa, np.swapaxes(sa, -1, -2))
+
+
+def _complex(re, im):
+    out = np.empty(re.shape, np.complex128)
+    out.real, out.imag = re, im
+    return out
+
+
+def reference_matmul_c128(a, b, s):
+    """:func:`ozaki.matmul_c128`'s four real products, each the reference."""
+    ar, ai, br, bi = a.real, a.imag, b.real, b.imag
+    return _complex(reference_matmul(ar, br, s) - reference_matmul(ai, bi, s),
+                    reference_matmul(ar, bi, s) + reference_matmul(ai, br, s))
+
+
+def reference_herk_c128(a, s):
+    """:func:`ozaki.herk_c128`: two real syrks and one real product."""
+    ar, ai = a.real, a.imag
+    m = reference_matmul(ai, np.swapaxes(ar, -1, -2), s)
+    return _complex(reference_syrk(ar, s) + reference_syrk(ai, s),
+                    m - np.swapaxes(m, -1, -2))
+
+
+def _under_dot(monkeypatch, dot, fn, *args):
+    """``fn(*args)`` as a host array with ``ozaki_dot`` set to ``dot``."""
+    from dlaf_tpu import config
+
+    monkeypatch.setenv("DLAF_OZAKI_DOT", dot)
+    config.initialize()
+    try:
+        return np.asarray(fn(*args))
+    finally:
+        monkeypatch.delenv("DLAF_OZAKI_DOT")
         config.initialize()
-        return config
-
-    def test_matmul_and_syrk_match(self, monkeypatch):
-        config = self._knob(monkeypatch)
-        try:
-            rng = np.random.default_rng(21)
-            a = rng.standard_normal((100, 200))
-            b = rng.standard_normal((200, 70))
-            a[0] *= 2.0**120
-            b[:, 3] *= 2.0**-90
-            got = np.asarray(matmul_f64(a, b))
-            assert _scaled_err(got, a @ b, a, b) < 16 * EPS
-            gs = np.asarray(syrk_f64(a))
-            assert _scaled_err(gs, a @ a.T, a, np.swapaxes(a, -1, -2)) < 16 * EPS
-        finally:
-            monkeypatch.delenv("DLAF_OZAKI_IMPL")
-            config.initialize()
-
-    @pytest.mark.parametrize("m,k", [(100, 200), (513, 64)])
-    def test_syrk_triangular_grid(self, m, k, monkeypatch):
-        """The symmetric kernel computes only lower-triangle tiles (scalar-
-        prefetched pair index); the mirrored result must match numpy at
-        ragged sizes (padding + edge tiles)."""
-        config = self._knob(monkeypatch)
-        try:
-            rng = np.random.default_rng(m)
-            a = rng.standard_normal((m, k))
-            a[0] *= 2.0**90
-            got = np.asarray(syrk_f64(a))
-            ss = np.abs(a).max(1)[:, None] * np.abs(a).max(1)[None, :] * k
-            assert (np.abs(got - a @ a.T) / ss).max() < 16 * EPS
-        finally:
-            monkeypatch.delenv("DLAF_OZAKI_IMPL")
-            config.initialize()
-
-    def test_masked_slice_product_predication(self):
-        """The predicated kernel must equal the plain product on live tile
-        pairs and produce exact zeros on dead ones."""
-        from dlaf_tpu.tile_ops import ozaki as oz
-        from dlaf_tpu.tile_ops.pallas_ozaki import masked_slice_product
-
-        rng = np.random.default_rng(31)
-        R, C, mb = 3, 2, 16
-        s = 8
-        a = rng.standard_normal((R * mb, mb))
-        b = rng.standard_normal((C * mb, mb))
-        sa = np.asarray(oz._scale(jnp.asarray(a), axis=-1))
-        sb = np.asarray(oz._scale(jnp.asarray(b), axis=-1))
-        ia = jnp.stack(oz._peel_slices(jnp.asarray(a / sa * 0.5), s))
-        ib = jnp.stack(oz._peel_slices(jnp.asarray(b / sb * 0.5), s))
-        mode = np.array([[1, 0], [0, 1], [1, 1]], dtype=np.int32)
-        hi, lo = masked_slice_product(ia.reshape(s, R, mb, mb),
-                                      ib.reshape(s, C, mb, mb),
-                                      jnp.asarray(mode), interpret=True)
-        acc = (np.asarray(hi, np.float64) + np.asarray(lo, np.float64)) * 4.0
-        acc = acc * sa.reshape(R, 1, mb, 1) * sb.reshape(1, C, 1, mb)
-        full = a @ b.T
-        for r in range(R):
-            for c in range(C):
-                blk = full[r * mb:(r + 1) * mb, c * mb:(c + 1) * mb]
-                if mode[r, c]:
-                    scale = (np.abs(a).max() * np.abs(b).max() * mb)
-                    assert np.abs(acc[r, c] - blk).max() / scale < 2**-40
-                else:
-                    assert np.all(acc[r, c] == 0.0)
-
-    def test_dist_cholesky_exact_flop_oz_pallas(self, monkeypatch, devices8):
-        """f64_gemm="mxu" + ozaki_impl="pallas" distributed: the predicated
-        trailing kernel (dead tile pairs skipped) must reproduce the plain
-        mxu path's factorization."""
-        monkeypatch.setenv("DLAF_F64_GEMM", "mxu")
-        monkeypatch.setenv("DLAF_F64_GEMM_MIN_DIM", "8")
-        import dlaf_tpu.config as config
-        config.initialize()
-        try:
-            from dlaf_tpu.algorithms.cholesky import cholesky
-            from dlaf_tpu.comm.grid import Grid
-            from dlaf_tpu.common.index2d import (GlobalElementSize,
-                                                 TileElementSize)
-            from dlaf_tpu.matrix.matrix import Matrix
-            from dlaf_tpu.miniapp.generators import hpd_element_fn
-
-            n, nb = 64, 8
-            mat = Matrix.from_element_fn(
-                hpd_element_fn(n, np.float64), GlobalElementSize(n, n),
-                TileElementSize(nb, nb), dtype=np.float64, grid=Grid(2, 4))
-            a = mat.to_numpy()
-            for uplo in ("L", "U"):
-                monkeypatch.setenv("DLAF_OZAKI_IMPL", "pallas")
-                config.initialize()
-                got = cholesky(uplo, mat).to_numpy()
-                monkeypatch.setenv("DLAF_OZAKI_IMPL", "jnp")
-                config.initialize()
-                ref = cholesky(uplo, mat).to_numpy()
-                tri = np.tril if uplo == "L" else np.triu
-                f = tri(got)
-                resid = (np.linalg.norm(f @ f.T - a) if uplo == "L"
-                         else np.linalg.norm(f.T @ f - a)) / np.linalg.norm(a)
-                assert resid < 60 * n * EPS, (uplo, resid)
-                assert np.abs(tri(got) - tri(ref)).max() < 1e-10
-        finally:
-            monkeypatch.delenv("DLAF_F64_GEMM")
-            monkeypatch.delenv("DLAF_F64_GEMM_MIN_DIM")
-            monkeypatch.delenv("DLAF_OZAKI_IMPL", raising=False)
-            config.initialize()
-
-    def test_cholesky_ozaki_under_pallas_impl(self, monkeypatch):
-        monkeypatch.setenv("DLAF_CHOLESKY_TRAILING", "ozaki")
-        config = self._knob(monkeypatch)
-        try:
-            from dlaf_tpu.algorithms.cholesky import cholesky
-            from dlaf_tpu.common.index2d import (GlobalElementSize,
-                                                 TileElementSize)
-            from dlaf_tpu.matrix.matrix import Matrix
-            from dlaf_tpu.miniapp.generators import hpd_element_fn
-
-            n, nb = 256, 64
-            mat = Matrix.from_element_fn(
-                hpd_element_fn(n, np.float64), GlobalElementSize(n, n),
-                TileElementSize(nb, nb), dtype=np.float64)
-            out = cholesky("L", mat)
-            f = np.tril(out.to_numpy())
-            resid = np.linalg.norm(f @ f.T - mat.to_numpy()) \
-                / np.linalg.norm(mat.to_numpy())
-            assert resid < 60 * n * EPS
-        finally:
-            monkeypatch.delenv("DLAF_OZAKI_IMPL")
-            monkeypatch.delenv("DLAF_CHOLESKY_TRAILING")
-            config.initialize()
 
 
-class TestFusedKernelExactness:
-    """The BASELINE.md round-2 pending interpret-mode parity pins
-    (ISSUE 15 satellite): the rewritten predicated-square-grid fused
-    slice kernels' numerical contract checked EXACTLY, not just within
-    tolerance — the per-shift int32 group sums are exact integers, and
-    the double-f32 fold is a deterministic f32 op sequence, so the
-    kernels can be pinned against an independent numpy replay of that
-    sequence bit for bit. (The kernels compile for the v5e,
-    tests/test_chip_compile.py, but have not run on a chip; these pins
-    make a chip run a drop-in check instead of a debug session.)"""
+def _assert_reference_bits(monkeypatch, dot, s, a, b=None):
+    """The product (``b`` given) or the syrk of ``a`` at ``s`` slices on
+    the ``dot`` route equals the plain reference bit for bit."""
+    if b is None:
+        got = _under_dot(monkeypatch, dot, lambda x: syrk_f64(x, slices=s),
+                         jnp.asarray(a))
+        want = reference_syrk(a, s)
+    else:
+        got = _under_dot(monkeypatch, dot,
+                         lambda x, y: matmul_f64(x, y, slices=s),
+                         jnp.asarray(a), jnp.asarray(b))
+        want = reference_matmul(a, b, s)
+    assert got.shape == want.shape
+    assert np.array_equal(got, want)
 
-    S = 6
 
-    def _slices(self, m, k, n, seed=41):
-        from dlaf_tpu.tile_ops import ozaki as oz
+def _rows_over_decades(rng, shape, lo=-6, hi=6):
+    return rng.standard_normal(shape) \
+        * 10.0 ** rng.integers(lo, hi, shape[:-1] + (1,))
 
-        rng = np.random.default_rng(seed)
-        a = rng.standard_normal((m, k))
-        b = rng.standard_normal((k, n))
-        sa = np.asarray(oz._scale(jnp.asarray(a), axis=-1))
-        sb = np.asarray(oz._scale(jnp.asarray(b), axis=-2))
-        ia = jnp.stack(oz._peel_slices(jnp.asarray(a / sa * 0.5), self.S))
-        ib = jnp.stack(oz._peel_slices(jnp.asarray(b / sb * 0.5), self.S))
-        return ia, ib
 
-    @staticmethod
-    def _fold_reference(ia, ib):
-        """Numpy replay of pallas_ozaki._fold_body: exact int64 group
-        sums, the exact int32 -> double-f32 split, and the two-sum fold
-        — the kernels must reproduce this BIT FOR BIT."""
-        from dlaf_tpu.tile_ops.ozaki import SLICE_BITS
+#: ``(m, k, n)`` of each form of the slice product at K = 40
+#: (``ozaki._sequenced_form``), and the syrk's ``(m, k)``
+REFERENCE_FORMS = {
+    "ragged": (48, 40, 56),         # bulk: both output sides wider than k
+    "groups": (56, 40, 40),         # panel: one side k wide
+    "slices_a_wide": (56, 40, 24),  # deep, A the wide operand
+    "slices_b_wide": (24, 40, 56),  # deep, B the wide operand
+    "syrk": (48, 40, None),
+}
 
-        s = ia.shape[0]
-        ia64 = np.asarray(ia, np.int64)
-        ib64 = np.asarray(ib, np.int64)
-        hi = np.zeros((ia.shape[1], ib.shape[2]), np.float32)
-        lo = np.zeros_like(hi)
-        for d in range(s):
-            p = np.zeros_like(hi, dtype=np.int64)
-            for t in range(d + 1):
-                p = p + ia64[t] @ ib64[d - t]
-            phi = p.astype(np.float32)
-            plo = (p - phi.astype(np.int64)).astype(np.float32)
-            scale = np.float32(2.0 ** (-SLICE_BITS * (d + 2)))
-            # Knuth two-sum in f32, exactly as the kernel spells it
-            b32 = phi * scale
-            ssum = hi + b32
-            bb = ssum - hi
-            err = (hi - (ssum - bb)) + (b32 - bb)
-            hi = ssum
-            lo = lo + (err + plo * scale)
-        return hi, lo
 
-    def test_fused_product_matches_exact_fold_replay(self):
-        from dlaf_tpu.tile_ops.pallas_ozaki import fused_slice_product
+class TestPlainReference:
+    """Every form of the slice product against :func:`reference_matmul`
+    and the syrk against :func:`reference_syrk`, bitwise, on both dot
+    routes, in 2-D and under ``jnp.vectorize`` batching. Cheap (one small
+    compile each), so ``quick``: every case stays in the default tier."""
 
-        ia, ib = self._slices(40, 64, 24)
-        hi, lo = fused_slice_product(ia, ib, block_m=16, block_n=16,
-                                     interpret=True)
-        rhi, rlo = self._fold_reference(ia, ib)
-        assert np.array_equal(np.asarray(hi), rhi)
-        assert np.array_equal(np.asarray(lo), rlo)
+    @pytest.mark.quick
+    @pytest.mark.parametrize("batch", [(), (2,)], ids=["2d", "batched"])
+    @pytest.mark.parametrize("dot", ["int8", "bf16"])
+    @pytest.mark.parametrize("s", [6, 7, 8])
+    @pytest.mark.parametrize("form", list(REFERENCE_FORMS))
+    def test_form_equals_reference(self, form, s, dot, batch, monkeypatch):
+        from dlaf_tpu.tile_ops.ozaki import _sequenced_form
 
-    def test_fused_dot_routes_bit_identical(self):
-        """int8 vs bf16 slice dots (the ozaki_dot A/B, integer-exact by
-        the k*2^12 <= 2^24 bound): identical hi AND lo planes."""
-        from dlaf_tpu.tile_ops.pallas_ozaki import fused_slice_product
+        m, k, n = REFERENCE_FORMS[form]
+        rng = np.random.default_rng([s, len(batch)])
+        a = _rows_over_decades(rng, batch + (m, k))
+        if n is None:
+            _assert_reference_bits(monkeypatch, dot, s, a)
+            return
+        assert _sequenced_form(m, n, k, s) == form.split("_")[0]
+        b = np.swapaxes(_rows_over_decades(rng, batch + (n, k)), -1, -2)
+        _assert_reference_bits(monkeypatch, dot, s, a, b)
 
-        ia, ib = self._slices(32, 48, 32)
-        h8, l8 = fused_slice_product(ia, ib, block_m=16, block_n=16,
-                                     interpret=True, dot="int8")
-        hb, lb = fused_slice_product(ia, ib, block_m=16, block_n=16,
-                                     interpret=True, dot="bf16")
-        assert np.array_equal(np.asarray(h8), np.asarray(hb))
-        assert np.array_equal(np.asarray(l8), np.asarray(lb))
+    @pytest.mark.quick
+    @pytest.mark.parametrize("dot", ["int8", "bf16"])
+    @pytest.mark.parametrize("s", [7, 8])
+    @pytest.mark.parametrize("op", ["matmul_ragged", "matmul_groups",
+                                    "matmul_slices", "herk"])
+    def test_c128_equals_reference(self, op, s, dot, monkeypatch):
+        """``matmul_c128`` / ``herk_c128`` against the same reference
+        composed the way they compose it."""
+        from dlaf_tpu.tile_ops.ozaki import herk_c128, matmul_c128
 
-    def test_fused_syrk_matches_product_on_lower_tiles(self):
-        """The predicated syrk (strict-upper tiles skipped) equals the
-        general product of the same slices on every lower tile, bit for
-        bit, and is exactly zero above the block diagonal."""
-        from dlaf_tpu.tile_ops.pallas_ozaki import (fused_slice_product,
-                                                    fused_slice_syrk)
+        form = {"matmul_ragged": "ragged", "matmul_groups": "groups",
+                "matmul_slices": "slices_a_wide", "herk": "syrk"}[op]
+        m, k, n = REFERENCE_FORMS[form]
+        rng = np.random.default_rng([s, 128])
 
-        ia, _ = self._slices(48, 32, 8)
-        block = 16
-        hs, ls = fused_slice_syrk(ia, block=block, interpret=True)
-        hp, lp = fused_slice_product(ia, jnp.swapaxes(ia, 1, 2),
-                                     block_m=block, block_n=block,
-                                     interpret=True)
-        m = ia.shape[1]
-        nt = m // block
-        for r in range(nt):
-            for c in range(nt):
-                sl = (slice(r * block, (r + 1) * block),
-                      slice(c * block, (c + 1) * block))
-                if c <= r:
-                    assert np.array_equal(np.asarray(hs[sl]),
-                                          np.asarray(hp[sl])), (r, c)
-                    assert np.array_equal(np.asarray(ls[sl]),
-                                          np.asarray(lp[sl])), (r, c)
-                else:
-                    assert np.all(np.asarray(hs[sl]) == 0.0)
-                    assert np.all(np.asarray(ls[sl]) == 0.0)
+        def cplx(shape):
+            return _rows_over_decades(rng, shape, -4, 4) \
+                + 1j * _rows_over_decades(rng, shape, -4, 4)
+
+        a = cplx((m, k))
+        if n is None:
+            got = _under_dot(monkeypatch, dot,
+                             lambda x: herk_c128(x, slices=s), jnp.asarray(a))
+            want = reference_herk_c128(a, s)
+        else:
+            b = cplx((k, n))
+            got = _under_dot(monkeypatch, dot,
+                             lambda x, y: matmul_c128(x, y, slices=s),
+                             jnp.asarray(a), jnp.asarray(b))
+            want = reference_matmul_c128(a, b, s)
+        assert got.dtype == np.complex128
+        assert np.array_equal(got, want)
 
 
 class TestContract:
@@ -832,33 +765,11 @@ class TestBf16DotRoute:
 
 
 class TestConcatGroupRoute:
-    """ozaki_group="concat": one k-concatenated dot per shift group must be
-    BIT-IDENTICAL to the per-pair "dots" form — the concatenated
-    contraction is exactly the sum of the per-pair contractions, in exact
-    integer arithmetic on every route (int8 i32-accumulated, bf16
-    f32-chunk-accumulated)."""
-
-    def _ab(self, monkeypatch, fn, *args, dot=None):
-        from dlaf_tpu import config
-
-        if dot is not None:
-            monkeypatch.setenv("DLAF_OZAKI_DOT", dot)
-        # pin the reference arm to "dots" explicitly: the default is
-        # "auto" (concat on TPU), which would make this A/B vacuous on
-        # exactly the platform where concat is the production form
-        monkeypatch.setenv("DLAF_OZAKI_GROUP", "dots")
-        config.initialize()
-        try:
-            ref = np.asarray(fn(*args))
-            monkeypatch.setenv("DLAF_OZAKI_GROUP", "concat")
-            config.initialize()
-            got = np.asarray(fn(*args))
-        finally:
-            monkeypatch.delenv("DLAF_OZAKI_GROUP", raising=False)
-            if dot is not None:
-                monkeypatch.delenv("DLAF_OZAKI_DOT")
-            config.initialize()
-        assert got.tobytes() == ref.tobytes()
+    """One k-concatenated dot per shift group: the concatenated contraction
+    is exactly the sum of the per-pair contractions, in exact integer
+    arithmetic on both dot routes (int8 i32-accumulated, bf16
+    f32-chunk-accumulated), so the product keeps the plain reference's
+    bits (:func:`reference_matmul`, :func:`reference_syrk`)."""
 
     @pytest.mark.parametrize("dot", ["int8", "bf16"])
     @pytest.mark.parametrize("m,k,s", [(64, 48, 7), (33, 256, 8),
@@ -867,8 +778,7 @@ class TestConcatGroupRoute:
         rng = np.random.default_rng(12)
         a = rng.standard_normal((m, k)) * 10.0 ** rng.integers(-6, 6, (m, 1))
         b = rng.standard_normal((k, m)) * 10.0 ** rng.integers(-6, 6, (1, m))
-        self._ab(monkeypatch, lambda x, y: matmul_f64(x, y, slices=s),
-                 jnp.asarray(a), jnp.asarray(b), dot=dot)
+        _assert_reference_bits(monkeypatch, dot, s, a, b)
 
     @pytest.mark.parametrize("dot", ["int8", "bf16"])
     @pytest.mark.parametrize("s", [7, 8])
@@ -876,23 +786,15 @@ class TestConcatGroupRoute:
         rng = np.random.default_rng(13)
         a = rng.standard_normal((96, 128)) * 10.0 ** rng.integers(-4, 4,
                                                                   (96, 1))
-        self._ab(monkeypatch, lambda x: syrk_f64(x, slices=s),
-                 jnp.asarray(a), dot=dot)
+        _assert_reference_bits(monkeypatch, dot, s, a)
 
-    def test_accuracy_f64_grade_under_concat(self, monkeypatch):
-        # same budget as TestOzaki.test_accuracy_f64_grade, via the knob
-        from dlaf_tpu import config
-
+    def test_accuracy_f64_grade_under_concat(self):
+        # same budget as TestOzaki.test_accuracy_f64_grade, on a panel
+        # product (the padded group scan)
         rng = np.random.default_rng(14)
         a = rng.standard_normal((40, 64))
         b = rng.standard_normal((64, 40))
-        monkeypatch.setenv("DLAF_OZAKI_GROUP", "concat")
-        config.initialize()
-        try:
-            got = np.asarray(matmul_f64(jnp.asarray(a), jnp.asarray(b)))
-        finally:
-            monkeypatch.delenv("DLAF_OZAKI_GROUP")
-            config.initialize()
+        got = np.asarray(matmul_f64(jnp.asarray(a), jnp.asarray(b)))
         ref = a @ b
         scale = (np.abs(a).max(axis=-1)[:, None]
                  * np.abs(b).max(axis=-2)[None, :] * a.shape[-1])
@@ -901,15 +803,13 @@ class TestConcatGroupRoute:
     def test_distributed_cholesky_mxu_under_concat(self, monkeypatch,
                                                    devices8):
         """The distributed mxu trailing einsums route through the same
-        matmul/syrk entry points, so group=concat must hold there too —
-        different contraction shapes (batched tile axes) than the local
-        arms above."""
+        matmul/syrk entry points — different contraction shapes (batched
+        tile axes) than the local arms above."""
         from dlaf_tpu import config
 
         monkeypatch.setenv("DLAF_F64_GEMM", "mxu")
         monkeypatch.setenv("DLAF_F64_GEMM_MIN_DIM", "8")
         monkeypatch.setenv("DLAF_F64_TRSM", "mixed")
-        monkeypatch.setenv("DLAF_OZAKI_GROUP", "concat")
         config.initialize()
         try:
             from dlaf_tpu.algorithms.cholesky import cholesky
@@ -930,39 +830,19 @@ class TestConcatGroupRoute:
             assert resid < 60 * n * EPS
         finally:
             for k in ("DLAF_F64_GEMM", "DLAF_F64_GEMM_MIN_DIM",
-                      "DLAF_F64_TRSM", "DLAF_OZAKI_GROUP"):
+                      "DLAF_F64_TRSM"):
                 monkeypatch.delenv(k)
             config.initialize()
 
 
 class TestScanAccumRoute:
-    """ozaki_accum="scan" (the sequenced schedule, O(1) live partials:
-    barriers between the ragged groups of a bulk product, lax.scan'd
-    zero-padded groups for panel products and the syrk, a scan over the
-    wide operand's slices for deep products) must be BIT-IDENTICAL to
-    the straight-line "xla" schedule under the concat group form —
-    padded columns and blocks are int8 zeros, which contribute exactly
-    nothing on either dot route, and the groups fold in the same order
-    with the same scales."""
-
-    def _ab(self, monkeypatch, fn, *args, dot):
-        from dlaf_tpu import config
-
-        monkeypatch.setenv("DLAF_OZAKI_GROUP", "concat")
-        monkeypatch.setenv("DLAF_OZAKI_DOT", dot)
-        monkeypatch.setenv("DLAF_OZAKI_ACCUM", "xla")
-        config.initialize()
-        try:
-            ref = np.asarray(fn(*args))
-            monkeypatch.setenv("DLAF_OZAKI_ACCUM", "scan")
-            config.initialize()
-            got = np.asarray(fn(*args))
-        finally:
-            for k in ("DLAF_OZAKI_GROUP", "DLAF_OZAKI_DOT",
-                      "DLAF_OZAKI_ACCUM"):
-                monkeypatch.delenv(k, raising=False)
-            config.initialize()
-        assert got.tobytes() == ref.tobytes()
+    """The sequenced schedule (O(1) live partials: barriers between the
+    ragged groups of a bulk product, lax.scan'd zero-padded groups for
+    panel products and the syrk, a scan over the wide operand's slices
+    for deep products) keeps the plain reference's bits — padded columns
+    and blocks are int8 zeros, which contribute exactly nothing on either
+    dot route, and the groups fold in the reference's order with its
+    scales."""
 
     @pytest.mark.parametrize("dot", ["int8", "bf16"])
     @pytest.mark.parametrize("m,k,s", [(64, 48, 7), (33, 256, 8),
@@ -971,8 +851,7 @@ class TestScanAccumRoute:
         rng = np.random.default_rng(21)
         a = rng.standard_normal((m, k)) * 10.0 ** rng.integers(-6, 6, (m, 1))
         b = rng.standard_normal((k, m)) * 10.0 ** rng.integers(-6, 6, (1, m))
-        self._ab(monkeypatch, lambda x, y: matmul_f64(x, y, slices=s),
-                 jnp.asarray(a), jnp.asarray(b), dot=dot)
+        _assert_reference_bits(monkeypatch, dot, s, a, b)
 
     #: deep products, ``k > min(m, n)`` (ISSUE 36): A the wide operand, B
     #: the wide operand, square, and a batch under ``jnp.vectorize``
@@ -986,7 +865,7 @@ class TestScanAccumRoute:
                                        monkeypatch):
         """The scan over the wide operand's slices (``I_t`` against the
         narrow operand's slices shifted into ``s`` blocks, summed in one
-        int32 carry) keeps the bits of the straight line."""
+        int32 carry) keeps the reference's bits."""
         from dlaf_tpu.tile_ops.ozaki import _sequenced_form
 
         assert _sequenced_form(m, n, k, s) == "slices"
@@ -994,14 +873,13 @@ class TestScanAccumRoute:
         a = rng.standard_normal(batch + (m, k)) \
             * 10.0 ** rng.integers(-6, 6, batch + (m, 1))
         b = rng.standard_normal((k, n)) * 10.0 ** rng.integers(-6, 6, (1, n))
-        self._ab(monkeypatch, lambda x, y: matmul_f64(x, y, slices=s),
-                 jnp.asarray(a), jnp.asarray(b), dot=dot)
+        _assert_reference_bits(monkeypatch, dot, s, a, b)
 
     def test_deep_matmul_past_int32_keeps_the_group_scan(self, monkeypatch):
         """Where a group sum could pass int32 (``s k 2^12 >= 2^31``) the
         slices form's int32 carry is not exact: the product keeps the
-        group scan, whose dots chunk into f64, and the straight line's
-        bits. The adversarial rows of ``test_concat_syrk_int32_wrap_window``
+        group scan, whose dots chunk into f64, and the reference's bits.
+        The adversarial rows of ``test_concat_syrk_int32_wrap_window``
         put the last group's sum past ``INT32_MIN``."""
         from dlaf_tpu.tile_ops import ozaki
 
@@ -1015,8 +893,7 @@ class TestScanAccumRoute:
         monkeypatch.setattr(ozaki, "_scan_slices", refuse)
         a = np.ones((m, k))
         a[:, 0] = 129.0 / 128.0
-        self._ab(monkeypatch, lambda x, y: matmul_f64(x, y, slices=s),
-                 jnp.asarray(a), jnp.asarray(-a.T), dot="int8")
+        _assert_reference_bits(monkeypatch, "int8", s, a, -a.T)
 
     @pytest.mark.parametrize("dot", ["int8", "bf16"])
     @pytest.mark.parametrize("s", [7, 8])
@@ -1024,82 +901,36 @@ class TestScanAccumRoute:
         rng = np.random.default_rng(22)
         a = rng.standard_normal((96, 128)) * 10.0 ** rng.integers(-4, 4,
                                                                   (96, 1))
-        self._ab(monkeypatch, lambda x: syrk_f64(x, slices=s),
-                 jnp.asarray(a), dot=dot)
+        _assert_reference_bits(monkeypatch, dot, s, a)
 
     @pytest.mark.quick
     @pytest.mark.parametrize("dot", ["int8", "bf16"])
     @pytest.mark.parametrize("which", ["bulk", "panel", "syrk"])
     def test_batched_bitwise_equal(self, which, dot, monkeypatch):
         """Under ``jnp.vectorize`` batching (a stack of tiles, as the
-        step builders hand them over) both forms of the sequenced
-        schedule (barriers between ragged groups of a bulk product, the
-        padded scan of a panel product and of the syrk) keep the bits of
-        the straight line."""
+        step builders hand them over) the forms of the sequenced schedule
+        (barriers between ragged groups of a bulk product, the padded scan
+        of a panel product and of the syrk) keep the reference's bits."""
         rng = np.random.default_rng(24)
         a = rng.standard_normal((3, 72, 56)) \
             * 10.0 ** rng.integers(-4, 4, (3, 72, 1))
         n = 64 if which == "bulk" else 24
         b = rng.standard_normal((56, n))        # broadcast over the batch
-        if which == "syrk":
-            self._ab(monkeypatch, lambda x: syrk_f64(x, slices=7),
-                     jnp.asarray(a), dot=dot)
-        else:
-            self._ab(monkeypatch, lambda x, y: matmul_f64(x, y, slices=7),
-                     jnp.asarray(a), jnp.asarray(b), dot=dot)
+        _assert_reference_bits(monkeypatch, dot, 7, a,
+                               None if which == "syrk" else b)
 
-    def test_auto_resolves_per_platform(self, monkeypatch):
-        """ozaki_accum="auto" (the default): scan on TPU (the bounded
-        live set) and the straight-line xla schedule elsewhere; explicit
-        values pass through untouched."""
-        import jax
-
-        from dlaf_tpu import config
-        from dlaf_tpu.obs.logging import forget_once as _forget_once
-        from dlaf_tpu.obs.logging import once_seen_keys as _once_keys
-        from dlaf_tpu.tile_ops.ozaki import _accum_impl
-
-        keys = [("ozaki_accum", b, c) for b, c in
-                (("cpu", "xla"), ("tpu", "scan"))]
-        pre = {k for k in keys if k in _once_keys("config")}
-        config.initialize()  # bare default: auto
-        try:
-            assert _accum_impl() == "xla"     # suite runs on CPU
-            monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-            assert _accum_impl() == "scan"
-            monkeypatch.setenv("DLAF_OZAKI_ACCUM", "xla")
-            config.initialize()
-            assert _accum_impl() == "xla"     # explicit outranks auto
-        finally:
-            monkeypatch.delenv("DLAF_OZAKI_ACCUM", raising=False)
-            for k in keys:
-                if k not in pre:
-                    _forget_once("config", k)
-            config.initialize()
-
-    def test_accuracy_under_jit(self, monkeypatch):
+    def test_accuracy_under_jit(self):
         """The scan schedule composes with jit and stays f64-grade."""
         import jax
 
-        from dlaf_tpu import config
-
-        monkeypatch.setenv("DLAF_OZAKI_GROUP", "concat")
-        monkeypatch.setenv("DLAF_OZAKI_ACCUM", "scan")
-        config.initialize()
-        try:
-            rng = np.random.default_rng(23)
-            a = rng.standard_normal((64, 96))
-            got = np.asarray(jax.jit(
-                lambda x: syrk_f64(x, slices=8))(jnp.asarray(a)))
-            np.testing.assert_allclose(got, a @ a.T, rtol=1e-14, atol=1e-12)
-        finally:
-            monkeypatch.delenv("DLAF_OZAKI_GROUP")
-            monkeypatch.delenv("DLAF_OZAKI_ACCUM")
-            config.initialize()
+        rng = np.random.default_rng(23)
+        a = rng.standard_normal((64, 96))
+        got = np.asarray(jax.jit(
+            lambda x: syrk_f64(x, slices=8))(jnp.asarray(a)))
+        np.testing.assert_allclose(got, a @ a.T, rtol=1e-14, atol=1e-12)
 
 
-@pytest.mark.parametrize("accum", ["xla", "scan"])
-def test_concat_syrk_int32_wrap_window(accum, monkeypatch):
+def test_concat_syrk_int32_wrap_window():
     """The concat syrk's elementwise pair sum (g + g.T + diag) must not
     wrap int32 in the window where s*k*2^12 >= 2^31 but the half-concat
     depth stays below _dot_i8's own f64-chunking threshold. Adversarial
@@ -1107,25 +938,15 @@ def test_concat_syrk_int32_wrap_window(accum, monkeypatch):
     64/129, whose base-128 expansion has balanced digits of EXACTLY
     +-64 at every level — so each pair dot reaches ~2^28 and a 4-pair
     half-group sum crosses 2^31 on the unguarded path."""
-    from dlaf_tpu import config
-
-    monkeypatch.setenv("DLAF_OZAKI_GROUP", "concat")
-    monkeypatch.setenv("DLAF_OZAKI_ACCUM", accum)
-    config.initialize()
-    try:
-        # 65543 unit columns: the d=7 half-group sum reaches
-        # -2*4*4096*65543 = -(2^31) - 229376, strictly past INT32_MIN
-        # (65536 columns land at exactly -2^31, which still represents)
-        k = (1 << 16) + 8
-        a = np.ones((8, k))
-        a[:, 0] = 129.0 / 128.0
-        got = np.asarray(syrk_f64(jnp.asarray(a), slices=8))
-        ref = a @ a.T
-        np.testing.assert_allclose(got, ref, rtol=1e-12)
-    finally:
-        monkeypatch.delenv("DLAF_OZAKI_GROUP")
-        monkeypatch.delenv("DLAF_OZAKI_ACCUM")
-        config.initialize()
+    # 65543 unit columns: the d=7 half-group sum reaches
+    # -2*4*4096*65543 = -(2^31) - 229376, strictly past INT32_MIN
+    # (65536 columns land at exactly -2^31, which still represents)
+    k = (1 << 16) + 8
+    a = np.ones((8, k))
+    a[:, 0] = 129.0 / 128.0
+    got = np.asarray(syrk_f64(jnp.asarray(a), slices=8))
+    ref = a @ a.T
+    np.testing.assert_allclose(got, ref, rtol=1e-12)
 
 
 #: the four cells' own products as ``(m, k, n)`` and the form the sequenced
@@ -1167,24 +988,15 @@ def test_sequenced_form_of_the_cells_products(shape, form):
     assert _sequenced_form(n, m, k, 7) == form      # symmetric in (m, n)
 
 
-#: ``(ozaki_group, ozaki_accum)`` of the three jnp syrk routes, by the
-#: ``route`` label ``dlaf_ozaki_mirror_total`` counts them under
-SYRK_ROUTES = {"scan": ("concat", "scan"), "concat": ("concat", "xla"),
-               "dots": ("dots", "xla")}
-
-
 @pytest.fixture()
-def route(request, monkeypatch):
-    """One of :data:`SYRK_ROUTES` configured for the test; its label."""
+def metrics_on(tmp_path):
+    """The metrics sink on for the test (the ``dlaf_ozaki_*`` counters
+    count only then); the default configuration again after it."""
     from dlaf_tpu import config
 
-    group, accum = SYRK_ROUTES[request.param]
-    monkeypatch.setenv("DLAF_OZAKI_GROUP", group)
-    monkeypatch.setenv("DLAF_OZAKI_ACCUM", accum)
-    config.initialize()
-    yield request.param
-    monkeypatch.delenv("DLAF_OZAKI_GROUP")
-    monkeypatch.delenv("DLAF_OZAKI_ACCUM")
+    config.initialize(config.Configuration(
+        metrics_path=str(tmp_path / "metrics.jsonl")))
+    yield
     config.initialize()
 
 
@@ -1215,11 +1027,10 @@ def _syrk_pairs(s):
 
 class TestRaggedGroups:
     """Ragged shift groups (ISSUE 28): a group's dot has its real depth,
-    sliced from one concatenation per operand. The straight-line schedule
-    ("concat" route) is ragged everywhere; the sequenced schedule ("scan"
-    route) is ragged for bulk products (both output dimensions wider than
-    the contraction) and keeps the zero-padded ``lax.scan`` for panel
-    products and the syrk, where every group has the widest depth:
+    sliced from one concatenation per operand. The product is ragged for
+    bulk products (both output dimensions wider than the contraction) and
+    keeps the zero-padded ``lax.scan`` for panel products and the syrk,
+    where every group has the widest depth:
     ``s * s * k`` deep in all for the product (49 k at s = 7 for 28 k
     real), ``s (h + 1) k`` for the syrk (28 k for 16 k real). Deep
     products (ISSUE 36: the contraction deeper than the narrower output
@@ -1239,8 +1050,7 @@ class TestRaggedGroups:
     @pytest.mark.quick
     @pytest.mark.parametrize("dot", ["int8", "bf16"])
     @pytest.mark.parametrize("s", [6, 7, 8])
-    @pytest.mark.parametrize("route", ["scan", "concat"], indirect=True)
-    def test_bulk_matmul_dots_have_their_real_depth(self, route, s, dot,
+    def test_bulk_matmul_dots_have_their_real_depth(self, s, dot,
                                                     monkeypatch):
         from dlaf_tpu import config
 
@@ -1249,18 +1059,17 @@ class TestRaggedGroups:
         try:
             depths = self._matmul_depths(self.BULK[0], self.K,
                                          self.BULK[1], s)
-        finally:    # before the route fixture re-initializes the config
+        finally:
             monkeypatch.delenv("DLAF_OZAKI_DOT")
+            config.initialize()
         # s (s + 1) / 2 * k deep in all: 28 k at s = 7
         assert sorted(depths) == [(d + 1) * self.K for d in range(s)]
 
     @pytest.mark.quick
     @pytest.mark.parametrize("m,n", PANELS)
-    @pytest.mark.parametrize("route", ["scan", "concat"], indirect=True)
-    def test_panel_matmul_scans_one_padded_body(self, route, m, n):
+    def test_panel_matmul_scans_one_padded_body(self, m, n):
         """A product one block wide keeps ONE dot of the widest depth in
-        a scan body under the sequenced schedule; the straight line is
-        ragged whatever the shape."""
+        a scan body."""
         import jax
 
         from dlaf_tpu.analysis import depgraph
@@ -1270,18 +1079,13 @@ class TestRaggedGroups:
             jnp.zeros((m, self.K)), jnp.zeros((self.K, n)))
         scans = sum(eqn.primitive.name == "scan"
                     for _, eqn in depgraph.iter_eqns(jaxpr))
-        if route == "scan":
-            assert depths == [7 * self.K] and scans == 1
-        else:
-            assert sorted(depths) == [(d + 1) * self.K for d in range(7)]
-            assert scans == 0
+        assert depths == [7 * self.K] and scans == 1
 
     @pytest.mark.quick
     @pytest.mark.parametrize("dot", ["int8", "bf16"])
     @pytest.mark.parametrize("m,n", DEEP)
-    @pytest.mark.parametrize("route", ["scan", "concat"], indirect=True)
-    def test_deep_matmul_scans_the_wide_operands_slices(self, route, m, n,
-                                                        dot, monkeypatch):
+    def test_deep_matmul_scans_the_wide_operands_slices(self, m, n, dot,
+                                                        monkeypatch):
         """A deep product's one scan body holds ONE dot ``K`` deep: a slice
         of the wide operand as it was peeled, (m, K), against the narrow
         operand's ``s`` shifted blocks, (K, s n), or with B the wide one
@@ -1303,41 +1107,31 @@ class TestRaggedGroups:
             depths = _dot_depths(fn, *args)
             text = jax.jit(fn).lower(*args).as_text()
             jaxpr = jax.make_jaxpr(fn)(*args)
-        finally:    # before the route fixture re-initializes the config
+        finally:
             monkeypatch.delenv("DLAF_OZAKI_DOT")
+            config.initialize()
         scans = sum(eqn.primitive.name == "scan"
                     for _, eqn in depgraph.iter_eqns(jaxpr))
-        if route == "scan":
-            assert depths == [self.K] and scans == 1
-            assert not re.search(rf"tensor<[0-9x]*{7 * self.K}x", text)
-            out = (m, 7 * n) if m >= n else (7 * m, n)
-            assert f"-> tensor<{out[0]}x{out[1]}x" in text
-        else:
-            assert sorted(depths) == [(d + 1) * self.K for d in range(7)]
-            assert scans == 0
+        assert depths == [self.K] and scans == 1
+        assert not re.search(rf"tensor<[0-9x]*{7 * self.K}x", text)
+        out = (m, 7 * n) if m >= n else (7 * m, n)
+        assert f"-> tensor<{out[0]}x{out[1]}x" in text
 
     @pytest.mark.quick
     @pytest.mark.parametrize("s", [6, 7, 8])
-    @pytest.mark.parametrize("route", ["scan", "concat"], indirect=True)
-    def test_syrk_dot_depths(self, route, s):
+    def test_syrk_dot_depths(self, s):
         depths = _dot_depths(lambda x: syrk_f64(x, slices=s),
                              jnp.zeros((self.BULK[0], self.K)))
         halves, diagonals = _syrk_pairs(s)
         if s == 7:
             assert (halves, diagonals) == (12, 4)   # 16 k; padded: 28 k
-        if route == "scan":     # one body: widest half pair + diagonal
-            assert sorted(depths) == [self.K, (s // 2) * self.K]
-        else:
-            want = [(d + 1) // 2 * self.K for d in range(1, s)] \
-                + [self.K] * diagonals
-            assert sorted(depths) == sorted(want)
+        # one body: widest half pair + diagonal
+        assert sorted(depths) == [self.K, (s // 2) * self.K]
 
     @pytest.mark.quick
-    @pytest.mark.parametrize("route", ["scan", "concat"], indirect=True)
-    def test_sequenced_schedule_orders_groups_by_a_barrier(self, route):
+    def test_sequenced_schedule_orders_groups_by_a_barrier(self):
         """One ``optimization_barrier`` between consecutive groups of a
-        bulk product under the sequenced schedule, none on the straight
-        line (what it buys is the TPU compiler's to show:
+        bulk product (what it buys is the TPU compiler's to show:
         tests/test_chip_compile.py)."""
         import jax
 
@@ -1348,32 +1142,23 @@ class TestRaggedGroups:
             jnp.zeros((self.K, self.BULK[1])))
         barriers = sum(eqn.primitive.name == "optimization_barrier"
                        for _, eqn in depgraph.iter_eqns(jaxpr))
-        assert barriers == (6 if route == "scan" else 0)
+        assert barriers == 6
 
     @pytest.mark.quick
     @pytest.mark.parametrize("s", [7, 8])
     @pytest.mark.parametrize("which", ["bulk", "panel", "syrk", "deep"])
-    @pytest.mark.parametrize("route", list(SYRK_ROUTES), indirect=True)
-    def test_mac_counter_real_and_zero_by_hand(self, route, which, s,
-                                               tmp_path):
+    def test_mac_counter_real_and_zero_by_hand(self, which, s, metrics_on):
         """``dlaf_ozaki_macs_total{route, kind}``: per traced 2D product
         ``real`` is ``m n k`` times the slice pairs it multiplies
         (product: s (s + 1) / 2; syrk: the half pairs and the diagonal
         pairs); ``zero`` is the padding of the two padded scans (product:
         s (s - 1) / 2 slots; syrk: ``s (s // 2 + 1)`` emitted less the
-        real pairs) and 0 everywhere else. A deep product under the
-        sequenced schedule counts the same slots under a route label of
-        its own, ``scan_slices``."""
-        import os
+        real pairs) and 0 everywhere else; all under the route label
+        ``scan``, but a deep product counts the same slots under a label
+        of its own, ``scan_slices``."""
+        from dlaf_tpu import obs
 
-        from dlaf_tpu import config, obs
-
-        config.initialize(config.Configuration(
-            metrics_path=str(tmp_path / "macs.jsonl"),
-            ozaki_group=os.environ["DLAF_OZAKI_GROUP"],
-            ozaki_accum=os.environ["DLAF_OZAKI_ACCUM"]))
-        label = "scan_slices" if (route, which) == ("scan", "deep") \
-            else route
+        label = "scan_slices" if which == "deep" else "scan"
         real, zero = (obs.registry().counter("dlaf_ozaki_macs_total",
                                              route=label, kind=kind)
                       for kind in ("real", "zero"))
@@ -1392,14 +1177,14 @@ class TestRaggedGroups:
             matmul_f64(jnp.stack([a, a]), b, slices=s)  # batched: one trace
             pairs = s * (s + 1) // 2
             out, padded = m * n, s * (s - 1) // 2
-        if route != "scan" or which == "bulk":
+        if which == "bulk":
             padded = 0
         assert real.snapshot()["value"] - base[0] == out * k * pairs
         assert zero.snapshot()["value"] - base[1] == out * k * padded
 
 
 class TestSyrkMirrorOnce:
-    """The syrk mirrors ONCE per call: every route folds the un-mirrored
+    """The syrk mirrors ONCE per call: it folds the un-mirrored
     half ``2 g_d + D_d`` of each shift group at half the group scale and
     forms ``C + C^T`` from the f64 accumulator after the group loop — no
     (m, m) transpose per shift group (ISSUE 26: 7 int32 transposes a step
@@ -1408,8 +1193,7 @@ class TestSyrkMirrorOnce:
 
     @pytest.mark.quick
     @pytest.mark.parametrize("s", [7, 8])
-    @pytest.mark.parametrize("route", list(SYRK_ROUTES), indirect=True)
-    def test_one_square_transpose_none_in_scan(self, route, s):
+    def test_one_square_transpose_none_in_scan(self, s):
         """jaxpr pin: exactly one transpose of an (m, m) array, and none
         inside a ``scan`` body (m differs from k and from every padded
         concat depth, so the dots' operand transposes are not square)."""
@@ -1427,12 +1211,11 @@ class TestSyrkMirrorOnce:
         assert not any(frame[0] == "scan" for frame in square[0])
         scans = [eqn for _, eqn in depgraph.iter_eqns(jaxpr)
                  if eqn.primitive.name == "scan"]
-        assert len(scans) == (1 if route == "scan" else 0)
+        assert len(scans) == 1
 
     @pytest.mark.quick
     @pytest.mark.parametrize("s", [7, 8])
-    @pytest.mark.parametrize("route", list(SYRK_ROUTES), indirect=True)
-    def test_accuracy_and_exact_symmetry(self, route, s, monkeypatch):
+    def test_accuracy_and_exact_symmetry(self, s, monkeypatch):
         """Rows scaled over ten orders of magnitude: the error against
         numpy stays within the syrk budget relative to ``|a||a|^T``, and
         the accumulator handed to ``_apply_scales`` is EXACTLY symmetric
@@ -1453,21 +1236,14 @@ class TestSyrkMirrorOnce:
         assert seen[0].tobytes() == seen[0].T.copy().tobytes()
 
     @pytest.mark.quick
-    @pytest.mark.parametrize("route", list(SYRK_ROUTES), indirect=True)
-    def test_mirror_counter_one_per_traced_call(self, route, tmp_path):
-        """``dlaf_ozaki_mirror_total{route}``: one count per traced
+    def test_mirror_counter_one_per_traced_call(self, metrics_on):
+        """``dlaf_ozaki_mirror_total{route="scan"}``: one count per traced
         ``syrk_f64`` call, whatever the slice count (the per-group form
         would have read ``s``)."""
-        import os
+        from dlaf_tpu import obs
 
-        from dlaf_tpu import config, obs
-
-        config.initialize(config.Configuration(
-            metrics_path=str(tmp_path / "mirror.jsonl"),
-            ozaki_group=os.environ["DLAF_OZAKI_GROUP"],
-            ozaki_accum=os.environ["DLAF_OZAKI_ACCUM"]))
         counter = obs.registry().counter("dlaf_ozaki_mirror_total",
-                                         route=route)
+                                         route="scan")
         base = counter.snapshot()["value"]
         a = jnp.asarray(np.random.default_rng(27).standard_normal((24, 16)))
         syrk_f64(a, slices=7)

@@ -104,85 +104,3 @@ def test_distributed_cholesky_pallas_branch(monkeypatch, devices8, uplo):
         np.testing.assert_array_equal(np.tril(out, -1), np.tril(a, -1))
     assert resid < 60 * n * eps
 
-
-def test_fold_dot_routes_bitwise_equal():
-    """The bf16 in-kernel dot route must produce BIT-identical (hi, lo)
-    pairs to the int8 route (7-bit slices are exact in bf16; f32
-    accumulation exact to k <= K_MAX <= 2^12)."""
-    import numpy as np
-
-    import jax.numpy as jnp
-    from dlaf_tpu.tile_ops.pallas_ozaki import (fused_slice_product,
-                                                fused_slice_syrk,
-                                                masked_slice_product)
-
-    rng = np.random.default_rng(9)
-    s, m, k = 4, 512, 256
-    ia = jnp.asarray(rng.integers(-64, 65, (s, m, k)), jnp.int8)
-    ib = jnp.asarray(rng.integers(-64, 65, (s, k, m)), jnp.int8)
-    h1, l1 = fused_slice_product(ia, ib, interpret=True)
-    h2, l2 = fused_slice_product(ia, ib, interpret=True, dot="bf16")
-    assert np.asarray(h1).tobytes() == np.asarray(h2).tobytes()
-    assert np.asarray(l1).tobytes() == np.asarray(l2).tobytes()
-
-    h1, l1 = fused_slice_syrk(ia, interpret=True)
-    h2, l2 = fused_slice_syrk(ia, interpret=True, dot="bf16")
-    assert np.asarray(h1).tobytes() == np.asarray(h2).tobytes()
-    assert np.asarray(l1).tobytes() == np.asarray(l2).tobytes()
-
-    iat = jnp.asarray(rng.integers(-64, 65, (s, 2, k, k)), jnp.int8)
-    mode = jnp.asarray(np.tril(np.ones((2, 2), np.int32)))
-    h1, l1 = masked_slice_product(iat, iat, mode, interpret=True)
-    h2, l2 = masked_slice_product(iat, iat, mode, interpret=True, dot="bf16")
-    assert np.asarray(h1).tobytes() == np.asarray(h2).tobytes()
-    assert np.asarray(l1).tobytes() == np.asarray(l2).tobytes()
-
-
-@pytest.mark.parametrize("uplo", ["L", "U"])
-def test_scan_cholesky_oz_pallas_branch(monkeypatch, devices8, uplo):
-    """trailing="scan" + f64_gemm=mxu + ozaki_impl=pallas: the predicated
-    kernel's mode mask is data, so it predicates the MXU work inside the
-    scanned step too — must match the plain scan result. A spy asserts
-    the predicated kernel actually ran (the plain mxu fallback would
-    produce the same numerics and hide a dead gate)."""
-    from dlaf_tpu import config
-    from dlaf_tpu.algorithms.cholesky import cholesky
-    from dlaf_tpu.comm.grid import Grid
-    from dlaf_tpu.common.index2d import TileElementSize
-    from dlaf_tpu.matrix.matrix import Matrix
-
-    rng = np.random.default_rng(3)
-    n, nb = 24, 4
-    x = rng.standard_normal((n, n))
-    a = x @ x.T + 2 * n * np.eye(n)
-    for k, v in (("DLAF_CHOLESKY_TRAILING", "scan"), ("DLAF_F64_GEMM", "mxu"),
-                 ("DLAF_F64_GEMM_MIN_DIM", "1"), ("DLAF_OZAKI_IMPL", "pallas")):
-        monkeypatch.setenv(k, v)
-    config.initialize()
-    import importlib
-
-    chol_mod = importlib.import_module("dlaf_tpu.algorithms.cholesky")
-    calls = []
-    real = chol_mod._masked_oz_update
-
-    def spy(*args, **kw):
-        calls.append(1)
-        return real(*args, **kw)
-
-    monkeypatch.setattr(chol_mod, "_masked_oz_update", spy)
-    try:
-        m = Matrix.from_global(a, TileElementSize(nb, nb), grid=Grid(2, 4))
-        out = cholesky(uplo, m).to_numpy()
-        assert calls, "predicated oz kernel was gated out of the scan path"
-        if uplo == "L":
-            f = np.tril(out)
-            resid = np.linalg.norm(f @ f.T - a) / np.linalg.norm(a)
-        else:
-            f = np.triu(out)
-            resid = np.linalg.norm(f.T @ f - a) / np.linalg.norm(a)
-        assert resid < 1e-13
-    finally:
-        for k in ("DLAF_CHOLESKY_TRAILING", "DLAF_F64_GEMM",
-                  "DLAF_F64_GEMM_MIN_DIM", "DLAF_OZAKI_IMPL"):
-            monkeypatch.delenv(k)
-        config.initialize()

@@ -293,27 +293,20 @@ def test_eigensolver_deep_mxu_mixed(gid, devices8, monkeypatch):
 @pytest.mark.parametrize("gid", [_next_grid()])
 def test_cholesky_deep_mxu_accum_scan(gid, devices8, monkeypatch):
     """Distributed Cholesky under the full TPU product route (mxu gemms,
-    mixed panels, concat group sums) with ozaki_accum="scan" — the
-    O(1)-live-partials schedule armed as the N=16384 OOM fix must
-    reproduce the same factorization the "xla" schedule gives through
-    the REAL distributed path (shard_map + contract + trsm_panel), not
-    just the 2D tile ops the bitwise unit tests cover."""
+    mixed panels) — the slice product's O(1)-live-partials schedule, the
+    N=16384 OOM fix, through the REAL distributed path (shard_map +
+    contract + trsm_panel), not just the 2D tile ops the bitwise unit
+    tests hold to the plain reference."""
     grid = _grid(gid, devices8)
     monkeypatch.setenv("DLAF_F64_GEMM", "mxu")
     monkeypatch.setenv("DLAF_F64_TRSM", "mixed")
-    monkeypatch.setenv("DLAF_OZAKI_GROUP", "concat")
+    config.initialize()
     a = hpd(N, seed=4)
-    outs = {}
-    for accum in ("xla", "scan"):
-        monkeypatch.setenv("DLAF_OZAKI_ACCUM", accum)
-        config.initialize()
-        outs[accum] = np.tril(cholesky(
-            "L", Matrix.from_global(a, TileElementSize(NB, NB),
-                                    grid=grid)).to_numpy())
-    # bit-identical schedules end to end
-    assert outs["scan"].tobytes() == outs["xla"].tobytes()
-    np.testing.assert_allclose(outs["scan"],
-                               sla.cholesky(a, lower=True), atol=1e-8 * N)
+    out = np.tril(cholesky(
+        "L", Matrix.from_global(a, TileElementSize(NB, NB),
+                                grid=grid)).to_numpy())
+    np.testing.assert_allclose(out, sla.cholesky(a, lower=True),
+                               atol=1e-8 * N)
 
 
 @pytest.mark.parametrize("gid", [_next_grid()])

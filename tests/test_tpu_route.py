@@ -5,8 +5,8 @@ The driver's tier-1 is ``JAX_PLATFORMS=cpu``, where every platform-keyed
 look-ahead, eight slices. The benchmark's cells run the other arm. Here
 the public entry points RUN (not compile) under the knob resolution of a
 TPU (``as_on_tpu``, tests/conftest.py): ozaki trailing, ``mixed`` panel
-solves, look-ahead and comm look-ahead on, ``concat`` groups under the
-sequenced schedule, bf16 slice dots, seven slices. Each result is held to
+solves, look-ahead and comm look-ahead on, bf16 slice dots, seven
+slices. Each result is held to
 a numpy float64 reference at the benchmark's own tolerance for a TPU
 (``c n 2^-47``: c = 60 factor/solve, 200 eigen — BENCHMARK.json
 ``guarantee``), and ``test_knob_resolves`` writes down, knob by knob,
@@ -326,8 +326,6 @@ KNOBS = {
     "panel_impl": (_direct(C.resolved_panel_impl), "fused", "xla"),
     "step_impl": (_direct(C.resolved_step_impl), "fused", "xla"),
     "ozaki_dot": (_direct(oz._slice_dot_impl), "bf16", "int8"),
-    "ozaki_group": (_direct(oz._group_impl), "concat", "dots"),
-    "ozaki_accum": (_direct(oz._accum_impl), "scan", "xla"),
     "qr_panel": (_direct(qr_panel._qr_panel_impl), "householder", "geqrf"),
     "hegst_impl": (_through_entry("hegst_impl", _tiny_gen_to_std),
                    "twosolve", "blocked"),
