@@ -309,7 +309,7 @@ def _local_phase(name: str, program: bool = True):
     """Host phase of the local branch (``stage.bt_band_to_tridiag.<name>``,
     unfenced: the wall of an async dispatch or of the hand-off's upload);
     one around a dispatched ``program`` counts it as the entry's
-    (``dlaf_entry_programs_total``), as ``reduction_to_band._local_phase``
+    (``dlaf_entry_programs_total``), as ``reduction_to_band._program_phase``
     does."""
     if program and obs.metrics_active():
         obs.counter("dlaf_entry_programs_total",

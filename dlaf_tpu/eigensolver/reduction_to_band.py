@@ -76,9 +76,9 @@ class BandReduction:
 # ---------------------------------------------------------------------------
 
 def _count_form(form: str, steps: int, columns: int) -> None:
-    """Trace-time accounting of the local builders, once per traced step
-    body: ``dlaf_red2band_bodies_total{form}`` (an unrolled builder traces
-    one body a panel, the scan builder one a telescoped segment),
+    """Trace-time accounting of the builders, once per traced step body:
+    ``dlaf_red2band_bodies_total{form}`` (an unrolled builder traces one
+    body a panel, a scan builder one a telescoped segment),
     ``dlaf_red2band_steps_total{form}`` (panel steps the body serves) and
     ``dlaf_red2band_panel_columns_total{form}`` (Householder columns its
     panel factorizations sweep, one sequential column step each). A
@@ -90,8 +90,8 @@ def _count_form(form: str, steps: int, columns: int) -> None:
                     form=form).inc(columns)
 
 
-def _local_phase(name: str):
-    """Host phase around ONE program the local branch dispatches
+def _program_phase(name: str):
+    """Host phase around ONE program the entry dispatches
     (``stage.reduction_to_band.<name>``, unfenced: the wall of an async
     dispatch), counted as the entry's (``dlaf_entry_programs_total``): it
     labels the device's idle gap before the program on a profiler
@@ -611,9 +611,9 @@ def _build_dist_red2band(dist, mesh, dtype, band, comm_la=False):
 def _build_dist_red2band_scan(dist, mesh, dtype, band):
     """``lax.scan`` form of the distributed reduction (config
     ``dist_step_mode="scan"``): one compiled panel step looped
-    ``ceil(n/b) - 1`` times — by far the framework's worst unrolled
-    compile case (config #4 is 127 panels at ~19 s/step on the hardware
-    AOT toolchain, docs/DESIGN.md).
+    ``ceil(n/b) - 1`` times, in one telescoped body a segment (config #4,
+    N=16384 at band 128 on 2x2, is 127 panels in eight bodies; its first
+    call compiled for 420 s cold on a v5e 2x2 host, PERF.md).
 
     Uniform-shape scheme: the panel's tile column and in-tile offset are
     traced; the window-height masked column is gathered in static global
@@ -644,65 +644,83 @@ def _build_dist_red2band_scan(dist, mesh, dtype, band):
             ctx = DistContext(dist)
             arange_nb = jnp.arange(nb)
 
+            # trace-time phase names (obs/scopes.py): the local body's, and
+            # ``gather`` (the panel column to every rank and the factored
+            # panel back to its owner column) and ``exchange`` (W's and M's
+            # psums, X's ordered gather, with the selects around them); the
+            # innermost phase wins
             # -- window-height masked panel column, top-aligned ----------
-            pan, bdy, tc, co, row_val_e, g_rows, raw = gather_sub_panel_dyn(
-                ctx, lt, p=p, b=b, n=n, row_off=lu_off, col_off=lc_off)
-            kc = ctx.kc(tc) - lc_off
-            vfull, taus = panel_qr(pan)
-            ntau = taus.shape[0]
-            if ntau < b:
-                taus = jnp.pad(taus, (0, b - ntau))
-            col_live = jnp.arange(b) < (n - bdy)
-            taus = jnp.where(col_live, taus, jnp.zeros_like(taus))
-            taus_out = taus_out.at[p].set(taus)
-            m_w = (nt - base) * nb
-            v = jnp.tril(vfull, -1) + jnp.eye(m_w, b, dtype=pan.dtype)
+            with obs.named_span("red2band.gather"):
+                pan, bdy, tc, co, row_val_e, g_rows, raw = \
+                    gather_sub_panel_dyn(ctx, lt, p=p, b=b, n=n,
+                                         row_off=lu_off, col_off=lc_off)
+                kc = ctx.kc(tc) - lc_off
+            with obs.named_span("red2band.panel"):
+                vfull, taus = panel_qr(pan)
+                ntau = taus.shape[0]
+                if ntau < b:
+                    taus = jnp.pad(taus, (0, b - ntau))
+                col_live = jnp.arange(b) < (n - bdy)
+                taus = jnp.where(col_live, taus, jnp.zeros_like(taus))
+                taus_out = taus_out.at[p].set(taus)
+                m_w = (nt - base) * nb
+                v = jnp.tril(vfull, -1) + jnp.eye(m_w, b, dtype=pan.dtype)
 
             def tiles_of(mat):
                 return tiles_of_rolled(ctx, mat, bdy, base * nb)
 
             # -- write the factored panel back (owner column, my rows) ---
-            vtiles = tiles_of(vfull)
-            my_new = vtiles[g_rows - base]
-            keep = (ctx.rank_c == ctx.owner_c(tc)) & row_val_e
-            new = jnp.where(keep[:, :, None], my_new, raw)
-            lt = jax.lax.dynamic_update_slice(lt, new[:, None],
-                                              (0, kc, 0, co))
+            with obs.named_span("red2band.gather"):
+                vtiles = tiles_of(vfull)
+                my_new = vtiles[g_rows - base]
+                keep = (ctx.rank_c == ctx.owner_c(tc)) & row_val_e
+                new = jnp.where(keep[:, :, None], my_new, raw)
+                lt = jax.lax.dynamic_update_slice(lt, new[:, None],
+                                                  (0, kc, 0, co))
 
             # -- trailing two-sided update over the window's slots -------
-            g_cols = ctx.g_cols(lc_off, ltc_w)
-            g_ecols = g_cols[:, None] * nb + arange_nb[None, :]
-            col_val_e = (g_ecols >= bdy) & (g_ecols < n)
-            # col tiles below the window's first row tile are fully above
-            # the boundary (masked); clip keeps their indices in range
-            selc = jnp.clip(g_cols - base, 0, nt - base - 1)
-            t = larft(v, taus)
-            v_tiles = tiles_of(v)
-            vt_tiles = tiles_of(v @ t)
-            vtl = jnp.where(col_val_e[:, :, None], vt_tiles[selc],
-                            jnp.zeros((ltc_w, nb, b), dtype=pan.dtype))
-            atr = jnp.where((row_val_e[:, None, :, None]
-                             & col_val_e[None, :, None, :]), lt,
-                            jnp.zeros_like(lt))
-            w_loc = tb.contract("rcab,cbd->rad", atr, vtl)
-            w_loc = cc.all_reduce(w_loc, COL_AXIS)
-            vr = jnp.where(row_val_e[:, :, None], v_tiles[g_rows - base],
-                           jnp.zeros((ltr_w, nb, b), dtype=pan.dtype))
-            m_mat = tb.contract("rab,rad->bd", jnp.conj(vr), w_loc)
-            m_mat = cc.all_reduce(m_mat, ROW_AXIS)
-            x_loc = w_loc - 0.5 * jnp.einsum("rab,bd->rad", vr,
-                                             t.conj().T @ m_mat,
-                                             preferred_element_type=lt.dtype)
-            xfull = gather_col_panel_ordered(ctx, x_loc, base, lu_off)
-            xc = jnp.where(col_val_e[:, :, None], xfull[selc],
-                           jnp.zeros((ltc_w, nb, b), dtype=pan.dtype))
-            vc = jnp.where(col_val_e[:, :, None], v_tiles[selc],
-                           jnp.zeros((ltc_w, nb, b), dtype=pan.dtype))
-            xr = jnp.where(row_val_e[:, :, None], x_loc,
-                           jnp.zeros_like(x_loc))
-            upd = (tb.contract("rad,cbd->rcab", xr, jnp.conj(vc))
-                   + tb.contract("rad,cbd->rcab", vr, jnp.conj(xc)))
-            return (lt - upd, taus_out), None
+            with obs.named_span("red2band.w"):
+                g_cols = ctx.g_cols(lc_off, ltc_w)
+                g_ecols = g_cols[:, None] * nb + arange_nb[None, :]
+                col_val_e = (g_ecols >= bdy) & (g_ecols < n)
+                # col tiles below the window's first row tile are fully
+                # above the boundary (masked); clip keeps their indices in
+                # range
+                selc = jnp.clip(g_cols - base, 0, nt - base - 1)
+            with obs.named_span("red2band.larft"):
+                t = larft(v, taus)
+            with obs.named_span("red2band.w"):
+                v_tiles = tiles_of(v)
+                vt_tiles = tiles_of(v @ t)
+                vtl = jnp.where(col_val_e[:, :, None], vt_tiles[selc],
+                                jnp.zeros((ltc_w, nb, b), dtype=pan.dtype))
+                atr = jnp.where((row_val_e[:, None, :, None]
+                                 & col_val_e[None, :, None, :]), lt,
+                                jnp.zeros_like(lt))
+                w_loc = tb.contract("rcab,cbd->rad", atr, vtl)
+                with obs.named_span("red2band.exchange"):
+                    w_loc = cc.all_reduce(w_loc, COL_AXIS)
+            with obs.named_span("red2band.x"):
+                vr = jnp.where(row_val_e[:, :, None], v_tiles[g_rows - base],
+                               jnp.zeros((ltr_w, nb, b), dtype=pan.dtype))
+                m_mat = tb.contract("rab,rad->bd", jnp.conj(vr), w_loc)
+                with obs.named_span("red2band.exchange"):
+                    m_mat = cc.all_reduce(m_mat, ROW_AXIS)
+                x_loc = w_loc - 0.5 * jnp.einsum(
+                    "rab,bd->rad", vr, t.conj().T @ m_mat,
+                    preferred_element_type=lt.dtype)
+            with obs.named_span("red2band.exchange"):
+                xfull = gather_col_panel_ordered(ctx, x_loc, base, lu_off)
+                xc = jnp.where(col_val_e[:, :, None], xfull[selc],
+                               jnp.zeros((ltc_w, nb, b), dtype=pan.dtype))
+            with obs.named_span("red2band.update"):
+                vc = jnp.where(col_val_e[:, :, None], v_tiles[selc],
+                               jnp.zeros((ltc_w, nb, b), dtype=pan.dtype))
+                xr = jnp.where(row_val_e[:, :, None], x_loc,
+                               jnp.zeros_like(x_loc))
+                upd = (tb.contract("rad,cbd->rcab", xr, jnp.conj(vc))
+                       + tb.contract("rad,cbd->rcab", vr, jnp.conj(xc)))
+                return (lt - upd, taus_out), None
 
         return step
 
@@ -721,6 +739,8 @@ def _build_dist_red2band_scan(dist, mesh, dtype, band):
 
         taus = taus0
         for (lu_off, lc_off), p0, seg_len in telescope_windows(npan, window):
+            # a window-height panel sweeps b columns a step on every rank
+            _count_form("dist_scan", seg_len, seg_len * b)
             sub = lt[lu_off:, lc_off:]
             # index-free scope: one traced body per telescope segment —
             # critpath reconstructs per-step timing by occurrence order
@@ -803,12 +823,12 @@ def reduction_to_band(a: Matrix, band_size: int | None = None, *,
             site, local = "reduction_to_band.local", _red2band_local
 
         with entry_span, quiet_donation():
-            with _local_phase("to_global"):
+            with _program_phase("to_global"):
                 g = to_global(a.storage, a.dist, donate)
             # program telemetry (DLAF_PROGRAM_TELEMETRY): off = passthrough
-            with _local_phase("reduce"):
+            with _program_phase("reduce"):
                 out, taus = obs.telemetry.call(site, local, g, nb=band)
-            with _local_phase("to_tiles"):
+            with _program_phase("to_tiles"):
                 storage = global_to_tiles_donated(out, a.dist)
             return BandReduction(a.with_storage(storage), taus, band)
     from ..config import resolved_comm_lookahead
@@ -825,8 +845,10 @@ def reduction_to_band(a: Matrix, band_size: int | None = None, *,
                                comm_la=not scan_mode
                                and resolved_comm_lookahead())
     with entry_span, quiet_donation():
-        storage, taus = obs.telemetry.call("reduction_to_band.dist", fn,
-                                           a.storage)
+        # ONE program a call on every device of the grid
+        with _program_phase("dispatch"):
+            storage, taus = obs.telemetry.call("reduction_to_band.dist", fn,
+                                               a.storage)
     return BandReduction(a.with_storage(storage), taus, band)
 
 

@@ -355,3 +355,33 @@ def test_red2band_scan_program_compiles_with_shared_kernels(one_chip,
     with pytest.raises(Exception, match="No such compile option"):
         r2b._red2band_local_scan_tpu.lower(
             jax.ShapeDtypeStruct((256, 256), jnp.float64), nb=64).compile()
+
+
+def test_dist_red2band_scan_program_compiles(mesh22, as_on_tpu):
+    """The distributed reduction's program as the entry hands a TPU
+    (``_dist_red2band_cached(..., scan=True, donate=True)``: the cell
+    ``red2band_d_n16384_2x2``'s, here 3 panels of 128 on one 256 tile a
+    device): one telescoped scan body with the panel gathers and the psums
+    (every f64 psum an all-gather of f32 planes) beside the seven-slice
+    products. At the cell's size the compiler emits its repeated kernels
+    once on its own ("with HLO functions", 278 MiB of code; PERF.md), so
+    the entry asks for no compile option there."""
+    from dlaf_tpu.common.index2d import (GlobalElementSize, GridSize2D,
+                                         TileElementSize)
+    from dlaf_tpu.matrix.distribution import Distribution
+    from dlaf_tpu.matrix.tiling import storage_tile_grid
+
+    r2b = importlib.import_module("dlaf_tpu.eigensolver.reduction_to_band")
+    n, nb, band = 512, 256, 128
+    dist = Distribution(GlobalElementSize(n, n), TileElementSize(nb, nb),
+                        grid_size=GridSize2D(2, 2))
+    sr, sc, _, _ = storage_tile_grid(dist)
+    C._clear_program_caches()
+    fn = r2b._dist_red2band_cached(dist, mesh22, "float64", band, scan=True,
+                                   donate=True)
+    text = _compile(fn, jax.ShapeDtypeStruct(
+        (sr, sc, nb, nb), jnp.float64,
+        sharding=NamedSharding(mesh22, P("row", "col"))))
+    assert " all-gather(" in text and " while(" in text
+    assert " convolution(" in text                 # the slice products
+    C._clear_program_caches()

@@ -241,8 +241,25 @@ BUILDERS = {
 }
 
 
+@pytest.fixture
+def no_persistent_cache():
+    """jax keeps metadata out of the persistent cache's key: the plain
+    program, once a compile of 5 s or more under a loaded host wrote it to
+    the checkout's cache, would serve the scoped dispatch below (and every
+    later run's), and its table would read ``stale``."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
 @pytest.mark.parametrize("which", sorted(BUILDERS))
 def test_builder_carries_its_phases_as_metadata_only(which, as_on_tpu,
+                                                     no_persistent_cache,
                                                      tmp_path, devices8):
     site, make, phases = BUILDERS[which]
     dispatch = make(devices8)
