@@ -125,7 +125,9 @@ def _bt_b2t_blocked(v_all, tau_all, e, *, b: int, n: int, group: int):
     whole levels ascending preserves the required "sweep s+1 fully before
     sweep s" order. Cross-level pairs separated by >= 2 steps are disjoint
     whenever G <= b+1 (enforced). Each level is then T = larft(V) and two
-    tall gemms instead of G separate rank-1 updates.
+    tall gemms instead of G separate rank-1 updates: seg - (V T)(V^H seg),
+    T folded into V so that its product is (L, G) x (G, G) whatever the
+    window's width.
     """
     dlaf_assert(group <= b + 1, "bt_b2t blocked: group must be <= band+1")
     n_sweeps, n_steps, _ = v_all.shape
@@ -151,19 +153,20 @@ def _bt_b2t_blocked(v_all, tau_all, e, *, b: int, n: int, group: int):
     base_seq = blk_idx * G + 1 + t_idx * b
 
     # phase scopes (`bt_b2t.<phase>`, read by telemetry.phase_table): the
-    # staircase's skew (_staircase), the T factor, W = T (V^H seg) with the
-    # segment's read, and seg - V W with the write-back
+    # staircase's skew (_staircase), the T factor and V T, W = V^H seg with
+    # the segment's read, and seg - (V T) W with the write-back
     def body(e_pad, xs):
         vcols, taus, base = xs
         with obs.named_span("bt_b2t.stair"):
             stair = _staircase(vcols, L)
         with obs.named_span("bt_b2t.tfactor"):
             t_mat = larft(stair, jnp.conj(taus))
+            vt = stair @ t_mat
         with obs.named_span("bt_b2t.project"):
             seg = lax.dynamic_slice(e_pad, (base, 0), (L, m))
-            w = t_mat @ tb.mm(jnp.conj(stair).T, seg)
+            w = tb.mm(jnp.conj(stair).T, seg)
         with obs.named_span("bt_b2t.apply"):
-            seg = seg - tb.mm(stair, w)
+            seg = seg - tb.mm(vt, w)
             e_pad = lax.dynamic_update_slice(e_pad, seg, (base, 0))
         return e_pad, None
 

@@ -6,7 +6,9 @@ float64, one rank-1 update a reflector in the published order; no jax, no
 code of ``dlaf_tpu``) on 64 sampled columns. Here the same comparison runs
 on the CPU on ALL columns of real chase output, for both forms of the
 application (``bt_b2t_impl``: the blocked compact-WY levels and the
-sweep-at-a-time scan) and both kinds of input (an array, a local ``Matrix``),
+sweep-at-a-time scan), both kinds of input (an array, a local ``Matrix``)
+and, for the blocked form, a window no wider than its staircase is tall (T
+is folded into V, ``seg - (V T)(V^H seg)``, at every width),
 at the cell's own limit ``100 n eps`` with the native epsilon; and the
 reference is tied to the model once: with its ``Q``, ``Q^T B Q`` is the
 tridiagonal ``(d, e)`` the chase returned.
@@ -77,13 +79,24 @@ def test_reference_imports_nothing_of_the_library():
                        np.ones((3, 2)), 4).dtype == np.float64
 
 
+SIZES = [(96, 8), (130, 16), (257, 32)]
+#: ``(n, b, m, impl)``: all ``n`` columns in both forms; and, in the blocked
+#: form, ``m = L`` columns, as many as its staircase has rows (``L = b + G -
+#: 1``, ``G = b`` on the CPU for ``b <= 64``), where the folded ``V T`` is
+#: wider than the window
+COLUMNS = [pytest.param(n, b, n, impl, id=f"{n}-{b}-{impl}")
+           for n, b in SIZES for impl in ("blocked", "sweeps")] + [
+    pytest.param(n, b, 2 * b - 1, "blocked", id=f"{n}-{b}-narrow-blocked")
+    for n, b in SIZES]
+
+
 @pytest.mark.parametrize("kind", ["array", "Matrix"])
-@pytest.mark.parametrize("impl", ["blocked", "sweeps"])
-@pytest.mark.parametrize("n, b", [(96, 8), (130, 16), (257, 32)])
-def test_all_columns_against_the_plain_reference(n, b, impl, kind, chased):
+@pytest.mark.parametrize("n, b, m, impl", COLUMNS)
+def test_all_columns_against_the_plain_reference(n, b, m, impl, kind, chased):
     _band_storage, tri = chased(n, b)
     C.initialize(C.Configuration(bt_b2t_impl=impl))
-    e = np.random.default_rng(7 * n + b).standard_normal((n, n))
+    e_all = np.random.default_rng(7 * n + b).standard_normal((n, n))
+    e = e_all[:, :m]
     want = ref.apply_q(tri.v, tri.tau, e, b)
     if kind == "Matrix":
         out = bt_band_to_tridiag(
@@ -98,6 +111,10 @@ def test_all_columns_against_the_plain_reference(n, b, impl, kind, chased):
     # column by column too: a wrong column hides in a Frobenius norm of n
     assert (np.linalg.norm(got - want, axis=0)
             <= tol * np.linalg.norm(want, axis=0)).all()
+    if m < n:
+        # the same columns inside all n agree with these
+        wide = np.asarray(bt_band_to_tridiag(tri, e_all))[:, :m]
+        assert np.linalg.norm(got - wide) <= tol * np.linalg.norm(want)
 
 
 @pytest.mark.parametrize("n, b", [(96, 8), (130, 16)])

@@ -15,10 +15,13 @@ host phases' spans and the slice products' multiply-accumulates per EXECUTED
 level.
 """
 
+import collections
 import importlib
 import importlib.util
 import os
+import re
 
+import jax
 import numpy as np
 import pytest
 
@@ -60,6 +63,10 @@ hand_slots = _load("layer_metrics", "bt_null_reflector_share.py").hand_count
 
 @pytest.fixture(autouse=True)
 def obs_reset():
+    # an empty registry on the way in too: the cases compare counters with
+    # hand counts, and a test that ran before in this process may have left
+    # its counts behind
+    obs._reset_for_tests()
     yield
     obs._reset_for_tests()
     C.finalize()
@@ -172,11 +179,18 @@ def test_matrix_branch_on_the_chips_route(n, b, route, tmp_path):
     assert all(table["counts"][p] > 0 for p in PHASES)
 
 
-@pytest.mark.parametrize("n, b", SIZES)
-def test_array_branch_is_one_program_a_call(n, b, route, tmp_path):
+#: ``(n, b, m)``: 40 columns, and windows no wider than the staircase is
+#: tall (``m = L = 2 b - 1`` columns, or one), where T is folded into V too
+ARRAY = [pytest.param(n, b, 40, id=f"{n}-{b}") for n, b in SIZES] + [
+    pytest.param(n, b, m, id=f"{n}-{b}-{tag}") for n, b in SIZES
+    for tag, m in (("L", 2 * b - 1), ("one", 1))]
+
+
+@pytest.mark.parametrize("n, b, m", ARRAY)
+def test_array_branch_is_one_program_a_call(n, b, m, route, tmp_path):
     _configure(tmp_path, b)
     tri = _chase(n, b)
-    e = np.random.default_rng(5 * n).standard_normal((n, 40))
+    e = np.random.default_rng(5 * n).standard_normal((n, m))
     got = np.asarray(bt_band_to_tridiag(tri, e))
     want = ref.apply_q(tri.v, tri.tau, e, b)
     assert np.linalg.norm(got - want) \
@@ -192,6 +206,40 @@ def test_array_branch_is_one_program_a_call(n, b, route, tmp_path):
     assert _span_count("stage.bt_band_to_tridiag.to_tiles") == 0
     assert _counters("dlaf_bt_b2t_levels_total", impl="blocked") \
         == hand_slots(n, b, b)[0]
+
+
+def _f64_dots(text):
+    """``Counter`` of ``(lhs, rhs, result)`` shapes of the module's f64
+    ``dot_general``s, e.g. ``("31x16", "16x16", "31x16")``."""
+    return collections.Counter(re.findall(
+        r"stablehlo\.dot_general .*: \(tensor<(\w+)xf64>, "
+        r"tensor<(\w+)xf64>\) -> tensor<(\w+)xf64>", text))
+
+
+@pytest.mark.parametrize("m", [40, 31], ids=["wide", "narrow"])
+def test_t_is_folded_into_v_at_every_width(m, as_on_tpu, monkeypatch,
+                                           tmp_path):
+    """The blocked program at n = 96, b = G = 16 (L = 31) lowered for a TPU
+    on the slice route, on a window wider than the staircase is tall (m =
+    40) and on one as wide (m = L): its one raw f64 product beside
+    ``larft``'s Gram and 2 x 4 doubling dots is ``V T``, (L, G) x (G, G),
+    and no f64 product has a (G, m) result (``T (V^H seg)``)."""
+    monkeypatch.setattr(tpu_info, "default_device", lambda: Device.TPU)
+    n, b = 96, 16
+    _configure(tmp_path, b)
+    n_sweeps, n_steps = n - 1, -(-(n - 1) // b)
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, np.float64)
+
+    text = bt._bt_b2t_blocked.trace(
+        spec(n_sweeps, n_steps, b), spec(n_sweeps, n_steps), spec(n, m),
+        b=b, n=n, group=b).lower(lowering_platforms=("tpu",)).as_text()
+    dots = _f64_dots(text)
+    g, L = f"{b}x{b}", f"{2 * b - 1}x{b}"
+    larft_dots = {(f"{b}x{2 * b - 1}", L, g): 1, (g, g, g): 8}
+    assert dots == collections.Counter({**larft_dots, (L, g, L): 1}), dots
+    assert not any(out == f"{b}x{m}" for *_, out in dots), dots
 
 
 def test_sweeps_form_counts_a_sweep_a_level(route, tmp_path):

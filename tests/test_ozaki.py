@@ -991,12 +991,14 @@ def test_sequenced_form_of_the_cells_products(shape, form):
 @pytest.fixture()
 def metrics_on(tmp_path):
     """The metrics sink on for the test (the ``dlaf_ozaki_*`` counters
-    count only then); the default configuration again after it."""
-    from dlaf_tpu import config
+    count only then); after it an empty registry, so no later test reads
+    these counts, and the default configuration again."""
+    from dlaf_tpu import config, obs
 
     config.initialize(config.Configuration(
         metrics_path=str(tmp_path / "metrics.jsonl")))
     yield
+    obs._reset_for_tests()
     config.initialize()
 
 
