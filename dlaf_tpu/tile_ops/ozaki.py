@@ -11,7 +11,8 @@ hardware:
    max (halving folded back in at recombine, so nothing overflows even at
    ``max ~ DBL_MAX``),
 2. peel ``s`` slices of ``q=7`` mantissa bits each: every slice is a small
-   integer in ``[-64, 64]`` — exactly representable in int8,
+   integer in ``[-64, 64]`` — exactly representable in int8 (a deep
+   product's wide operand in one pass over it, :func:`_peel_stack`),
 3. contract slice pairs on the MXU with **exact** int32 accumulation
    (``|sum| <= k * 2^12 * s < 2^31`` for any practical ``k``),
 4. recombine partial products grouped by total shift ``d = t+u`` (at most
@@ -54,6 +55,7 @@ import contextlib
 import functools
 import threading
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
@@ -123,6 +125,133 @@ def _peel_slices(xn, s: int):
         out.append(it8)
         r = r - it8.astype(jnp.float32).astype(xn.dtype) * (1.0 / sc)
     return out
+
+
+def _count_peel(form: str, elements: int) -> None:
+    """Trace-time count of the elements a product's peels slice,
+    ``dlaf_ozaki_peeled_total{form}``, per traced 2D operand and EXECUTED
+    step (``obs.traced_step_count()``, as :func:`_count_macs`): ``chain``
+    where a product calls :func:`_peel_slices`, ``one_pass`` for
+    :func:`_peel_stack` (whose host branch, the chain itself, is not
+    counted again)."""
+    from .. import obs
+
+    if obs.metrics_active():
+        obs.counter("dlaf_ozaki_peeled_total", form=form).inc(
+            elements * obs.traced_step_count())
+
+
+def _fast_two_sum(a, b):
+    """``(s, e)``, ``s = fl(a + b)`` and ``e = a + b - s`` exactly, for
+    ``|a| >= |b|`` (or ``a`` zero)."""
+    s = a + b
+    return s, b - (s - a)
+
+
+def _planes(xn):
+    """``(hi, lo)``: ``hi = f32(xn)`` and ``lo`` the f32 of what is left.
+    Where f64 is the TPU's double-f32 emulation this is its own
+    representation, and the split moves no bit."""
+    hi = xn.astype(jnp.float32)
+    return hi, (xn - hi.astype(xn.dtype)).astype(jnp.float32)
+
+
+def _peel_planes(planes, s: int, store) -> None:
+    """:func:`_peel_slices` on a double-f32 pair ``planes = (h, l)``, in
+    f32 alone: ``store(t, I_t)`` receives each int8 slice once. ``h`` is
+    kept the f32 rounding of the whole residual ``h + l`` by
+    ``fast_two_sum(h, l)``, the renormalization the emulated f64 multiply
+    and subtraction apply before :func:`_peel_slices` casts ``r
+    2^q(t+1)``; once more before the first step, since the pair a program
+    is handed need not be normalized (the chip's own transfer of an f64
+    array leaves some exact f32 ties the other way, PERF.md). So
+    ``round(h 2^q(t+1))`` is the native f32 round of ``f32(r 2^q(t+1))``
+    that :func:`_peel_slices` takes (its first hardening rule), and the
+    residual subtracts the stored int8 value (its second) from ``h``,
+    which is exact (``|h 2^q(t+1) - I_t| <= 1/2`` on a 24-bit grid at or
+    below ``h``'s). Where IEEE binary64 holds the pair's sum exactly (48
+    bits), the slices are :func:`_peel_slices`' in binary64, whose
+    residuals are exact there too (the oracle of tests/test_ozaki.py)."""
+    h, l = _fast_two_sum(*planes)
+    for t in range(s):
+        sc = float(2.0 ** (SLICE_BITS * (t + 1)))
+        it8 = jnp.round(h * sc).astype(jnp.int8)
+        store(t, it8)
+        h, l = _fast_two_sum(h - it8.astype(jnp.float32) * (1.0 / sc), l)
+
+
+#: the one-pass peel's block (rows, columns) and the column strip its body
+#: works on at a time: a block's planes and slices, double-buffered, take
+#: 2 x 15 B x 256 x 1024 = 7.5 MiB of VMEM; a strip's planes are 32 vregs
+_PEEL_BLOCK = (256, 1024)
+_PEEL_STRIP = 128
+
+
+def _peel_kernel(hi_ref, lo_ref, out_ref, *, s: int, width: int):
+    """One block: the planes read once, the residual kept in registers and
+    VMEM across the ``s`` steps, each slice written once into its place
+    in the stack, a column strip of ``width`` at a time."""
+    import numpy as np
+    from jax.experimental import pallas as pl
+
+    def peel(cols):
+        def store(t, it8):
+            out_ref[t, :, cols] = it8
+
+        _peel_planes((hi_ref[:, cols], lo_ref[:, cols]), s, store)
+
+    strips = hi_ref.shape[-1] // width
+    if strips == 1:
+        peel(slice(None))
+        return
+
+    def strip(j):
+        peel(pl.ds(pl.multiple_of(j * width, width), width))
+        return j + 1
+
+    # a while, not a fori_loop: a static trip count would make it a scan,
+    # and a product's scans are its schedule (tests/test_ozaki.py)
+    lax.while_loop(lambda j: j < strips, strip, np.int32(0))
+
+
+def _peel_stack_tpu(hi, lo, s: int, *, interpret: bool = False):
+    """:func:`_peel_stack` on a TPU, from the double-f32 planes ``hi``,
+    ``lo`` (:func:`_planes`): one Pallas kernel over (256, 1024) blocks (a
+    dimension below the block's takes its full extent, as the chase's 255
+    rows do). ``interpret`` runs the kernel off the chip, for the tests."""
+    import numpy as np
+    from jax.experimental import pallas as pl
+
+    rows, cols = hi.shape
+    br, bc = min(rows, _PEEL_BLOCK[0]), min(cols, _PEEL_BLOCK[1])
+    width = _PEEL_STRIP if bc % _PEEL_STRIP == 0 else bc
+    plane = pl.BlockSpec((br, bc), lambda i, j: (i, j))
+    return pl.pallas_call(
+        functools.partial(_peel_kernel, s=s, width=width),
+        grid=(pl.cdiv(rows, br), pl.cdiv(cols, bc)),
+        in_specs=[plane, plane],
+        out_specs=pl.BlockSpec((s, br, bc), lambda i, j: (np.int32(0), i, j)),
+        out_shape=jax.ShapeDtypeStruct((s, rows, cols), jnp.int8),
+        interpret=interpret,
+    )(hi, lo)
+
+
+def _peel_stack(xn, s: int):
+    """``jnp.stack(_peel_slices(xn, s))``, bit for bit, in one pass over
+    the normalized block: read once, the residual kept on chip across the
+    ``s`` steps, each slice written once into the ``s8[s, rows, cols]``
+    stack (:func:`_peel_planes`). :func:`_peel_slices` compiles to ``s``
+    passes on a TPU, each reading and writing the residual's two f32
+    planes beside its slice: 17 B an element a slice where the work needs
+    15 B an element. The backend the program is lowered for picks the
+    form (``lax.platform_dependent``: the chip's compiler never sees the
+    other): on a TPU one Pallas kernel on the two f32 planes its f64
+    carries; elsewhere the chain itself (two f32 planes hold 48 of IEEE
+    binary64's 53 bits)."""
+    _count_peel("one_pass", xn.size)
+    return lax.platform_dependent(
+        xn, tpu=lambda x: _peel_stack_tpu(*_planes(x), s),
+        default=lambda x: jnp.stack(_peel_slices(x, s)))
 
 
 # int32 accumulation of int8 x int8 products (each |p| <= 2^12) is provably
@@ -215,9 +344,10 @@ def _sequenced_form(m: int, n: int, k: int, s: int) -> str:
       W``; ``bt_reduction_to_band``'s ``V^H C``). Stacking the wide
       operand's padded groups writes and reads ``s^2`` times its size a
       product; this form scans the wide operand's ``s`` slices as they
-      were peeled against the narrow operand's slices shifted into ``s``
-      blocks, so the zero slots sit on the narrow side only, and the
-      ``s`` groups add up in one int32 carry. It needs every group sum
+      were peeled (in one pass, :func:`_peel_stack`) against the narrow
+      operand's slices shifted into ``s`` blocks, so the zero slots sit on
+      the narrow side only, and the ``s`` groups add up in one int32
+      carry. It needs every group sum
       exact in int32 (``s k 2^12 < 2^31``); deeper than that the product
       keeps ``"groups"``, whose dots chunk into f64."""
     if min(m, n) > k:
@@ -225,21 +355,21 @@ def _sequenced_form(m: int, n: int, k: int, s: int) -> str:
     return "slices" if k > min(m, n) and _exact_i32(s, k) else "groups"
 
 
-def _scan_slices(ia, ib, s: int):
+def _scan_slices(wide, narrow, a_wide: bool, s: int):
     """``[G_0 .. G_{s-1}]``, the int32 shift-group sums ``G_d = sum_t I_t
     J_{d-t}`` of a deep product (:func:`_sequenced_form` ``"slices"``),
-    by one ``lax.scan`` over the WIDE operand's slices. With A (m, k) the
-    wide one (m >= n), step ``t`` multiplies ``I_t`` as it was peeled by
-    ``[0 x t | J_0 | ... | J_{s-1-t}]`` (k, s n): block ``d`` of the (m,
-    s n) product is ``I_t J_{d-t}`` for ``d >= t`` and exactly zero
-    before it, and the steps add into one int32 carry. With B the wide
-    one the roles are exchanged: step ``u`` multiplies ``[0 x u; I_0; ...;
-    I_{s-1-u}]`` (s m, k) by ``J_u``. The wide operand is stacked once
-    (``s`` times its size in int8), nothing of it is concatenated or
-    padded."""
-    m, n = ia[0].shape[-2], ib[0].shape[-1]
-    a_wide = m >= n
-    narrow, axis = (ib, -1) if a_wide else (ia, -2)
+    by one ``lax.scan`` over the WIDE operand's slices ``wide``, stacked
+    as :func:`_peel_stack` wrote them. With A (m, k) the wide one
+    (``a_wide``, m > n), step ``t`` multiplies ``I_t`` by ``[0 x t | J_0
+    | ... | J_{s-1-t}]`` (k, s n), the narrow operand's slices
+    ``narrow``: block ``d`` of the (m, s n) product is ``I_t J_{d-t}``
+    for ``d >= t`` and exactly zero before it, and the steps add into one
+    int32 carry. With B the wide one the roles are exchanged: step ``u``
+    multiplies ``[0 x u; I_0; ...; I_{s-1-u}]`` (s m, k) by ``J_u``.
+    Nothing of the wide operand is concatenated or padded."""
+    m, n = (wide.shape[-2], narrow[0].shape[-1]) if a_wide \
+        else (narrow[0].shape[-2], wide.shape[-1])
+    axis = -1 if a_wide else -2
     zero = jnp.zeros_like(narrow[0])
     shifted = jnp.stack([jnp.concatenate([zero] * t + narrow[:s - t],
                                          axis=axis) for t in range(s)])
@@ -252,7 +382,7 @@ def _scan_slices(ia, ib, s: int):
     g, _ = lax.scan(body,
                     jnp.zeros((m, s * n) if a_wide else (s * m, n),
                               jnp.int32),
-                    (jnp.stack(ia if a_wide else ib), shifted))
+                    (wide, shifted))
     return [g[:, d * n:(d + 1) * n] if a_wide else g[d * m:(d + 1) * m]
             for d in range(s)]
 
@@ -415,18 +545,31 @@ def _matmul_f64_2d(a, b, *, slices=DEFAULT_SLICES):
     k = a.shape[-1]
     sa = _scale(a, axis=-1)           # (m, 1)
     sb = _scale(b, axis=-2)           # (1, n)
-    ia = _peel_slices(_normalize(a, sa), s)
-    ib = _peel_slices(_normalize(b, sb), s)
     m, n = a.shape[-2], b.shape[-1]
     form = _sequenced_form(m, n, k, s)
     if form == "slices":
-        # deep product: the s^2 slots of the padded scan (zeros among
-        # them, on the narrow operand's side), emitted as s dots
+        # deep product: the wide operand peeled in one pass, the s^2 slots
+        # of the padded scan (zeros among them, on the narrow operand's
+        # side) emitted as s dots
+        # B on a tie: the reduction's M = V^H W would hand the kernel V^H,
+        # a column block of the trailing matrix transposed, and the
+        # compiler then laid the whole trailing matrix out column-major
+        # and copied it twice a step (PERF.md section 6)
+        a_wide = m > n
+        wide, narrow = (a, sa), (b, sb)
+        if not a_wide:
+            wide, narrow = narrow, wide
         _count_macs("scan_slices", m * n, s * (s + 1) // 2 * k, s * s * k)
+        _count_peel("chain", narrow[0].size)
         acc = None
-        for d, g_d in enumerate(_scan_slices(ia, ib, s)):
+        for d, g_d in enumerate(_scan_slices(
+                _peel_stack(_normalize(*wide), s),
+                _peel_slices(_normalize(*narrow), s), a_wide, s)):
             acc = _fold_group(acc, d, g_d)
         return _apply_scales(acc, sa, sb)
+    _count_peel("chain", a.size + b.size)
+    ia = _peel_slices(_normalize(a, sa), s)
+    ib = _peel_slices(_normalize(b, sb), s)
     if form == "groups":
         # panel product: uniform zero-padded groups scanned with an f64
         # carry (one body; s (s - 1) / 2 of its s^2 slots are zero columns)
@@ -488,6 +631,7 @@ def _syrk_f64_2d(a, *, slices=DEFAULT_SLICES):
     s = int(slices)
     k = a.shape[-1]
     sa = _scale(a, axis=-1)           # (m, 1)
+    _count_peel("chain", a.size)
     ia = _peel_slices(_normalize(a, sa), s)
     cast = (lambda x: x) if _exact_i32(s, k) \
         else (lambda x: x.astype(jnp.float64))

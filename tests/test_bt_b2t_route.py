@@ -208,6 +208,26 @@ def test_array_branch_is_one_program_a_call(n, b, m, route, tmp_path):
         == hand_slots(n, b, b)[0]
 
 
+@pytest.mark.parametrize("n, b", SIZES)
+def test_peel_counter_reads_the_hand_count(n, b, route, tmp_path):
+    """``dlaf_ozaki_peeled_total{form}`` of one traced call on a window of
+    m = 40 columns, per EXECUTED level: ``V^H seg`` ((G, L) x (L, m), L = b
+    + G - 1 deep) peels the window ``seg``, its wider side, in one pass and
+    ``V^H`` by the chain; ``(V T) W`` ((L, G) x (G, m), ragged) peels both
+    by the chain."""
+    _configure(tmp_path, b)
+    m, group = 40, b
+    depth = b + group - 1
+    bt_band_to_tridiag(_chase(n, b),
+                       np.random.default_rng(n).standard_normal((n, m)))
+    levels = hand_slots(n, b, group)[0]
+    assert _counters("dlaf_ozaki_peeled_total", form="one_pass") \
+        == levels * depth * m
+    assert _counters("dlaf_ozaki_peeled_total", form="chain") \
+        == levels * (group * depth + depth * group + group * m)
+    assert route["groups"] == [b], route
+
+
 def _f64_dots(text):
     """``Counter`` of ``(lhs, rhs, result)`` shapes of the module's f64
     ``dot_general``s, e.g. ``("31x16", "16x16", "31x16")``."""

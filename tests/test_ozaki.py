@@ -1116,7 +1116,7 @@ class TestRaggedGroups:
                     for _, eqn in depgraph.iter_eqns(jaxpr))
         assert depths == [self.K] and scans == 1
         assert not re.search(rf"tensor<[0-9x]*{7 * self.K}x", text)
-        out = (m, 7 * n) if m >= n else (7 * m, n)
+        out = (m, 7 * n) if m > n else (7 * m, n)
         assert f"-> tensor<{out[0]}x{out[1]}x" in text
 
     @pytest.mark.quick
@@ -1314,3 +1314,175 @@ class TestPeelBoundaryRegression:
         # 8 slices x 7 bits = 56 kept bits; the dropped residual is
         # < 2^-57 of the normalized scale
         assert err < 2.0 ** -56, f"reconstruction off budget: {err}"
+
+
+# ---------------------------------------------------------------------------
+# the one-pass peel of a deep product's wide operand (ozaki._peel_stack)
+# ---------------------------------------------------------------------------
+
+def _peel_check():
+    """``scripts/peel_chip_check.py``: the blocks these tests share with
+    the check the chip runs, and the host's binary64 chain."""
+    import importlib
+    import os
+    import sys
+
+    sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "scripts"))
+    return importlib.import_module("peel_chip_check")
+
+
+def _adversarial_block(shape, seed):
+    return _peel_check().adversarial_block(shape, seed)
+
+
+class TestOnePassPeel:
+    """The wide operand of a deep product (``_sequenced_form`` ``"slices"``)
+    is peeled by ``_peel_stack``: one pass over the block, the residual as
+    f32 planes kept across the ``s`` steps, each slice written once into
+    its place in the stack. It must give ``jnp.stack(_peel_slices(xn, s))``
+    bit for bit. On a TPU that is a Pallas kernel on the two f32 planes
+    its f64 carries; on this CPU, where f64 is IEEE binary64, it is the
+    chain itself. The oracle of the kernel's arithmetic is the chain in
+    binary64 on pairs whose sum binary64 holds exactly: there every
+    residual is exact. ``scripts/peel_chip_check.py`` holds the kernel to
+    the chain on the chip. Cheap (the whole class runs in ~25 s), so
+    ``quick``: every case stays in the default tier (conftest's stride)."""
+
+    SHAPES = [(255, 512), (300, 40), (40, 300)]
+
+    @pytest.mark.quick
+    @pytest.mark.parametrize("s", [7, 8])
+    @pytest.mark.parametrize("shape", SHAPES,
+                             ids=["x".join(map(str, sh)) for sh in SHAPES])
+    def test_one_pass_stack_is_the_chains_bits(self, shape, s):
+        import jax
+
+        from dlaf_tpu.tile_ops import ozaki as oz
+
+        xn = jnp.asarray(_adversarial_block(shape, seed=shape[0] + s))
+        one = np.asarray(jax.jit(lambda v: oz._peel_stack(v, s))(xn))
+        chain = np.asarray(jax.jit(
+            lambda v: jnp.stack(oz._peel_slices(v, s)))(xn))
+        assert one.dtype == np.int8 and one.shape == (s,) + shape
+        assert np.abs(chain).max() <= 65
+        np.testing.assert_array_equal(one, chain)
+
+    @pytest.mark.quick
+    @pytest.mark.parametrize("planes", ["split", "hand_built"])
+    @pytest.mark.parametrize("s", [7, 8])
+    @pytest.mark.parametrize("shape", SHAPES,
+                             ids=["x".join(map(str, sh)) for sh in SHAPES])
+    def test_two_plane_peel_is_the_exact_chain(self, shape, s, planes):
+        """``_peel_planes``, the kernel's arithmetic, on double-f32 pairs
+        (``split``: ``_planes`` of a binary64 array that two f32 planes
+        hold; ``hand_built``: exact ties of the pair and un-normalized
+        pairs beside ties at every slice's scale) gives the chain's slices
+        in binary64, the library's and the host's alike."""
+        from dlaf_tpu.tile_ops import ozaki as oz
+
+        check = _peel_check()
+        hi, lo = check.double_f32_pairs(shape, seed=shape[1] + s)
+        x = hi.astype(np.float64) + lo
+        want = check.host_chain(x, s)
+        np.testing.assert_array_equal(
+            np.stack(oz._peel_slices(jnp.asarray(x), s)), want)
+        pair = oz._planes(jnp.asarray(x)) if planes == "split" \
+            else (jnp.asarray(hi), jnp.asarray(lo))
+        got = []
+        oz._peel_planes(pair, s, lambda t, i: got.append(i))
+        np.testing.assert_array_equal(np.stack(got), want)
+
+    #: the kernel's blocking: strips of a block (640 = 5 x 128 columns),
+    #: edge blocks on both axes (300 rows by 256, 1100 columns by 1024),
+    #: and a block narrower than a strip that is not one (255 columns)
+    KERNEL_SHAPES = [(255, 640), (300, 1100), (128, 255)]
+
+    @pytest.mark.quick
+    @pytest.mark.parametrize("shape", KERNEL_SHAPES,
+                             ids=["x".join(map(str, sh))
+                                  for sh in KERNEL_SHAPES])
+    def test_one_pass_kernel_blocks_in_interpret_mode(self, shape):
+        """The TPU's Pallas kernel, run in interpret mode on hand-built
+        double-f32 pairs, writes the chain's slices in binary64, element
+        for element, whatever the grid, the strips and the edge blocks."""
+        from dlaf_tpu.tile_ops import ozaki as oz
+
+        check = _peel_check()
+        hi, lo = check.double_f32_pairs(shape, seed=sum(shape))
+        got = np.asarray(oz._peel_stack_tpu(jnp.asarray(hi), jnp.asarray(lo),
+                                            7, interpret=True))
+        np.testing.assert_array_equal(
+            got, check.host_chain(hi.astype(np.float64) + lo, 7))
+
+    @pytest.mark.quick
+    def test_chip_check_rehearses_on_the_cpu(self):
+        """``scripts/peel_chip_check.py``'s comparisons run end to end at
+        small shapes (the kernel in interpret mode) and find nothing that
+        differs."""
+        res = _peel_check().check(shapes=[(255, 640), (40, 300)],
+                                  products=[(96, 200, 24), (24, 200, 96),
+                                            (24, 200, 24)],
+                                  interpret=True)
+        assert len(res["stack"]) == 8 and len(res["exact"]) == 4
+        assert len(res["product"]) == 3
+        assert not any(v for part in res.values() for v in part.values()), res
+
+    @pytest.mark.quick
+    @pytest.mark.parametrize("s", [7, 8])
+    @pytest.mark.parametrize("side", ["a_wide", "b_wide"])
+    def test_slices_form_keeps_the_chains_bits(self, side, s, monkeypatch):
+        """``_matmul_f64_2d``'s ``"slices"`` form with the one-pass peel
+        gives the bits it gave with the wide operand peeled by the chain,
+        with A or B the wide operand, rows and columns over twelve
+        decades and adversarial entries beside them."""
+        from dlaf_tpu.tile_ops import ozaki as oz
+
+        m, k, n = (96, 200, 24) if side == "a_wide" else (24, 200, 96)
+        assert oz._sequenced_form(m, n, k, s) == "slices"
+        rng = np.random.default_rng(45 + s)
+        a = _adversarial_block((m, k), 1) \
+            + rng.standard_normal((m, k)) * 10.0 ** rng.integers(-6, 6,
+                                                                 (m, 1))
+        b = _adversarial_block((k, n), 2) \
+            + rng.standard_normal((k, n)) * 10.0 ** rng.integers(-6, 6,
+                                                                 (1, n))
+        got = np.asarray(matmul_f64(a, b, slices=s))
+        monkeypatch.setattr(oz, "_peel_stack",
+                            lambda xn, s: jnp.stack(oz._peel_slices(xn, s)))
+        want = np.asarray(matmul_f64(a, b, slices=s))
+        assert got.tobytes() == want.tobytes()
+
+    #: (product, (m, k, n) or the syrk's (m, k)) and which operand of it
+    #: the one pass peels: only a deep product's wide one
+    COUNTS = {"a_wide": ((24, 40, 8), "a"), "b_wide": ((8, 40, 24), "b"),
+              "panel": ((40, 40, 56), None), "bulk": ((48, 40, 56), None),
+              "syrk": ((24, 40), None)}
+
+    @pytest.mark.quick
+    @pytest.mark.parametrize("which", list(COUNTS))
+    def test_peeled_counter_by_hand(self, which, metrics_on):
+        """``dlaf_ozaki_peeled_total{form}`` per traced 2D product: the
+        wide operand's elements under ``one_pass``, every other operand's
+        under ``chain``; a Cholesky- or solve-shaped product (one block
+        deep, or bulk) and the syrk read ``one_pass`` 0."""
+        from dlaf_tpu import obs
+
+        one, chain = (obs.registry().counter("dlaf_ozaki_peeled_total",
+                                             form=form)
+                      for form in ("one_pass", "chain"))
+        base = one.snapshot()["value"], chain.snapshot()["value"]
+        shape, wide = self.COUNTS[which]
+        rng = np.random.default_rng(7)
+        if which == "syrk":
+            syrk_f64(jnp.asarray(rng.standard_normal(shape)), slices=7)
+            sizes = {"a": shape[0] * shape[1]}
+        else:
+            m, k, n = shape
+            matmul_f64(jnp.asarray(rng.standard_normal((m, k))),
+                       jnp.asarray(rng.standard_normal((k, n))), slices=7)
+            sizes = {"a": m * k, "b": k * n}
+        want_one = sizes.get(wide, 0)
+        assert one.snapshot()["value"] - base[0] == want_one
+        assert chain.snapshot()["value"] - base[1] \
+            == sum(sizes.values()) - want_one

@@ -331,6 +331,40 @@ def test_local_reduction_to_band_on_the_chips_route(case, n, route,
     case(n, route, tmp_path)
 
 
+def _hand_peels(n, band, chunk=0):
+    """``(one_pass, chain)`` elements the slice products of one call peel,
+    per EXECUTED step and chunk. W = A (V T) peels its wide operand, the
+    trailing block's rows (in chunks: ``ceil(m / chunk)`` chunks of
+    ``chunk`` rows), in one pass and V T by the chain, once a chunk; M =
+    V^H W ((band, m) x (m, band): a square output, whose wide operand is
+    the right one) peels W in one pass and V^H by the chain; the rank-2b
+    update ``[X / alpha | V] [alpha V | X]^H`` is no deep product: both
+    its operands by the chain, the right one once a chunk."""
+    one = chain = off = 0
+    for seg in telescope_segments(-(-n // band) - 1):
+        m = (-(-n // band) - off) * band
+        chunks = -(-m // chunk) if 0 < chunk < m else 1
+        rows = chunks * chunk if chunks > 1 else m
+        one += seg * (rows * m + band * m)
+        chain += seg * (chunks * m * band + m * band
+                        + rows * 2 * band + chunks * 2 * band * m)
+        off += seg
+    return one, chain
+
+
+@pytest.mark.parametrize("chunk", [0, 96], ids=["whole", "chunked"])
+def test_peel_counter_reads_the_hand_count(chunk, route, tmp_path):
+    """``dlaf_ozaki_peeled_total{form}`` of one traced call: W's and M's
+    wide operands under ``one_pass``, the rest under ``chain``."""
+    n = 33 * BAND
+    _configure(tmp_path, **({"red2band_trail_chunk": chunk} if chunk else {}))
+    _reduce(n)
+    one, chain = _hand_peels(n, BAND, chunk)
+    assert _counters("dlaf_ozaki_peeled_total", form="one_pass") == one
+    assert _counters("dlaf_ozaki_peeled_total", form="chain") == chain
+    assert route["builders"] == ["scan"], route
+
+
 def test_hand_count_of_the_cells_shape():
     """N=8192, band=128: what the traced run on the chip has to read. 63
     panels in 8 bodies, 8064 columns; segment 0 (8192 rows) in two row
